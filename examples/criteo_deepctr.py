@@ -331,4 +331,8 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the script entry, not main(): tests call main() in-process and
+    # compile cold
+    from openembedding_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -85,6 +85,8 @@ def main(argv=None):
             s.bind(("127.0.0.1", 0))
             return s.getsockname()[1]
 
+    # this process trained above and still holds its devices; replicas
+    # are CPU children (ha.spawn_replica) — one process for each chip
     if args.shards > 1:
         # shard groups x replicas: every process loads its slice directly
         groups = [[free_port() for _ in range(args.replicas)]
@@ -147,4 +149,8 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # the script entry, not main(): tests call main() in-process and
+    # compile cold
+    from openembedding_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -1193,14 +1193,12 @@ def fuzz_ingest(ctx: SeedContext, cls: str, rng: random.Random,
 
 # --- sanitizer builds --------------------------------------------------------
 
-def sanitizer_libs(*, build: bool = True,
-                   variants: Tuple[str, ...] = ("asan", "ubsan")
+def sanitizer_libs(variants: Tuple[str, ...] = ("asan", "ubsan")
                    ) -> Dict[str, str]:
     """{'asan': .so path, 'ubsan': .so path} — built via the Makefile's
     sanitizer targets (``make -C native asan ubsan``)."""
     from ..serving import native as native_mod
-    return {v: native_mod.build_library(force=build, variant=v)
-            for v in variants}
+    return {v: native_mod.build_library(variant=v) for v in variants}
 
 
 # --- the run -----------------------------------------------------------------
@@ -1214,7 +1212,7 @@ def all_classes(lanes: Tuple[str, ...] = ("ckpt", "wire", "ingest")
 def run_fuzz(*, seed: int = 0, iters: Optional[int] = None,
              lanes: Tuple[str, ...] = ("ckpt", "wire", "ingest"),
              deadline: float = DEADLINE_S, tmp_root: Optional[str] = None,
-             build: bool = True, ctx: Optional[SeedContext] = None,
+             ctx: Optional[SeedContext] = None,
              libs: Optional[Dict[str, str]] = None,
              log: Optional[Callable[[str], None]] = None
              ) -> Dict[str, Any]:
@@ -1237,7 +1235,7 @@ def run_fuzz(*, seed: int = 0, iters: Optional[int] = None,
             ctx = SeedContext(os.path.join(tmp_root, "ctx"))
         scrub_roots.append(ctx.tmp_root)
         if libs is None:
-            libs = sanitizer_libs(build=build) if "ckpt" in lanes else {}
+            libs = sanitizer_libs() if "ckpt" in lanes else {}
         shard_src: Dict[str, str] = {}
         if "ingest" in lanes:
             from ..data.stream import write_synthetic_shards

@@ -4,9 +4,8 @@ graftscope (``scope.py``) made latency and collective bytes observable;
 this module covers the third cost axis — memory. Every registered
 plane's pull/push program is lowered exactly as the training path runs
 it (:mod:`.programs` ``compile_*``) and its XLA memory analysis is
-extracted through ``utils.jaxcompat.compiled_memory_stats`` (the
-0.4.x/0.5.x API shapes differ; backends without the analysis yield
-None, never a crash): per-device argument / output / temp / alias
+extracted through ``utils.jaxcompat.compiled_memory_stats`` (None on
+a backend that reports no analysis): per-device argument / output / temp / alias
 bytes, plus the derived peak estimate. Two consumers:
 
 * **The peak-temp contract** (:func:`..analysis.contracts.

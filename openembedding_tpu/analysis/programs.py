@@ -339,9 +339,8 @@ def compile_train_step(mesh, plane: str = "a2a", *, vocab: int = 4096,
             batch_data["sparse"][f]
     state = trainer.init(jax.random.PRNGKey(0),
                          trainer.shard_batch(batch_data))
-    step = trainer._build_train_step()
-    compiled = step.lower(state,
-                          trainer.shard_batch(batch_data)).compile()
+    compiled = trainer.lower_train_step(
+        state, trainer.shard_batch(batch_data)).compile()
     return compiled, contract_params(mesh, batch=batch, dim=dim,
                                      vocab=vocab,
                                      state_nbytes=_state_nbytes(state))

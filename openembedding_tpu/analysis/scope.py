@@ -91,13 +91,13 @@ def _trace_state_clean() -> bool:
     """False while JAX is tracing (the span is running at trace time,
     once per compile — not once per step). True when jax was never even
     imported: host-only processes cannot be under a trace."""
-    jax = sys.modules.get("jax")
-    if jax is None:
+    if "jax" not in sys.modules:
         return True
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:  # noqa: BLE001 — any API drift reads as "clean"
-        return True
+    # no public spelling in jax 0.9.0; if this one moves too the import
+    # raises — a wrong "clean" would feed trace-time spans into the
+    # latency histograms
+    from jax._src import core
+    return core.trace_state_clean()
 
 
 def _profiler():

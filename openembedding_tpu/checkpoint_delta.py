@@ -42,6 +42,7 @@ import dataclasses
 import io
 import json
 import os
+import re
 import threading
 import time
 import uuid
@@ -105,6 +106,35 @@ class DeltaDecodeError(ValueError):
     not be decoded at all."""
 
 
+# --- bounded JSON ------------------------------------------------------------
+
+# the native reader's kMaxDepth (native/oe_serving.cc): both readers refuse
+# the same nesting, and a manifest this build writes nests under ten
+JSON_MAX_DEPTH = 64
+_JSON_STRING = re.compile(r'"(?:[^"\\]|\\.)*"')
+_JSON_BRACKET = re.compile(r"[\[{\]}]")
+
+
+def _loads_bounded(text: str, what: str) -> Any:
+    """``json.loads`` of UNTRUSTED text, container nesting bounded HERE
+    before the parser runs. How deep the interpreter's own parser goes
+    before it raises ``RecursionError`` — or whether it does — changes
+    with the Python version (3.12 parses 2000 levels); the refusal must
+    not."""
+    depth = deepest = 0
+    for m in _JSON_BRACKET.finditer(_JSON_STRING.sub("", text)):
+        if m.group() in "[{":
+            depth += 1
+            deepest = max(deepest, depth)
+        else:
+            depth -= 1
+    if deepest > JSON_MAX_DEPTH:
+        raise DeltaDecodeError(
+            f"{what}: JSON nesting depth {deepest} exceeds the limit "
+            f"{JSON_MAX_DEPTH}")
+    return json.loads(text)
+
+
 # --- manifest ----------------------------------------------------------------
 
 def read_manifest(path: str) -> Optional[Dict[str, Any]]:
@@ -112,7 +142,9 @@ def read_manifest(path: str) -> Optional[Dict[str, Any]]:
     mpath = fs.join(path, DELTA_MANIFEST_FILE)
     if not fs.exists(mpath):
         return None
-    manifest = fs.read_json(mpath)
+    with fs.open_file(mpath, "rb") as f:
+        manifest = _loads_bounded(f.read().decode("utf-8"),
+                                  f"delta manifest at {path!r}")
     if not isinstance(manifest, dict):
         raise DeltaDecodeError(
             f"delta manifest at {path!r} is JSON "
@@ -1076,7 +1108,10 @@ def decode_delta(data: bytes) -> Delta:
             f"delta wire frame has no header line ({len(data)} bytes, "
             "no newline)")
     try:
-        head = json.loads(data[:nl])
+        head = _loads_bounded(data[:nl].decode("utf-8"),
+                              "delta wire header")
+    except DeltaDecodeError:
+        raise
     except ValueError as e:
         raise DeltaDecodeError(
             f"delta wire header (bytes 0..{nl}) is not valid JSON: {e}"

@@ -354,8 +354,8 @@ class EmbeddingCollection:
             rng = jax.random.PRNGKey(0)
 
         # one jitted program for ALL variables: per-variable table creation
-        # would compile (and on a remote-compile TPU link, round-trip) one
-        # program per variable — 2F programs for an F-feature model
+        # would compile one program per variable — 2F programs for an
+        # F-feature model
         def _create_all(key):
             states = {}
             for name, spec in self.specs.items():

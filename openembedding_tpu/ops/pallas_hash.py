@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_gather import LANES, check_row_tile
+
 ROWS_PER_STEP = 16  # queries in flight per grid step
 
 
@@ -126,26 +128,29 @@ def probe_gather(table_keys: jnp.ndarray, weights: jnp.ndarray,
     ``starts`` are the per-query aligned probe starts
     (``hash_table.probe_starts``); the ``chain * bucket`` slots from each
     start are compared against the query key and the matched row is DMA'd
-    directly. Returns ``(rows [n, dim], hit [n] bool)``. The weights' row
-    dim must be lane-aligned (pad the TABLE once at creation if needed,
-    cf. ``pallas_gather.pad_table``).
+    directly. Returns ``(rows [n, dim], hit [n] bool)``. The weights must
+    be float32 with 128-lane rows (``pallas_gather.check_row_tile``; pad
+    the TABLE once at creation if needed, ``pallas_gather.pad_table``).
     """
     n = query.shape[0]
     capacity = table_keys.shape[0]
     dim = weights.shape[1]
-    if query.dtype.itemsize > 4 or table_keys.dtype.itemsize > 4:
+    if query.dtype != jnp.int32 or table_keys.dtype != jnp.int32:
         # int64 keys would alias mod 2^32 through the int32 scalar-prefetch
         # cast — wide keys must use the XLA path (module contract)
         raise ValueError(
-            f"probe_gather requires <=32-bit keys (got query "
+            f"probe_gather requires int32 keys (got query "
             f"{query.dtype}, table {table_keys.dtype}); int64-key tables "
             "use the XLA probe path")
-    if dim % 128:
+    check_row_tile("probe_gather weights", weights.dtype, dim)
+    # `bucket` is a static argument (a Python int under the trace)
+    if bucket != LANES or capacity % bucket:  # graftlint: disable=JG003
+        # sub-bucket tables (capacity < 128) collapse to one short bucket
+        # whose key row is not a lane tile
         raise ValueError(
-            f"weights row dim {dim} is not lane-aligned; pad the table once "
-            "at creation (pallas_gather.pad_table)")
-    if capacity % bucket:
-        raise ValueError(f"capacity {capacity} not a multiple of {bucket}")
+            f"probe_gather needs {LANES}-slot buckets and a capacity that "
+            f"is a multiple of them (got bucket {bucket}, capacity "
+            f"{capacity})")
     npad = -(-n // ROWS_PER_STEP) * ROWS_PER_STEP
     bkt = (starts // bucket).astype(jnp.int32)
     qk = query.astype(jnp.int32)

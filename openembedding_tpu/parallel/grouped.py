@@ -67,7 +67,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import hash_table as hash_lib
@@ -75,7 +75,6 @@ from .. import table as table_lib
 from ..analysis import scope
 from ..ops import dedup
 from ..utils import observability
-from ..utils.jaxcompat import shard_map
 from . import alltoall as a2a
 
 GROUPED_PLANE = "a2a+grouped"

@@ -54,14 +54,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import struct
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import hash_table as hash_lib
 from .. import table as table_lib
 from ..analysis import scope
 from ..analysis.lint import host_fn
-from ..utils.jaxcompat import shard_map
 from . import alltoall as a2a
 
 

@@ -66,13 +66,12 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..meta import EmbeddingVariableMeta
 from ..ops import dedup
 from ..utils import observability
-from ..utils.jaxcompat import shard_map
 from ..optim.initializers import make_initializer
 from ..optim.optimizers import SparseOptimizer, make_optimizer
 from .. import table as table_lib

@@ -28,8 +28,11 @@ _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
 _LIB_PATH = os.path.join(_NATIVE_DIR, "liboe_serving.so")
 
 
-def build_library(force: bool = False, variant: str = "") -> str:
-    """Compile liboe_serving.so if absent (or ``force``); returns its path.
+def build_library(variant: str = "") -> str:
+    """``make`` liboe_serving.so and return its path. Always through make
+    (it is incremental): a ``.so`` already on disk may be older than the
+    tracked ``oe_serving.cc``, and the library served must be the one the
+    tracked source builds.
 
     ``variant`` selects a sanitizer build for the graftfuzz gate:
     ``"asan"`` / ``"ubsan"`` compile ``liboe_serving_<variant>.so`` via
@@ -42,8 +45,6 @@ def build_library(force: bool = False, variant: str = "") -> str:
         raise ValueError(f"unknown native build variant {variant!r}")
     lib_path = (os.path.join(_NATIVE_DIR, f"liboe_serving_{variant}.so")
                 if variant else _LIB_PATH)
-    if not force and os.path.exists(lib_path):
-        return lib_path
     if not os.path.isdir(_NATIVE_DIR):
         raise RuntimeError(
             "native/ sources not found — the native serving library builds "

@@ -203,11 +203,13 @@ class ServingModel:
             if observability.evaluate_performance():
                 observability.record_batch_stats(
                     {name: np.asarray(indices)})
-        idx = jnp.asarray(indices)
         # narrow id columns address wide tables via the same widening
         # bridge the training pull uses; pair_ndim=2 so the serving wire's
-        # flat pair lists always read as pairs
-        idx = self.collection._widen(spec, idx, pair_ndim=2)
+        # flat pair lists always read as pairs. Widen BEFORE the device
+        # conversion: host int64 ids are split on host, and with x64 off
+        # jnp.asarray first would wrap them to int32 (another key's row)
+        idx = jnp.asarray(self.collection._widen(spec, indices,
+                                                 pair_ndim=2))
         seq_ndim = 3 if spec.use_hash and spec.key_dtype == "wide" else 2
         as_rows = spec.pooling is None or idx.ndim < seq_ndim
         if self.shard_slice is not None:

@@ -101,10 +101,9 @@ class Trainer:
         device step still overlaps across the window; 1 restores the
         single-lookahead pipeline; results are bit-identical at any
         depth (the planned-residency chain in offload.host_prepare).
-        Default 4: measured on the offload A/B (bench_suite.json
-        offload_ab_*) K=4 gave 3.3x serial vs K=1's 1.8x — cold host
-        pages amortize across a deeper window; the reference's default
-        budget is deeper still (64)."""
+        Default 4: cold host pages amortize across a deeper window (the
+        reference's default budget is deeper still, 64); not measured on
+        the chip in this round."""
         if sparse_as_dense:
             from .hybrid import HybridModel
             module = HybridModel(inner=module,
@@ -239,6 +238,17 @@ class Trainer:
         emb = self.collection.apply_gradients(state.emb, pull_inputs,
                                               row_g)
         return params, opt_state, emb, loss
+
+    def lower_train_step(self, state: TrainState, batch):
+        """The serial step program lowered for ``state`` and ``batch`` —
+        the same jitted function :meth:`train_step` runs, for
+        ``compile().memory_analysis()`` and compiled-HLO audits. ``batch``
+        is placed as :meth:`shard_batch` places it; ShapeDtypeStructs that
+        carry the shardings lower it from shapes alone (AOT for a described
+        topology, ``tests/test_tpu_lowering.py``)."""
+        if self._train_step is None:
+            self._train_step = self._build_train_step()
+        return self._train_step.lower(state, batch)
 
     def _build_train_step(self):
         collection = self.collection
@@ -594,13 +604,10 @@ class Trainer:
 
     # --- helpers -------------------------------------------------------------
     def shard_batch(self, batch):
-        """Place host batch arrays batch-sharded over the data axis."""
-        def place(x):
-            if x is None:
-                return None
-            x = jnp.asarray(x)
-            return jax.device_put(x, self._batch_sharding)
-        return jax.tree.map(place, batch)
+        """Place host batch arrays batch-sharded over the data axis — each
+        device takes its slice straight from host memory (a jnp.asarray
+        first would commit the whole batch to device 0 and reshard)."""
+        return jax.device_put(batch, self._batch_sharding)
 
     def model_sign(self, state: TrainState) -> str:
         """Version-stamped serving signature for this state."""

@@ -1,102 +1,33 @@
-"""Version bridges over the moving parts of the JAX API.
+"""The one JAX result this repo reads through a name of its own.
 
-The framework targets the current JAX surface (``jax.shard_map``,
-``jax_num_cpu_devices``); older installs (<= 0.4.x) carry the same
-machinery under different names (``jax.experimental.shard_map`` with
-``check_rep``, virtual host devices via ``--xla_force_host_platform_
-device_count``). Every call site imports from here so the whole mesh
-simulation and shard_map plane run unchanged on both.
+The code targets the installed JAX (0.9.0) directly: ``jax.shard_map``,
+``jax.enable_x64``, ``jax.config.update("jax_num_cpu_devices", n)``.
+What is left here is a reduction, not a version bridge.
 """
 
 from __future__ import annotations
 
-import os
-
-import jax
-
-try:  # JAX >= 0.5: top-level export with the check_vma kwarg
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma)
-except ImportError:  # <= 0.4.x: experimental module, kwarg named check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
-
-
-try:  # JAX >= 0.5: top-level scoped-x64 context manager
-    enable_x64 = jax.enable_x64
-except AttributeError:  # <= 0.4.x: experimental module, same signature
-    from jax.experimental import enable_x64
-
 
 def compiled_memory_stats(compiled):
-    """Normalized ``compiled.memory_analysis()`` as a plain dict, or None.
-
-    The underlying object moved between jaxlib releases
-    (``CompiledMemoryStats`` attributes ``*_size_in_bytes`` on 0.4.x,
-    occasionally absent or None per backend), so every caller routes
-    through this shim: the keys below are stable, missing fields read 0,
-    and a backend without the analysis yields None instead of raising.
+    """``compiled.memory_analysis()`` as a plain dict, or None where the
+    backend reports no analysis.
 
     Keys: ``argument_bytes``, ``output_bytes``, ``temp_bytes``,
     ``alias_bytes``, ``generated_code_bytes``, plus the derived
     ``peak_bytes`` (= argument + output + temp - alias, the standard
     per-device live-memory estimate for one program invocation).
     """
-    try:
-        stats = compiled.memory_analysis()
-    except Exception:  # noqa: BLE001 — unimplemented per backend
-        return None
+    stats = compiled.memory_analysis()
     if stats is None:
         return None
-
-    def _pick(*names) -> int:
-        for n in names:
-            v = getattr(stats, n, None)
-            if v is None and isinstance(stats, dict):
-                v = stats.get(n)
-            if v is not None:
-                return int(v)
-        return 0
-
     out = {
-        "argument_bytes": _pick("argument_size_in_bytes", "argument_size"),
-        "output_bytes": _pick("output_size_in_bytes", "output_size"),
-        "temp_bytes": _pick("temp_size_in_bytes", "temp_size"),
-        "alias_bytes": _pick("alias_size_in_bytes", "alias_size"),
-        "generated_code_bytes": _pick("generated_code_size_in_bytes",
-                                      "generated_code_size"),
+        "argument_bytes": int(stats.argument_size_in_bytes),
+        "output_bytes": int(stats.output_size_in_bytes),
+        "temp_bytes": int(stats.temp_size_in_bytes),
+        "alias_bytes": int(stats.alias_size_in_bytes),
+        "generated_code_bytes": int(stats.generated_code_size_in_bytes),
     }
     out["peak_bytes"] = max(
         0, out["argument_bytes"] + out["output_bytes"] + out["temp_bytes"]
         - out["alias_bytes"])
     return out
-
-
-def set_num_cpu_devices(n: int) -> None:
-    """Request ``n`` virtual CPU devices BEFORE the backend initializes.
-
-    Newer JAX has a first-class config; older versions only honor the
-    XLA host-platform flag, which must be in ``XLA_FLAGS`` when the
-    backend comes up (same before-first-use constraint as the config).
-    """
-    try:
-        jax.config.update("jax_num_cpu_devices", int(n))
-    except AttributeError:
-        import re
-        flags = os.environ.get("XLA_FLAGS", "")
-        flag = f"--xla_force_host_platform_device_count={int(n)}"
-        if "xla_force_host_platform_device_count" in flags:
-            # REPLACE a pre-existing (possibly different) count — silently
-            # keeping it would surface later as a mesh-size mismatch
-            flags = re.sub(
-                r"--?xla_force_host_platform_device_count=\d+", flag,
-                flags)
-            os.environ["XLA_FLAGS"] = flags
-        else:
-            os.environ["XLA_FLAGS"] = f"{flags} {flag}".strip()

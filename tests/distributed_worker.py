@@ -17,9 +17,8 @@ def main() -> int:
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     import jax
-    from openembedding_tpu.utils.jaxcompat import set_num_cpu_devices
     jax.config.update("jax_platforms", "cpu")
-    set_num_cpu_devices(2)
+    jax.config.update("jax_num_cpu_devices", 2)
 
     from openembedding_tpu import distributed
     distributed.initialize(master_endpoint=f"127.0.0.1:{port}",

@@ -303,7 +303,7 @@ def test_wire_lane_deterministic_and_covered(ctx):
     """Two same-seed wire-lane runs produce byte-identical reports,
     every wire class fires, zero violations; a short run leaves the
     unfired classes marked silent (ok=False)."""
-    kw = dict(seed=7, lanes=("wire",), ctx=ctx, libs={}, build=False)
+    kw = dict(seed=7, lanes=("wire",), ctx=ctx, libs={})
     a = fuzz.run_fuzz(**kw)
     b = fuzz.run_fuzz(**kw)
     assert json.dumps(a, sort_keys=True) == json.dumps(b, sort_keys=True)
@@ -311,7 +311,7 @@ def test_wire_lane_deterministic_and_covered(ctx):
     assert sorted(a["classes"]) == sorted(fuzz.WIRE_CLASSES)
     assert all(c["fired"] for c in a["classes"].values())
     short = fuzz.run_fuzz(seed=7, iters=1, lanes=("wire",), ctx=ctx,
-                          libs={}, build=False)
+                          libs={})
     assert short["silent_classes"] and not short["ok"]
 
 
@@ -330,7 +330,7 @@ def test_ingest_lane_skips_or_fails_loudly(ctx):
     completion (damage skipped AND counted) or dies with a typed error
     — never a hang, never an untyped escape, pool still usable."""
     report = fuzz.run_fuzz(seed=3, lanes=("ingest",), ctx=ctx, libs={},
-                           build=False, deadline=60.0)
+                           deadline=60.0)
     assert report["ok"], (report["violations"]
                           or report["silent_classes"])
     assert sorted(report["classes"]) == sorted(fuzz.INGEST_CLASSES)

@@ -1,10 +1,7 @@
 """graftwatch trajectory schema + regression gate (tools/graftwatch.py).
 
-Pure-host lanes (no lowering): the run-record schema validator, the
-bench-history backfill audit (every entry of ``bench_suite.json`` and
-the ``BENCH_r0*.json`` attempt logs is schema-valid or explicitly
-grandfathered with its missing fields listed — ISSUE 7 satellite), and
-the rolling-baseline gate — including the acceptance-criterion negative
+Pure-host lanes (no lowering): the run-record schema validator and the
+rolling-baseline gate — including the acceptance-criterion negative
 test: an injected synthetic 2x-slower record exits nonzero.
 """
 
@@ -78,38 +75,6 @@ def test_load_trajectory_rejects_corrupt_lines(tmp_path):
     with pytest.raises(ValueError, match="invalid record"):
         gw.load_trajectory(str(path))
     assert gw.load_trajectory(str(tmp_path / "missing.jsonl")) == []
-
-
-# --- bench-history backfill (satellite) --------------------------------------
-
-def test_bench_history_all_readable():
-    """Every committed bench entry passes the schema or is explicitly
-    grandfathered with its missing fields listed — no silently
-    unreadable history."""
-    invalid, lines = gw.validate_bench_files()
-    assert invalid == 0, [ln for ln in lines if ln.startswith("INVALID")]
-    assert any(ln.startswith("ok") for ln in lines)
-    for ln in lines:
-        if ln.startswith("grandfathered"):
-            assert "missing [" in ln and "missing []" not in ln, ln
-
-
-def test_classify_bench_entry_shapes():
-    ok, missing = gw.classify_bench_entry(
-        {"metric": "m", "value": 1.0, "unit": "examples/s",
-         "vs_baseline": 1.0, "config": {}, "ts": "2026-01-01T00:00:00"})
-    assert ok == "ok" and missing == []
-    # honest error records are first-class bench history
-    assert gw.classify_bench_entry(
-        {"metric": "m", "error": "device wedged"}) == ("ok", [])
-    status, missing = gw.classify_bench_entry({"metric": "m", "value": 1.0})
-    assert status == "grandfathered" and "ts" in missing
-    # the legacy driver attempt logs grandfather whole, with a reason
-    status, missing = gw.classify_bench_entry(
-        {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "..."})
-    assert status == "grandfathered" and missing
-    assert gw.classify_bench_entry([1, 2])[0] == "invalid"
-    assert gw.classify_bench_entry({"value": 1.0})[0] == "invalid"
 
 
 def test_record_from_bench_conversion():

@@ -1,9 +1,9 @@
 """Pallas sparse-gather kernel: parity with the XLA pull contract.
 
-Runs in interpret mode on the CPU mesh (the kernel compiles to a Mosaic
-pipeline on real TPUs; bench records 546 GB/s vs XLA gather's 1331 GB/s on
-the round's chip — XLA remains the default pull path, the kernel is the
-native-op scaffold)."""
+Runs in interpret mode on the CPU mesh. On a TPU the kernel compiles to a
+Mosaic pipeline (``tests/test_tpu_lowering.py`` compiles it for v5e,
+``chip_smoke.py`` runs it there); XLA's gather remains the default pull
+path, the kernel is the native-op scaffold."""
 
 import numpy as np
 
@@ -28,10 +28,31 @@ def test_gather_parity_and_invalid_ids(devices8):
     np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
-def test_gather_rejects_ragged_dim(devices8):
-    table = jnp.zeros((16, 9), jnp.float32)
-    with pytest.raises(ValueError, match="lane-aligned"):
+@pytest.mark.parametrize("dtype,dim", [(jnp.float32, 9), (jnp.float32, 256),
+                                       (jnp.bfloat16, 128)])
+def test_gather_refuses_what_mosaic_refuses(devices8, dtype, dim):
+    """The guard raises before lowering for every row shape other than
+    float32 x 128 (the one Mosaic compiles the one-row DMA for)."""
+    table = jnp.zeros((16, dim), dtype)
+    with pytest.raises(ValueError, match="float32 rows of exactly 128"):
         gather_rows(table, jnp.zeros((4,), jnp.int32), interpret=True)
+
+
+def test_probe_gather_refuses_what_mosaic_refuses(devices8):
+    from openembedding_tpu.ops.pallas_hash import probe_gather
+    q = jnp.zeros((4,), jnp.int32)
+    with pytest.raises(ValueError, match="float32 rows of exactly 128"):
+        probe_gather(jnp.zeros((256,), jnp.int32),
+                     jnp.zeros((256, 256), jnp.float32), q, q,
+                     chain=2, bucket=128, empty=0, interpret=True)
+    with pytest.raises(ValueError, match="128-slot buckets"):
+        probe_gather(jnp.zeros((64,), jnp.int32),
+                     jnp.zeros((64, 128), jnp.float32), q, q,
+                     chain=1, bucket=64, empty=0, interpret=True)
+    with pytest.raises(ValueError, match="int32 keys"):
+        probe_gather(jnp.zeros((256,), jnp.int16),
+                     jnp.zeros((256, 128), jnp.float32), q, q,
+                     chain=2, bucket=128, empty=0, interpret=True)
 
 
 def test_gather_lane_aligned_and_step_multiple(devices8):

@@ -65,6 +65,12 @@ def test_registry_lifecycle_and_lookup(dumped_model):
     # read-only: unknown hash key -> zeros, and the table is unchanged
     zero = model.lookup("hsh", np.array([999999], np.int32))
     np.testing.assert_array_equal(np.asarray(zero), np.zeros((1, DIM)))
+    # a host int64 id is split on host, never wrapped to int32: 2^40 + 5
+    # is an unseen key, not trained key 5's row
+    far = model.lookup("hsh", np.array([(1 << 40) + 5, 5], np.int64))
+    np.testing.assert_array_equal(np.asarray(far)[0], np.zeros(DIM))
+    np.testing.assert_allclose(np.asarray(far)[1],
+                               np.asarray(expected["hsh"])[0], rtol=1e-6)
 
     reg.delete_model(sign)
     with pytest.raises(KeyError):

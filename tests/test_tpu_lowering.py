@@ -1,0 +1,112 @@
+"""AOT compilation for TPU v5e from a host without one.
+
+libtpu serves its compilers (XLA:TPU and Mosaic) for a described
+topology even where no chip is attached, so what ``chip_smoke.py`` runs
+on the chip is compiled here first: the DeepFM train step at full Criteo
+width on 1x1 and 2x2, and each Pallas kernel at one shape its guard
+admits and one it refuses (a ``ValueError`` before lowering, never a
+``MosaicError`` out of the compiler).
+
+``slow``, and never in a lane that runs in parallel: libtpu takes
+``/tmp/libtpu_lockfile``, and a second process compiling at the same time
+aborts.
+"""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+import chip_smoke
+from openembedding_tpu import hash_table as hl
+from openembedding_tpu.analysis import contracts
+from openembedding_tpu.data import criteo
+from openembedding_tpu.ops import pallas_gather as pg, pallas_hash as ph
+from openembedding_tpu.parallel.mesh import DATA_AXIS, create_mesh
+
+pytestmark = pytest.mark.slow
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu says is the reason
+        pytest.skip(f"libtpu yields no v5e:2x2 topology: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+def _abstract(tree, shardings):
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, shardings)
+
+
+def _compile_deepfm_step(mesh, *, use_hash):
+    """The step program of chip_smoke.py's training phases, from shapes
+    alone."""
+    coll, trainer, mapper = chip_smoke.build_deepfm(mesh, use_hash=use_hash)
+    batch = mapper.fuse_batch(next(iter(criteo.synthetic_criteo(
+        chip_smoke.BATCH, num_buckets=chip_smoke.ROWS_PER_FEATURE,
+        num_batches=1))))
+    state = jax.eval_shape(trainer.init, jax.random.PRNGKey(0), batch)
+    repl = NamedSharding(mesh, P())
+    state = state.replace(
+        emb=_abstract(state.emb, coll.state_shardings()),
+        **{k: _abstract(getattr(state, k),
+                        jax.tree.map(lambda _: repl, getattr(state, k)))
+           for k in ("step", "params", "opt_state")})
+    by_batch = NamedSharding(mesh, P(DATA_AXIS))
+    batch = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, jax.dtypes.canonicalize_dtype(x.dtype),
+            sharding=by_batch), batch)
+    return trainer.lower_train_step(state, batch).compile()
+
+
+@pytest.mark.parametrize("use_hash", [False, True], ids=["array", "hash"])
+@pytest.mark.parametrize("shape", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
+def test_deepfm_step_compiles_for_v5e(v5e, shape, use_hash):
+    data, model = shape
+    mesh = create_mesh(data, model, v5e[:data * model])
+    compiled = _compile_deepfm_step(mesh, use_hash=use_hash)
+    ops = contracts.summarize(compiled.as_text())
+    if mesh.size == 1:
+        assert not ops, f"one chip has no peer to exchange with: {ops}"
+    else:
+        assert "all-to-all" in ops, ops
+    # two 27M-row tables + Adagrad slots (array) must fit the 16 GB chip
+    assert compiled.memory_analysis().argument_size_in_bytes < 15 << 30
+
+
+def _on(dev, shape, dtype):
+    mesh = create_mesh(1, 1, [dev])
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(mesh, P()))
+
+
+def test_pallas_gather_admitted_and_refused(v5e):
+    idx = _on(v5e[0], (4096,), jnp.int32)
+    pg.gather_rows.trace(_on(v5e[0], (1 << 16, 128), jnp.float32),
+                         idx).lower().compile()
+    for dtype, dim in ((jnp.float32, 256), (jnp.bfloat16, 128)):
+        with pytest.raises(ValueError, match="float32 rows of exactly 128"):
+            pg.gather_rows.trace(_on(v5e[0], (1 << 16, dim), dtype), idx)
+
+
+def test_pallas_probe_gather_admitted_and_refused(v5e):
+    cap, n = 1 << 16, 4096
+    bucket, _nb, chain = hl.table_layout(cap, hl.DEFAULT_MAX_PROBES)
+    kw = dict(chain=chain, bucket=bucket, empty=int(hl.empty_key(jnp.int32)))
+    keys = _on(v5e[0], (cap,), jnp.int32)
+    q = _on(v5e[0], (n,), jnp.int32)
+    ph.probe_gather.trace(keys, _on(v5e[0], (cap, 128), jnp.float32), q, q,
+                          **kw).lower().compile()
+    for dtype, dim in ((jnp.float32, 256), (jnp.bfloat16, 128)):
+        with pytest.raises(ValueError, match="float32 rows of exactly 128"):
+            ph.probe_gather.trace(keys, _on(v5e[0], (cap, dim), dtype), q, q,
+                                  **kw)

@@ -16,7 +16,6 @@ import optax
 from openembedding_tpu import EmbeddingCollection, EmbeddingSpec, Trainer
 from openembedding_tpu.models import deepctr
 from openembedding_tpu.parallel.mesh import create_mesh
-from openembedding_tpu.utils import jaxcompat
 
 FEATURES = ("c0", "c1", "c2")
 VOCAB = 100
@@ -133,7 +132,7 @@ def test_int64_keys_require_int64_table(devices8):
     big = np.array([2**33 + 7], dtype=np.int64)
     # without x64, jnp.asarray itself truncates int64 -> int32 before the
     # table ever sees the key, so the aliasing guard only engages under x64
-    with jaxcompat.enable_x64(True):
+    with jax.enable_x64(True):
         with pytest.raises(ValueError, match="key_dtype"):
             coll.pull(states, {"h": jnp.asarray(big)}, batch_sharded=False)
 

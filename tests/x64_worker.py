@@ -15,9 +15,8 @@ def main() -> int:
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     import jax
-    from openembedding_tpu.utils.jaxcompat import set_num_cpu_devices
     jax.config.update("jax_platforms", "cpu")
-    set_num_cpu_devices(4)
+    jax.config.update("jax_num_cpu_devices", 4)
     jax.config.update("jax_enable_x64", True)
 
     import numpy as np

@@ -44,8 +44,7 @@ def main(argv=None) -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
     jax.config.update("jax_platforms", "cpu")
-    from openembedding_tpu.utils.jaxcompat import set_num_cpu_devices
-    set_num_cpu_devices(data * model)
+    jax.config.update("jax_num_cpu_devices", data * model)
 
     from openembedding_tpu.parallel.mesh import create_mesh
     from openembedding_tpu.analysis import contracts, programs

@@ -83,9 +83,6 @@ def main(argv=None) -> int:
                     help="comma-separated lane subset (ckpt,wire,ingest)")
     ap.add_argument("--deadline", type=float, default=30.0,
                     help="per-probe hang deadline in seconds")
-    ap.add_argument("--no-build", action="store_true",
-                    help="reuse existing sanitizer .so's instead of "
-                         "rebuilding (local iteration only; CI builds)")
     ap.add_argument("--regress", action="store_true",
                     help="replay the pinned regression corpus "
                          "(tests/fixtures/fuzz_corpus.py) instead of "
@@ -126,7 +123,7 @@ def main(argv=None) -> int:
         tmp = tempfile.mkdtemp(prefix="graftfuzz-regress-")
         try:
             ctx = fuzz.SeedContext(os.path.join(tmp, "ctx"))
-            libs = fuzz.sanitizer_libs(build=not args.no_build)
+            libs = fuzz.sanitizer_libs()
             report = fuzz.run_regress(ctx, libs, os.path.join(tmp, "w"),
                                       deadline=args.deadline, log=print)
         finally:
@@ -139,7 +136,7 @@ def main(argv=None) -> int:
     else:
         report = fuzz.run_fuzz(seed=args.seed, iters=args.iters,
                                lanes=lanes, deadline=args.deadline,
-                               build=not args.no_build, log=print)
+                               log=print)
         _print_coverage(report)
         for v in report["violations"]:
             print(f"[iter {v['iter']} {v['class']}] {v['detail']}",

@@ -2,7 +2,6 @@
 
     python -m tools.graftwatch --record --quick     # cpu8 micro-bench
     python -m tools.graftwatch --gate               # regression gate
-    python -m tools.graftwatch --validate-bench     # bench-file audit
 
 Bench entries used to be schemaless one-off JSON blobs: no git sha, no
 hardware fingerprint, nothing consuming them — a perf regression
@@ -23,10 +22,6 @@ reproducible per-config records, ``documents/en/benchmark.md``):
   own eps_min/eps_max spread. No baseline -> soft pass with a warning
   (the first record on new hardware cannot regress against anything);
   baseline present + any metric worse than the band -> exit 1.
-* ``--validate-bench`` audits every entry of ``bench_suite.json`` and
-  the ``BENCH_r0*.json`` attempt logs against the bench-entry schema:
-  entries either pass or are explicitly grandfathered with their
-  missing fields listed — no silently unreadable history.
 
 ``bench.py --trajectory <path>`` appends its own throughput entries
 through :func:`record_from_bench`, so real device rounds land in the
@@ -37,7 +32,7 @@ counts) to the same file, and the gate covers their latency quantiles:
 a serving regression is **p99 up OR sustained QPS down** beyond the
 noise band.
 
-Gate/validate modes import no jax — they run anywhere, instantly.
+The gate imports no jax — it runs anywhere, instantly.
 """
 
 from __future__ import annotations
@@ -304,71 +299,6 @@ def validate_record(rec: Any) -> List[str]:
                             p.append(f"serving.batch.{k}: expected "
                                      "number >= 0")
     return p
-
-
-# bench_suite.json entry schema (the pre-trajectory record shape every
-# runner in bench.py emits); honest error records are first-class
-_BENCH_REQUIRED: Tuple[Tuple[str, Any], ...] = (
-    ("value", _NUM), ("unit", str), ("vs_baseline", _NUM),
-    ("config", dict), ("ts", str))
-
-
-def classify_bench_entry(entry: Any) -> Tuple[str, List[str]]:
-    """("ok" | "grandfathered" | "invalid", missing-field list).
-
-    ``ok``: a well-formed bench record or an honest error record.
-    ``grandfathered``: readable history predating a field (listed) —
-    the legacy ``BENCH_r0*.json`` driver attempt logs land here whole.
-    ``invalid``: unreadable as bench history at all.
-    """
-    if not isinstance(entry, dict):
-        return "invalid", ["entry is not a JSON object"]
-    if {"n", "cmd", "rc"} <= set(entry):
-        return "grandfathered", [
-            "legacy driver attempt log (n/cmd/rc/tail) — predates the "
-            "bench-entry schema; kept as wedge-history provenance"]
-    if not isinstance(entry.get("metric"), str):
-        return "invalid", ["metric: required str"]
-    if isinstance(entry.get("error"), str):
-        return "ok", []
-    missing = [key for key, types in _BENCH_REQUIRED
-               if not isinstance(entry.get(key), types)]
-    return ("ok" if not missing else "grandfathered"), missing
-
-
-def validate_bench_files(root: str = REPO_ROOT) -> Tuple[int, List[str]]:
-    """Audit bench_suite.json + BENCH_r0*.json; returns (invalid count,
-    report lines)."""
-    import glob
-    lines: List[str] = []
-    invalid = 0
-    paths = [os.path.join(root, "bench_suite.json")]
-    paths += sorted(glob.glob(os.path.join(root, "BENCH_r0*.json")))
-    for path in paths:
-        name = os.path.basename(path)
-        try:
-            with open(path) as f:
-                data = json.load(f)
-        except FileNotFoundError:
-            continue
-        except (OSError, json.JSONDecodeError) as e:
-            invalid += 1
-            lines.append(f"INVALID {name}: unreadable JSON ({e})")
-            continue
-        entries = data if isinstance(data, list) else [data]
-        for i, entry in enumerate(entries):
-            status, missing = classify_bench_entry(entry)
-            label = entry.get("metric", f"entry[{i}]") \
-                if isinstance(entry, dict) else f"entry[{i}]"
-            if status == "invalid":
-                invalid += 1
-                lines.append(f"INVALID {name}:{label}: {missing}")
-            elif status == "grandfathered":
-                lines.append(f"grandfathered {name}:{label}: "
-                             f"missing {missing}")
-            else:
-                lines.append(f"ok   {name}:{label}")
-    return invalid, lines
 
 
 # --- trajectory IO -----------------------------------------------------------
@@ -677,8 +607,7 @@ def run_record(args) -> List[Dict[str, Any]]:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
     jax.config.update("jax_platforms", "cpu")
-    from openembedding_tpu.utils.jaxcompat import set_num_cpu_devices
-    set_num_cpu_devices(data * model)
+    jax.config.update("jax_num_cpu_devices", data * model)
 
     import numpy as np
     import jax.numpy as jnp
@@ -804,9 +733,6 @@ def main(argv=None) -> int:
                     help="armed gate: a group with no baseline FAILS "
                          "instead of soft-passing (use once baselines "
                          "for this fingerprint are committed)")
-    ap.add_argument("--validate-bench", action="store_true",
-                    help="audit bench_suite.json + BENCH_r0*.json "
-                         "against the bench-entry schema")
     ap.add_argument("--quick", action="store_true",
                     help="CI-sized micro-bench (fewer/smaller blocks)")
     ap.add_argument("--trajectory", default=TRAJECTORY_FILE,
@@ -826,22 +752,9 @@ def main(argv=None) -> int:
     args.steps = args.steps or (4 if args.quick else 10)
     args.blocks = args.blocks or (3 if args.quick else 5)
 
-    if not (args.record or args.gate or args.validate_bench):
-        ap.error("pick at least one of --record / --gate "
-                 "/ --validate-bench")
+    if not (args.record or args.gate):
+        ap.error("pick at least one of --record / --gate")
     rc = 0
-
-    if args.validate_bench:
-        invalid, lines = validate_bench_files()
-        for ln in lines:
-            print(ln)
-        if invalid:
-            print(f"graftwatch: {invalid} unreadable bench entr(ies)",
-                  file=sys.stderr)
-            rc = 1
-        else:
-            print("graftwatch: bench history readable "
-                  "(schema-valid or explicitly grandfathered)")
 
     if args.record:
         try:

@@ -51,8 +51,7 @@ def main(argv=None) -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
     jax.config.update("jax_platforms", "cpu")
-    from openembedding_tpu.utils.jaxcompat import set_num_cpu_devices
-    set_num_cpu_devices(args.devices)
+    jax.config.update("jax_num_cpu_devices", args.devices)
 
     import optax
     from openembedding_tpu import EmbeddingCollection, Trainer
