@@ -1,0 +1,11 @@
+"""Host milliseconds per step in the trainer's ``trainer.place_batch`` span
+(``shard_batch``: the batch put on the device), over the steps that begin
+inside the traced device window: ``stage_reduce``."""
+
+from ..stage_reduce import host_ms_per_step
+
+TIMING = True
+
+
+def read(run):
+    return host_ms_per_step(run, "trainer.place_batch")

@@ -522,7 +522,8 @@ def span(kind: str, detail: Optional[Mapping[str, Any]] = None,
 
     ``labels`` become histogram labels AND trace args — keep them
     low-cardinality (plane, table, method). ``detail`` goes to the trace
-    event only (signs, paths, step numbers). Error exits are recorded
+    event only (signs, paths, step numbers), in the ring and as keywords
+    of the profiler's ``TraceAnnotation``. Error exits are recorded
     with the exception type and re-raised. Under a JAX trace the event
     is recorded once, tagged ``trace_time``, and skips the histograms.
     """
@@ -530,7 +531,7 @@ def span(kind: str, detail: Optional[Mapping[str, Any]] = None,
     prof = _profiler()
     if prof is not None:
         try:
-            ann = prof.TraceAnnotation(kind)
+            ann = prof.TraceAnnotation(kind, **(detail or {}))
         except Exception:  # noqa: BLE001 — annotation is best-effort
             ann = None
     return Span(kind, dict(labels) or None,
@@ -548,6 +549,32 @@ def step_span(step: int, name: str = "step") -> Span:
         except Exception:  # noqa: BLE001 — annotation is best-effort
             ann = None
     return Span(name, None, {"step": int(step)}, ann)
+
+
+def stage(name: str):
+    """Name one stage of a jitted program: ``stage("dedup")(fn)(*arrays)``.
+
+    Under a JAX trace ``fn`` runs as an inner ``jax.jit`` of a function
+    called ``name``, the way the planes' ``pull_a2a`` / ``push_a2a`` are
+    named: the name becomes a function symbol of the lowered module (so
+    it is part of the persistent compile cache's key, which strips
+    ``jax.named_scope`` metadata) and a component of every instruction's
+    ``op_name`` in the optimized HLO, where a device trace is read back
+    to stages. XLA inlines the call; the program computes what it
+    computed. ``fn`` takes arrays (pytrees) only and closes over anything
+    static. Called eagerly there is no program to name, and ``fn`` is
+    returned as it is.
+    """
+    def wrap(fn):
+        if _trace_state_clean():
+            return fn
+        import jax
+
+        def staged(*args):
+            return fn(*args)
+        staged.__name__ = staged.__qualname__ = name
+        return jax.jit(staged)
+    return wrap
 
 
 # ---------------------------------------------------------------------------

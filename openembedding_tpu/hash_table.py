@@ -53,6 +53,7 @@ import numpy as np
 from jax import lax
 from flax import struct
 
+from .analysis import scope
 from .meta import EmbeddingVariableMeta
 from .ops import dedup
 from .optim.initializers import Initializer, make_initializer
@@ -302,15 +303,16 @@ def init_rows(initializer: Initializer, base_rng: jax.Array,
     """Deterministic initializer row per key: fold key into the base PRNG.
     Wide keys fold both words, so rows depend on the full 64-bit key."""
     if is_wide(keys):
-        def one(k):
+        def one(base_rng, k):
             r = jax.random.fold_in(base_rng, k[0])
             return initializer.init(jax.random.fold_in(r, k[1]),
                                     (dim,), dtype)
     else:
-        def one(k):
+        def one(base_rng, k):
             return initializer.init(jax.random.fold_in(base_rng, k),
                                     (dim,), dtype)
-    return jax.vmap(one)(keys)
+    return scope.stage("init_rows")(jax.vmap(one, in_axes=(None, 0)))(
+        base_rng, keys)
 
 
 def check_key_dtype(table_keys: jnp.ndarray, query: jnp.ndarray) -> jnp.ndarray:
@@ -347,6 +349,12 @@ def find_rows(table_keys: jnp.ndarray, query: jnp.ndarray,
     2^22-slot table) — the bucket-aligned layout is what makes the probe a
     row gather.
     """
+    return scope.stage("probe")(
+        lambda table_keys, query: _find_rows(table_keys, query, max_probes))(
+            table_keys, query)
+
+
+def _find_rows(table_keys, query, max_probes):
     query = check_key_dtype(table_keys, query)
     capacity = table_keys.shape[0]
     n = query.shape[0]
@@ -395,6 +403,13 @@ def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
     Returns ``(table_keys, slot [n] (-1 = failed), inserted [n],
     failed [n])``.
     """
+    return scope.stage("probe")(
+        lambda table_keys, new_keys, valid: _find_or_insert(
+            table_keys, new_keys, valid, max_probes))(
+                table_keys, new_keys, valid)
+
+
+def _find_or_insert(table_keys, new_keys, valid, max_probes):
     capacity = table_keys.shape[0]
     n = new_keys.shape[0]
     empty = empty_key(table_keys.dtype)
@@ -516,18 +531,25 @@ def pull(state: HashTableState, indices: jnp.ndarray,
         flat = check_key_dtype(state.keys, indices.ravel())
         invalid = flat == empty_key(state.keys.dtype)
         out_shape = indices.shape + (state.dim,)
-    slot = find_rows(state.keys, flat, max_probes)
-    hit = slot >= 0
-    rows = jnp.take(state.weights, jnp.where(hit, slot, 0), axis=0, mode="clip")
-    if initializer is None:
-        fresh = jnp.zeros_like(rows)
-    else:
+    if initializer is not None:
         initializer = make_initializer(initializer)
-        fresh = init_rows(initializer, state.init_rng, flat, state.dim,
-                          state.weights.dtype)
-    rows = jnp.where(hit[:, None], rows, fresh)
-    rows = jnp.where(invalid[:, None], jnp.zeros_like(rows), rows)
-    return rows.reshape(out_shape)
+
+    @scope.stage("resolve")
+    def read(keys, weights, init_rng, flat, invalid):
+        slot = find_rows(keys, flat, max_probes)
+        hit = slot >= 0
+        rows = jnp.take(weights, jnp.where(hit, slot, 0), axis=0,
+                        mode="clip")
+        if initializer is None:
+            fresh = jnp.zeros_like(rows)
+        else:
+            fresh = init_rows(initializer, init_rng, flat, state.dim,
+                              weights.dtype)
+        rows = jnp.where(hit[:, None], rows, fresh)
+        return jnp.where(invalid[:, None], jnp.zeros_like(rows), rows)
+
+    return read(state.keys, state.weights, state.init_rng, flat,
+                invalid).reshape(out_shape)
 
 
 def apply_gradients(state: HashTableState,
@@ -574,20 +596,18 @@ def apply_gradients(state: HashTableState,
     ok = valid & (slot >= 0)
     safe_slot = jnp.where(ok, slot, 0)
 
-    w = jnp.take(state.weights, safe_slot, axis=0)
+    w, s = table_lib.gather_rows(state.weights, state.slots, safe_slot)
     fresh = init_rows(initializer, state.init_rng, uniq, dim,
                       state.weights.dtype)
     w = jnp.where(inserted[:, None], fresh, w)
-    s = {k: jnp.take(v, safe_slot, axis=0) for k, v in state.slots.items()}
 
     new_w, new_s = table_lib.optimizer_block_update(optimizer, w, s,
                                                     summed, counts)
 
     oob = jnp.asarray(state.capacity, jnp.int32)
     scatter_idx = jnp.where(ok, safe_slot, oob)
-    weights = state.weights.at[scatter_idx].set(new_w, mode="drop")
-    slots = {k: state.slots[k].at[scatter_idx].set(new_s[k], mode="drop")
-             for k in state.slots}
+    weights, slots = table_lib.scatter_rows(state.weights, state.slots,
+                                            scatter_idx, new_w, new_s)
     return HashTableState(
         keys=keys_arr, weights=weights, slots=slots,
         init_rng=state.init_rng,

@@ -17,6 +17,8 @@ from typing import Tuple
 
 import jax.numpy as jnp
 
+from ..analysis import scope
+
 # Padding sentinel for empty unique slots. Indices/keys are remapped away from
 # this value by callers when the key space could include it.
 FILL = jnp.iinfo(jnp.int32).min
@@ -43,10 +45,15 @@ def unique_indices(indices: jnp.ndarray, capacity: int | None = None,
     indices = indices.ravel()
     if capacity is None:
         capacity = indices.shape[0]
-    fill = jnp.asarray(fill_value, dtype=indices.dtype)
-    uniq, inverse = jnp.unique(indices, size=capacity, fill_value=fill,
-                               return_inverse=True)
-    return uniq, inverse.ravel(), uniq != fill
+
+    @scope.stage("dedup")
+    def unique(indices):
+        fill = jnp.asarray(fill_value, dtype=indices.dtype)
+        uniq, inverse = jnp.unique(indices, size=capacity, fill_value=fill,
+                                   return_inverse=True)
+        return uniq, inverse.ravel(), uniq != fill
+
+    return unique(indices)
 
 
 def combine_gradients(grads: jnp.ndarray, inverse: jnp.ndarray, capacity: int,
@@ -65,12 +72,18 @@ def combine_gradients(grads: jnp.ndarray, inverse: jnp.ndarray, capacity: int,
     reference's server-side MpscGradientReducer merging client pre-reduces.
     """
     n, dim = grads.shape
-    summed = jnp.zeros((capacity, dim), dtype=grads.dtype).at[inverse].add(
-        grads, mode="drop")
-    add = jnp.int32(1) if in_counts is None else in_counts.astype(jnp.int32)
-    counts = jnp.zeros((capacity,), dtype=jnp.int32).at[inverse].add(
-        add, mode="drop")
-    return summed, counts
+
+    @scope.stage("dedup")
+    def combine(grads, inverse, in_counts):
+        summed = jnp.zeros((capacity, dim), dtype=grads.dtype).at[
+            inverse].add(grads, mode="drop")
+        add = jnp.int32(1) if in_counts is None \
+            else in_counts.astype(jnp.int32)
+        counts = jnp.zeros((capacity,), dtype=jnp.int32).at[inverse].add(
+            add, mode="drop")
+        return summed, counts
+
+    return combine(grads, inverse, in_counts)
 
 
 def overflow_count(inverse: jnp.ndarray, capacity: int) -> jnp.ndarray:
@@ -98,23 +111,29 @@ def unique_rows(rows: jnp.ndarray, capacity: int | None = None,
     n, k = rows.shape
     if capacity is None:
         capacity = n
-    order = jnp.arange(n, dtype=jnp.int32)
-    for c in range(k):
-        order = order[jnp.argsort(rows[order, c], stable=True)]
-    srt = rows[order]
-    new_group = jnp.concatenate([
-        jnp.ones((1,), bool),
-        jnp.any(srt[1:] != srt[:-1], axis=1)])
-    # group ordinal per sorted row -> unique slot; first of group writes it
-    slot_sorted = jnp.cumsum(new_group.astype(jnp.int32)) - 1
-    inverse = jnp.zeros((n,), jnp.int32).at[order].set(slot_sorted)
-    fill = jnp.asarray(fill_value, rows.dtype)
-    uniq = jnp.full((capacity, k), fill, dtype=rows.dtype)
-    dst = jnp.where(new_group, slot_sorted, capacity)
-    uniq = uniq.at[dst].set(srt, mode="drop")
-    valid = (jnp.arange(capacity) <= (slot_sorted[-1] if n else -1)) \
-        & (uniq[:, -1] != fill)
-    return uniq, inverse, valid
+
+    @scope.stage("dedup")
+    def unique(rows):
+        order = jnp.arange(n, dtype=jnp.int32)
+        for c in range(k):
+            order = order[jnp.argsort(rows[order, c], stable=True)]
+        srt = rows[order]
+        new_group = jnp.concatenate([
+            jnp.ones((1,), bool),
+            jnp.any(srt[1:] != srt[:-1], axis=1)])
+        # group ordinal per sorted row -> unique slot; first of group
+        # writes it
+        slot_sorted = jnp.cumsum(new_group.astype(jnp.int32)) - 1
+        inverse = jnp.zeros((n,), jnp.int32).at[order].set(slot_sorted)
+        fill = jnp.asarray(fill_value, rows.dtype)
+        uniq = jnp.full((capacity, k), fill, dtype=rows.dtype)
+        dst = jnp.where(new_group, slot_sorted, capacity)
+        uniq = uniq.at[dst].set(srt, mode="drop")
+        valid = (jnp.arange(capacity) <= (slot_sorted[-1] if n else -1)) \
+            & (uniq[:, -1] != fill)
+        return uniq, inverse, valid
+
+    return unique(rows)
 
 
 def unique_pairs(pairs: jnp.ndarray, capacity: int | None = None,

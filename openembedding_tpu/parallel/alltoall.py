@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..analysis import scope
 from ..analysis.lint import host_fn
 from ..ops import dedup
 from ..utils import observability
@@ -211,13 +212,15 @@ def grid_all_to_all(x: jnp.ndarray, axes: Sequence[str],
     holds data from the peer matching on later axes — the composition routes
     every block to exactly its (j0, ..., jn) owner.
     """
-    n = x.shape[0]
-    shape = tuple(sizes) + x.shape[1:]
-    y = x.reshape(shape)
-    for k, (ax, size) in enumerate(zip(axes, sizes)):
-        if size > 1:
-            y = lax.all_to_all(y, ax, split_axis=k, concat_axis=k)
-    return y.reshape((n,) + x.shape[1:])
+    @scope.stage("exchange")
+    def route_blocks(x):
+        y = x.reshape(tuple(sizes) + x.shape[1:])
+        for k, (ax, size) in enumerate(zip(axes, sizes)):
+            if size > 1:
+                y = lax.all_to_all(y, ax, split_axis=k, concat_axis=k)
+        return y.reshape(x.shape)
+
+    return route_blocks(x)
 
 
 def grid_info(mesh, shard_axes: Sequence[str], model_axis: str,
@@ -324,11 +327,18 @@ def exchange_pull(flat_idx: jnp.ndarray,
     every entry exactly once, so rounds never compound the error).
     ``None`` leaves the program byte-identical to the uncompressed one.
     """
-    my_part = linear_shard_id(split_axes, split_sizes)
     n = flat_idx.shape[0]
     wide = flat_idx.ndim == 2
     kw = flat_idx.shape[1] if wide else 1  # key words per entry
-    sl, m = split_slice(flat_idx, math.prod(split_sizes), my_part, sentinel)
+    parts = math.prod(split_sizes)
+    m = -(-n // parts)
+
+    @scope.stage("route")
+    def my_slice(flat_idx):
+        my_part = linear_shard_id(split_axes, split_sizes)
+        return split_slice(flat_idx, parts, my_part, sentinel)[0]
+
+    sl = my_slice(flat_idx)
     if wide:
         uniq, inverse, _valid = dedup.unique_rows(sl, m,
                                                   fill_value=sentinel)
@@ -336,15 +346,32 @@ def exchange_pull(flat_idx: jnp.ndarray,
         uniq, inverse, _valid = dedup.unique_indices(sl, m,
                                                      fill_value=sentinel)
     cap = bucket_capacity(m, num_shards, capacity, slack)
-    owners = owner_fn(uniq)
+    owners = scope.stage("route")(owner_fn)(uniq)
     out_dtype = jax.eval_shape(resolve_fn, uniq).dtype
     acc_dtype = out_dtype if wire_dtype is None else jnp.dtype(wire_dtype)
 
-    def one_round(pending, acc):
+    @scope.stage("route")
+    def to_buckets(pending, uniq):
         dest, ok = bucketize(pending, num_shards, cap)
-        send = fill_buckets(uniq, dest, num_shards, cap, sentinel)
+        return fill_buckets(uniq, dest, num_shards, cap, sentinel), dest, ok
+
+    @scope.stage("route")
+    def from_buckets(resp, dest, ok, pending, acc):
+        flat_resp = resp.reshape((num_shards * cap, dim))
+        got = jnp.take(flat_resp, jnp.where(ok, dest, 0), axis=0)
+        acc = acc + jnp.where(ok[:, None], got, jnp.zeros_like(got))
+        return jnp.where(ok, jnp.int32(num_shards), pending), acc
+
+    @scope.stage("exchange")
+    def count_left(pending):
+        return lax.psum(jnp.sum(pending < num_shards).astype(jnp.int32),
+                        tuple(grid_axes))
+
+    def one_round(pending, acc):
+        send, dest, ok = to_buckets(pending, uniq)
         req = grid_all_to_all(send, grid_axes, grid_sizes)
-        rows = resolve_fn(req.reshape((-1, kw)) if wide else req.ravel())
+        rows = scope.stage("resolve")(resolve_fn)(
+            req.reshape((-1, kw)) if wide else req.ravel())
         if wire_dtype is not None:
             # the ONE lossy point of a compressed pull: owner-resolved
             # rows narrow to the wire dtype before the response leg,
@@ -355,13 +382,8 @@ def exchange_pull(flat_idx: jnp.ndarray,
                                grid_axes, grid_sizes)
         if wire_dtype is not None:
             resp = unpin_wire(resp, acc_dtype)
-        flat_resp = resp.reshape((num_shards * cap, dim))
-        got = jnp.take(flat_resp, jnp.where(ok, dest, 0), axis=0)
-        acc = acc + jnp.where(ok[:, None], got, jnp.zeros_like(got))
-        pending = jnp.where(ok, jnp.int32(num_shards), pending)
-        left = lax.psum(jnp.sum(pending < num_shards).astype(jnp.int32),
-                        tuple(grid_axes))
-        return pending, acc, left
+        pending, acc = from_buckets(resp, dest, ok, pending, acc)
+        return pending, acc, count_left(pending)
 
     pending0 = owners.astype(jnp.int32)
     acc0 = jnp.zeros((m, dim), dtype=acc_dtype)
@@ -377,15 +399,17 @@ def exchange_pull(flat_idx: jnp.ndarray,
             lambda c: c[2] > 0,
             lambda c: one_round(c[0], c[1]),
             (pending, uniq_rows, left))
-    slice_rows = jnp.take(uniq_rows, inverse, axis=0)
+    slice_rows = scope.stage("expand")(
+        lambda rows, inverse: jnp.take(rows, inverse, axis=0))(
+            uniq_rows, inverse)
+    assemble = scope.stage("exchange")(
+        lambda rows: lax.all_gather(rows, tuple(split_axes), tiled=True))
     if wire_dtype is not None:
         # the row-assembly gather ships the pinned 16-bit wire form too;
         # the upcast after it is exact (bf16 -> f32 loses nothing)
-        out = lax.all_gather(pin_wire(slice_rows), tuple(split_axes),
-                             tiled=True)
+        out = assemble(pin_wire(slice_rows))
         return unpin_wire(out[:n], acc_dtype).astype(out_dtype)
-    out = lax.all_gather(slice_rows, tuple(split_axes), tiled=True)
-    return out[:n]
+    return assemble(slice_rows)[:n]
 
 
 def exchange_push(flat_idx: jnp.ndarray,
@@ -457,11 +481,17 @@ def exchange_push(flat_idx: jnp.ndarray,
       owners zero them by key validity so no NaN can reach an applier.
     """
     dim = grads.shape[-1]
-    my_part = linear_shard_id(split_axes, split_sizes)
     parts = math.prod(split_sizes)
     wide = flat_idx.ndim == 2
-    sl, m = split_slice(flat_idx, parts, my_part, sentinel)
-    g2 = split_slice_rows(grads.reshape((-1, dim)), parts, my_part)
+    m = -(-flat_idx.shape[0] // parts)
+
+    @scope.stage("route")
+    def my_slice(flat_idx, grads):
+        my_part = linear_shard_id(split_axes, split_sizes)
+        return (split_slice(flat_idx, parts, my_part, sentinel)[0],
+                split_slice_rows(grads.reshape((-1, dim)), parts, my_part))
+
+    sl, g2 = my_slice(flat_idx, grads)
     if wide:
         uniq, inverse, _valid = dedup.unique_rows(sl, m,
                                                   fill_value=sentinel)
@@ -470,8 +500,13 @@ def exchange_push(flat_idx: jnp.ndarray,
                                                      fill_value=sentinel)
     summed, counts = dedup.combine_gradients(g2, inverse, m)
     cap = bucket_capacity(m, num_shards, capacity, slack)
-    owners = owner_fn(uniq)
-    dest, ok = bucketize(owners, num_shards, cap)
+
+    @scope.stage("route")
+    def to_owners(uniq):
+        owners = owner_fn(uniq)
+        return (owners,) + bucketize(owners, num_shards, cap)
+
+    owners, dest, ok = to_owners(uniq)
     kw = flat_idx.shape[1] if wide else 1  # key words per exchange entry
 
     quant = ef_state is not None
@@ -484,7 +519,8 @@ def exchange_push(flat_idx: jnp.ndarray,
     def _key_valid(k):
         return (k[:, -1] != sentinel) if wide else (k != sentinel)
 
-    def routed(st):
+    @scope.stage("route")
+    def to_buckets(uniq, counts, payload, scale, dest):
         ku = uniq if wide else uniq[:, None]
         cols = [ku, counts.astype(ku.dtype)[:, None]]
         if quant:
@@ -492,14 +528,12 @@ def exchange_push(flat_idx: jnp.ndarray,
             cols.append(lax.bitcast_convert_type(
                 scale, jnp.int32).astype(ku.dtype)[:, None])
         kc = jnp.concatenate(cols, axis=1)       # [m, kw+1(+1)]
-        payload = q8 if quant else (
-            summed if wire_dtype is None
-            else pin_wire(summed.astype(wire_dtype)))
-        send_kc = fill_buckets(kc, dest, num_shards, cap, sentinel)
-        send_g = fill_buckets(payload, dest, num_shards, cap, 0)
-        rkc = grid_all_to_all(send_kc, grid_axes, grid_sizes)
-        rg = grid_all_to_all(send_g, grid_axes, grid_sizes)
-        flat_kc = rkc.reshape((-1, kc.shape[1]))
+        return (fill_buckets(kc, dest, num_shards, cap, sentinel),
+                fill_buckets(payload, dest, num_shards, cap, 0))
+
+    @scope.stage("route")
+    def from_buckets(rkc, rg):
+        flat_kc = rkc.reshape((-1, rkc.shape[-1]))
         k = flat_kc[:, :kw] if wide else flat_kc[:, 0]
         rc = flat_kc[:, kw].astype(jnp.int32)
         g = rg.reshape((flat_kc.shape[0], dim))
@@ -513,22 +547,34 @@ def exchange_push(flat_idx: jnp.ndarray,
             g = g.astype(summed.dtype) * rscale[:, None]
         elif wire_dtype is not None:
             g = unpin_wire(g, wire_dtype).astype(summed.dtype)
-        return apply_fn(st, k, g, rc)
+        return k, g, rc
 
+    @scope.stage("push_routed")
+    def routed(st):
+        payload = q8 if quant else (
+            summed if wire_dtype is None
+            else pin_wire(summed.astype(wire_dtype)))
+        send_kc, send_g = to_buckets(uniq, counts, payload, scale, dest)
+        rkc = grid_all_to_all(send_kc, grid_axes, grid_sizes)
+        rg = grid_all_to_all(send_g, grid_axes, grid_sizes)
+        return apply_fn(st, *from_buckets(rkc, rg))
+
+    gather_all = scope.stage("exchange")(
+        lambda x: lax.all_gather(x, tuple(grid_axes), tiled=True))
+
+    @scope.stage("push_spilled")
     def gathered(st):
-        ga = tuple(grid_axes)
-        k = lax.all_gather(uniq, ga, tiled=True)  # [P*m] or [P*m, 2]
-        c = lax.all_gather(counts, ga, tiled=True)
+        k = gather_all(uniq)            # [P*m] or [P*m, 2]
+        c = gather_all(counts)
         if quant:
-            gq = lax.all_gather(q8, ga, tiled=True)
-            gs = lax.all_gather(scale, ga, tiled=True)
-            g = gq.astype(summed.dtype) * gs[:, None]
+            g = gather_all(q8).astype(summed.dtype) \
+                * gather_all(scale)[:, None]
         elif wire_dtype is not None:
             narrowed = pin_wire(summed.astype(wire_dtype))
-            g = unpin_wire(lax.all_gather(narrowed, ga, tiled=True),
+            g = unpin_wire(gather_all(narrowed),
                            wire_dtype).astype(summed.dtype)
         else:
-            g = lax.all_gather(summed, ga, tiled=True)
+            g = gather_all(summed)
         return apply_fn(st, k, g, c)
 
     if cap >= m:
@@ -536,7 +582,8 @@ def exchange_push(flat_idx: jnp.ndarray,
         out = routed(state)
         return (out, new_ef) if quant else out
     local_spill = jnp.sum((owners < num_shards) & ~ok).astype(jnp.int32)
-    spilled = lax.psum(local_spill, tuple(grid_axes))
+    spilled = scope.stage("exchange")(
+        lambda x: lax.psum(x, tuple(grid_axes)))(local_spill)
     # per-device residue: the callback fires on every device shard, so the
     # host accumulator sums locals into the global total
     record_stat("a2a_extra_entries_push", local_spill, record_stats)

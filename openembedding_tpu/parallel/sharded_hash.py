@@ -26,6 +26,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..analysis import scope
 from ..meta import EmbeddingVariableMeta
 from ..utils import observability
 from ..optim.initializers import make_initializer
@@ -226,11 +227,16 @@ def create_sharded_hash_table(meta: EmbeddingVariableMeta,
 def _mask_non_owned(spec: HashShardingSpec, flat: jnp.ndarray,
                     me: jnp.ndarray) -> jnp.ndarray:
     empty = hash_lib.empty_key(flat.dtype)
-    if hash_lib.is_wide(flat):
-        owned = (spec.owner_shard(flat) == me) & (flat[:, 1] != empty)
-        return jnp.where(owned[:, None], flat, empty)
-    owned = (spec.owner_shard(flat) == me) & (flat != empty)
-    return jnp.where(owned, flat, empty)
+
+    @scope.stage("route")
+    def mask(flat, me):
+        if hash_lib.is_wide(flat):
+            owned = (spec.owner_shard(flat) == me) & (flat[:, 1] != empty)
+            return jnp.where(owned[:, None], flat, empty)
+        owned = (spec.owner_shard(flat) == me) & (flat != empty)
+        return jnp.where(owned, flat, empty)
+
+    return mask(flat, me)
 
 
 def _my_shard(mesh: Mesh, spec: HashShardingSpec) -> jnp.ndarray:
@@ -430,7 +436,8 @@ def _pull_program(mesh: Mesh, spec: HashShardingSpec, initializer: Any,
                                    lax.axis_index(spec.model_axis))
             rows = hash_lib.pull(local, flat, initializer,
                                  max_probes=spec.max_probes)
-            rows = lax.psum(rows, spec.model_axis)
+            rows = scope.stage("exchange")(
+                lambda rows: lax.psum(rows, spec.model_axis))(rows)
             return rows.reshape(out_shape)
 
     row = spec.row_spec()
@@ -598,8 +605,10 @@ def _apply_program(mesh: Mesh, spec: HashShardingSpec,
             flat = idx.reshape(-1, 2) if spec.wide else idx.ravel()
             g2 = g.reshape(-1, dim)
             if batch_sharded:
-                flat = lax.all_gather(flat, spec.data_axis, tiled=True)
-                g2 = lax.all_gather(g2, spec.data_axis, tiled=True)
+                flat, g2 = scope.stage("exchange")(
+                    lambda *xs: tuple(lax.all_gather(x, spec.data_axis,
+                                                     tiled=True)
+                                      for x in xs))(flat, g2)
             flat = _mask_non_owned(spec, flat,
                                    lax.axis_index(spec.model_axis))
             local = hash_lib.HashTableState(

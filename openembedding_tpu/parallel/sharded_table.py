@@ -69,6 +69,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..analysis import scope
 from ..meta import EmbeddingVariableMeta
 from ..ops import dedup
 from ..utils import observability
@@ -372,6 +373,17 @@ def deliver_rows_sharded(arr: jnp.ndarray, phys: jnp.ndarray,
     return fn(arr, phys, rows)
 
 
+def _masked_local(spec: ShardingSpec, flat: jnp.ndarray):
+    """``(owned [n], local row [n])`` of ``flat`` on this model-axis shard
+    (the masked-local body of the psum plane). Invalid indices (negative
+    or beyond the padded vocab) are owned by nobody: the pull's psum
+    returns zero rows for them, like ``table_lib.pull``."""
+    s = lax.axis_index(spec.model_axis)
+    shard, local = spec.shard_and_local(flat)
+    owned = (shard == s) & (flat >= 0) & (flat < spec.padded_vocab)
+    return owned, local
+
+
 @functools.lru_cache(maxsize=None)
 def _pull_program(mesh: Mesh, spec: ShardingSpec, dim: int,
                   batch_sharded: bool, record_stats: bool = False):
@@ -438,16 +450,18 @@ def _pull_program(mesh: Mesh, spec: ShardingSpec, dim: int,
             _pull = _pull_core
     else:
         def _pull(weights, idx):
-            s = lax.axis_index(spec.model_axis)
-            flat = idx.ravel()
-            shard, local = spec.shard_and_local(flat)
-            # invalid indices (negative or beyond the padded vocab) are owned
-            # by nobody -> psum returns zero rows, like table_lib.pull
-            owned = (shard == s) & (flat >= 0) & (flat < spec.padded_vocab)
-            rows = jnp.take(weights, jnp.where(owned, local, 0), axis=0,
-                            mode="clip")
-            rows = jnp.where(owned[:, None], rows, jnp.zeros_like(rows))
-            rows = lax.psum(rows, spec.model_axis)
+            owned, local = scope.stage("route")(
+                lambda flat: _masked_local(spec, flat))(idx.ravel())
+
+            @scope.stage("resolve")
+            def read(weights, owned, local):
+                rows = jnp.take(weights, jnp.where(owned, local, 0), axis=0,
+                                mode="clip")
+                return jnp.where(owned[:, None], rows, jnp.zeros_like(rows))
+
+            rows = scope.stage("exchange")(
+                lambda rows: lax.psum(rows, spec.model_axis))(
+                    read(weights, owned, local))
             return rows.reshape(idx.shape + (dim,))
 
     if spec.is_cached:
@@ -520,10 +534,14 @@ def _apply_program(mesh: Mesh, spec: ShardingSpec,
                     jnp.int32)
 
             def apply_fn(st, keys, grads, counts):
-                shard, local = spec.shard_and_local(keys)
-                mine = ((keys >= 0) & (keys < spec.padded_vocab)
-                        & (shard == me))
-                masked = jnp.where(mine, local, -1)
+                @scope.stage("route")
+                def mask(keys, me):
+                    shard, local = spec.shard_and_local(keys)
+                    mine = ((keys >= 0) & (keys < spec.padded_vocab)
+                            & (shard == me))
+                    return jnp.where(mine, local, -1)
+
+                masked = mask(keys, me)
                 new = table_lib.apply_gradients(
                     table_lib.TableState(weights=st[0], slots=st[1]),
                     optimizer, masked, grads,
@@ -588,16 +606,22 @@ def _apply_program(mesh: Mesh, spec: ShardingSpec,
                                   g.reshape(-1, dim))
     else:
         def _apply(weights, slots, idx, g):
-            s = lax.axis_index(spec.model_axis)
             flat = idx.ravel()
             g2 = g.reshape(-1, dim)
             if batch_sharded:
-                flat = lax.all_gather(flat, spec.data_axis, tiled=True)
-                g2 = lax.all_gather(g2, spec.data_axis, tiled=True)
-            shard, local = spec.shard_and_local(flat)
-            owned = (shard == s) & (flat >= 0) & (flat < spec.padded_vocab)
-            # non-owned entries become index -1 -> dropped in apply_gradients
-            masked = jnp.where(owned, local, -1)
+                flat, g2 = scope.stage("exchange")(
+                    lambda *xs: tuple(lax.all_gather(x, spec.data_axis,
+                                                     tiled=True)
+                                      for x in xs))(flat, g2)
+
+            @scope.stage("route")
+            def mask(flat):
+                owned, local = _masked_local(spec, flat)
+                # non-owned entries become index -1 -> dropped in
+                # apply_gradients
+                return jnp.where(owned, local, -1)
+
+            masked = mask(flat)
             local_state = table_lib.TableState(weights=weights, slots=slots)
             new_state = table_lib.apply_gradients(
                 local_state, optimizer, masked, g2,

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from .analysis import scope
 from .meta import EmbeddingVariableMeta
 from .ops import dedup
 from .optim.initializers import Initializer, make_initializer
@@ -95,11 +96,15 @@ def pull(state: TableState, indices: jnp.ndarray) -> jnp.ndarray:
     contract as the sharded path and as apply_gradients, which drops them.
     Output shape = indices.shape + [dim].
     """
-    flat = indices.ravel()
-    valid = (flat >= 0) & (flat < state.capacity)
-    rows = jnp.take(state.weights, jnp.where(valid, flat, 0), axis=0, mode="clip")
-    rows = jnp.where(valid[:, None], rows, jnp.zeros_like(rows))
-    return rows.reshape(indices.shape + (state.dim,))
+    @scope.stage("resolve")
+    def read(weights, flat):
+        valid = (flat >= 0) & (flat < state.capacity)
+        rows = jnp.take(weights, jnp.where(valid, flat, 0), axis=0,
+                        mode="clip")
+        return jnp.where(valid[:, None], rows, jnp.zeros_like(rows))
+
+    return read(state.weights, indices.ravel()).reshape(
+        indices.shape + (state.dim,))
 
 
 def optimizer_block_update(optimizer: SparseOptimizer,
@@ -112,15 +117,19 @@ def optimizer_block_update(optimizer: SparseOptimizer,
     float32 even for bfloat16 tables, results are cast back to each
     array's storage dtype. Shared by the array/hash apply paths and the
     hot-row replica update (``parallel/hot_cache.py``)."""
-    compute = jnp.promote_types(weights.dtype, jnp.float32)
-    new_w, new_s = optimizer.update_rows(
-        weights.astype(compute),
-        {k: v.astype(jnp.promote_types(v.dtype, jnp.float32))
-         for k, v in slots.items()},
-        summed.astype(compute), counts)
-    new_w = new_w.astype(weights.dtype)
-    new_s = {k: new_s[k].astype(slots[k].dtype) for k in new_s}
-    return new_w, new_s
+    @scope.stage("apply_update")
+    def update(weights, slots, summed, counts):
+        compute = jnp.promote_types(weights.dtype, jnp.float32)
+        new_w, new_s = optimizer.update_rows(
+            weights.astype(compute),
+            {k: v.astype(jnp.promote_types(v.dtype, jnp.float32))
+             for k, v in slots.items()},
+            summed.astype(compute), counts)
+        new_w = new_w.astype(weights.dtype)
+        new_s = {k: new_s[k].astype(slots[k].dtype) for k in new_s}
+        return new_w, new_s
+
+    return update(weights, slots, summed, counts)
 
 
 def apply_gradients(state: TableState,
@@ -154,14 +163,38 @@ def apply_gradients(state: TableState,
     # Gather touched rows + slots; padding slots gather row 0 then are dropped
     # on the scatter, so their (garbage) update never lands.
     safe_uniq = jnp.where(valid, uniq, 0)
-    w = jnp.take(state.weights, safe_uniq, axis=0)
-    s = {k: jnp.take(v, safe_uniq, axis=0) for k, v in state.slots.items()}
+    w, s = gather_rows(state.weights, state.slots, safe_uniq)
 
     new_w, new_s = optimizer_block_update(optimizer, w, s, summed, counts)
 
     oob = jnp.asarray(state.capacity, dtype=safe_uniq.dtype)
     scatter_idx = jnp.where(valid, safe_uniq, oob)  # padding -> dropped
-    weights = state.weights.at[scatter_idx].set(new_w, mode="drop")
-    slots = {k: state.slots[k].at[scatter_idx].set(new_s[k], mode="drop")
-             for k in state.slots}
+    weights, slots = scatter_rows(state.weights, state.slots, scatter_idx,
+                                  new_w, new_s)
     return TableState(weights=weights, slots=slots)
+
+
+def gather_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
+                at: jnp.ndarray):
+    """Rows of the weights and of every slot array at ``at``: the read
+    half of a sparse update (array and hash apply paths)."""
+    @scope.stage("apply_gather")
+    def gather(weights, slots, at):
+        return (jnp.take(weights, at, axis=0),
+                {k: jnp.take(v, at, axis=0) for k, v in slots.items()})
+
+    return gather(weights, slots, at)
+
+
+def scatter_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
+                 at: jnp.ndarray, new_w: jnp.ndarray,
+                 new_s: Dict[str, jnp.ndarray]):
+    """Write updated rows back at ``at``; out-of-range entries (padding)
+    are dropped. The write half of a sparse update."""
+    @scope.stage("apply_scatter")
+    def scatter(weights, slots, at, new_w, new_s):
+        return (weights.at[at].set(new_w, mode="drop"),
+                {k: slots[k].at[at].set(new_s[k], mode="drop")
+                 for k in slots})
+
+    return scatter(weights, slots, at, new_w, new_s)

@@ -230,11 +230,21 @@ class Trainer:
                                  dense_ids)
             return self.loss_fn(logits, batch["label"])
 
-        loss, (dense_g, row_g) = jax.value_and_grad(
-            lfn, argnums=(0, 1))(state.params, rows)
-        updates, opt_state = self.tx.update(dense_g, state.opt_state,
-                                            state.params)
-        params = optax.apply_updates(state.params, updates)
+        # value_and_grad, taken apart so that the forward and the backward
+        # pass are each a named stage of the step program
+        loss, backward = scope.stage("dense_fwd")(
+            lambda params, rows: jax.vjp(lfn, params, rows))(
+                state.params, rows)
+        dense_g, row_g = scope.stage("dense_bwd")(
+            lambda backward, loss: backward(jnp.ones_like(loss)))(
+                backward, loss)
+
+        @scope.stage("dense_update")
+        def update(dense_g, opt_state, params):
+            updates, opt_state = self.tx.update(dense_g, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state
+
+        params, opt_state = update(dense_g, state.opt_state, state.params)
         emb = self.collection.apply_gradients(state.emb, pull_inputs,
                                               row_g)
         return params, opt_state, emb, loss
@@ -331,7 +341,7 @@ class Trainer:
         return pipeline_lib.drain(state)
 
     def _pipelined_train_step(self, state: TrainState, batch,
-                              next_batch) -> tuple:
+                              next_batch, at) -> tuple:
         if self._pipelined_step is None:
             self._pipelined_step = self._build_pipelined_train_step()
         if state.pipe is None or self._pipe_for is not batch \
@@ -355,9 +365,12 @@ class Trainer:
         # the in-program pull/push are NOT separable host-side — see
         # observability.plane_timings overlap attribution)
         record = observability.evaluate_performance()
-        state, metrics = observability.plane_timed(
-            "step", self.pipeline_plane, record, self._pipelined_step,
-            state, self.shard_batch(batch), self.shard_batch(pre))
+        with scope.span("trainer.place_batch", detail=at):
+            placed = self.shard_batch(batch), self.shard_batch(pre)
+        with scope.span("trainer.dispatch", detail=at):
+            state, metrics = observability.plane_timed(
+                "step", self.pipeline_plane, record, self._pipelined_step,
+                state, *placed)
         # a lookahead miss self-prefetches the CURRENT batch — still a
         # valid buffer if the caller steps the same batch again (single-
         # batch smoke loops); any other batch re-primes
@@ -406,36 +419,46 @@ class Trainer:
             self._train_step = self._build_train_step()
         # graftscope: one span per whole host-visible step, with
         # StepTraceAnnotation pass-through so a concurrent jax.profiler
-        # device trace attributes its work to the same step numbers
+        # device trace attributes its work to the same step numbers. Its
+        # three children carry that number: placing the batch, dispatching
+        # the jitted program, and the bookkeeping on both sides of it
+        at = {"step": self._host_step}
         try:
             with scope.step_span(self._host_step):
-                # per-table batch-shape stats (pull_indices/pull_unique
-                # counters + pull_rows/unique_ratio/key_skew histograms);
-                # gated inside — a host np.unique per column, off by
-                # default like the reference's accumulators
-                observability.record_batch_stats(batch["sparse"])
-                state, uniqs = self._apply_prepared_offload(state, batch)
+                with scope.span("trainer.bookkeeping", detail=at):
+                    # per-table batch-shape stats (pull_indices/
+                    # pull_unique counters + pull_rows/unique_ratio/
+                    # key_skew histograms); gated inside — a host
+                    # np.unique per column, off by default like the
+                    # reference's accumulators
+                    observability.record_batch_stats(batch["sparse"])
+                    state, uniqs = self._apply_prepared_offload(state,
+                                                                batch)
                 if self._pipelined:
                     state, metrics = self._pipelined_train_step(
-                        state, batch, next_batch)
+                        state, batch, next_batch, at)
                 else:
-                    state, metrics = self._train_step(
-                        state, self.shard_batch(batch))
-                if self.collection.dirty_trackers:
-                    # delta-checkpoint dirty marks from the HOST batch:
-                    # the jitted step's in-trace ids are tracers, so the
-                    # collection cannot mark there (once per compile);
-                    # here marks land once per step, pipelined plane
-                    # included (its push(N) commits inside step N)
-                    cols, _ = self._split_sparse(batch["sparse"])
-                    self.collection.mark_dirty(cols)
-                for name, table in self.offload.items():
-                    table.note_update(batch["sparse"][name],
-                                      uniq=uniqs.get(name))
-                state = self._note_hot_cache(state, batch)
-                if next_batch is not None and self.offload \
-                        and not self._prep_started(next_batch):
-                    self._start_host_prepare(next_batch)
+                    with scope.span("trainer.place_batch", detail=at):
+                        placed = self.shard_batch(batch)
+                    with scope.span("trainer.dispatch", detail=at):
+                        state, metrics = self._train_step(state, placed)
+                with scope.span("trainer.bookkeeping", detail=at):
+                    if self.collection.dirty_trackers:
+                        # delta-checkpoint dirty marks from the HOST
+                        # batch: the jitted step's in-trace ids are
+                        # tracers, so the collection cannot mark there
+                        # (once per compile); here marks land once per
+                        # step, pipelined plane included (its push(N)
+                        # commits inside step N)
+                        cols, _ = self._split_sparse(batch["sparse"])
+                        self.collection.mark_dirty(cols)
+                    for name, table in self.offload.items():
+                        table.note_update(batch["sparse"][name],
+                                          uniq=uniqs.get(name))
+                    state = self._note_hot_cache(state, batch)
+                    if next_batch is not None and self.offload \
+                            and not self._prep_started(next_batch):
+                        self._start_host_prepare(next_batch)
         finally:
             # advance on ERROR exits too: a caller that catches and
             # retries must not reuse the step number (duplicate ids in
@@ -763,7 +786,8 @@ class Trainer:
                 batch = window.popleft()
                 pops0 = (None if self_accounted
                          else observability.ingest_stall_records())
-                stall_s = refill()
+                with scope.span("trainer.next_batch"):
+                    stall_s = refill()
                 if not self_accounted \
                         and observability.ingest_stall_records() == pops0:
                     observability.record_ingest_stall(stall_s)

@@ -12,6 +12,9 @@ admits and one it refuses (a ``ValueError`` before lowering, never a
 aborts.
 """
 
+import functools
+import re
+
 import pytest
 
 import jax
@@ -19,6 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import chip_smoke
+from benchmark import stage_reduce
 from openembedding_tpu import hash_table as hl
 from openembedding_tpu.analysis import contracts
 from openembedding_tpu.data import criteo
@@ -46,6 +50,7 @@ def _abstract(tree, shardings):
         tree, shardings)
 
 
+@functools.lru_cache(maxsize=None)      # two tests read each program
 def _compile_deepfm_step(mesh, *, use_hash):
     """The step program of chip_smoke.py's training phases, from shapes
     alone."""
@@ -81,6 +86,33 @@ def test_deepfm_step_compiles_for_v5e(v5e, shape, use_hash):
         assert "all-to-all" in ops, ops
     # two 27M-row tables + Adagrad slots (array) must fit the 16 GB chip
     assert compiled.memory_analysis().argument_size_in_bytes < 15 << 30
+
+
+_OPCODE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (?:\(.*?\)|\S+) "
+                     r"(gather|scatter|sort|while|fusion)\(")
+
+
+@pytest.mark.parametrize("use_hash", [False, True], ids=["array", "hash"])
+def test_v5e_step_keeps_its_stage_names(v5e, use_hash):
+    """What the chip's compiler leaves of the names: every gather, scatter,
+    sort and while of the compiled step, and nine fusions in ten, belong to
+    a stage (``stage_reduce.instruction_stages``: the stage in the
+    instruction's own ``op_name`` as ``trace_reduce.scope_names`` reads it,
+    else its caller's, else its operand's)."""
+    mesh = create_mesh(1, 1, v5e[:1])
+    hlo = _compile_deepfm_step(mesh, use_hash=use_hash).as_text()
+    stages = stage_reduce.instruction_stages(hlo)
+    found = [m.groups() for m in map(_OPCODE.match, hlo.splitlines()) if m]
+    assert {op for _, op in found} >= {"gather", "scatter", "sort", "fusion"}
+    if use_hash:
+        assert any(op == "while" and stages.get(inst) == "probe"
+                   for inst, op in found)
+    lost = [inst for inst, op in found
+            if op != "fusion" and inst not in stages]
+    assert not lost, lost
+    fusions = [inst for inst, op in found if op == "fusion"]
+    named = sum(inst in stages for inst in fusions)
+    assert named >= 0.9 * len(fusions), (named, len(fusions))
 
 
 def _on(dev, shape, dtype):

@@ -212,3 +212,45 @@ def test_auc_lift_on_learnable_task(devices8):
         auc1.update(b["label"], np.asarray(trainer.eval_step(state, b)))
     assert auc0.result() < 0.6, f"untrained AUC {auc0.result():.3f}"
     assert auc1.result() > 0.9, f"trained AUC {auc1.result():.3f}"
+
+
+def test_fit_host_spans_share_their_steps_number(devices8):
+    """Three steps of ``fit`` under span tracing: each ``step`` span holds
+    one ``trainer.place_batch``, one ``trainer.dispatch`` and the
+    ``trainer.bookkeeping`` on both sides of it, all with the step's
+    number, and a ``trainer.next_batch`` lies between two steps."""
+    from openembedding_tpu.analysis import scope
+
+    trainer = build_trainer("deepfm", create_mesh(2, 4, devices8))
+    batches = list(synthetic_batches(3))
+    state = trainer.init(jax.random.PRNGKey(0),
+                         trainer.shard_batch(batches[0]))
+    scope.set_tracing(True)
+    scope.reset()
+    try:
+        trainer.fit(state, batches)
+        events = [e for e in scope.export_chrome_trace()["traceEvents"]
+                  if e.get("ph") == "X"]
+    finally:
+        scope.set_tracing(None)
+        scope.reset()
+
+    def inside(outer, e):
+        return outer["ts"] <= e["ts"] and \
+            e["ts"] + e["dur"] <= outer["ts"] + outer["dur"]
+
+    steps = [e for e in events if e["name"] == "step"]
+    assert [e["args"]["step"] for e in steps] == [0, 1, 2]
+    for step in steps:
+        held = [e for e in events
+                if e["name"].startswith("trainer.") and inside(step, e)]
+        assert [e["name"] for e in held] == [
+            "trainer.bookkeeping", "trainer.place_batch",
+            "trainer.dispatch", "trainer.bookkeeping"]
+        assert {e["args"]["step"] for e in held} == {step["args"]["step"]}
+    fetches = [e for e in events if e["name"] == "trainer.next_batch"]
+    assert len(fetches) == 3 and not any(
+        inside(step, e) for step in steps for e in fetches)
+    for before, after in zip(steps, steps[1:]):
+        assert any(before["ts"] + before["dur"] <= e["ts"]
+                   and e["ts"] + e["dur"] <= after["ts"] for e in fetches)
