@@ -58,6 +58,7 @@ from .meta import EmbeddingVariableMeta
 from .ops import dedup
 from .optim.initializers import Initializer, make_initializer
 from .optim.optimizers import SparseOptimizer, make_optimizer
+from .parallel.alltoall import record_stat
 from . import table as table_lib
 
 BUCKET = 128            # slots per bucket = one int32 lane row
@@ -379,60 +380,153 @@ def _find_rows(table_keys, query, max_probes):
     return jnp.where(hit & valid, slot, -1)
 
 
+def insert_width(n: int) -> int:
+    """Keys the insert loop of a :func:`find_or_insert` call of ``n`` keys
+    runs over when at most that many miss: an eighth of the call, rounded
+    up to 1024. A training push misses ~5% of its keys; a bulk load misses
+    all of them and takes the full-width loop."""
+    return -(-n // (8 * 1024)) * 1024
+
+
 def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
                    valid: jnp.ndarray,
-                   max_probes: int = DEFAULT_MAX_PROBES
+                   max_probes: int = DEFAULT_MAX_PROBES,
+                   record_stats: bool = False
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Find each (unique) key's slot, inserting missing keys.
 
-    One pass per chain level: every unplaced key probes its level-j bucket —
-    a contiguous 128-slot row — matches existing entries, then unmatched
-    keys are assigned free slots by RANK: contenders for the same bucket are
-    grouped (stable sort by bucket id), ranked within the group, and rank r
-    takes the bucket's (r+1)-th free slot. Keys are unique, ranks within a
-    bucket are unique, so assignments never collide; keys ranked past the
-    free count overflow to the next chain level — which is exactly the
-    "only overflow when the bucket filled up" invariant lookup relies on.
+    Two phases. *Find*: every key probes its chain for a match, one row
+    gather a level and no insert machinery, as the pull's
+    :func:`find_rows` does; a key found there is done. *Insert*: the keys
+    that missed, in their original order, are compacted into a buffer of
+    :func:`insert_width` keys and only that buffer runs the insert loop
+    below; its slots are written back to the keys' positions. When more
+    keys miss than the buffer holds (a bulk load, a cold table's first
+    steps) the loop runs over all ``n`` keys instead: both loops are in
+    the program, the observed count leaves one of them without a key to
+    place, and a loop stops at the first level that finds none. Where the
+    buffer would be no narrower than the call (small calls) the loop is
+    all there is. A key already in the table can never take a slot (its
+    earlier chain buckets are full), and compaction keeps every other
+    contender's rank within its bucket, so slots, ``inserted``, ``failed``
+    and the key array are the same whichever loop placed the keys.
 
-    Every level costs O(batch * 128) gathers + O(batch log batch) sort work
-    — *independent of table capacity* (an earlier design materialized a
-    [capacity] claim buffer per probe round: O(max_probes * capacity) HBM
+    The insert loop makes one pass per chain level: every unplaced key
+    probes its level-j bucket — a contiguous 128-slot row — matches
+    existing entries, then unmatched keys are assigned free slots by RANK:
+    contenders for the same bucket are grouped (stable sort by bucket id),
+    ranked within the group, and rank r takes the bucket's (r+1)-th free
+    slot. Keys are unique, ranks within a bucket are unique, so assignments
+    never collide; keys ranked past the free count overflow to the next
+    chain level — which is exactly the "only overflow when the bucket
+    filled up" invariant lookup relies on.
+
+    Every level costs O(width * 128) gathers + O(width log width) sort
+    work — *independent of table capacity* (an earlier design materialized
+    a [capacity] claim buffer per probe round: O(max_probes * capacity) HBM
     traffic per insert call, benign at 2^23 rows, fatal at the reference's
     10^9-row scale, documents/en/pmem.md north star).
+
+    ``record_stats`` (the trace-time gate of ``alltoall.record_stat``)
+    counts ``hash_insert_compact`` / ``hash_insert_full``, the calls that
+    ran the loop over the buffer / over every key, and
+    ``hash_insert_missed``, the keys that were not in the table.
 
     Returns ``(table_keys, slot [n] (-1 = failed), inserted [n],
     failed [n])``.
     """
     return scope.stage("probe")(
         lambda table_keys, new_keys, valid: _find_or_insert(
-            table_keys, new_keys, valid, max_probes))(
+            table_keys, new_keys, valid, max_probes, record_stats))(
                 table_keys, new_keys, valid)
 
 
-def _find_or_insert(table_keys, new_keys, valid, max_probes):
+def _find_or_insert(table_keys, new_keys, valid, max_probes, record_stats):
+    n = new_keys.shape[0]
+    m = insert_width(n)
+    if m >= n:
+        out = _insert_levels(table_keys, new_keys, valid, max_probes)
+        _, _, inserted, failed = out
+        record_stat("hash_insert_full", jnp.int32(1), record_stats)
+        record_stat("hash_insert_missed",
+                    jnp.sum(inserted | failed, dtype=jnp.int32), record_stats)
+        return out
+
+    found = _find_levels(table_keys, new_keys, valid, max_probes)
+    miss = valid & (found < 0)
+    missed = jnp.sum(miss, dtype=jnp.int32)
+    fits = missed <= m
+    # The misses' positions in their order, then n: ascending, so every
+    # contender keeps its rank within its bucket.
+    at = jnp.sort(jnp.where(miss, jnp.arange(n, dtype=jnp.int32), n))[:m]
+    # One of the two loops has keys to place and the other runs no level.
+    # Under a lax.cond the v5e compiler copies the key array on its way
+    # into a branch's loop (512 MiB a table at 2^26 wide slots); through
+    # two whiles it stays in place, as through the one.
+    table_keys, slot_m, _, _ = _insert_levels(
+        table_keys, jnp.take(new_keys, at, axis=0, mode="clip"),
+        (at < n) & fits, max_probes)
+    table_keys, slot, _, _ = _insert_levels(
+        table_keys, new_keys, valid & ~fits, max_probes,
+        slot0=found.at[at].set(slot_m, mode="drop"))
+    record_stat("hash_insert_compact", fits.astype(jnp.int32), record_stats)
+    record_stat("hash_insert_full", (~fits).astype(jnp.int32), record_stats)
+    record_stat("hash_insert_missed", missed, record_stats)
+    # a key that missed is in no bucket the loop reads for it: it is placed
+    # or it fails, it never hits
+    return table_keys, slot, miss & (slot >= 0), miss & (slot < 0)
+
+
+def _bucket_masks(keys_arr, query, bkt, max_probes):
+    """One row gather: bucket ``bkt[i]`` of the table against ``query[i]``.
+    ``([n, bucket] slot holds the key, [n, bucket] slot is free)``."""
+    bsz, nb, _chain = table_layout(keys_arr.shape[0], max_probes)
+    empty = empty_key(keys_arr.dtype)
+    if is_wide(keys_arr):
+        rows = jnp.take(keys_arr.reshape(nb, bsz, 2), bkt, axis=0)
+        return ((rows[..., 0] == query[:, None, 0])
+                & (rows[..., 1] == query[:, None, 1]),
+                rows[..., 1] == empty)
+    rows = jnp.take(keys_arr.reshape(nb, bsz), bkt, axis=0)
+    return rows == query[:, None], rows == empty
+
+
+def _find_levels(table_keys, query, valid, max_probes):
+    """What :func:`find_rows` finds, one chain level at a time: a bulk
+    load's whole-chain gather is a 4 GiB temporary (and as much again for
+    its relayout) beside the insert loop's own; one level of it is what
+    one level of that loop holds."""
+    capacity = table_keys.shape[0]
+    bsz, _nb, chain = table_layout(capacity, max_probes)
+    b0 = probe_starts(query, capacity, max_probes) // bsz
+
+    def level(j, slot):
+        match, _ = _bucket_masks(table_keys, query, b0 + j, max_probes)
+        hit = valid & (slot < 0) & jnp.any(match, axis=1)
+        first = jnp.argmax(match, axis=1).astype(jnp.int32)
+        return jnp.where(hit, (b0 + j) * bsz + first, slot)
+
+    return lax.fori_loop(0, chain, level,
+                         jnp.full((query.shape[0],), -1, jnp.int32))
+
+
+def _insert_levels(table_keys, new_keys, valid, max_probes, slot0=None):
+    """The insert loop of :func:`find_or_insert` over every key given, one
+    chain level at a time while a valid key is neither found nor placed.
+    ``slot0`` is what ``slot`` reads for a key the loop does neither to."""
     capacity = table_keys.shape[0]
     n = new_keys.shape[0]
-    empty = empty_key(table_keys.dtype)
-    wide = is_wide(table_keys)
     bsz, nb, chain = table_layout(capacity, max_probes)
     h = probe_starts(new_keys, capacity, max_probes)
     b0 = h // bsz
     oob = jnp.asarray(capacity, jnp.int32)
     ids = jnp.arange(n, dtype=jnp.int32)
 
-    def level(j, carry):
-        keys_arr, slot, done, inserted = carry
+    def level(carry):
+        j, keys_arr, slot, done, inserted = carry
         bj = b0 + j
         start = bj * bsz
-        if wide:
-            rows = jnp.take(keys_arr.reshape(nb, bsz, 2), bj, axis=0)
-            match = ((rows[..., 0] == new_keys[:, None, 0])
-                     & (rows[..., 1] == new_keys[:, None, 1]))
-            emptym = rows[..., 1] == empty
-        else:
-            rows = jnp.take(keys_arr.reshape(nb, bsz), bj, axis=0)
-            match = rows == new_keys[:, None]
-            emptym = rows == empty
+        match, emptym = _bucket_masks(keys_arr, new_keys, bj, max_probes)
         active = valid & ~done
         # already present (keys are unique; at most one slot matches)
         hitm = active & jnp.any(match, axis=1)
@@ -461,13 +555,18 @@ def _find_or_insert(table_keys, new_keys, valid, max_probes):
         slot = jnp.where(place, pslot, slot)
         done = done | place
         inserted = inserted | place
-        return keys_arr, slot, done, inserted
+        return j + 1, keys_arr, slot, done, inserted
 
-    slot0 = jnp.full((n,), -1, jnp.int32)
+    if slot0 is None:
+        slot0 = jnp.full((n,), -1, jnp.int32)
     done0 = ~valid
     ins0 = jnp.zeros((n,), bool)
-    table_keys, slot, done, inserted = lax.fori_loop(
-        0, chain, level, (table_keys, slot0, done0, ins0))
+    def unplaced(carry):
+        j, _keys_arr, _slot, done, _inserted = carry
+        return (j < chain) & ~jnp.all(done)
+
+    _, table_keys, slot, done, inserted = lax.while_loop(
+        unplaced, level, (jnp.int32(0), table_keys, slot0, done0, ins0))
     failed = valid & ~done
     return table_keys, slot, inserted, failed
 
@@ -476,7 +575,8 @@ def insert_rows(state: HashTableState,
                 keys: jnp.ndarray,
                 weights: jnp.ndarray,
                 slot_rows: Optional[Dict[str, jnp.ndarray]] = None,
-                max_probes: int = DEFAULT_MAX_PROBES) -> HashTableState:
+                max_probes: int = DEFAULT_MAX_PROBES,
+                record_stats: bool = False) -> HashTableState:
     """Directly set rows (and optionally optimizer-state rows) for keys.
 
     The load-path primitive (reference EmbeddingInitItems delivery,
@@ -492,7 +592,7 @@ def insert_rows(state: HashTableState,
         keys = check_key_dtype(state.keys, keys.ravel())
         valid = keys != empty
     keys_arr, slot, _inserted, failed = find_or_insert(
-        state.keys, keys, valid, max_probes)
+        state.keys, keys, valid, max_probes, record_stats)
     ok = valid & (slot >= 0)
     oob = jnp.asarray(state.capacity, jnp.int32)
     scatter_idx = jnp.where(ok, slot, oob)
@@ -560,7 +660,8 @@ def apply_gradients(state: HashTableState,
                     *,
                     dedup_capacity: Optional[int] = None,
                     max_probes: int = DEFAULT_MAX_PROBES,
-                    in_counts: Optional[jnp.ndarray] = None) -> HashTableState:
+                    in_counts: Optional[jnp.ndarray] = None,
+                    record_stats: bool = False) -> HashTableState:
     """Combine duplicate grads, insert missing keys, update touched rows.
 
     The hash-table analogue of ``table.apply_gradients``: dedup -> claim/probe
@@ -592,7 +693,7 @@ def apply_gradients(state: HashTableState,
                                              in_counts)
 
     keys_arr, slot, inserted, failed = find_or_insert(
-        state.keys, uniq, valid, max_probes)
+        state.keys, uniq, valid, max_probes, record_stats)
     ok = valid & (slot >= 0)
     safe_slot = jnp.where(ok, slot, 0)
 

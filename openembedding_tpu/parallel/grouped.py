@@ -589,7 +589,7 @@ def _hash_push_program(mesh: Mesh, plan: GroupPlan, batch_sharded: bool,
                 ns = hash_lib.apply_gradients(
                     cur, m.optimizer, m.initializer, masked,
                     gw[:, :m.dim], max_probes=m.spec.max_probes,
-                    in_counts=cw)
+                    in_counts=cw, record_stats=record_stats)
                 new.append((ns.keys, ns.weights, ns.slots,
                             fails + ns.insert_failures))
             return tuple(new)

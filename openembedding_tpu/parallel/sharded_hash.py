@@ -246,7 +246,8 @@ def _my_shard(mesh: Mesh, spec: HashShardingSpec) -> jnp.ndarray:
 
 @functools.lru_cache(maxsize=None)
 def _insert_rows_program(mesh: Mesh, spec: HashShardingSpec,
-                         slot_names: tuple, in_slot_names: tuple):
+                         slot_names: tuple, in_slot_names: tuple,
+                         record_stats: bool = False):
     """Cached jitted insert program: the checkpoint loader streams many
     same-shaped chunks, and rebuilding the shard_map closure per chunk would
     retrace every call."""
@@ -258,7 +259,8 @@ def _insert_rows_program(mesh: Mesh, spec: HashShardingSpec,
         flat = k.reshape(-1, 2) if spec.wide else k.ravel()
         masked = _mask_non_owned(spec, flat, _my_shard(mesh, spec))
         new = hash_lib.insert_rows(local, masked, w, srows or None,
-                                   max_probes=spec.max_probes)
+                                   max_probes=spec.max_probes,
+                                   record_stats=record_stats)
         failed = lax.psum(new.insert_failures, spec.shard_axes)
         return new.keys, new.weights, new.slots, failed
 
@@ -289,7 +291,8 @@ def insert_rows_sharded(state: hash_lib.HashTableState,
     """
     slot_rows = slot_rows or {}
     fn = _insert_rows_program(mesh, spec, tuple(state.slots),
-                              tuple(slot_rows))
+                              tuple(slot_rows),
+                              observability.evaluate_performance())
     tkeys, tweights, tslots, failed = fn(
         state.keys, state.weights, state.slots, state.init_rng,
         keys, weights, slot_rows)
@@ -301,7 +304,8 @@ def insert_rows_sharded(state: hash_lib.HashTableState,
 
 @functools.lru_cache(maxsize=None)
 def _insert_packed_program(mesh: Mesh, spec: HashShardingSpec,
-                           dim: int, layout: tuple):
+                           dim: int, layout: tuple,
+                           record_stats: bool = False):
     """Jitted insert taking ONE packed f32 buffer instead of the
     keys/weights/slots pytree: column 0 carries int32 keys bitcast to
     f32, columns [1, 1+dim) the weight row, the rest each slot's row
@@ -324,7 +328,8 @@ def _insert_packed_program(mesh: Mesh, spec: HashShardingSpec,
                  for name, s, c, shape in layout}
         masked = _mask_non_owned(spec, k, _my_shard(mesh, spec))
         new = hash_lib.insert_rows(local, masked, w, srows or None,
-                                   max_probes=spec.max_probes)
+                                   max_probes=spec.max_probes,
+                                   record_stats=record_stats)
         failed = lax.psum(new.insert_failures, spec.shard_axes)
         return new.keys, new.weights, new.slots, failed
 
@@ -350,7 +355,8 @@ def insert_rows_sharded_packed(state: hash_lib.HashTableState,
     if spec.wide:
         raise ValueError("packed insert supports int32-key tables only")
     dim = state.weights.shape[-1]
-    fn = _insert_packed_program(mesh, spec, dim, layout)
+    fn = _insert_packed_program(mesh, spec, dim, layout,
+                                observability.evaluate_performance())
     tkeys, tweights, tslots, failed = fn(
         state.keys, state.weights, state.slots, state.init_rng, packed)
     return hash_lib.HashTableState(
@@ -524,7 +530,8 @@ def _apply_program(mesh: Mesh, spec: HashShardingSpec,
                 new = hash_lib.apply_gradients(
                     cur, optimizer, initializer, masked, grads,
                     dedup_capacity=dedup_capacity,
-                    max_probes=spec.max_probes, in_counts=counts)
+                    max_probes=spec.max_probes, in_counts=counts,
+                    record_stats=record_stats)
                 return (new.keys, new.weights, new.slots,
                         fails + new.insert_failures)
 
@@ -616,7 +623,8 @@ def _apply_program(mesh: Mesh, spec: HashShardingSpec,
                 insert_failures=jnp.zeros((), jnp.int32))
             new = hash_lib.apply_gradients(
                 local, optimizer, initializer, flat, g2,
-                dedup_capacity=dedup_capacity, max_probes=spec.max_probes)
+                dedup_capacity=dedup_capacity, max_probes=spec.max_probes,
+                record_stats=record_stats)
             # per-shard failure deltas -> replicated global total
             failed = lax.psum(new.insert_failures, spec.model_axis)
             return new.keys, new.weights, new.slots, failed

@@ -6,6 +6,8 @@ matrix) — ground truth here is a Python dict replica updated with the same
 deterministic rules, plus single-vs-sharded cross-checks.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -325,3 +327,191 @@ def test_wide_keys_insert_rows_and_find():
     assert len(set(np.asarray(slots).tolist())) == 3
     got = np.asarray(ht.pull(t, pairs, None))
     np.testing.assert_allclose(got, np.asarray(w), rtol=1e-6)
+
+
+# --- two-phase find_or_insert against the full-width level loop -------------
+
+TP_N = 2048                     # keys a call; insert_width(TP_N) == 1024
+TP_CAPACITY = 1 << 14           # 128 buckets, a chain of two
+TP_BUCKET = 40                  # the start bucket the contended cases fill
+
+
+def _level_loop_oracle(table_keys, new_keys, valid, max_probes):
+    """The insert loop over ALL keys of the call, as find_or_insert ran it
+    before it found present keys first: the oracle of the cases below."""
+    capacity, n = table_keys.shape[0], new_keys.shape[0]
+    empty = ht.empty_key(table_keys.dtype)
+    wide = ht.is_wide(table_keys)
+    bsz, nb, chain = ht.table_layout(capacity, max_probes)
+    b0 = ht.probe_starts(new_keys, capacity, max_probes) // bsz
+    ids = jnp.arange(n, dtype=jnp.int32)
+    slot = jnp.full((n,), -1, jnp.int32)
+    done = ~valid
+    inserted = jnp.zeros((n,), bool)
+    for j in range(chain):
+        bj = b0 + j
+        start = bj * bsz
+        if wide:
+            rows = jnp.take(table_keys.reshape(nb, bsz, 2), bj, axis=0)
+            match = ((rows[..., 0] == new_keys[:, None, 0])
+                     & (rows[..., 1] == new_keys[:, None, 1]))
+            emptym = rows[..., 1] == empty
+        else:
+            rows = jnp.take(table_keys.reshape(nb, bsz), bj, axis=0)
+            match = rows == new_keys[:, None]
+            emptym = rows == empty
+        active = valid & ~done
+        hitm = active & jnp.any(match, axis=1)
+        slot = jnp.where(hitm, start + jnp.argmax(match, axis=1), slot)
+        done = done | hitm
+        active = active & ~hitm
+        bid = jnp.where(active, bj, nb)
+        order = jnp.argsort(bid, stable=True)
+        sorted_bid = bid[order]
+        seg = jnp.concatenate([
+            jnp.ones((1,), bool), sorted_bid[1:] != sorted_bid[:-1]])
+        group_start = jax.lax.cummax(jnp.where(seg, ids, 0))
+        rank = jnp.zeros((n,), jnp.int32).at[order].set(ids - group_start)
+        cum = jnp.cumsum(emptym, axis=1).astype(jnp.int32)
+        place = active & (rank < cum[:, -1])
+        tgt = jnp.argmax((cum == rank[:, None] + 1) & emptym, axis=1)
+        pslot = (start + tgt).astype(jnp.int32)
+        table_keys = table_keys.at[
+            jnp.where(place, pslot, capacity)].set(new_keys, mode="drop")
+        slot = jnp.where(place, pslot, slot).astype(jnp.int32)
+        done = done | place
+        inserted = inserted | place
+    return table_keys, slot, inserted, valid & ~done
+
+
+@functools.lru_cache(maxsize=None)
+def _tp_table(wide):
+    """(table keys holding ``present``, present [TP_N], fresh [TP_N],
+    contending [400]): distinct keys; the last all start at bucket
+    TP_BUCKET, whose chain holds 256."""
+    rng = np.random.RandomState(7)
+    if wide:
+        pool = np.unique(rng.randint(1, 2**62, size=80000, dtype=np.int64))
+    else:
+        pool = np.unique(rng.randint(1, 2**31 - 1, size=80000)
+                         .astype(np.int32))
+    rng.shuffle(pool)
+
+    def as_keys(k):
+        return jnp.asarray(ht.split64(k) if wide else k)
+
+    start = np.asarray(ht.probe_starts(as_keys(pool), TP_CAPACITY,
+                                       ht.DEFAULT_MAX_PROBES)) // ht.BUCKET
+    crowd = pool[start == TP_BUCKET][:400]
+    assert len(crowd) == 400
+    rest = pool[start != TP_BUCKET]
+    present = as_keys(rest[:TP_N])
+    shape = (TP_CAPACITY, 2) if wide else (TP_CAPACITY,)
+    table = jnp.full(shape, ht.empty_key(jnp.int32), jnp.int32)
+    table, slot, _, failed = _level_loop_oracle(
+        table, present, jnp.ones((TP_N,), bool), ht.DEFAULT_MAX_PROBES)
+    assert not bool(failed.any()) and bool((slot >= 0).all())
+    return table, present, as_keys(rest[TP_N:2 * TP_N]), as_keys(crowd)
+
+
+def _tp_case(case, wide):
+    """(table keys holding the present keys, the call's keys, valid,
+    how many of them miss)."""
+    table, present, fresh, crowd = _tp_table(wide)
+    empty = ht.empty_key(jnp.int32)
+    m = ht.insert_width(TP_N)
+    assert m == 1024
+    misses = {"none_missing": 0, "five_percent": 102, "exactly_m": m,
+              "m_plus_1": m + 1, "all_missing": TP_N,
+              "bucket_overflow": 200, "window_full": 400}[case]
+    new = fresh[:misses]
+    if case == "bucket_overflow":   # 200 > the 128 slots of one bucket
+        new = crowd[:200]
+    if case == "window_full":       # 400 > the 256 slots of the chain
+        new = crowd
+    # the misses spread among the hits, one invalid key in the call
+    keys = np.asarray(present).copy()
+    at = np.random.RandomState(3).permutation(TP_N)[:misses]
+    keys[np.sort(at)] = np.asarray(new)
+    valid = np.ones((TP_N,), bool)
+    if misses < TP_N:
+        gap = np.setdiff1d(np.arange(TP_N), at)[5]
+        keys[gap] = empty
+        valid[gap] = False
+    return table, jnp.asarray(keys), jnp.asarray(valid), misses
+
+
+TP_CASES = ["none_missing", "five_percent", "exactly_m", "m_plus_1",
+            "all_missing", "bucket_overflow", "window_full"]
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["narrow", "wide"])
+@pytest.mark.parametrize("case", TP_CASES)
+def test_two_phase_find_or_insert_equals_level_loop(case, wide):
+    """Find first, insert the misses from a compacted buffer (or, past its
+    width, from the whole call): keys, slots, inserted and failed equal the
+    full-width loop's bit for bit whichever loop placed them."""
+    table, keys, valid, misses = _tp_case(case, wide)
+    want = _level_loop_oracle(table, keys, valid, ht.DEFAULT_MAX_PROBES)
+    got = jax.jit(ht.find_or_insert)(table, keys, valid)
+    for name, g, w in zip(("keys", "slot", "inserted", "failed"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    _, slot, inserted, failed = map(np.asarray, got)
+    assert int((inserted | failed).sum()) == misses
+    if case == "bucket_overflow":
+        level = (slot[inserted] // ht.BUCKET) - TP_BUCKET
+        assert set(level.tolist()) == {0, 1} and not failed.any()
+    if case == "window_full":
+        assert failed.sum() > 100 and (slot[failed] == -1).all()
+        state = ht.HashTableState(
+            keys=table, weights=jnp.zeros((TP_CAPACITY, DIM)), slots={},
+            init_rng=jax.random.PRNGKey(0),
+            insert_failures=jnp.asarray(3, jnp.int32))
+        state = jax.jit(ht.insert_rows)(state, keys,
+                                        jnp.ones((TP_N, DIM)))
+        assert int(state.insert_failures) == 3 + int(failed.sum())
+    else:
+        assert not failed.any()
+
+
+@pytest.mark.parametrize("case,compact,full", [
+    ("five_percent", 1, 0), ("exactly_m", 1, 0), ("m_plus_1", 0, 1),
+    ("all_missing", 0, 1)])
+def test_find_or_insert_counts_its_branch(case, compact, full):
+    from openembedding_tpu.utils import observability
+    table, keys, valid, misses = _tp_case(case, True)
+    fn = jax.jit(lambda t, k, v: ht.find_or_insert(t, k, v,
+                                                   record_stats=True))
+    observability.GLOBAL.reset()
+    observability.set_evaluate_performance(True)
+    try:
+        jax.block_until_ready(fn(table, keys, valid))
+        jax.effects_barrier()
+    finally:
+        observability.set_evaluate_performance(False)
+    got = observability.GLOBAL.snapshot()
+    observability.GLOBAL.reset()
+    assert {k: int(got.get(k, {}).get("count", 0)) for k in (
+        "hash_insert_compact", "hash_insert_full", "hash_insert_missed")
+    } == {"hash_insert_compact": compact, "hash_insert_full": full,
+          "hash_insert_missed": misses}
+
+
+def test_find_or_insert_default_program_has_no_host_callback():
+    from openembedding_tpu.analysis import contracts
+    table, keys, valid, _ = _tp_case("five_percent", True)
+    default = jax.jit(ht.find_or_insert).lower(
+        table, keys, valid).compile().as_text()
+    contracts.check_no_host_transfers(default)
+    # the find and the two insert loops, chosen by what they are given to
+    # do and by no conditional (which would copy the key array)
+    assert default.count(" while(") == 3 and " conditional(" not in default
+    recording = jax.jit(lambda t, k, v: ht.find_or_insert(
+        t, k, v, record_stats=True)).lower(
+            table, keys, valid).compile().as_text()
+    assert contracts.host_transfer_ops(recording) == ["host-callback"] * 3
+    # a call no wider than the buffer is the loop alone
+    small = jax.jit(ht.find_or_insert).lower(
+        table, keys[:1024], valid[:1024]).compile().as_text()
+    assert small.count(" while(") == 1

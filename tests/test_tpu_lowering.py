@@ -115,6 +115,31 @@ def test_v5e_step_keeps_its_stage_names(v5e, use_hash):
     assert named >= 0.9 * len(fusions), (named, len(fusions))
 
 
+@pytest.mark.parametrize("shape", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
+def test_v5e_hash_push_finds_then_inserts_in_place(v5e, shape):
+    """Each table's push holds three loops under ``probe``: the find, the
+    insert loop over the buffer of misses and the one over the whole call.
+    Which of the two places keys is decided by what each is given, under
+    no conditional of the probe's own: through one the chip's compiler
+    copies the key array, and on one chip no copy of it is left."""
+    data, model = shape
+    mesh = create_mesh(data, model, v5e[:data * model])
+    hlo = _compile_deepfm_step(mesh, use_hash=True).as_text()
+    stages = stage_reduce.instruction_stages(hlo)
+    found = [m.groups() for m in map(_OPCODE.match, hlo.splitlines()) if m]
+    loops = [inst for inst, op in found
+             if op == "while" and stages.get(inst) == "probe"]
+    assert loops and len(loops) % 6 == 0, loops     # two tables
+    assert not re.search(r'conditional\(.*op_name="[^"]*jit\(probe\)/cond',
+                         hlo)
+    if mesh.size == 1:
+        assert len(loops) == 6, loops
+        keys = f"s32[{chip_smoke.HASH_CAPACITY},2]"
+        copies = [line.strip()[:120] for line in hlo.splitlines()
+                  if f"= {keys}" in line and " copy(" in line]
+        assert not copies, copies
+
+
 def _on(dev, shape, dtype):
     mesh = create_mesh(1, 1, [dev])
     return jax.ShapeDtypeStruct(shape, dtype,
