@@ -832,7 +832,7 @@ def apply_delta_to_states(collection: EmbeddingCollection,
                     "category-swap; load the base full or re-save")
             state = _apply_hash_payload(collection, name, state, payload,
                                         shard_slice=shard_slice,
-                                        with_opt=with_opt)
+                                        with_opt=with_opt, donate=donate)
         else:
             if spec.use_hash:
                 raise ValueError(
@@ -929,7 +929,7 @@ def _apply_array_payload(collection, name, state, payload, *,
 
 
 def _apply_hash_payload(collection, name, state, payload, *,
-                        shard_slice, with_opt):
+                        shard_slice, with_opt, donate=True):
     sspec = collection.sharding_spec(name)
     keys = np.asarray(payload["keys"])
     key_dtype = np.dtype(state.keys.dtype)
@@ -971,7 +971,7 @@ def _apply_hash_payload(collection, name, state, payload, *,
             srows[sname] = jnp.asarray(cs)
         state = sh.insert_rows_sharded(
             state, jnp.asarray(ck), jnp.asarray(cw), srows,
-            mesh=collection.mesh, spec=sspec)
+            mesh=collection.mesh, spec=sspec, donate=donate)
     grew = int(jax.device_get(state.insert_failures - before))
     if grew > 0:
         raise RuntimeError(

@@ -29,11 +29,20 @@ Protocol mapping:
   DRAM + files replace libpmemobj).
 * ``restore(dir)``  ≈ load_pmem_pool (:191-201): base + increments replayed
   newest-wins.
+
+Two more public calls of :class:`ShardedOffloadedTable`, for a table whose
+rows are known before training starts: ``load_rows(ids, weights,
+slot_rows)`` writes rows (and their optimizer slots) into the host store
+by id range or id array (``restore`` goes through it), and ``warm(cache,
+ids)`` makes a set of ids cache-resident in bulk chunks, the books updated
+as a step's ``apply_prepared`` updates them. Inserts update the cache in
+place (the table operands are donated): use the state a call returns.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import threading
 from typing import Any, Dict, Optional
@@ -55,6 +64,14 @@ from . import table as table_lib
 
 OFFLOAD_META_FILE = "offload_meta"
 COMPACT_CHAIN_LEN = 8   # rebase the incremental chain past this many entries
+STEP_CHUNK = 1 << 16    # most keys one between-steps insert call takes
+BULK_CHUNK = 1 << 21    # keys per call of a bulk insert (warm, eviction)
+WRITEBACK_CHUNK = 1 << 17       # rows one write-back read fetches
+WRITEBACK_ASYNC_ROWS = 1 << 22  # most rows read ahead of the writer thread
+
+
+def _pow2_ceil(n: int) -> int:
+    return 1 << max(5, (max(int(n), 1) - 1).bit_length())
 
 
 def _persist_store(path: str, *, vocab: int, meta: EmbeddingVariableMeta,
@@ -158,11 +175,20 @@ def _gc_orphans(path: str, chain) -> int:
     return n
 
 
-def _replay_store(path: str, *, vocab: int, host_weights: np.ndarray,
-                  host_slots: Dict[str, np.ndarray],
-                  host_work_id: np.ndarray) -> int:
-    """Shared restore: replay base + increments (newest wins by order).
-    Returns the highest persisted work id. Orphan files newer than the
+def _store_rows(table, ids, weights, slot_rows, work_id) -> None:
+    """Write rows (and the optimizer slots given) into ``table``'s host
+    store at ``ids`` (a slice or an id array), stamped ``work_id``."""
+    table.host_weights[ids] = weights
+    for sname in table.host_slots:
+        if sname in slot_rows:
+            table.host_slots[sname][ids] = slot_rows[sname]
+    table.host_work_id[ids] = work_id
+
+
+def _replay_store(path: str, *, vocab: int, load) -> int:
+    """Shared restore: replay base + increments (newest wins by order)
+    through ``load(ids, weights, slot_rows, work_id)``, the store's row
+    loader. Returns the highest persisted work id. Orphan files newer than the
     committed meta (the debris of a kill mid-persist) are simply IGNORED —
     only the meta's chain is ever read; the next persist (the directory's
     single writer) garbage-collects them."""
@@ -173,11 +199,9 @@ def _replay_store(path: str, *, vocab: int, host_weights: np.ndarray,
     max_work = 0
     for entry in meta["checkpoints"]:
         data = np.load(fs.open_file(fs.join(path, entry["file"]), "rb"))
-        ids = data["ids"]
-        host_weights[ids] = data["weights"]
-        for sname in host_slots:
-            host_slots[sname][ids] = data[f"slot_{sname}"]
-        host_work_id[ids] = data["work_id"]
+        load(data["ids"], data["weights"],
+             {k[len("slot_"):]: data[k] for k in data.files
+              if k.startswith("slot_")}, data["work_id"])
         max_work = max(max_work, int(entry["work_id"]))
     return max_work
 
@@ -327,9 +351,8 @@ class HostOffloadedTable:
 
     def restore(self, path: str) -> None:
         """Replay base + increments (newest wins by construction)."""
-        max_work = _replay_store(
-            path, vocab=self.vocab, host_weights=self.host_weights,
-            host_slots=self.host_slots, host_work_id=self.host_work_id)
+        max_work = _replay_store(path, vocab=self.vocab,
+                                 load=functools.partial(_store_rows, self))
         # keep the watermark monotonic for an in-place restore of a table
         # that has trained past the checkpoint
         self.work_id = max(self.work_id, max_work + 1)
@@ -359,6 +382,8 @@ class PreparedBatch:
     slot_rows: Dict[str, np.ndarray]      # host_slots[*][missing]
     needs_evict: bool = False
     gen: int = 0                          # residency generation stamp
+    lookups: int = 0                      # ids the batch held, duplicates
+                                          # included: sizes the insert
 
 
 class ShardedOffloadedTable:
@@ -380,14 +405,16 @@ class ShardedOffloadedTable:
       probing the device — the reference tracks the same facts in its DRAM
       index (PmemEmbeddingTable.h:143-163);
     * overflow evicts the **least-recently-touched batch** (default: down
-      to half capacity), not the whole cache: the cache is streamed to the
-      host once, dirty rows written back, and the still-hot survivors are
-      re-inserted (the reference's LRU eviction, :382-395);
-    * writeback is **asynchronous**: device->host copies are launched with
-      ``copy_to_host_async`` and a writer thread filters + scatters them
-      into the host store while training continues (the VariableAsyncTask
-      role, variable/VariableAsyncTask.h:12-78). ``prepare``/``persist``
-      join the writer before reading host rows.
+      to half capacity), not the whole cache: dirty rows are written
+      back, the cache is emptied in its own buffers, and the still-hot
+      survivors are re-inserted in bulk (the reference's LRU eviction,
+      :382-395);
+    * writeback is **asynchronous** and copies what is dirty, not the
+      table: the device gathers the dirty rows (``read_rows_sharded``),
+      the copies are launched with ``copy_to_host_async`` and a writer
+      thread scatters them into the host store while training continues
+      (the VariableAsyncTask role, variable/VariableAsyncTask.h:12-78).
+      ``prepare``/``persist`` join the writer before reading host rows.
 
     The work_id watermark + incremental base/delta persistence protocol is
     unchanged from :class:`HostOffloadedTable` (the ICDE'23 checkpoint
@@ -450,15 +477,16 @@ class ShardedOffloadedTable:
 
         # host store, eagerly initialized in bounded chunks (a table bigger
         # than HBM must not be materialized on device either)
-        rng = jax.random.PRNGKey(seed)
         from .optim import initializers as init_lib
         if isinstance(self.initializer, init_lib.Constant):
             # constant init fills host-side: the chunked device path would
             # push the whole store through device transfers to compute a
-            # constant
+            # constant. Nothing is put on the device here, not even a
+            # PRNG key: a caller can place its caches first
             self.host_weights = _alloc("weights", (self.vocab, dim), dtype,
                                        fill=self.initializer.value)
         else:
+            rng = jax.random.PRNGKey(seed)
             self.host_weights = _alloc("weights", (self.vocab, dim), dtype)
             chunk = max(1, (64 << 20) // max(1, dim * dtype.itemsize))
             for lo in range(0, self.vocab, chunk):
@@ -593,44 +621,70 @@ class ShardedOffloadedTable:
             raise RuntimeError("async writeback failed") from err
 
     def _start_writeback(self, cache, dirty_ids: np.ndarray) -> None:
-        """Launch device->host copy of the cache + background scatter of
-        ``dirty_ids`` rows into the host store."""
+        """Copy the ``dirty_ids`` rows of the cache into the host store:
+        the device gathers just those rows (``read_rows_sharded``, in
+        calls of ``WRITEBACK_CHUNK`` keys), so what crosses to the host is
+        what is dirty and not the table. The reads are dispatched here,
+        ahead of whatever the caller does to the cache next; a writer
+        thread fetches and scatters them while training continues. A set
+        too large to hold on the device at once (an eviction after a long
+        run without a flush) is written back chunk by chunk before this
+        returns."""
+        from .parallel import sharded_hash as sh
         self._join_writeback()
         # an async persist is READING host rows; the scatter below is the
         # only host-row writer — wait until the snapshot is on disk
         self._join_persist()
-        arrays = {"keys": cache.keys, "weights": cache.weights,
-                  **{f"slot_{k}": v for k, v in cache.slots.items()}}
-        for a in arrays.values():
-            for shard in a.addressable_shards:
-                shard.data.copy_to_host_async()
+        if not dirty_ids.size:
+            return
         work = self.work_id
+        key_dtype = np.dtype(cache.keys.dtype)
+        size = min(WRITEBACK_CHUNK, _pow2_ceil(dirty_ids.size))
+
+        def read(lo):
+            sub = dirty_ids[lo:lo + size]
+            keys = np.full((size,), hash_lib.empty_key(key_dtype), key_dtype)
+            keys[:sub.size] = sub
+            out = sh.read_rows_sharded(cache, jnp.asarray(keys),
+                                       mesh=self.mesh, spec=self.spec)
+            for leaf in jax.tree.leaves(out):
+                leaf.copy_to_host_async()
+            return sub, out
+
+        def store(sub, out):
+            found, rows, srows = jax.device_get(out)
+            found = np.asarray(found[:sub.size])
+            # an id the cache does not hold (never inserted: out of the
+            # budget's reach, or dropped by a rebuild) has nothing to write
+            ids = sub[found]
+            sync_point("offload.writeback.scatter")
+            if ids.size:
+                self.host_weights[ids] = rows[:sub.size][found]
+                for sname in self.host_slots:
+                    self.host_slots[sname][ids] = \
+                        srows[sname][:sub.size][found]
+                self.host_work_id[ids] = work
+
+        starts = range(0, dirty_ids.size, size)
+        if dirty_ids.size > WRITEBACK_ASYNC_ROWS:
+            with scope.span("offload.writeback", table=self.name):
+                ahead = read(0)
+                for lo in starts:
+                    now, ahead = ahead, (read(lo + size)
+                                         if lo + size < dirty_ids.size
+                                         else None)
+                    store(*now)
+            with self._book:
+                self._dirty.clear_chunks(dirty_ids)
+            return
+        pending = [read(lo) for lo in starts]
 
         def _run():
             try:
                 sync_point("offload.writeback.run")
                 with scope.span("offload.writeback", table=self.name):
-                    host = {k: np.asarray(jax.device_get(v))
-                            for k, v in arrays.items()}
-                    keys = host["keys"]
-                    # the jitted step auto-inserts whatever batch keys it
-                    # sees; out-of-range ids must not index the vocab-sized
-                    # host store (negative would alias a real row — silent
-                    # corruption)
-                    live = (keys != hash_lib.empty_key(keys.dtype)) \
-                        & (keys >= 0) & (keys < self.vocab)
-                    ids = keys[live]
-                    mask = np.zeros(self.vocab, bool)
-                    mask[dirty_ids] = True
-                    sel = mask[ids]
-                    ids = ids[sel]
-                    sync_point("offload.writeback.scatter")
-                    if ids.size:
-                        self.host_weights[ids] = host["weights"][live][sel]
-                        for sname in self.host_slots:
-                            self.host_slots[sname][ids] = \
-                                host[f"slot_{sname}"][live][sel]
-                        self.host_work_id[ids] = work
+                    for sub, out in pending:
+                        store(sub, out)
             except BaseException as e:  # noqa: BLE001 — re-raised at join
                 # _writer_err_dirty re-marks the rows AT THE JOIN (see
                 # __init__: the writer must not take _book itself)
@@ -675,66 +729,87 @@ class ShardedOffloadedTable:
             col += cols
         return dim, col, tuple(layout)
 
+    def _step_insert_size(self, lookups: int, misses: int) -> int:
+        """Padded size of the insert between two steps. The program
+        compiles once a size, and miss counts differ from step to step, so
+        a table has ONE size while a step's misses fit it: the largest
+        power of two in an eighth of the batch's lookups (a batch of the
+        same shape gives the same size whatever it misses). A step that
+        misses more (a cold cache, the batch after an eviction) takes the
+        second size, ``STEP_CHUNK``, in as many calls as it needs."""
+        small = min(STEP_CHUNK, max(32, 1 << (max(lookups // 8, 1)
+                                              .bit_length() - 1)))
+        return small if misses <= small else STEP_CHUNK
+
     def _insert_rows(self, cache, ids: np.ndarray, rows: np.ndarray,
-                     slot_rows: Dict[str, np.ndarray]):
-        """Device half of an insert: pre-gathered host rows -> HBM cache.
+                     slot_rows: Dict[str, np.ndarray], size: int):
+        """Device half of an insert: pre-gathered host rows -> HBM cache,
+        in calls of ``size`` keys each (the last one padded with EMPTY
+        keys, which the insert skips). The cache is updated in place: the
+        program donates keys, weights and slots.
 
         The payload ships as ONE packed f32 buffer per chunk (keys bitcast
         into column 0) when dtypes allow — the per-step transfer count is
         a measured cost on high-latency links (`python -m tools.offload_diag puts`) —
         with the generic per-array path as the fallback."""
         from .parallel import sharded_hash as sh
-        chunk = 1 << 16
         key_dtype = np.dtype(cache.keys.dtype)
         packed_fmt = self._packed_layout(key_dtype)
-        for lo in range(0, ids.size, chunk):
-            sub = ids[lo:lo + chunk]
-            # pad to the next power of two: miss counts are data-dependent
-            # and the jitted insert program compiles per shape — a handful
-            # of bucket sizes instead of one compile per distinct count
-            size = 1 << max(5, int(np.ceil(np.log2(max(2, sub.size)))))
-            size = min(size, chunk)
-            if packed_fmt is not None:
-                dim, total_cols, layout = packed_fmt
-                buf = np.zeros((size, total_cols), np.float32)
-                kcol = np.full((size,), hash_lib.empty_key(np.int32),
-                               np.int32)
-                kcol[:sub.size] = sub
-                buf[:, 0] = kcol.view(np.float32)
-                buf[:sub.size, 1:1 + dim] = \
-                    rows[lo:lo + chunk].reshape(sub.size, dim)
-                for sname, start, cols, _shape in layout:
-                    buf[:sub.size, start:start + cols] = \
-                        slot_rows[sname][lo:lo + chunk].reshape(
-                            sub.size, cols)
-                cache = sh.insert_rows_sharded_packed(
-                    cache, jnp.asarray(buf), layout,
-                    mesh=self.mesh, spec=self.spec)
-                continue
-            ck = np.full((size,), hash_lib.empty_key(key_dtype), key_dtype)
-            ck[:sub.size] = sub
-            cw = np.zeros((size,) + self.host_weights.shape[1:],
-                          self.host_weights.dtype)
-            cw[:sub.size] = rows[lo:lo + chunk]
-            srows = {}
-            for sname, arr in self.host_slots.items():
-                cs = np.zeros((size,) + arr.shape[1:], arr.dtype)
-                cs[:sub.size] = slot_rows[sname][lo:lo + chunk]
-                srows[sname] = jnp.asarray(cs)
-            cache = sh.insert_rows_sharded(
-                cache, jnp.asarray(ck), jnp.asarray(cw), srows,
-                mesh=self.mesh, spec=self.spec)
-        # DEFER the overflow readback: ``insert_failures`` is CUMULATIVE
-        # (hash_table.py:494, psum-merged across shards,
-        # sharded_hash.py:214), so the latest copy subsumes every earlier
-        # one — keep exactly one independent buffer (the jitted step
-        # donates the cache pytree, deleting its buffers) and read it
-        # ONLY at join points (flush/persist/restore/finish). Any
-        # per-step read — even of a counter copied steps earlier, even
-        # with ``copy_to_host_async`` primed — costs a synchronous device
-        # round trip; one per table per step serializes the tier
-        # (`python -m tools.offload_diag pipeline`).
-        self._overflow_latest = cache.insert_failures + jnp.int32(0)
+        h2d_bytes = 0
+        for lo in range(0, ids.size, size):
+            sub = ids[lo:lo + size]
+            # the host's own work of an insert (pack, start the copy) and
+            # the program's call are timed apart: with a step running, the
+            # first program call after it waits inside the runtime until
+            # that step is done (PERF.md, PR 28), and that is no host work
+            with scope.span("offload.insert_pack", table=self.name):
+                if packed_fmt is not None:
+                    dim, total_cols, layout = packed_fmt
+                    buf = np.zeros((size, total_cols), np.float32)
+                    kcol = np.full((size,), hash_lib.empty_key(np.int32),
+                                   np.int32)
+                    kcol[:sub.size] = sub
+                    buf[:, 0] = kcol.view(np.float32)
+                    buf[:sub.size, 1:1 + dim] = \
+                        rows[lo:lo + size].reshape(sub.size, dim)
+                    for sname, start, cols, _shape in layout:
+                        buf[:sub.size, start:start + cols] = \
+                            slot_rows[sname][lo:lo + size].reshape(
+                                sub.size, cols)
+                    h2d_bytes += buf.nbytes
+                    args = (jnp.asarray(buf), layout)
+                else:
+                    ck = np.full((size,), hash_lib.empty_key(key_dtype),
+                                 key_dtype)
+                    ck[:sub.size] = sub
+                    cw = np.zeros((size,) + self.host_weights.shape[1:],
+                                  self.host_weights.dtype)
+                    cw[:sub.size] = rows[lo:lo + size]
+                    h2d_bytes += ck.nbytes + cw.nbytes
+                    srows = {}
+                    for sname, arr in self.host_slots.items():
+                        cs = np.zeros((size,) + arr.shape[1:], arr.dtype)
+                        cs[:sub.size] = slot_rows[sname][lo:lo + size]
+                        h2d_bytes += cs.nbytes
+                        srows[sname] = jnp.asarray(cs)
+                    args = (jnp.asarray(ck), jnp.asarray(cw), srows)
+            # DEFER the overflow readback: ``insert_failures`` is CUMULATIVE
+            # (hash_table.py:494, psum-merged across shards,
+            # sharded_hash.py:214), so the latest copy subsumes every earlier
+            # one — keep exactly one independent buffer (the jitted step
+            # donates the cache pytree, deleting its buffers) and read it
+            # ONLY at join points (flush/persist/restore/finish). Any
+            # per-step read — even of a counter copied steps earlier, even
+            # with ``copy_to_host_async`` primed — costs a synchronous device
+            # round trip; one per table per step serializes the tier
+            # (`python -m tools.offload_diag pipeline`).
+            with scope.span("offload.insert_dispatch", table=self.name):
+                insert = sh.insert_rows_sharded_packed \
+                    if packed_fmt is not None else sh.insert_rows_sharded
+                cache = insert(cache, *args, mesh=self.mesh, spec=self.spec)
+                self._overflow_latest = cache.insert_failures + jnp.int32(0)
+        scope.HISTOGRAMS.inc("offload_h2d_bytes", h2d_bytes,
+                             table=self.name)
         return cache
 
     def check_overflow(self, cache=None) -> None:
@@ -778,11 +853,21 @@ class ShardedOffloadedTable:
                 "occupancy_threshold")
 
     def _insert_from_host(self, cache, ids: np.ndarray):
-        rows, srows = self._gather_host(ids)
-        return self._insert_rows(cache, ids, rows, srows)
+        """Bulk insert of host rows: ``BULK_CHUNK`` keys a call (one size
+        for the whole set), each chunk gathered as it goes so that the
+        host holds one chunk's rows at a time."""
+        size = min(BULK_CHUNK, _pow2_ceil(ids.size))
+        for lo in range(0, ids.size, size):
+            sub = ids[lo:lo + size]
+            rows, srows = self._gather_host(sub)
+            cache = self._insert_rows(cache, sub, rows, srows, size)
+        return cache
 
-    def host_prepare(self, ids) -> PreparedBatch:
+    def host_prepare(self, ids, *, lookups: Optional[int] = None
+                     ) -> PreparedBatch:
         """Host-only half of :meth:`prepare`: residency math + host gather.
+        (``lookups``: the size of the batch these ids came from, where
+        ``ids`` is already its unique set — a recomputed prepare.)
 
         Misses are computed against ``resident OR planned``, and the
         result's own misses are marked PLANNED before returning — so a
@@ -800,7 +885,10 @@ class ShardedOffloadedTable:
         :meth:`check_overflow`; per-step reads would serialize the
         pipeline on a device round trip per table).
         """
-        ids = np.unique(np.asarray(ids).ravel())
+        ids = np.asarray(ids).ravel()
+        if lookups is None:
+            lookups = int(ids.size)
+        ids = np.unique(ids)
         ids = ids[(ids >= 0) & (ids < self.vocab)]
         budget = int(self.occupancy_threshold * self.cache_capacity)
         while True:
@@ -812,21 +900,33 @@ class ShardedOffloadedTable:
                     # eviction rebuilds the cache (sync path); no gather
                     return PreparedBatch(uniq=ids, missing=missing,
                                          rows=None, slot_rows={},
-                                         needs_evict=True, gen=gen)
+                                         needs_evict=True, gen=gen,
+                                         lookups=lookups)
             # gather OUTSIDE the lock (large memmap reads; safe — missing
             # rows are neither resident nor planned, so neither writeback
             # nor eviction touches them)
             rows, srows = self._gather_host(missing)
             with self._book:
                 if self._gen != gen:
-                    self.gen_retries += 1
+                    self._count_gen_retry()
                     continue  # evicted under the gather; recompute
                 # mark AFTER the gather succeeded — a failed prepare
                 # leaks nothing
                 self._planned[missing] = True
                 self._planned_count += int(missing.size)
+            # counted here, on whichever thread prepares: the step's
+            # critical path carries no counter of the tier but the bytes
+            # it copies
+            scope.HISTOGRAMS.inc("offload_unique_rows", ids.size,
+                                 table=self.name)
+            scope.HISTOGRAMS.inc("offload_miss_rows", missing.size,
+                                 table=self.name)
             return PreparedBatch(uniq=ids, missing=missing, rows=rows,
-                                 slot_rows=srows, gen=gen)
+                                 slot_rows=srows, gen=gen, lookups=lookups)
+
+    def _count_gen_retry(self) -> None:
+        self.gen_retries += 1
+        scope.HISTOGRAMS.inc("offload_gen_retries", table=self.name)
 
     def cancel_prepared(self, prep: PreparedBatch) -> None:
         """Release a prepared batch that will never be applied (the
@@ -843,7 +943,11 @@ class ShardedOffloadedTable:
         Falls back to the synchronous evict path when the batch overflows
         the budget, and recomputes stale prepares (an eviction between
         prepare and apply rebuilt the cache). Returns the updated cache
-        state."""
+        state; the one passed in is donated to the insert."""
+        with scope.span("offload.apply_prepared", table=self.name):
+            return self._apply_prepared(cache, prep)
+
+    def _apply_prepared(self, cache, prep: PreparedBatch):
         with self._book:
             # needs_evict prepares are NOT exempt: after the first evict
             # of an overflow episode, the rest of the lookahead window's
@@ -864,10 +968,10 @@ class ShardedOffloadedTable:
                 self._gen += 1
                 self._planned[:] = False
                 self._planned_count = 0
-                self.gen_retries += 1
-                inner = self.host_prepare(prep.uniq)
+                self._count_gen_retry()
+                inner = self.host_prepare(prep.uniq, lookups=prep.lookups)
                 try:
-                    return self.apply_prepared(cache, inner)
+                    return self._apply_prepared(cache, inner)
                 except BaseException:
                     # the INNER prep holds the live planned marks (the
                     # caller only knows the stale outer prep, whose
@@ -912,7 +1016,9 @@ class ShardedOffloadedTable:
         if missing.size == 0:
             return cache
         try:
-            return self._insert_rows(cache, missing, rows, slot_rows)
+            return self._insert_rows(
+                cache, missing, rows, slot_rows,
+                self._step_insert_size(prep.lookups, missing.size))
         except BaseException:
             # unwind the optimistic marks to the pre-apply state: a caller
             # that survives the error (retry loop) must not find the books
@@ -935,15 +1041,90 @@ class ShardedOffloadedTable:
         ``apply_prepared``.)"""
         return self.apply_prepared(cache, self.host_prepare(ids))
 
+    def _cleared(self, cache):
+        """``cache`` with every slot free, as :meth:`create_cache` makes
+        one (keys EMPTY, weights 0, slots at their start), written into
+        its own donated buffers."""
+        empty = hash_lib.empty_key(np.dtype(cache.keys.dtype))
+        starts = {k: self.optimizer.slot_init(k) for k in cache.slots}
+
+        def cleared(keys, weights, slots):
+            return (jnp.full_like(keys, empty), jnp.zeros_like(weights),
+                    {k: jnp.full_like(v, starts[k])
+                     for k, v in slots.items()})
+
+        table = (cache.keys, cache.weights, cache.slots)
+        # keep_unused: the fills read nothing of the operands, and an
+        # operand jit drops is not donated: a second cache would be made
+        keys, weights, slots = jax.jit(
+            cleared, donate_argnums=(0, 1, 2), keep_unused=True,
+            out_shardings=jax.tree.map(lambda a: a.sharding, table))(*table)
+        return cache.replace(keys=keys, weights=weights, slots=slots,
+                             insert_failures=cache.insert_failures * 0)
+
+    def load_rows(self, ids, weights, slot_rows=None) -> None:
+        """Write known rows into the host store: ``ids`` is a range
+        ``slice(lo, hi)`` (one contiguous copy a call: the way to fill a
+        store of 10^8 rows chunk by chunk) or an array of row ids;
+        ``weights`` ``[n, dim]`` and ``slot_rows`` ``{slot: [n, ...]}``
+        their rows (a slot left out keeps what it holds). The rows are
+        stamped with the current ``work_id``, so the next ``persist``
+        carries them. A row that is resident in the cache, or planned
+        into it, would be read back stale: refused. ``restore`` replays
+        its files through the same writer."""
+        self._join_writeback()
+        self._join_persist()
+        with self._book:
+            if self._resident[ids].any() or self._planned[ids].any():
+                raise ValueError(
+                    f"offloaded table {self.name!r}: load_rows over rows "
+                    "the cache holds; load before warming, or restore")
+        _store_rows(self, ids, weights, slot_rows or {}, self.work_id)
+
+    def warm(self, cache, ids):
+        """Make ``ids`` cache-resident in bulk: the state a cache is in
+        between two evictions, reached in ``BULK_CHUNK`` keys a call
+        instead of a step's misses at a time. The books move as
+        :meth:`apply_prepared` moves them (resident marks and count, last
+        touch); nothing is evicted: a set that does not fit the budget is
+        refused. Returns the updated cache state."""
+        ids = np.unique(np.asarray(ids).ravel())
+        ids = ids[(ids >= 0) & (ids < self.vocab)]
+        self._join_writeback()
+        with self._book:
+            missing = ids[~(self._resident[ids] | self._planned[ids])]
+            budget = int(self.occupancy_threshold * self.cache_capacity)
+            if self._resident_count + self._planned_count \
+                    + missing.size > budget:
+                raise ValueError(
+                    f"offloaded table {self.name!r}: warming "
+                    f"{missing.size} rows passes the cache's budget of "
+                    f"{budget}")
+            self._resident[missing] = True
+            self._resident_count += int(missing.size)
+        self._last_touch[ids] = self.work_id
+        if missing.size == 0:
+            return cache
+        try:
+            with scope.span("offload.warm", table=self.name):
+                return self._insert_from_host(cache, missing)
+        except BaseException:
+            with self._book:    # the books must not claim rows never sent
+                self._resident[missing] = False
+                self._resident_count -= int(missing.size)
+            raise
+
     def _evict(self, cache, protect: np.ndarray, budget: int,
                incoming: int):
         """LRU-batch eviction: write back dirty rows, keep the hottest
         survivors, rebuild the cache with them (open-addressing tables
-        never delete, so eviction = writeback + rebuild-from-host)."""
+        never delete, so eviction = writeback + rebuild-from-host). The
+        rebuilt cache is the old one's buffers, emptied in place: a second
+        cache beside the first does not fit a chip the first fills."""
         sync_point("offload.evict")
         with scope.span("offload.evict", table=self.name):
             self._join_writeback()
-            # eviction DISCARDS the cache (create_cache zeroes the
+            # eviction DISCARDS the cache (_cleared zeroes the
             # cumulative insert_failures) — read the pending overflow
             # evidence from the LIVE counter first (the _overflow_latest
             # copy misses failures the jitted step accumulated after the
@@ -969,7 +1150,7 @@ class ShardedOffloadedTable:
             dirty_ids = resident_ids[self._dirty.mask_rows(resident_ids)]
             self._start_writeback(cache, dirty_ids)
             self._join_writeback()
-            cache = self.create_cache(jax.random.PRNGKey(int(self.work_id)))
+            cache = self._cleared(cache)
             self._resident[:] = False
             self._resident_count = 0
             # invalidate every in-flight prepare: their miss sets were
@@ -978,6 +1159,7 @@ class ShardedOffloadedTable:
             self._planned[:] = False
             self._planned_count = 0
             self.evictions += 1
+            scope.HISTOGRAMS.inc("offload_evictions", table=self.name)
             if keep.size:
                 cache = self._insert_from_host(cache, np.sort(keep))
                 self._resident[keep] = True
@@ -996,13 +1178,14 @@ class ShardedOffloadedTable:
         amortized over N steps) so hand-driven loops and ``fit()``
         without ``persist_dir`` detect an HBM-cache insert overflow
         within N steps instead of only at ``finish()``."""
-        if uniq is None:
-            uniq = np.unique(np.asarray(ids).ravel())
-            uniq = uniq[(uniq >= 0) & (uniq < self.vocab)]
-        with self._book:
-            self._dirty.mark_rows(uniq)
-        self.work_id += 1
-        self._batches_since_persist += 1
+        with scope.span("offload.note_update", table=self.name):
+            if uniq is None:
+                uniq = np.unique(np.asarray(ids).ravel())
+                uniq = uniq[(uniq >= 0) & (uniq < self.vocab)]
+            with self._book:
+                self._dirty.mark_rows(uniq)
+            self.work_id += 1
+            self._batches_since_persist += 1
         n = self.overflow_check_every_n_batches
         if n > 0:
             self._batches_since_overflow_check += 1
@@ -1121,9 +1304,8 @@ class ShardedOffloadedTable:
         # before this restore may have run against initializer rows, and
         # the same cache_capacity would overflow again after it
         self.check_overflow()
-        max_work = _replay_store(
-            path, vocab=self.vocab, host_weights=self.host_weights,
-            host_slots=self.host_slots, host_work_id=self.host_work_id)
+        max_work = _replay_store(path, vocab=self.vocab,
+                                 load=functools.partial(_store_rows, self))
         self.work_id = max(self.work_id, max_work + 1)
         self.persisted_work = max_work
         self._batches_since_persist = 0

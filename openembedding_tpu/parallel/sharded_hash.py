@@ -244,15 +244,25 @@ def _my_shard(mesh: Mesh, spec: HashShardingSpec) -> jnp.ndarray:
     return a2a.linear_shard_id(axes, tuple(mesh.shape[a] for a in axes))
 
 
+OFFLOAD_INSERT_STAGE = "offload_insert"     # the packed insert in a trace
+
+
 @functools.lru_cache(maxsize=None)
 def _insert_rows_program(mesh: Mesh, spec: HashShardingSpec,
                          slot_names: tuple, in_slot_names: tuple,
-                         record_stats: bool = False):
+                         record_stats: bool = False, donate: bool = True):
     """Cached jitted insert program: the checkpoint loader streams many
     same-shaped chunks, and rebuilding the shard_map closure per chunk would
-    retrace every call."""
+    retrace every call.
 
-    def _insert(tkeys, tweights, tslots, init_rng, k, w, srows):
+    The table operands (keys, weights, slots: arguments 0-2) are donated,
+    so the outputs alias them and the table is updated in place, as the
+    jitted step does with ``TrainState.emb``: without it every call
+    returns a second copy of the table. The running count of failed
+    inserts goes in and comes out with this call's added, so no second
+    program follows the insert."""
+
+    def _insert(tkeys, tweights, tslots, failures, init_rng, k, w, srows):
         local = hash_lib.HashTableState(
             keys=tkeys, weights=tweights, slots=tslots, init_rng=init_rng,
             insert_failures=jnp.zeros((), jnp.int32))
@@ -262,17 +272,17 @@ def _insert_rows_program(mesh: Mesh, spec: HashShardingSpec,
                                    max_probes=spec.max_probes,
                                    record_stats=record_stats)
         failed = lax.psum(new.insert_failures, spec.shard_axes)
-        return new.keys, new.weights, new.slots, failed
+        return new.keys, new.weights, new.slots, failures + failed
 
     row = spec.row_spec()
     slot_specs = {name: row for name in slot_names}
     in_slot_specs = {name: P() for name in in_slot_names}
     fn = shard_map(_insert, mesh=mesh,
-                   in_specs=(row, row, slot_specs, P(), P(), P(),
+                   in_specs=(row, row, slot_specs, P(), P(), P(), P(),
                              in_slot_specs),
                    out_specs=(row, row, slot_specs, P()),
                    check_vma=False)
-    return jax.jit(fn)
+    return jax.jit(fn, donate_argnums=(0, 1, 2) if donate else ())
 
 
 def insert_rows_sharded(state: hash_lib.HashTableState,
@@ -281,25 +291,31 @@ def insert_rows_sharded(state: hash_lib.HashTableState,
                         slot_rows=None,
                         *,
                         mesh: Mesh,
-                        spec: HashShardingSpec) -> hash_lib.HashTableState:
+                        spec: HashShardingSpec,
+                        donate: bool = True) -> hash_lib.HashTableState:
     """Load-path row delivery: every shard inserts its owned keys verbatim.
 
     ``keys``/``weights``/``slot_rows`` are replicated host batches (the
     checkpoint loader streams chunks); non-owned keys are masked to EMPTY and
     skipped locally — the reference's owning-server delivery
     (EmbeddingLoadOperator.cpp:58-111).
+
+    The table is updated IN PLACE: ``state``'s keys, weights and slots are
+    donated to the program and must not be read again (use the returned
+    state). ``donate=False`` keeps them alive and returns a copy, as
+    ``sharded_table.deliver_rows_sharded`` does for the serving hot-swap,
+    whose in-flight readers hold the pre-swap state.
     """
     slot_rows = slot_rows or {}
     fn = _insert_rows_program(mesh, spec, tuple(state.slots),
                               tuple(slot_rows),
-                              observability.evaluate_performance())
-    tkeys, tweights, tslots, failed = fn(
-        state.keys, state.weights, state.slots, state.init_rng,
-        keys, weights, slot_rows)
+                              observability.evaluate_performance(), donate)
+    tkeys, tweights, tslots, failures = fn(
+        state.keys, state.weights, state.slots, state.insert_failures,
+        state.init_rng, keys, weights, slot_rows)
     return hash_lib.HashTableState(
         keys=tkeys, weights=tweights, slots=tslots,
-        init_rng=state.init_rng,
-        insert_failures=state.insert_failures + failed)
+        init_rng=state.init_rng, insert_failures=failures)
 
 
 @functools.lru_cache(maxsize=None)
@@ -315,9 +331,13 @@ def _insert_packed_program(mesh: Mesh, spec: HashShardingSpec,
     EVERY step; one coalesced transfer replaces 2+len(slots) separate
     host->device arrays, and the per-transfer fixed cost is the one that
     shows (`python -m tools.offload_diag puts`). The unpack (slice + bitcast) fuses into
-    the insert program."""
+    the insert program, which updates the table in place as
+    :func:`_insert_rows_program` does. Only the offload tier packs its
+    rows, so the program is named for it: ``OFFLOAD_INSERT_STAGE`` is the
+    module's name in a device trace and, through ``scope.stage``, a part
+    of every instruction's ``op_name``."""
 
-    def _insert(tkeys, tweights, tslots, init_rng, packed):
+    def _insert(tkeys, tweights, tslots, failures, init_rng, packed):
         local = hash_lib.HashTableState(
             keys=tkeys, weights=tweights, slots=tslots, init_rng=init_rng,
             insert_failures=jnp.zeros((), jnp.int32))
@@ -331,15 +351,19 @@ def _insert_packed_program(mesh: Mesh, spec: HashShardingSpec,
                                    max_probes=spec.max_probes,
                                    record_stats=record_stats)
         failed = lax.psum(new.insert_failures, spec.shard_axes)
-        return new.keys, new.weights, new.slots, failed
+        return new.keys, new.weights, new.slots, failures + failed
 
     row = spec.row_spec()
     slot_specs = {name: row for name, _, _, _ in layout}
     fn = shard_map(_insert, mesh=mesh,
-                   in_specs=(row, row, slot_specs, P(), P()),
+                   in_specs=(row, row, slot_specs, P(), P(), P()),
                    out_specs=(row, row, slot_specs, P()),
                    check_vma=False)
-    return jax.jit(fn)
+
+    def named(*args):
+        return scope.stage(OFFLOAD_INSERT_STAGE)(fn)(*args)
+    named.__name__ = named.__qualname__ = OFFLOAD_INSERT_STAGE
+    return jax.jit(named, donate_argnums=(0, 1, 2))
 
 
 def insert_rows_sharded_packed(state: hash_lib.HashTableState,
@@ -351,18 +375,64 @@ def insert_rows_sharded_packed(state: hash_lib.HashTableState,
                                ) -> hash_lib.HashTableState:
     """:func:`insert_rows_sharded` behavior from ONE packed f32 buffer
     (int32 keys only — the offload cache's key plane; wide tables use
-    the unpacked path). See :func:`_insert_packed_program`."""
+    the unpacked path), in place like it. See
+    :func:`_insert_packed_program`."""
     if spec.wide:
         raise ValueError("packed insert supports int32-key tables only")
     dim = state.weights.shape[-1]
     fn = _insert_packed_program(mesh, spec, dim, layout,
                                 observability.evaluate_performance())
-    tkeys, tweights, tslots, failed = fn(
-        state.keys, state.weights, state.slots, state.init_rng, packed)
+    tkeys, tweights, tslots, failures = fn(
+        state.keys, state.weights, state.slots, state.insert_failures,
+        state.init_rng, packed)
     return hash_lib.HashTableState(
         keys=tkeys, weights=tweights, slots=tslots,
-        init_rng=state.init_rng,
-        insert_failures=state.insert_failures + failed)
+        init_rng=state.init_rng, insert_failures=failures)
+
+
+@functools.lru_cache(maxsize=None)
+def _read_rows_program(mesh: Mesh, spec: HashShardingSpec,
+                       slot_names: tuple):
+    """Jitted read of whole rows (weights AND optimizer slots) for given
+    keys: each shard finds the keys it owns and gathers their rows, nought
+    elsewhere, and a psum over the shard axes hands every device the
+    rows. Nothing is inserted or drawn: an absent key reads ``found``
+    false."""
+
+    def _read(tkeys, tweights, tslots, k):
+        flat = k.reshape(-1, 2) if spec.wide else k.ravel()
+        masked = _mask_non_owned(spec, flat, _my_shard(mesh, spec))
+        slot = hash_lib.find_rows(tkeys, masked, max_probes=spec.max_probes)
+        found = slot >= 0
+        at = jnp.where(found, slot, 0)
+
+        def take(rows):
+            got = jnp.take(rows, at, axis=0)
+            mask = found.reshape((-1,) + (1,) * (got.ndim - 1))
+            return lax.psum(jnp.where(mask, got, jnp.zeros((), got.dtype)),
+                            spec.shard_axes)
+
+        return (lax.psum(found.astype(jnp.int32), spec.shard_axes) > 0,
+                take(tweights), {name: take(tslots[name])
+                                 for name in slot_names})
+
+    row = spec.row_spec()
+    slot_specs = {name: row for name in slot_names}
+    fn = shard_map(_read, mesh=mesh,
+                   in_specs=(row, row, slot_specs, P()),
+                   out_specs=(P(), P(), {name: P() for name in slot_names}),
+                   check_vma=False)
+    return jax.jit(fn)
+
+
+def read_rows_sharded(state: hash_lib.HashTableState, keys: jnp.ndarray, *,
+                      mesh: Mesh, spec: HashShardingSpec):
+    """(found [n], weights [n, dim], {slot: [n, ...]}) of ``keys``
+    (replicated; EMPTY keys read not found), as the table holds them now:
+    the write-back path's read, which copies the rows it names and not
+    the table."""
+    return _read_rows_program(mesh, spec, tuple(state.slots))(
+        state.keys, state.weights, state.slots, keys)
 
 
 @functools.lru_cache(maxsize=None)

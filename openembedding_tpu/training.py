@@ -102,8 +102,11 @@ class Trainer:
         single-lookahead pipeline; results are bit-identical at any
         depth (the planned-residency chain in offload.host_prepare).
         Default 4: cold host pages amortize across a deeper window (the
-        reference's default budget is deeper still, 64); not measured on
-        the chip in this round."""
+        reference's default budget is deeper still, 64). On the chip at
+        depth 4 the step loop waits 0.004 ms a step for the lookahead
+        thread (``train_offload_wait_ms_per_step``, cell
+        ``deepfm_dim9_offload.train_zipf_offload``: a host prepare of 8.1
+        ms against a 71.5 ms device step; PERF.md, PR 28)."""
         if sparse_as_dense:
             from .hybrid import HybridModel
             module = HybridModel(inner=module,
@@ -519,6 +522,8 @@ class Trainer:
         prev = self._preps[-1][0] if self._preps else None
         results: Dict[str, Any] = {}
         err: list = []
+        # the step this batch is prepared for: batches queue in step order
+        at = {"step": self._host_step + len(self._preps)}
 
         def _run():
             if prev is not None:
@@ -526,7 +531,8 @@ class Trainer:
             try:
                 sync_point("trainer.prep.run")
                 for name, table in self.offload.items():
-                    with scope.span("lookahead.prepare", table=name):
+                    with scope.span("offload.host_prepare", detail=at,
+                                    table=name):
                         results[name] = table.host_prepare(
                             batch["sparse"][name])
             except BaseException as e:  # noqa: BLE001 — re-raised at join
@@ -556,9 +562,13 @@ class Trainer:
         if not self.offload:
             return state, {}
         prepped = None
+        at = {"step": self._host_step}
         if self._preps and self._preps[0][1] is batch:
             t, _, results, err = self._preps.popleft()
-            t.join()
+            # the host time of the tier that is exposed: the step loop
+            # blocked on the lookahead thread
+            with scope.span("offload.wait_prepare", detail=at):
+                t.join()
             if err:
                 # release the tables this entry DID prepare, then the rest
                 # of the window (its math built on this entry's marks)
@@ -576,8 +586,10 @@ class Trainer:
         for i, name in enumerate(names):
             table = self.offload[name]
             prep = prepped.get(name) if prepped is not None else None
-            if prep is None:
-                prep = table.host_prepare(batch["sparse"][name])
+            if prep is None:    # nothing looked ahead: prepared in line
+                with scope.span("offload.host_prepare", detail=at,
+                                table=name):
+                    prep = table.host_prepare(batch["sparse"][name])
             try:
                 emb[name] = table.apply_prepared(emb[name], prep)
             except BaseException:

@@ -1031,3 +1031,236 @@ def test_check_overflow_prefers_live_cache_counter(devices8):
     # a clean cache passes, and the copy (None) is not consulted
     assert t.flush(cache) == 0
     t._join_writeback()
+
+
+# --- in-place inserts, the public load and warm calls, one insert size -------
+
+def _pull(t, cache, ids, mesh):
+    from openembedding_tpu.parallel import sharded_hash as sh
+    return np.asarray(sh.pull_sharded(cache, jnp.asarray(ids), None,
+                                      mesh=mesh, spec=t.spec,
+                                      batch_sharded=False))
+
+
+def _aliased_operands(program, *args):
+    """Numbers of the operands that the lowered program donates and that
+    its compiled outputs alias, output n to operand n."""
+    import re
+    lowered = program.lower(*args)
+    donors = [int(n) for n in re.findall(
+        r"%arg(\d+): tensor<[^>]*> \{[^}]*jax\.buffer_donor = true",
+        lowered.as_text())]
+    header = next(line for line in lowered.compile().as_text().splitlines()
+                  if line.startswith("HloModule"))
+    aliased = [int(o) for o, i in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", header)
+        if o == i]
+    assert sorted(donors) == sorted(aliased), (donors, header)
+    return sorted(aliased)
+
+
+@pytest.mark.parametrize("packed", [True, False],
+                         ids=["packed", "unpacked"])
+def test_insert_program_updates_the_table_in_place(devices8, packed):
+    """Keys, weights and every slot of the table are donated to the insert
+    program and its outputs alias them: no call returns a second table."""
+    from openembedding_tpu.parallel import sharded_hash as sh
+    from openembedding_tpu.parallel.mesh import create_mesh
+    mesh = create_mesh(2, 4, devices8)
+    t = _mk_sharded(mesh)
+    cache = t.create_cache()
+    n, dim = 64, 4
+    if packed:
+        _, columns, layout = t._packed_layout(np.dtype(np.int32))
+        program = sh._insert_packed_program(mesh, t.spec, dim, layout)
+        rows = (jnp.zeros((n, columns), jnp.float32),)
+    else:
+        program = sh._insert_rows_program(mesh, t.spec, tuple(cache.slots),
+                                          tuple(cache.slots))
+        rows = (jnp.zeros((n,), jnp.int32), jnp.zeros((n, dim)),
+                {k: jnp.zeros((n,) + v.shape[1:], v.dtype)
+                 for k, v in cache.slots.items()})
+    operands = _aliased_operands(program, cache.keys, cache.weights,
+                                 cache.slots, cache.insert_failures,
+                                 cache.init_rng, *rows)
+    assert operands == list(range(2 + len(cache.slots))), operands
+
+
+@pytest.mark.parametrize("donate", [True, False])
+def test_bulk_insert_donates_unless_told_not_to(devices8, donate):
+    """``insert_rows_sharded`` consumes the state it is given; the serving
+    hot-swap, whose readers hold the old state, asks for a copy."""
+    from openembedding_tpu.parallel import sharded_hash as sh
+    from openembedding_tpu.parallel.mesh import create_mesh
+    mesh = create_mesh(2, 4, devices8)
+    t = _mk_sharded(mesh)
+    old = t.create_cache()
+    ids = np.arange(32, dtype=np.int32)
+    new = sh.insert_rows_sharded(
+        old, jnp.asarray(ids), jnp.ones((32, 4), jnp.float32),
+        {k: jnp.zeros((32,) + v.shape[1:], v.dtype)
+         for k, v in old.slots.items()},
+        mesh=mesh, spec=t.spec, donate=donate)
+    assert old.keys.is_deleted() == donate
+    assert old.weights.is_deleted() == donate
+    np.testing.assert_array_equal(_pull(t, new, ids, mesh),
+                                  np.ones((32, 4), np.float32))
+
+
+@pytest.mark.parametrize("ids", [slice(100, 356), np.arange(900, 100, -7)],
+                         ids=["range", "array"])
+def test_load_rows_then_warm_serves_the_loaded_rows(devices8, ids):
+    """``load_rows`` writes known rows and slot rows into the store by id
+    range or id array; ``warm`` makes them resident in bulk, the books as
+    ``apply_prepared`` leaves them, and a later prepare misses nothing."""
+    from openembedding_tpu.parallel.mesh import create_mesh
+    mesh = create_mesh(2, 4, devices8)
+    t = _mk_sharded(mesh, cache=4096)
+    which = np.arange(t.vocab)[ids]
+    rows = np.random.RandomState(0).randn(which.size, 4).astype(np.float32)
+    t.load_rows(ids, rows)
+    np.testing.assert_array_equal(t.host_weights[which], rows)
+    assert (t.host_work_id[which] == t.work_id).all()
+    old = t.create_cache()
+    cache = t.warm(old, which)
+    assert old.keys.is_deleted()            # updated in place
+    assert t._resident_count == which.size and t._resident[which].all()
+    assert (t._last_touch[which] == t.work_id).all()
+    np.testing.assert_array_equal(_pull(t, cache, which, mesh), rows)
+    prep = t.host_prepare(which[:50])
+    assert prep.missing.size == 0 and not prep.needs_evict
+    t.cancel_prepared(prep)
+    with pytest.raises(ValueError, match="the cache holds"):
+        t.load_rows(ids, rows)
+    t.finish()
+
+
+def test_warm_refuses_what_passes_the_budget(devices8):
+    from openembedding_tpu.parallel.mesh import create_mesh
+    mesh = create_mesh(2, 4, devices8)
+    t = _mk_sharded(mesh, cache=256)        # budget 179 rows
+    cache = t.create_cache()
+    with pytest.raises(ValueError, match="budget"):
+        t.warm(cache, np.arange(200))
+    assert t._resident_count == 0 and not cache.keys.is_deleted()
+
+
+def test_step_insert_has_one_size_while_the_misses_fit(devices8):
+    """Miss counts differ from step to step; the insert between two steps
+    is padded to one size for a table (the largest power of two in an
+    eighth of the batch's lookups), so a stream of batches of one shape
+    compiles it once. Only a step that misses more meets the second."""
+    from openembedding_tpu import offload
+    from openembedding_tpu.parallel import sharded_hash as sh
+    from openembedding_tpu.parallel.mesh import create_mesh
+    mesh = create_mesh(2, 4, devices8)
+    t = _mk_sharded(mesh, vocab=1 << 16, cache=1 << 15)
+    assert t._step_insert_size(106496, 5000) == 8192
+    assert t._step_insert_size(106496, 8192) == 8192
+    assert t._step_insert_size(106496, 8193) == offload.STEP_CHUNK
+    assert t._step_insert_size(64, 3) == 32
+    cache = t.create_cache()
+    rng = np.random.RandomState(1)
+    compiled = []
+
+    def on(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    lo, sizes = 0, []
+    for misses in (300, 17, 511, 1, 256, 90):
+        # 4,096 lookups a batch (size 512): fresh ids, the rest repeats
+        ids = np.concatenate([np.arange(lo, lo + misses),
+                              rng.randint(0, max(lo, 1), 4096 - misses)])
+        lo += misses
+        before = len(compiled)
+        cache = t.prepare(cache, ids.astype(np.int32))
+        sizes.append(len(compiled) - before)
+    program = sh._insert_packed_program(      # the cached one, by its key
+        mesh, t.spec, 4, t._packed_layout(np.dtype(np.int32))[2], False)
+    assert program._cache_size() == 1
+    assert sizes[1:] == [0] * 5, sizes      # every compile was the first's
+    ids = np.arange(lo, lo + 600).astype(np.int32)      # more than 512
+    ids = np.concatenate([ids, np.zeros(4096 - 600, np.int32)])
+    cache = t.prepare(cache, ids)
+    assert program._cache_size() == 2
+    assert t._resident_count == lo + 600
+    t.finish()
+
+
+@pytest.mark.parametrize("writeback", ["ahead", "chunk_by_chunk"])
+def test_eviction_rebuilds_the_cache_in_its_own_buffers(devices8, writeback,
+                                                        monkeypatch):
+    """Eviction empties and refills the cache it is given (donated): no
+    second cache is allocated beside the first, and what it wrote back
+    and re-inserted reads exactly as last written. Both ways of the
+    write-back: every read dispatched ahead of a writer thread, and (more
+    dirty rows than ``WRITEBACK_ASYNC_ROWS``) chunk by chunk, one read
+    ahead of the chunk being stored, before the call returns."""
+    from openembedding_tpu import offload
+    from openembedding_tpu.analysis import scope
+    from openembedding_tpu.parallel.mesh import create_mesh
+    if writeback == "chunk_by_chunk":       # 150 dirty rows: five chunks
+        monkeypatch.setattr(offload, "WRITEBACK_ASYNC_ROWS", 64)
+        monkeypatch.setattr(offload, "WRITEBACK_CHUNK", 32)
+    mesh = create_mesh(2, 4, devices8)
+    t = _mk_sharded(mesh)                   # budget 179 rows
+    before = scope.HISTOGRAMS.counter("offload_evictions", table="t")
+    cache = t.prepare(t.create_cache(), np.arange(0, 150, dtype=np.int32))
+    grads = np.ones((150, 4), np.float32)
+    from openembedding_tpu.parallel import sharded_hash as sh
+    cache = sh.apply_gradients_sharded(
+        cache, t.optimizer, t.initializer,
+        jnp.asarray(np.arange(0, 150, dtype=np.int32)), jnp.asarray(grads),
+        mesh=mesh, spec=t.spec, batch_sharded=False)
+    t.note_update(np.arange(0, 150))
+    full = cache
+    cache = t.prepare(cache, np.arange(500, 600, dtype=np.int32))
+    assert t.evictions == 1 and full.keys.is_deleted()
+    assert scope.HISTOGRAMS.counter("offload_evictions", table="t") \
+        == before + 1
+    assert t._writer is None and not t._dirty.mask_rows(np.arange(150)).any()
+    assert int(cache.insert_failures) == 0
+    np.testing.assert_array_equal(t.host_weights[:150],
+                                  np.full((150, 4), -0.75, np.float32))
+    kept = np.nonzero(t._resident[:150])[0]
+    assert kept.size
+    np.testing.assert_array_equal(_pull(t, cache, kept, mesh),
+                                  t.host_weights[kept])
+    t.finish()
+
+
+def test_fit_leaves_the_tiers_spans_and_counters(devices8):
+    """A fit with ``offload=`` feeds the tier's four spans (the wait for
+    the lookahead thread among them) and its row and byte counters, a
+    table; no eviction, no retry."""
+    from openembedding_tpu.analysis import scope
+    from openembedding_tpu.parallel.mesh import create_mesh
+    mesh = create_mesh(2, 4, devices8)
+    helper = TestPipelinedOffload()
+    trainer, table, lin = helper._trainer(mesh, cache=4096)
+    batches = helper._batches(6)
+    H = scope.HISTOGRAMS
+    spans = {"offload.host_prepare": {"table": "off"},
+             "offload.wait_prepare": {},
+             "offload.apply_prepared": {"table": "off"},
+             "offload.note_update": {"table": "off:linear"}}
+    series = {k: "span_" + k.replace(".", "_") + "_seconds" for k in spans}
+    calls = {k: H.count(series[k], **l) for k, l in spans.items()}
+    counts = {c: H.counter(c, table="off") for c in
+              ("offload_miss_rows", "offload_unique_rows",
+               "offload_h2d_bytes", "offload_evictions",
+               "offload_gen_retries")}
+    state = trainer.init(jax.random.PRNGKey(0),
+                         trainer.shard_batch(batches[0]))
+    state, _ = trainer.fit(state, batches)
+    for k, l in spans.items():
+        assert H.count(series[k], **l) - calls[k] >= 6, k
+    grew = {c: H.counter(c, table="off") - v for c, v in counts.items()}
+    assert grew["offload_unique_rows"] >= grew["offload_miss_rows"] > 0
+    assert grew["offload_miss_rows"] == table._resident_count
+    # one packed buffer a step: 32 keys x (key + row + accumulator) floats
+    assert grew["offload_h2d_bytes"] % (9 * 4) == 0 \
+        and grew["offload_h2d_bytes"] > 0
+    assert grew["offload_evictions"] == grew["offload_gen_retries"] == 0

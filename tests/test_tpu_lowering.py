@@ -167,3 +167,51 @@ def test_pallas_probe_gather_admitted_and_refused(v5e):
         with pytest.raises(ValueError, match="float32 rows of exactly 128"):
             ph.probe_gather.trace(keys, _on(v5e[0], (cap, dim), dtype), q, q,
                                   **kw)
+
+
+@pytest.mark.parametrize("keys", [1 << 13, 1 << 21], ids=["step", "bulk"])
+def test_v5e_offload_insert_updates_the_cache_in_place(v5e, keys):
+    """The insert the offload tier runs between two steps (8,192 keys) and
+    its bulk insert (2**21), at the offload cell's size: a cache of 2**26
+    slots, dim 9 with its accumulator, 4.8 GiB of arguments on one chip.
+    The table operands are donated and every output aliases its operand;
+    no copy of a table-sized array is left, so the program fits beside the
+    second table's cache, which the un-donated program's second copy of
+    the table did not."""
+    from openembedding_tpu import offload
+    from openembedding_tpu.meta import EmbeddingVariableMeta
+    from openembedding_tpu.parallel import sharded_hash as sh
+    capacity, dim = 1 << 26, 9
+    mesh = create_mesh(1, 1, v5e[:1])
+    tier = offload.ShardedOffloadedTable(
+        "fields", EmbeddingVariableMeta(embedding_dim=dim,
+                                        vocabulary_size=1024),
+        {"category": "adagrad"}, {"category": "constant", "value": 0.0},
+        vocab=1024, cache_capacity=capacity, mesh=mesh)
+    cache = jax.eval_shape(tier.create_cache)
+    row = NamedSharding(mesh, tier.spec.row_spec())
+    whole = NamedSharding(mesh, P())
+    table = _abstract((cache.keys, cache.weights, cache.slots),
+                      (row, row, {k: row for k in cache.slots}))
+    _, columns, layout = tier._packed_layout(cache.keys.dtype)
+    program = sh._insert_packed_program(mesh, tier.spec, dim, layout)
+    compiled = program.lower(
+        *table, _abstract(cache.insert_failures, whole),
+        _abstract(cache.init_rng, whole),
+        jax.ShapeDtypeStruct((keys, columns), jnp.float32,
+                             sharding=whole)).compile()
+    hlo = compiled.as_text()
+    header = next(line for line in hlo.splitlines()
+                  if line.startswith("HloModule"))
+    assert sh.OFFLOAD_INSERT_STAGE in header
+    aliased = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+                         header)
+    assert sorted(aliased) == [("0", "0"), ("1", "1"), ("2", "2")], header
+    sized = (f"s32[{capacity}]", f"f32[{capacity},{dim}]")
+    copies = [line.strip()[:120] for line in hlo.splitlines()
+              if " copy(" in line and any(f"= {s}" in line for s in sized)]
+    assert not copies, copies
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= capacity * (4 + 2 * dim * 4)
+    assert memory.temp_size_in_bytes < (5 << 30 if keys > 1 << 13
+                                        else 1 << 30), memory
