@@ -652,6 +652,46 @@ def pull(state: HashTableState, indices: jnp.ndarray,
                 invalid).reshape(out_shape)
 
 
+def merge_gradients(state: HashTableState,
+                    initializer: Any,
+                    indices: jnp.ndarray,
+                    grads: jnp.ndarray,
+                    *,
+                    dedup_capacity: Optional[int] = None,
+                    max_probes: int = DEFAULT_MAX_PROBES,
+                    in_counts: Optional[jnp.ndarray] = None,
+                    record_stats: bool = False):
+    """The first half of :func:`apply_gradients`, which touches the key
+    array alone: deduplicate the keys and combine their gradients into a
+    buffer of ``dedup_capacity`` (default ``n``) slots, find or insert each
+    key. Returns ``(keys, failed, merged)``: the new key array, the number
+    of keys no window held, and ``table.apply_rows``'s ``(rows, live,
+    summed, counts, fresh, inserted)``."""
+    initializer = make_initializer(initializer)
+    dim = state.dim
+    empty = empty_key(state.keys.dtype)
+    if state.wide:
+        flat_idx = _wide_query(state.keys, indices)
+        capacity = dedup_capacity or flat_idx.shape[0]
+        uniq, inverse, valid = dedup.unique_pairs(
+            flat_idx, capacity, fill_value=empty)
+        valid = valid & (uniq[:, 1] != empty)
+    else:
+        flat_idx = check_key_dtype(state.keys, indices.ravel())
+        capacity = dedup_capacity or flat_idx.shape[0]
+        uniq, inverse, valid = dedup.unique_indices(
+            flat_idx, capacity, fill_value=empty)
+        valid = valid & (uniq != empty)
+    summed, counts = dedup.combine_gradients(grads.reshape(-1, dim), inverse,
+                                             capacity, in_counts)
+    keys_arr, slot, inserted, failed = find_or_insert(
+        state.keys, uniq, valid, max_probes, record_stats)
+    fresh = init_rows(initializer, state.init_rng, uniq, dim,
+                      state.weights.dtype)
+    return (keys_arr, jnp.sum(failed).astype(jnp.int32),
+            (slot, valid & (slot >= 0), summed, counts, fresh, inserted))
+
+
 def apply_gradients(state: HashTableState,
                     optimizer: SparseOptimizer,
                     initializer: Any,
@@ -669,47 +709,20 @@ def apply_gradients(state: HashTableState,
     optimizer -> scatter. Window-overflow keys are dropped and counted.
     ``in_counts`` ([n]) marks grads that are already pre-reduced sums of that
     many originals (owner side of the all-to-all exchange).
+
+    The dedup, the combine and the find (:func:`merge_gradients`) run over
+    ``dedup_capacity`` (default ``n``) slots; the gather, the optimizer and
+    the scatter are ``table.apply_rows``, whose cost follows the distinct
+    keys of the batch and not ``dedup_capacity``.
     """
-    optimizer = make_optimizer(optimizer)
-    initializer = make_initializer(initializer)
-    dim = state.dim
-    empty = empty_key(state.keys.dtype)
-    if state.wide:
-        flat_idx = _wide_query(state.keys, indices)
-        n = flat_idx.shape[0]
-        capacity = dedup_capacity or n
-        uniq, inverse, valid = dedup.unique_pairs(
-            flat_idx, capacity, fill_value=empty)
-        valid = valid & (uniq[:, 1] != empty)
-    else:
-        flat_idx = check_key_dtype(state.keys, indices.ravel())
-        n = flat_idx.shape[0]
-        capacity = dedup_capacity or n
-        uniq, inverse, valid = dedup.unique_indices(
-            flat_idx, capacity, fill_value=empty)
-        valid = valid & (uniq != empty)
-    flat_grads = grads.reshape(-1, dim)
-    summed, counts = dedup.combine_gradients(flat_grads, inverse, capacity,
-                                             in_counts)
-
-    keys_arr, slot, inserted, failed = find_or_insert(
-        state.keys, uniq, valid, max_probes, record_stats)
-    ok = valid & (slot >= 0)
-    safe_slot = jnp.where(ok, slot, 0)
-
-    w, s = table_lib.gather_rows(state.weights, state.slots, safe_slot)
-    fresh = init_rows(initializer, state.init_rng, uniq, dim,
-                      state.weights.dtype)
-    w = jnp.where(inserted[:, None], fresh, w)
-
-    new_w, new_s = table_lib.optimizer_block_update(optimizer, w, s,
-                                                    summed, counts)
-
-    oob = jnp.asarray(state.capacity, jnp.int32)
-    scatter_idx = jnp.where(ok, safe_slot, oob)
-    weights, slots = table_lib.scatter_rows(state.weights, state.slots,
-                                            scatter_idx, new_w, new_s)
+    keys_arr, failed, merged = merge_gradients(
+        state, initializer, indices, grads, dedup_capacity=dedup_capacity,
+        max_probes=max_probes, in_counts=in_counts,
+        record_stats=record_stats)
+    weights, slots = table_lib.apply_rows(
+        state.weights, state.slots, make_optimizer(optimizer), *merged,
+        record_stats=record_stats)
     return HashTableState(
         keys=keys_arr, weights=weights, slots=slots,
         init_rng=state.init_rng,
-        insert_failures=state.insert_failures + jnp.sum(failed).astype(jnp.int32))
+        insert_failures=state.insert_failures + failed)

@@ -415,7 +415,7 @@ def exchange_pull(flat_idx: jnp.ndarray,
 def exchange_push(flat_idx: jnp.ndarray,
                   grads: jnp.ndarray,
                   state,
-                  apply_fn: Callable,
+                  merge_fn: Callable,
                   owner_fn: Callable[[jnp.ndarray], jnp.ndarray],
                   *,
                   sentinel,
@@ -432,12 +432,22 @@ def exchange_push(flat_idx: jnp.ndarray,
     """Owner-routed push: pre-reduce, route (key, grad sum, count) to owners.
     EXACT for any key distribution.
 
-    ``apply_fn(state, keys [K], grads [K, dim], counts [K]) -> state`` runs
-    on the owner with the merged per-peer pre-reduces and returns the updated
-    local state (a pytree with stable structure/shapes/dtypes — it is
-    threaded through ``lax.cond``). Entries with a sentinel key are padding
-    and must be ignored by ``apply_fn`` (both built-in appliers drop them via
-    the invalid-key contract; their count values are garbage by design).
+    ``merge_fn(state, keys [K], grads [K, dim], counts [K]) -> (state,
+    merged)`` runs on the owner with the per-peer pre-reduces and merges
+    them: ``merged`` is a pytree of arrays whose leading axis is the slots
+    of the owner's deduplicated buffer (``table.merge_gradients``), and
+    ``state`` what merging itself changes (a hash table's key array; a
+    pytree with stable structure/shapes/dtypes — it is threaded through
+    ``lax.cond``). Entries with a sentinel key are padding and must be
+    ignored by ``merge_fn`` (both built-in mergers drop them via the
+    invalid-key contract; their count values are garbage by design).
+    Returns ``(state, merged)``, for the caller to apply
+    (``table.apply_rows``) after the exchange: the apply's loop carries the
+    table, and the v5e compiler copies a table that a loop inside a branch
+    of a conditional carries. Each branch merges at its own size, and
+    ``merged`` is padded at the tail with zeros (dead slots) to the longer
+    of the two; the apply walks the occupied prefix, so the padding costs
+    nothing.
 
     Unlike the pull (idempotent reads, residue rounds compose), a push must
     apply each key's optimizer update EXACTLY ONCE per step with all peer
@@ -456,8 +466,8 @@ def exchange_push(flat_idx: jnp.ndarray,
 
     Both branches are exact; the reference gets the same guarantee from
     variable-size RPCs + server-side MpscGradientReducer
-    (EmbeddingPushOperator.cpp:29-104). Note for appliers that dedup with a
-    bounded capacity: the OWNED-UNIQUE count an applier sees is identical
+    (EmbeddingPushOperator.cpp:29-104). Note for mergers that dedup with a
+    bounded capacity: the OWNED-UNIQUE count a merger sees is identical
     in both branches (each peer slice contributes a key at most once either
     way — the gathered batch is longer but not more unique), so capacity
     sizing is branch-independent. Keys and counts share one integer
@@ -478,7 +488,7 @@ def exchange_push(flat_idx: jnp.ndarray,
       resid))`` instead of ``result`` — both computed before the
       overflow branch, so feedback is branch-independent. Padding rows'
       scales are garbage on the routed wire (single-fill buffer);
-      owners zero them by key validity so no NaN can reach an applier.
+      owners zero them by key validity so no NaN can reach a merger.
     """
     dim = grads.shape[-1]
     parts = math.prod(split_sizes)
@@ -557,7 +567,7 @@ def exchange_push(flat_idx: jnp.ndarray,
         send_kc, send_g = to_buckets(uniq, counts, payload, scale, dest)
         rkc = grid_all_to_all(send_kc, grid_axes, grid_sizes)
         rg = grid_all_to_all(send_g, grid_axes, grid_sizes)
-        return apply_fn(st, *from_buckets(rkc, rg))
+        return merge_fn(st, *from_buckets(rkc, rg))
 
     gather_all = scope.stage("exchange")(
         lambda x: lax.all_gather(x, tuple(grid_axes), tiled=True))
@@ -575,7 +585,7 @@ def exchange_push(flat_idx: jnp.ndarray,
                            wire_dtype).astype(summed.dtype)
         else:
             g = gather_all(summed)
-        return apply_fn(st, k, g, c)
+        return merge_fn(st, k, g, c)
 
     if cap >= m:
         # buckets can hold the whole slice: bucketize cannot overflow
@@ -587,7 +597,21 @@ def exchange_push(flat_idx: jnp.ndarray,
     # per-device residue: the callback fires on every device shard, so the
     # host accumulator sums locals into the global total
     record_stat("a2a_extra_entries_push", local_spill, record_stats)
-    out = lax.cond(spilled == 0, routed, gathered, state)
+    # each merged leaf as long as the longer branch leaves it
+    lengths = jax.tree.map(
+        lambda a, b: max(a.shape[0], b.shape[0]),
+        *(jax.eval_shape(branch, state)[1] for branch in (routed, gathered)))
+
+    def padded(branch):
+        def run(st):
+            st, merged = branch(st)
+            return st, jax.tree.map(
+                lambda x, n: jnp.pad(
+                    x, [(0, n - x.shape[0])] + [(0, 0)] * (x.ndim - 1)),
+                merged, lengths)
+        return run
+
+    out = lax.cond(spilled == 0, padded(routed), padded(gathered), state)
     return (out, new_ef) if quant else out
 
 

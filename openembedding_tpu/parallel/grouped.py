@@ -380,33 +380,31 @@ def _array_push_program(mesh: Mesh, plan: GroupPlan, batch_sharded: bool,
                     ((0, 0), (0, plan.bucket_dim - m.dim)))
             for t, m in enumerate(members)])
 
-        def apply_fn(st, keys, g, counts):
-            new = []
+        def merge_fn(st, keys, g, counts):
+            merged = []
             for t, (kw, _ow, gw, cw) in _sorted_member_windows(
                     keys, bounds, plan.bases[:-1], g, counts):
                 m = members[t]
-                w_t, s_t = st[t]
                 shard, local = m.spec.shard_and_local(
                     kw - plan.bases[t])
                 mine = ((kw >= plan.bases[t])
                         & (kw < plan.bases[t + 1]) & (shard == me))
-                masked = jnp.where(mine, local, -1)
-                ns = table_lib.apply_gradients(
-                    table_lib.TableState(weights=w_t, slots=s_t),
-                    m.optimizer, masked, gw[:, :m.dim],
-                    in_counts=cw)
-                new.append((ns.weights, ns.slots))
-            return tuple(new)
+                merged.append(table_lib.merge_gradients(
+                    jnp.where(mine, local, -1), gw[:, :m.dim],
+                    in_counts=cw))
+            return st, tuple(merged)
 
-        return a2a.exchange_push(
-            flat_all, g_all,
-            tuple((weights[t], slots[t]) for t in range(T)),
-            apply_fn, owner, sentinel=dedup.FILL,
+        _, merged = a2a.exchange_push(
+            flat_all, g_all, (), merge_fn, owner, sentinel=dedup.FILL,
             num_shards=first.num_shards, grid_axes=grid_axes,
             grid_sizes=grid_sizes, split_axes=split_axes,
             split_sizes=split_sizes, capacity=first.a2a_capacity,
             slack=first.a2a_slack, record_stats=record_stats,
             wire_dtype=first.push_wire_dtype)
+        return tuple(
+            table_lib.apply_rows(weights[t], slots[t], m.optimizer,
+                                 *merged[t], record_stats=record_stats)
+            for t, m in enumerate(members))
 
     _apply.__name__ = "grouped_push"
     row = first.row_spec()
@@ -569,13 +567,13 @@ def _hash_push_program(mesh: Mesh, plan: GroupPlan, batch_sharded: bool,
                     ((0, 0), (0, plan.bucket_dim - m.dim)))
             for t, m in enumerate(members)])
 
-        def apply_fn(st, q, g, counts):
+        def merge_fn(st, q, g, counts):
             keyc_all = q[:, :kw] if plan.wide else q[:, 0]
-            new = []
+            new, merged = [], []
             for t, (tag, _ow, keyc, gw, cw) in _sorted_member_windows(
                     q[:, kw], bounds, range(T), keyc_all, g, counts):
                 m = members[t]
-                k_t, w_t, s_t, fails = st[t]
+                k_t, fails = st[t]
                 mine = (tag == t) & (m.spec.owner_shard(keyc) == me)
                 if plan.wide:
                     masked = jnp.where(mine[:, None], keyc,
@@ -584,29 +582,33 @@ def _hash_push_program(mesh: Mesh, plan: GroupPlan, batch_sharded: bool,
                     masked = jnp.where(mine, keyc,
                                        jnp.asarray(empty, keyc.dtype))
                 cur = hash_lib.HashTableState(
-                    keys=k_t, weights=w_t, slots=s_t, init_rng=rngs[t],
+                    keys=k_t, weights=tweights[t], slots=tslots[t],
+                    init_rng=rngs[t],
                     insert_failures=jnp.zeros((), jnp.int32))
-                ns = hash_lib.apply_gradients(
-                    cur, m.optimizer, m.initializer, masked,
-                    gw[:, :m.dim], max_probes=m.spec.max_probes,
-                    in_counts=cw, record_stats=record_stats)
-                new.append((ns.keys, ns.weights, ns.slots,
-                            fails + ns.insert_failures))
-            return tuple(new)
+                k_t, failed, rows = hash_lib.merge_gradients(
+                    cur, m.initializer, masked, gw[:, :m.dim],
+                    max_probes=m.spec.max_probes, in_counts=cw,
+                    record_stats=record_stats)
+                new.append((k_t, fails + failed))
+                merged.append(rows)
+            return tuple(new), tuple(merged)
 
-        res = a2a.exchange_push(
+        res, merged = a2a.exchange_push(
             flat_all, g_all,
-            tuple((tkeys[t], tweights[t], tslots[t],
-                   jnp.zeros((), jnp.int32)) for t in range(T)),
-            apply_fn, owner, sentinel=empty,
+            tuple((tkeys[t], jnp.zeros((), jnp.int32)) for t in range(T)),
+            merge_fn, owner, sentinel=empty,
             num_shards=first.num_shards, grid_axes=grid_axes,
             grid_sizes=grid_sizes, split_axes=split_axes,
             split_sizes=split_sizes, capacity=first.a2a_capacity,
             slack=first.a2a_slack, record_stats=record_stats,
             wire_dtype=first.push_wire_dtype)
         # per-shard failure deltas -> replicated global totals
-        return tuple((k, w, s, lax.psum(f, first.shard_axes))
-                     for k, w, s, f in res)
+        return tuple(
+            (k, *table_lib.apply_rows(tweights[t], tslots[t],
+                                      members[t].optimizer, *merged[t],
+                                      record_stats=record_stats),
+             lax.psum(f, first.shard_axes))
+            for t, (k, f) in enumerate(res))
 
     _apply.__name__ = "grouped_hash_push"
     row = first.row_spec()

@@ -32,6 +32,7 @@ from ..utils import observability
 from ..optim.initializers import make_initializer
 from ..optim.optimizers import SparseOptimizer, make_optimizer
 from .. import hash_table as hash_lib
+from .. import table as table_lib
 from . import alltoall as a2a
 from . import hot_cache
 from . import precision
@@ -590,31 +591,34 @@ def _apply_program(mesh: Mesh, spec: HashShardingSpec,
                 return jnp.where(valid, spec.owner_shard(q),
                                  spec.num_shards).astype(jnp.int32)
 
-            def apply_fn(st, q, grads, counts):
-                tkeys, tweights, tslots, fails = st
+            def merge_fn(st, q, grads, counts):
+                tkeys, fails = st
                 cur = hash_lib.HashTableState(
-                    keys=tkeys, weights=tweights, slots=tslots,
+                    keys=tkeys, weights=weights, slots=slots,
                     init_rng=init_rng,
                     insert_failures=jnp.zeros((), jnp.int32))
-                masked = _mask_non_owned(spec, q, me)
-                new = hash_lib.apply_gradients(
-                    cur, optimizer, initializer, masked, grads,
+                tkeys, failed, merged = hash_lib.merge_gradients(
+                    cur, initializer, _mask_non_owned(spec, q, me), grads,
                     dedup_capacity=dedup_capacity,
                     max_probes=spec.max_probes, in_counts=counts,
                     record_stats=record_stats)
-                return (new.keys, new.weights, new.slots,
-                        fails + new.insert_failures)
+                return (tkeys, fails + failed), merged
 
-            return a2a.exchange_push(
-                flat, g2,
-                (keys, weights, slots, jnp.zeros((), jnp.int32)),
-                apply_fn, owner,
+            out = a2a.exchange_push(
+                flat, g2, (keys, jnp.zeros((), jnp.int32)), merge_fn, owner,
                 sentinel=sentinel, num_shards=spec.num_shards,
                 grid_axes=grid_axes, grid_sizes=grid_sizes,
                 split_axes=split_axes, split_sizes=split_sizes,
                 capacity=spec.a2a_capacity, slack=spec.a2a_slack,
                 record_stats=record_stats,
                 wire_dtype=spec.push_wire_dtype, ef_state=ef)
+            ((keys, fails), merged), new_ef = \
+                out if ef is not None else (out, None)
+            weights, slots = table_lib.apply_rows(
+                weights, slots, optimizer, *merged,
+                record_stats=record_stats)
+            table = (keys, weights, slots, fails)
+            return table if ef is None else (table, new_ef)
 
         if spec.is_cached:
             def _apply(keys, weights, slots, init_rng, ckeys, crows,

@@ -533,7 +533,7 @@ def _apply_program(mesh: Mesh, spec: ShardingSpec,
                 return jnp.where(valid, shard, spec.num_shards).astype(
                     jnp.int32)
 
-            def apply_fn(st, keys, grads, counts):
+            def merge_fn(st, keys, grads, counts):
                 @scope.stage("route")
                 def mask(keys, me):
                     shard, local = spec.shard_and_local(keys)
@@ -541,22 +541,22 @@ def _apply_program(mesh: Mesh, spec: ShardingSpec,
                             & (shard == me))
                     return jnp.where(mine, local, -1)
 
-                masked = mask(keys, me)
-                new = table_lib.apply_gradients(
-                    table_lib.TableState(weights=st[0], slots=st[1]),
-                    optimizer, masked, grads,
-                    dedup_capacity=dedup_capacity, in_counts=counts)
-                return new.weights, new.slots
+                return st, table_lib.merge_gradients(
+                    mask(keys, me), grads, dedup_capacity=dedup_capacity,
+                    in_counts=counts)
 
-            return a2a.exchange_push(
-                flat, g2,
-                (weights, slots), apply_fn, owner,
+            out = a2a.exchange_push(
+                flat, g2, (), merge_fn, owner,
                 sentinel=dedup.FILL, num_shards=spec.num_shards,
                 grid_axes=grid_axes, grid_sizes=grid_sizes,
                 split_axes=split_axes, split_sizes=split_sizes,
                 capacity=spec.a2a_capacity, slack=spec.a2a_slack,
                 record_stats=record_stats,
                 wire_dtype=spec.push_wire_dtype, ef_state=ef)
+            (_, merged), new_ef = out if ef is not None else (out, None)
+            table = table_lib.apply_rows(weights, slots, optimizer, *merged,
+                                         record_stats=record_stats)
+            return table if ef is None else (table, new_ef)
 
         if spec.is_cached:
             def _apply(weights, slots, ckeys, crows, cslots, idx, g):
@@ -625,7 +625,7 @@ def _apply_program(mesh: Mesh, spec: ShardingSpec,
             local_state = table_lib.TableState(weights=weights, slots=slots)
             new_state = table_lib.apply_gradients(
                 local_state, optimizer, masked, g2,
-                dedup_capacity=dedup_capacity)
+                dedup_capacity=dedup_capacity, record_stats=record_stats)
             return new_state.weights, new_state.slots
 
     slot_specs = {name: spec.row_spec() for name in slot_names}

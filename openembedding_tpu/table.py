@@ -13,9 +13,13 @@ EmbeddingOptimizerVariable.h:242-297 pull/push/update composition):
   eagerly at creation with a PRNG (statistically identical, compiler-friendly).
 * ``apply_gradients`` replaces the reference's push + store pipeline
   (MpscGradientReducer reduce → per-row optimizer update under shard lock):
-  capacity-padded dedup, scatter-add combine, gather touched rows, vectorized
-  optimizer ``update_rows``, scatter back. Exactly the touched-rows-only
-  sparse semantics, in one fused XLA program instead of two RPC round trips.
+  capacity-padded dedup, scatter-add combine, then gather touched rows,
+  vectorized optimizer ``update_rows``, scatter back (``apply_rows``, shared
+  with the hash table). Exactly the touched-rows-only sparse semantics, in
+  one fused XLA program instead of two RPC round trips. The gather, update
+  and scatter walk only the occupied prefix of the padded unique buffer, in
+  fixed chunks: the apply's cost follows the distinct rows of the batch,
+  not ``dedup_capacity``.
 
 The hash-table variant for unbounded (2^63) key spaces lives in
 ``hash_table.py``; both present the same pull/apply surface.
@@ -27,6 +31,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from flax import struct
 
 from .analysis import scope
@@ -34,10 +39,15 @@ from .meta import EmbeddingVariableMeta
 from .ops import dedup
 from .optim.initializers import Initializer, make_initializer
 from .optim.optimizers import SparseOptimizer, make_optimizer
+from .parallel.alltoall import record_stat
 
 
 # Shared default: small-uniform like the reference's default variable config.
 DEFAULT_INITIALIZER = {"category": "uniform", "minval": -1e-3, "maxval": 1e-3}
+
+# Slots of the unique buffer one trip of the sparse apply gathers, updates
+# and scatters (apply_rows). Fixed from runs on a TPU v5e: PERF.md section 6.
+APPLY_CHUNK = 4096
 
 
 def resolve_dtype(meta: EmbeddingVariableMeta):
@@ -132,13 +142,33 @@ def optimizer_block_update(optimizer: SparseOptimizer,
     return update(weights, slots, summed, counts)
 
 
+def merge_gradients(indices: jnp.ndarray, grads: jnp.ndarray, *,
+                    dedup_capacity: Optional[int] = None,
+                    in_counts: Optional[jnp.ndarray] = None):
+    """The first half of :func:`apply_gradients`, which needs no table:
+    deduplicate ``indices`` and combine their gradients into a buffer of
+    ``dedup_capacity`` (default ``n``) slots. Returns :func:`apply_rows`'s
+    ``(rows, live, summed, counts)``."""
+    flat_idx = indices.ravel()
+    flat_grads = grads.reshape(-1, grads.shape[-1])
+    capacity = dedup_capacity or flat_idx.shape[0]
+    uniq, inverse, valid = dedup.unique_indices(flat_idx, capacity)
+    # negative indices are invalid keys: pull clamps them to row 0, the
+    # update must NOT let them wrap around onto a real row.
+    valid = valid & (uniq >= 0)
+    summed, counts = dedup.combine_gradients(flat_grads, inverse, capacity,
+                                             in_counts)
+    return uniq, valid, summed, counts
+
+
 def apply_gradients(state: TableState,
                     optimizer: SparseOptimizer,
                     indices: jnp.ndarray,
                     grads: jnp.ndarray,
                     *,
                     dedup_capacity: Optional[int] = None,
-                    in_counts: Optional[jnp.ndarray] = None) -> TableState:
+                    in_counts: Optional[jnp.ndarray] = None,
+                    record_stats: bool = False) -> TableState:
     """Push + update in one step: combine duplicate grads, update touched rows.
 
     ``indices`` is [n] (or any shape), ``grads`` matches with a trailing
@@ -146,32 +176,125 @@ def apply_gradients(state: TableState,
     summed with counts — the reference's documented sparse-update contract.
     ``in_counts`` ([n]) marks grads that are already pre-reduced sums of that
     many originals (owner side of the all-to-all exchange).
+
+    The dedup and the combine (:func:`merge_gradients`) run over
+    ``dedup_capacity`` slots; the gather, the optimizer and the scatter run
+    over the distinct rows of the batch (:func:`apply_rows`), so their cost
+    follows those and not ``dedup_capacity``. ``record_stats`` is
+    :func:`apply_rows`'s.
     """
-    dim = state.dim
-    flat_idx = indices.ravel()
-    flat_grads = grads.reshape(-1, dim)
-    n = flat_idx.shape[0]
-    capacity = dedup_capacity or n
-
-    uniq, inverse, valid = dedup.unique_indices(flat_idx, capacity)
-    # negative indices are invalid keys: pull clamps them to row 0, the
-    # update must NOT let them wrap around onto a real row.
-    valid = valid & (uniq >= 0)
-    summed, counts = dedup.combine_gradients(flat_grads, inverse, capacity,
-                                             in_counts)
-
-    # Gather touched rows + slots; padding slots gather row 0 then are dropped
-    # on the scatter, so their (garbage) update never lands.
-    safe_uniq = jnp.where(valid, uniq, 0)
-    w, s = gather_rows(state.weights, state.slots, safe_uniq)
-
-    new_w, new_s = optimizer_block_update(optimizer, w, s, summed, counts)
-
-    oob = jnp.asarray(state.capacity, dtype=safe_uniq.dtype)
-    scatter_idx = jnp.where(valid, safe_uniq, oob)  # padding -> dropped
-    weights, slots = scatter_rows(state.weights, state.slots, scatter_idx,
-                                  new_w, new_s)
+    merged = merge_gradients(indices, grads, dedup_capacity=dedup_capacity,
+                             in_counts=in_counts)
+    weights, slots = apply_rows(state.weights, state.slots, optimizer,
+                                *merged, record_stats=record_stats)
     return TableState(weights=weights, slots=slots)
+
+
+def apply_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
+               optimizer: SparseOptimizer, rows: jnp.ndarray,
+               live: jnp.ndarray, summed: jnp.ndarray, counts: jnp.ndarray,
+               fresh: Optional[jnp.ndarray] = None,
+               inserted: Optional[jnp.ndarray] = None,
+               *, record_stats: bool = False):
+    """The sparse apply over a deduplicated buffer: gather the rows, run the
+    optimizer, scatter them back. Shared by the array and the hash
+    ``apply_gradients``.
+
+    ``rows`` [capacity] is each buffer slot's table row, ``live`` the slots
+    that hold one (a dead slot's row may be anything: it is read as row 0
+    and its write is dropped), ``summed`` [capacity, dim] and ``counts``
+    the combined gradients. ``fresh`` [capacity, dim] replaces the gathered
+    weights where ``inserted`` is set (the hash path's new keys).
+
+    Both dedups leave the live slots in a prefix of the buffer, and a
+    batch's distinct rows fill a fraction of it (a third, for Criteo-shaped
+    ids). So the buffer is walked in chunks of :data:`APPLY_CHUNK` slots up
+    to the last live one, in a loop that carries the table: the cost follows
+    the rows a step touches, not the buffer's capacity. A buffer of one
+    chunk or less takes the body once, with no loop. Every live row is
+    distinct, so the rows written and their values are the one-pass
+    apply's. Arrays of one-element rows are the exception in how, not in
+    what: the trips stage their new rows and one scatter after the loop
+    writes them.
+
+    ``record_stats`` (the trace-time gate of ``alltoall.record_stat``)
+    counts ``apply_slots_live`` and ``apply_slots_walked`` a call: the
+    second over the buffer's capacity is the share of it the loop walked.
+    """
+    capacity = rows.shape[0]
+    chunk = APPLY_CHUNK
+    oob = jnp.asarray(weights.shape[0], rows.dtype)
+    arrays, tree = jax.tree.flatten((weights, slots))
+
+    def updated(arrays, rows, live, summed, counts, fresh, inserted):
+        """(where a run of slots is written, its new rows array by array)."""
+        # a dead slot gathers row 0 and is dropped on the scatter, so its
+        # (garbage) update never lands
+        at = jnp.where(live, rows, 0)
+        w, s = gather_rows(*jax.tree.unflatten(tree, arrays), at)
+        if fresh is not None:
+            w = jnp.where(inserted[:, None], fresh, w)
+        new = optimizer_block_update(optimizer, w, s, summed, counts)
+        return jnp.where(live, at, oob), jax.tree.leaves(new)
+
+    # An array of one-element rows (a linear column, Adam's powers) is
+    # written once, after the loop: the v5e compiler scatters into it by a
+    # pass of ~1.5 ms whatever the count above ~50,000 slots, and below
+    # that row by row, for as much a trip of 4,096 (PERF.md section 6).
+    late = [x[0].size == 1 for x in arrays]
+
+    def pick(xs, when):
+        return [x for x, last in zip(xs, late) if last == when]
+
+    def put(xs, when, ys):
+        ys = iter(ys)
+        return [next(ys) if last == when else x for x, last in zip(xs, late)]
+
+    # the loop's own instructions (slices, the trip count) read as
+    # apply_update; the trips' gathers and scatters keep their stages
+    @scope.stage("apply_update")
+    def walk(arrays, *per_slot):
+        bound = jnp.max(jnp.where(
+            per_slot[1], jnp.arange(1, capacity + 1, dtype=jnp.int32), 0))
+        trips = (bound + (chunk - 1)) // chunk
+        # whole chunks only: a last slice clamped backwards would gather a
+        # row the trip before it had updated, and apply its gradient twice
+        short = -capacity % chunk
+        per_slot = jax.tree.map(
+            lambda x: jnp.pad(x, [(0, short)] + [(0, 0)] * (x.ndim - 1)),
+            per_slot)
+
+        def trip(i, carry):
+            arrays, staged = carry
+            part = jax.tree.map(
+                lambda x: lax.dynamic_slice_in_dim(x, i * chunk, chunk),
+                per_slot)
+            at, new = updated(arrays, *part)
+            arrays = put(arrays, False, scatter_rows(
+                pick(arrays, False), at, pick(new, False)))
+            staged = [lax.dynamic_update_slice_in_dim(x, n, i * chunk, 0)
+                      for x, n in zip(staged, pick(new, True))]
+            return arrays, staged
+
+        arrays, staged = lax.fori_loop(0, trips, trip, (arrays, [
+            jnp.zeros((capacity + short,) + x.shape[1:], x.dtype)
+            for x in pick(arrays, True)]))
+        if staged:
+            rows, live = per_slot[:2]
+            arrays = put(arrays, True, scatter_rows(
+                pick(arrays, True), jnp.where(live, rows, oob), staged))
+        return arrays, trips * chunk
+
+    per_slot = (rows, live, summed, counts, fresh, inserted)
+    if capacity <= chunk:
+        arrays = scatter_rows(arrays, *updated(arrays, *per_slot))
+        walked = jnp.int32(capacity)
+    else:
+        arrays, walked = walk(arrays, *per_slot)
+    record_stat("apply_slots_live", jnp.sum(live, dtype=jnp.int32),
+                record_stats)
+    record_stat("apply_slots_walked", walked, record_stats)
+    return jax.tree.unflatten(tree, arrays)
 
 
 def gather_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
@@ -186,15 +309,12 @@ def gather_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
     return gather(weights, slots, at)
 
 
-def scatter_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
-                 at: jnp.ndarray, new_w: jnp.ndarray,
-                 new_s: Dict[str, jnp.ndarray]):
-    """Write updated rows back at ``at``; out-of-range entries (padding)
-    are dropped. The write half of a sparse update."""
+def scatter_rows(arrays, at: jnp.ndarray, new):
+    """Write the updated rows ``new`` back at ``at``, array by array;
+    out-of-range entries (padding) are dropped. The write half of a sparse
+    update."""
     @scope.stage("apply_scatter")
-    def scatter(weights, slots, at, new_w, new_s):
-        return (weights.at[at].set(new_w, mode="drop"),
-                {k: slots[k].at[at].set(new_s[k], mode="drop")
-                 for k in slots})
+    def scatter(arrays, at, new):
+        return [x.at[at].set(n, mode="drop") for x, n in zip(arrays, new)]
 
-    return scatter(weights, slots, at, new_w, new_s)
+    return scatter(arrays, at, new)

@@ -515,3 +515,113 @@ def test_find_or_insert_default_program_has_no_host_callback():
     small = jax.jit(ht.find_or_insert).lower(
         table, keys[:1024], valid[:1024]).compile().as_text()
     assert small.count(" while(") == 1
+
+
+# --- the chunked sparse apply (table.apply_rows) on the hash path -----------
+
+CHUNK = 8       # table.APPLY_CHUNK, set small for these tests
+ADAGRAD = {"category": "adagrad", "learning_rate": 0.5}
+UNIFORM = {"category": "uniform", "minval": -1.0, "maxval": 1.0}
+
+
+def _apply_case(case, wide):
+    """(table capacity, keys in it, the push's keys as int64, empties mixed
+    in): 6 keys are in the table before the push."""
+    rng = np.random.default_rng(29)
+    pool = rng.permutation(np.arange(1, 5000, dtype=np.int64))[:64]
+    if wide:        # distinct high words and equal low words among them
+        pool = pool + (pool % 5 << 40) - (pool % 3 << 35)
+    present, fresh = pool[:6], pool[6:]
+    mixed = np.concatenate([present[:4], fresh])
+    capacity, empties = 1024, 0
+    if case == "no_live_key":
+        push, empties = mixed[:0], 20
+    elif case == "one_live_key":
+        push = np.full(3 * CHUNK, fresh[0])
+    elif case == "one_chunk":
+        push = np.resize(mixed[:CHUNK], 3 * CHUNK)
+    elif case == "one_chunk_and_one":
+        push, empties = np.resize(mixed[:CHUNK + 1], 3 * CHUNK - 2), 2
+    elif case == "ragged_capacity":     # 21 slots in chunks of 8
+        push, empties = np.resize(mixed[:18], 20), 1
+    elif case == "every_slot_live":
+        push = mixed[:3 * CHUNK]
+    elif case == "fresh_and_failed":    # 16 slots for 6 + 20 keys
+        push, capacity = mixed[:3 * CHUNK], 16
+    elif case == "fits_one_chunk":      # no loop: the body once
+        push = np.resize(mixed[:3], CHUNK)
+    else:
+        raise ValueError(case)
+    return capacity, present, push, empties
+
+
+HASH_APPLY_CASES = ["no_live_key", "one_live_key", "one_chunk",
+                    "one_chunk_and_one", "ragged_capacity",
+                    "every_slot_live", "fresh_and_failed", "fits_one_chunk"]
+
+
+@pytest.mark.parametrize("in_counts", [False, True], ids=["", "in_counts"])
+@pytest.mark.parametrize("wide", [False, True], ids=["narrow", "wide"])
+@pytest.mark.parametrize("case", HASH_APPLY_CASES)
+def test_chunked_apply_is_the_plain_apply_bit_for_bit(monkeypatch, case,
+                                                      wide, in_counts):
+    """Against a NumPy apply that takes each key's slot from the new key
+    array (placing keys is the probe's, tested above): a key that is in the
+    table afterwards holds Adagrad's update of its row, or of its initial
+    row if the push inserted it; no other row moved; a key the window had no
+    room for is counted."""
+    from test_table import dyadic, numpy_adagrad, recorded
+    monkeypatch.setattr(ht.table_lib, "APPLY_CHUNK", CHUNK)
+    rng = np.random.default_rng(7)
+    capacity, present, push, empties = _apply_case(case, wide)
+    opt = make_optimizer(ADAGRAD)
+
+    def as_keys(k64):
+        return jnp.asarray(ht.split64(k64) if wide
+                           else k64.astype(np.int32))
+
+    empty = ht.empty_key(jnp.int32)
+    keys = np.asarray(as_keys(push))
+    keys = np.concatenate([keys, np.full((empties,) + keys.shape[1:],
+                                         empty, np.int32)])
+    keys = keys[rng.permutation(len(keys))]
+    n = len(keys)
+    grads = dyadic(rng, (n, DIM))
+    counts = rng.integers(1, 4, size=n) if in_counts else None
+    before = ht.create_hash_table(META, opt, capacity=capacity,
+                                  key_width=64 if wide else 32)
+    before = ht.insert_rows(before, as_keys(present),
+                            dyadic(rng, (6, DIM)),
+                            {"accum": 1 + dyadic(rng, (6, DIM)) ** 2})
+    new, stats = recorded(lambda: jax.jit(
+        lambda t, k, g, c: ht.apply_gradients(
+            t, opt, UNIFORM, k, g, in_counts=c, record_stats=True))(
+                before, jnp.asarray(keys), grads, counts))
+
+    # distinct keys in the unique buffer's order: by (high word,) low word
+    distinct = np.unique(keys.reshape(n, -1)[:, ::-1], axis=0)[:, ::-1]
+    query = jnp.asarray(distinct.reshape((-1,) + keys.shape[1:]))
+    valid = distinct[:, -1] != empty
+    was = np.asarray(ht.find_rows(before.keys, query))
+    now = np.asarray(ht.find_rows(new.keys, query))
+    initial = np.asarray(ht.pull(before, query, UNIFORM))
+    want_w = np.asarray(before.weights).copy()
+    want_a = np.asarray(before.slots["accum"]).copy()
+    for i in np.flatnonzero(valid & (now >= 0)):
+        g = grads[(keys.reshape(n, -1) == distinct[i]).all(axis=1)].sum(
+            axis=0, dtype=np.float32)
+        assert was[i] in (-1, now[i])
+        w = want_w[now[i]] if was[i] >= 0 else initial[i]
+        want_w[now[i]], want_a[now[i]] = numpy_adagrad(w, want_a[now[i]], g)
+    np.testing.assert_array_equal(np.asarray(new.weights), want_w)
+    np.testing.assert_array_equal(np.asarray(new.slots["accum"]), want_a)
+    failed = int((valid & (now < 0)).sum())
+    assert int(new.insert_failures) == failed
+    assert failed == (10 if case == "fresh_and_failed" else 0)
+    live = valid & (now >= 0)
+    bound = int(np.flatnonzero(live).max()) + 1 if live.any() else 0
+    walked = n if n <= CHUNK else -(-bound // CHUNK) * CHUNK
+    assert {k: stats.get(k, 0) for k in (
+        "apply_slots_live", "apply_slots_walked")} == {
+            "apply_slots_live": int(live.sum()),
+            "apply_slots_walked": walked}

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import chip_smoke
-from benchmark import stage_reduce
+from benchmark import stage_reduce, trace_reduce
 from openembedding_tpu import hash_table as hl
 from openembedding_tpu.analysis import contracts
 from openembedding_tpu.data import criteo
@@ -50,14 +50,24 @@ def _abstract(tree, shardings):
         tree, shardings)
 
 
-@functools.lru_cache(maxsize=None)      # two tests read each program
+# The benchmark's cells (benchmark/configs): 26 x 3 * 2**20 array rows a chip,
+# 2**26 hash slots. chip_smoke's own sizes make arrays small enough (a key
+# array of 32 MiB, a linear column of 104 MiB) for the compiler to stage
+# them through fast memory in copies; the cells' are not.
+ROWS_PER_FEATURE = 3 << 20
+HASH_CAPACITY = 1 << 26
+
+
+@functools.lru_cache(maxsize=None)      # several tests read each program
 def _compile_deepfm_step(mesh, *, use_hash):
     """The step program of chip_smoke.py's training phases, from shapes
     alone."""
-    coll, trainer, mapper = chip_smoke.build_deepfm(mesh, use_hash=use_hash)
+    rows = ROWS_PER_FEATURE * mesh.size
+    coll, trainer, mapper = chip_smoke.build_deepfm(
+        mesh, use_hash=use_hash, rows_per_feature=rows,
+        hash_capacity=HASH_CAPACITY)
     batch = mapper.fuse_batch(next(iter(criteo.synthetic_criteo(
-        chip_smoke.BATCH, num_buckets=chip_smoke.ROWS_PER_FEATURE,
-        num_batches=1))))
+        chip_smoke.BATCH, num_buckets=rows, num_batches=1))))
     state = jax.eval_shape(trainer.init, jax.random.PRNGKey(0), batch)
     repl = NamedSharding(mesh, P())
     state = state.replace(
@@ -84,7 +94,7 @@ def test_deepfm_step_compiles_for_v5e(v5e, shape, use_hash):
         assert not ops, f"one chip has no peer to exchange with: {ops}"
     else:
         assert "all-to-all" in ops, ops
-    # two 27M-row tables + Adagrad slots (array) must fit the 16 GB chip
+    # two 82M-row tables + Adagrad slots (array) must fit the 16 GB chip
     assert compiled.memory_analysis().argument_size_in_bytes < 15 << 30
 
 
@@ -134,10 +144,38 @@ def test_v5e_hash_push_finds_then_inserts_in_place(v5e, shape):
                          hlo)
     if mesh.size == 1:
         assert len(loops) == 6, loops
-        keys = f"s32[{chip_smoke.HASH_CAPACITY},2]"
+        keys = f"s32[{HASH_CAPACITY},2]"
         copies = [line.strip()[:120] for line in hlo.splitlines()
                   if f"= {keys}" in line and " copy(" in line]
         assert not copies, copies
+
+
+@pytest.mark.parametrize("shape,use_hash", [((1, 1), False), ((1, 1), True),
+                                            ((2, 2), False)],
+                         ids=["1x1-array", "1x1-hash", "2x2-array"])
+def test_v5e_apply_walks_its_buffer_in_place(v5e, shape, use_hash):
+    """The sparse apply of each of the two tables is a loop over the chunks
+    of the unique buffer, under an apply stage and under no conditional
+    (on four chips the push's branches only merge; the apply follows
+    them): a loop in a branch gets its table copied in. No copy of an
+    array as long as a device's share of a table is left."""
+    data, model = shape
+    mesh = create_mesh(data, model, v5e[:data * model])
+    hlo = _compile_deepfm_step(mesh, use_hash=use_hash).as_text()
+    paths = trace_reduce.scope_names(hlo)
+    stages = stage_reduce.instruction_stages(hlo, paths)
+    found = [m.groups() for m in map(_OPCODE.match, hlo.splitlines()) if m]
+    loops = [inst for inst, op in found if op == "while"
+             and stages.get(inst, "").startswith("apply_")]
+    assert len(loops) == 2, loops
+    assert not [inst for inst in loops if "/cond/" in paths.get(inst, "")]
+    rows = (HASH_CAPACITY // mesh.size if use_hash
+            else chip_smoke.FEATURES * ROWS_PER_FEATURE)
+    copied = [line.strip()[:120] for line in hlo.splitlines()
+              if (" copy(" in line or " copy-start(" in line)
+              and int((re.search(r"= \(?\w+\[(\d+)", line) or [0, 0])[1])
+              >= rows]
+    assert not copied, copied
 
 
 def _on(dev, shape, dtype):
