@@ -425,7 +425,7 @@ def analyze_overlap(hlo_text: str) -> OverlapReport:
     branch counts as one exchange node). Exchange nodes are scoped
     pull/push by their ``op_name`` trace paths — the plane-identifiable
     ``jit(pull_*)`` / ``jit(push_*)`` scopes every data-plane program
-    carries (``sharded_table``/``sharded_hash``/``grouped``).
+    carries (``parallel/sharded.py``, ``parallel/grouped.py``).
     """
     entry, comps = parse_hlo_computations(hlo_text)
     instrs = comps.get(entry, [])

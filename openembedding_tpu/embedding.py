@@ -43,6 +43,7 @@ from .meta import (EmbeddingVariableMeta, ModelMeta, ModelVariableMeta,
 from .optim.initializers import make_initializer
 from .optim.optimizers import make_optimizer
 from . import table as table_lib
+from .parallel import sharded
 from .parallel import sharded_table as st
 from .parallel import sharded_hash as sh
 from .parallel.mesh import MODEL_AXIS
@@ -156,6 +157,10 @@ class EmbeddingCollection:
         self._optimizers = {}
         self._initializers = {}
         self._shardings = {}
+        # what parallel/sharded.py's builder asks of each table's kind;
+        # the serving (read_only) contract is a store of its own
+        self._stores = {}
+        self._serving_stores = {}
         for i, spec in enumerate(specs):
             if spec.name in self.specs:
                 raise ValueError(f"duplicate embedding name {spec.name!r}")
@@ -178,6 +183,11 @@ class EmbeddingCollection:
                     cache_k=spec.cache_k,
                     exchange_precision=spec.exchange_precision,
                     push_precision=spec.push_precision)
+                self._stores[spec.name] = sh.HashStore(
+                    self._shardings[spec.name],
+                    self._initializers[spec.name])
+                self._serving_stores[spec.name] = sh.HashStore(
+                    self._shardings[spec.name])
             else:
                 self._shardings[spec.name] = st.make_sharding_spec(
                     spec.meta(), mesh, num_shards=spec.num_shards,
@@ -186,6 +196,9 @@ class EmbeddingCollection:
                     cache_k=spec.cache_k,
                     exchange_precision=spec.exchange_precision,
                     push_precision=spec.push_precision)
+                store = st.ArrayStore(self._shardings[spec.name])
+                self._stores[spec.name] = store
+                self._serving_stores[spec.name] = store
 
     # --- dirty tracking (delta checkpoints, checkpoint.py mode="delta") ----
     def enable_dirty_tracking(self, *, target_chunks: int = 1024,
@@ -399,17 +412,14 @@ class EmbeddingCollection:
         # masked-local program and returns a bare table, so attaching a
         # wrapper here would flip the state pytree STRUCTURE after the
         # first push (a forced retrace under the donated step jit)
-        if getattr(sspec, "is_int8_ef", False) and sspec.num_shards > 1 \
+        if sspec.is_int8_ef and sspec.num_shards > 1 \
                 and not isinstance(table_state, precision.EFState):
-            spec = self.specs[name]
-            wide = spec.use_hash and spec.key_dtype == "wide"
-            sentinel, key_dtype = precision.ef_key_space(
-                use_hash=spec.use_hash, wide=wide,
-                key_dtype=None if wide or not spec.use_hash
-                else spec.key_dtype)
-            return precision.empty_ef(table_state, dim=spec.output_dim,
-                                      wide=wide, sentinel=sentinel,
-                                      key_dtype=key_dtype)
+            # the key space the push dispatch sizes the residual in
+            # (precision.ef_key_space): were the two to differ, sized_ef
+            # would reset the residual every step
+            return precision.empty_ef(
+                table_state, dim=self.specs[name].output_dim,
+                **self._stores[name].ef_space(table_state))
         return hot_cache.attach_empty(table_state, sspec, self.mesh)
 
     def state_shardings(self) -> Dict[str, Any]:
@@ -463,16 +473,11 @@ class EmbeddingCollection:
             spec = self.specs[name]
             if name in raw:
                 r = raw[name]
-            elif spec.use_hash:
-                r = sh.pull_sharded(
-                    states[name], idx,
-                    None if read_only else self._initializers[name],
-                    mesh=self.mesh, spec=self._shardings[name],
-                    batch_sharded=batch_sharded)
             else:
-                r = st.pull_sharded(
-                    states[name], idx, mesh=self.mesh,
-                    spec=self._shardings[name], batch_sharded=batch_sharded)
+                stores = self._serving_stores if read_only else self._stores
+                r = sharded.pull_sharded(
+                    states[name], idx, mesh=self.mesh, store=stores[name],
+                    batch_sharded=batch_sharded)
             if spec.pooling and not serving_rows:
                 # wide sequence features carry [B, L, 2] pair ids; the
                 # combiner counts validity on the hi word (ragged.py)
@@ -565,17 +570,10 @@ class EmbeddingCollection:
                 grouped_idx[name] = idx_in
                 grouped_grads[name] = g
                 continue
-            if spec.use_hash:
-                new_states[name] = sh.apply_gradients_sharded(
-                    states[name], self._optimizers[name],
-                    self._initializers[name], idx_in, g,
-                    mesh=self.mesh, spec=self._shardings[name],
-                    batch_sharded=batch_sharded)
-            else:
-                new_states[name] = st.apply_gradients_sharded(
-                    states[name], self._optimizers[name], idx_in, g,
-                    mesh=self.mesh, spec=self._shardings[name],
-                    batch_sharded=batch_sharded)
+            new_states[name] = sharded.apply_gradients_sharded(
+                states[name], self._optimizers[name], idx_in, g,
+                mesh=self.mesh, store=self._stores[name],
+                batch_sharded=batch_sharded)
         if grouped_idx:
             from .parallel import grouped
             new_states.update(grouped.apply_gradients_grouped(

@@ -1,0 +1,453 @@
+"""Sharded embedding tables over a device mesh: the one pull/push builder.
+
+TPU-native replacement for the reference's parameter-server data plane:
+
+* The reference shards each variable's key space ``index % global_shard_num``
+  across PS processes and pulls rows by RPC
+  (/root/reference/openembedding/server/EmbeddingPullOperator.cpp:60-112,
+  key stored as ``index / shard_num``). Here the same modulo layout shards
+  rows across TPU devices along the mesh ``model`` axis, and the pull is a
+  shard_map region: local gather of owned rows + ``psum`` over the model
+  axis — XLA collectives over ICI instead of TCP/RDMA round trips.
+* The push + store pipeline (client pre-reduce -> MpscGradientReducer ->
+  EmbeddingStoreOperator commit, EmbeddingPushOperator.cpp:29-161,
+  EmbeddingStoreOperator.cpp:23-81) becomes: ``all_gather`` of (indices,
+  row-grads) over the data axis, then every model shard dedups/combines the
+  global batch, masks ownership, and applies its rows' optimizer update
+  locally — one fused XLA program, synchronous per step (the reference's
+  fake-gradient batch barrier is unnecessary: the SPMD step IS the barrier).
+* ``num_shards`` semantics: the reference's shard-per-server default
+  (WorkerContext.cpp:66-85) corresponds to one shard per mesh model slice.
+
+Data planes (``PlaneSpec.plane``, shared by array and hash tables):
+* ``"a2a"`` (default) — owner-routed all-to-all exchange (see
+  ``parallel/alltoall.py``): tables sharded over the WHOLE mesh (data x
+  model), per-device traffic O(batch_slice * dim). The reference's
+  dedup->shard->request->scatter pipeline, TPU-native.
+* ``"psum"`` — tables sharded over the model axis only (replicated across
+  the data axis); pull = gather + psum, push = all_gather + masked local
+  update. Simpler program, more ICI bytes and D-fold HBM replication; kept
+  as the ablation baseline and for meshes where replicas are wanted.
+* ``"a2a+cache"`` — the a2a layout plus a frequency-tracked top-K hot-row
+  replica in every device's HBM (``parallel/hot_cache.py``): pulls for hot
+  keys are served locally with no exchange round, pushes pre-reduce
+  locally and merge with one psum over the K cached rows — exactly
+  equivalent to ``"a2a"``, built for Zipfian key streams.
+* ``"a2a+grouped"`` — the a2a layout, but the COLLECTION batches all
+  same-shape tables into one exchange per group per step
+  (``parallel/grouped.py``): a T-table model pays O(#groups) collective
+  rounds instead of O(T). Per-table calls on this plane (serving probes,
+  checkpoint paths) behave exactly like ``"a2a"``.
+* ``"a2a+pipelined"`` — the a2a layout, but the TRAINER double-buffers
+  the exchange (``parallel/pipelined.py``): batch N+1's rows are pulled
+  inside step N's jitted program (after step N's push commits, so
+  results stay bit-identical to ``"a2a"``) and the pull's index/key-leg
+  collectives overlap step N's dense compute. Per-table calls behave
+  exactly like ``"a2a"`` — the plane only changes the step schedule.
+* ``"a2a+grouped+pipelined"`` — both: grouped collection-level exchange
+  AND the pipelined step schedule, so the prefetched exchange is one
+  collective round per GROUP.
+
+One builder, two stores. Every plane variant (routed, masked-local,
+hot-cache, int8-EF) is written here once; what differs between an array
+table and a hash table sits behind a *store*: a small frozen (hashable: it
+keys the program caches) object next to the state it understands,
+``sharded_table.ArrayStore`` and ``sharded_hash.HashStore``. A store
+answers:
+
+* ``spec`` (a :class:`PlaneSpec`), ``prefix`` (of the program's name),
+  ``key_bytes`` (of a key on the wire, for the cache statistics);
+* ``operands(table)`` — the arrays of the state that enter a program,
+  ``specs(slot_names)`` — their PartitionSpecs (a program's table outputs
+  are laid out like its operands), ``local(*operands)`` — the kind's
+  per-shard state inside ``shard_map`` (with ``.weights`` / ``.slots``),
+  ``rebuild(table, outs)`` — a program's outputs as a state again;
+* ``batch_shape(idx.shape)``, ``sentinel(dtype)``, ``valid(flat)``,
+  ``owner(keys)`` — the key space;
+* ``resolve(local, keys, me)`` / ``read_local(local, flat)`` — the pull's
+  owner side behind the exchange / on the masked-local body;
+* ``carry(local)``, ``merge(local, carry, keys, grads, counts, me, ...)``
+  / ``apply_local(local, optimizer, flat, grads, ...)`` — the push's owner
+  side likewise, ``outputs(carry, weights, slots, axes)`` — what leaves
+  the program, ``slot_of(carry, keys, me)`` — a key's slot in this shard
+  (-1: not here), where the owner writes a cached key's row back;
+* ``ef_space(table)`` — the int8-EF residual's key space.
+
+No code in this file asks which kind it holds: a step that needs to is a
+method the store lacks.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax, shard_map
+from jax.sharding import Mesh, PartitionSpec as P
+
+from ..analysis import scope
+from ..optim.optimizers import SparseOptimizer, make_optimizer
+from ..utils import observability
+from .. import table as table_lib
+from . import alltoall as a2a
+from . import hot_cache
+from . import precision
+from .mesh import DATA_AXIS, MODEL_AXIS
+
+
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class PlaneSpec:
+    """The fields the sharding specs of both kinds share and what derives
+    from them; each spec adds its own fields and layout maths."""
+
+    num_shards: int
+    data_axis: str = DATA_AXIS
+    model_axis: str = MODEL_AXIS
+    plane: str = "a2a"   # "a2a" | "psum" | "a2a+cache" | "a2a+grouped"
+                         # | "a2a+pipelined" | "a2a+grouped+pipelined"
+    a2a_capacity: int = 0    # per-destination bucket rows; 0 = auto
+    a2a_slack: float = 2.0   # auto capacity = slack * mean bucket size
+    cache_k: int = 0         # hot-row replica slots ("a2a+cache" plane)
+    # compressed-exchange rungs (parallel/precision.py): pulled rows /
+    # pushed pre-reduced grads on the wire; master weights + optimizer
+    # slots stay at the table's storage dtype in the shard
+    exchange_precision: str = "f32"   # "f32" | "bf16"
+    push_precision: str = "f32"       # "f32" | "bf16" | "int8_ef"
+
+    @property
+    def is_cached(self) -> bool:
+        return self.plane == "a2a+cache"
+
+    @property
+    def routes(self) -> bool:
+        """Pull and push ride the owner-routed exchange. A single shard
+        has nothing to route: the masked-local body (whose collectives are
+        free over size-1 axes) skips the bucketing machinery (~25% faster
+        on one chip for the headline config), as the ``psum`` plane does
+        by definition. The cached plane always routes: its residue masking
+        composes with the exchange. A grouped-plane table addressed PER
+        TABLE (serving probes, checkpoint paths) takes the plain a2a
+        program — grouping only exists at the collection level."""
+        return (self.plane != "psum" and self.num_shards > 1) \
+            or self.is_cached
+
+    @property
+    def plane_label(self) -> str:
+        """Observable plane token incl. the precision suffix — keys the
+        HLO module names, plane_timed spans, contract registry and the
+        graftscope byte ledger (``precision.plane_label``)."""
+        return precision.plane_label(self.plane, self.exchange_precision,
+                                     self.push_precision)
+
+    @property
+    def pull_wire_dtype(self):
+        return precision.wire_dtype(self.exchange_precision)
+
+    @property
+    def push_wire_dtype(self):
+        # int8_ef carries its own int8 payload inside exchange_push
+        return precision.wire_dtype(self.push_precision) \
+            if self.push_precision == "bf16" else None
+
+    @property
+    def is_int8_ef(self) -> bool:
+        return self.push_precision == "int8_ef"
+
+    @property
+    def is_grouped(self) -> bool:
+        """Collection-level multi-table exchange (``parallel/grouped.py``)."""
+        return self.plane in ("a2a+grouped", "a2a+grouped+pipelined")
+
+    @property
+    def is_pipelined(self) -> bool:
+        """Trainer-level double-buffered exchange schedule
+        (``parallel/pipelined.py``)."""
+        return self.plane in ("a2a+pipelined", "a2a+grouped+pipelined")
+
+    @property
+    def shard_axes(self) -> tuple:
+        """Mesh axes the table's row dimension is sharded over."""
+        if self.plane != "psum":
+            return (self.data_axis, self.model_axis)
+        return (self.model_axis,)
+
+    def row_spec(self) -> P:
+        return P(self.shard_axes)
+
+
+def _flat_keys(store, idx: jnp.ndarray, dim: int):
+    """``idx`` as the flat key stream and the shape of its rows."""
+    batch_shape = store.batch_shape(idx.shape)
+    return (idx.reshape((-1,) + idx.shape[len(batch_shape):]),
+            batch_shape + (dim,))
+
+
+def _program_name(store, verb: str) -> str:
+    # plane-identifiable HLO module name (jit names the module after the
+    # callable): a contract-audit failure then says WHICH plane's
+    # program regressed (analysis/contracts.py); compressed planes carry
+    # their precision suffix (pull_a2a_bf16, ...)
+    return f"{store.prefix}{verb}_{store.spec.plane_label.replace('+', '_')}"
+
+
+def _exchange_args(mesh: Mesh, spec: PlaneSpec, batch_sharded: bool,
+                   record_stats: bool) -> dict:
+    """What ``a2a.exchange_pull`` and ``a2a.exchange_push`` are both told."""
+    grid_axes, grid_sizes, split_axes, split_sizes = a2a.grid_info(
+        mesh, spec.shard_axes, spec.model_axis, batch_sharded)
+    return dict(num_shards=spec.num_shards, grid_axes=grid_axes,
+                grid_sizes=grid_sizes, split_axes=split_axes,
+                split_sizes=split_sizes, capacity=spec.a2a_capacity,
+                slack=spec.a2a_slack, record_stats=record_stats)
+
+
+def _my_shard(grid: dict) -> jnp.ndarray:
+    return a2a.linear_shard_id(grid["grid_axes"], grid["grid_sizes"])
+
+
+@functools.lru_cache(maxsize=None)
+def _pull_program(mesh: Mesh, store, dim: int, batch_sharded: bool,
+                  record_stats: bool = False):
+    """Cached jitted pull: eager callers (serving lookups, tests) would
+    otherwise rebuild + retrace the shard_map closure every call."""
+    spec = store.spec
+    batch_spec = P(spec.data_axis) if batch_sharded else P()
+    cache_specs = ()
+
+    if spec.routes:
+        grid = _exchange_args(mesh, spec, batch_sharded, record_stats)
+
+        def _pull_core(arrays, flat, me):
+            local = store.local(*arrays)
+            return a2a.exchange_pull(
+                flat, lambda keys: store.resolve(local, keys, me),
+                store.owner, sentinel=store.sentinel(flat.dtype), dim=dim,
+                wire_dtype=spec.pull_wire_dtype, **grid)
+
+        if spec.is_cached:
+            cache_specs = (P(), P())        # replicated on every device
+
+            def _pull(arrays, ckeys, crows, idx):
+                flat, out_shape = _flat_keys(store, idx, dim)
+                valid = store.valid(flat)
+                pos, hit = hot_cache.lookup(ckeys, flat, valid)
+                served = jnp.where(hit[:, None],
+                                   jnp.take(crows, pos, axis=0),
+                                   jnp.zeros((1, dim), crows.dtype))
+                hot_cache.record_cache_stats(
+                    hit, valid,
+                    entry_bytes=dim * crows.dtype.itemsize + store.key_bytes,
+                    split_axes=grid["split_axes"],
+                    split_sizes=grid["split_sizes"], record=record_stats)
+                resid = hot_cache.mask_hits(flat, hit,
+                                            store.sentinel(flat.dtype))
+                rows = _pull_core(arrays, resid, _my_shard(grid))
+                return (rows + served).reshape(out_shape)
+        else:
+            def _pull(arrays, idx):
+                me = _my_shard(grid)
+                flat, out_shape = _flat_keys(store, idx, dim)
+                return _pull_core(arrays, flat, me).reshape(out_shape)
+    else:
+        def _pull(arrays, idx):
+            flat, out_shape = _flat_keys(store, idx, dim)
+            rows = scope.stage("exchange")(
+                lambda rows: lax.psum(rows, spec.model_axis))(
+                    store.read_local(store.local(*arrays), flat))
+            return rows.reshape(out_shape)
+
+    _pull.__name__ = _program_name(store, "pull")
+    fn = shard_map(_pull, mesh=mesh,
+                   in_specs=(store.specs(()),) + cache_specs + (batch_spec,),
+                   out_specs=batch_spec,
+                   check_vma=False)
+    return jax.jit(fn)
+
+
+def pull_sharded(state, indices: jnp.ndarray, *, mesh: Mesh, store,
+                 batch_sharded: bool = True) -> jnp.ndarray:
+    """Distributed embedding lookup through ``store``'s table: the
+    reference's pull RPC fan-out + response scatter
+    (EmbeddingPullOperator.cpp:40-252).
+
+    ``indices``: any shape (wide hash keys: a trailing pair axis), sharded
+    over the data axis on dim 0 when ``batch_sharded`` (the normal training
+    path) else replicated. Returns rows with the same batch sharding. On the
+    ``"a2a+cache"`` plane ``state`` is a :class:`hot_cache.CachedState`.
+    """
+    spec = store.spec
+    record = observability.evaluate_performance()
+    if spec.is_cached:
+        table, cache = state.table, (state.cache.keys, state.cache.rows)
+    else:
+        # int8_ef states wrap the table with the push residual; pulls read
+        # through the wrapper (serving restores may hand a bare table)
+        table, cache = precision.unwrap(state), ()
+    fn = _pull_program(mesh, store, table.weights.shape[-1], batch_sharded,
+                       record)
+    return observability.plane_timed(
+        "pull", spec.plane_label, record, fn,
+        store.operands(table.replace(slots={})), *cache, indices)
+
+
+@functools.lru_cache(maxsize=None)
+def _apply_program(mesh: Mesh, store, optimizer: SparseOptimizer, dim: int,
+                   batch_sharded: bool, dedup_capacity: Optional[int],
+                   slot_names: tuple, record_stats: bool = False):
+    spec = store.spec
+    batch_spec = P(spec.data_axis) if batch_sharded else P()
+    table_specs = store.specs(slot_names)
+    extra_in = extra_out = ()
+
+    if spec.routes:
+        grid = _exchange_args(mesh, spec, batch_sharded, record_stats)
+
+        def _push_core(arrays, flat, g2, ef=None):
+            local = store.local(*arrays)
+            merge_fn = functools.partial(
+                store.merge, local, me=_my_shard(grid),
+                dedup_capacity=dedup_capacity, record_stats=record_stats)
+            out = a2a.exchange_push(
+                flat, g2, store.carry(local), merge_fn, store.owner,
+                sentinel=store.sentinel(flat.dtype),
+                wire_dtype=spec.push_wire_dtype, ef_state=ef, **grid)
+            (carry, merged), new_ef = out if ef is not None else (out, ())
+            weights, slots = table_lib.apply_rows(
+                local.weights, local.slots, optimizer, *merged,
+                record_stats=record_stats)
+            return carry, weights, slots, new_ef
+
+        if spec.is_cached:
+            cache_slot_specs = {name: P() for name in slot_names}
+            extra_in = (P(), P(), cache_slot_specs)
+            extra_out = (P(), cache_slot_specs)
+
+            def _apply(arrays, ckeys, crows, cslots, idx, g):
+                me = _my_shard(grid)
+                flat, _ = _flat_keys(store, idx, dim)
+                g2 = g.reshape(-1, dim)
+                valid = store.valid(flat)
+                pos, hit = hot_cache.lookup(ckeys, flat, valid)
+                summed, counts = hot_cache.cache_pre_reduce(
+                    pos, hit, g2, ckeys.shape[0], grid["split_axes"],
+                    grid["split_sizes"], grid["grid_axes"])
+                hot_cache.record_cache_stats(
+                    hit, valid,
+                    entry_bytes=dim * crows.dtype.itemsize
+                    + store.key_bytes + 4,
+                    split_axes=grid["split_axes"],
+                    split_sizes=grid["split_sizes"], record=record_stats)
+                # residue rides the exchange with hits masked invalid
+                resid = hot_cache.mask_hits(flat, hit,
+                                            store.sentinel(flat.dtype))
+                carry, weights, slots, _ = _push_core(arrays, resid, g2)
+                # identical psum'd totals on every device -> identical
+                # replica update everywhere; the owner scatters its rows
+                # back so the table stays authoritative
+                cache = hot_cache.update_replica(
+                    optimizer, hot_cache.HotCacheState(
+                        keys=ckeys, rows=crows, slots=cslots),
+                    summed, counts)
+                # owner write-back: admitted keys are PRESENT in their
+                # owner's shard; the scatter drops non-owned / untouched
+                # rows
+                slot = store.slot_of(carry, ckeys, me)
+                sc = jnp.where((slot >= 0) & (counts > 0), slot,
+                               weights.shape[0])
+                weights = weights.at[sc].set(
+                    cache.rows.astype(weights.dtype), mode="drop")
+                slots = {name: slots[name].at[sc].set(
+                    cache.slots[name].astype(slots[name].dtype),
+                    mode="drop") for name in slots}
+                return (store.outputs(carry, weights, slots,
+                                      spec.shard_axes),
+                        (cache.rows, cache.slots))
+        else:
+            if spec.is_int8_ef:
+                # the EF residual buffers shard over the exchange grid:
+                # each device owns exactly its sender slice's block
+                extra_in = extra_out = (P(spec.shard_axes),) * 2
+
+            def _apply(arrays, *rest):
+                *ef, idx, g = rest
+                flat, _ = _flat_keys(store, idx, dim)
+                carry, weights, slots, new_ef = _push_core(
+                    arrays, flat, g.reshape(-1, dim), ef=tuple(ef) or None)
+                return (store.outputs(carry, weights, slots,
+                                      spec.shard_axes), new_ef)
+    else:
+        def _apply(arrays, idx, g):
+            flat, _ = _flat_keys(store, idx, dim)
+            g2 = g.reshape(-1, dim)
+            if batch_sharded:
+                flat, g2 = scope.stage("exchange")(
+                    lambda *xs: tuple(lax.all_gather(x, spec.data_axis,
+                                                     tiled=True)
+                                      for x in xs))(flat, g2)
+            carry, weights, slots = store.apply_local(
+                store.local(*arrays), optimizer, flat, g2,
+                dedup_capacity=dedup_capacity, record_stats=record_stats)
+            return store.outputs(carry, weights, slots, spec.model_axis), ()
+
+    _apply.__name__ = _program_name(store, "push")
+    fn = shard_map(_apply, mesh=mesh,
+                   in_specs=(table_specs,) + extra_in
+                   + (batch_spec, batch_spec),
+                   out_specs=(table_specs, extra_out),
+                   check_vma=False)
+    return jax.jit(fn)
+
+
+def apply_gradients_sharded(state, optimizer: SparseOptimizer,
+                            indices: jnp.ndarray, grads: jnp.ndarray, *,
+                            mesh: Mesh, store, batch_sharded: bool = True,
+                            dedup_capacity: Optional[int] = None):
+    """Distributed push+update: every shard applies its owned rows.
+
+    On the routed planes each key's pre-reduced grads reach its single
+    owner shard; on the masked-local body data-axis devices all_gather the
+    global (indices, grads) so the update is computed identically on every
+    data replica of a model shard — replacing the reference's single-owner
+    store RPC (WorkerContext.cpp:115-123) with deterministic replicated
+    application. On the ``"a2a+cache"`` plane ``state`` is a
+    :class:`hot_cache.CachedState`.
+    """
+    spec = store.spec
+    optimizer = make_optimizer(optimizer)
+    record = observability.evaluate_performance()
+    extra = ()
+    if spec.is_cached:
+        table = state.table
+        extra = (state.cache.keys, state.cache.rows, state.cache.slots)
+    elif spec.is_int8_ef and spec.num_shards > 1:
+        # (a single shard has no wire: its push is the exact masked-local
+        # program and the state stays a bare table)
+        table = precision.unwrap(state)
+        table, *extra = precision.ensure_ef(
+            state, dim=table.weights.shape[-1],
+            n_flat=int(np.prod(store.batch_shape(indices.shape))),
+            data=mesh.shape[spec.data_axis],
+            model=mesh.shape[spec.model_axis],
+            batch_sharded=batch_sharded, **store.ef_space(table))
+    else:
+        table = precision.unwrap(state)
+    fn = _apply_program(mesh, store, optimizer, table.weights.shape[-1],
+                        batch_sharded, dedup_capacity, tuple(table.slots),
+                        record)
+    outs, new_extra = observability.plane_timed(
+        "push", spec.plane_label, record, fn,
+        store.operands(table), *extra, indices, grads)
+    table = store.rebuild(table, outs)
+    if spec.is_cached:
+        rows, slots = new_extra
+        return hot_cache.CachedState(
+            table=table, cache=hot_cache.HotCacheState(
+                keys=state.cache.keys, rows=rows, slots=slots))
+    if new_extra:
+        keys, resid = new_extra
+        return precision.EFState(table=table, keys=keys, resid=resid)
+    return table

@@ -1,17 +1,15 @@
-"""Hash-table embeddings sharded over the device mesh.
+"""Hash-table embeddings sharded over the device mesh: layout, creation, the
+bulk insert and read of whole rows, and the hash store of the shared
+pull/push builder (``parallel/sharded.py``, which describes the planes).
 
-Same two data planes as ``sharded_table`` but for unbounded key spaces:
-
-* ``"a2a"`` (default) — owner-routed exchange over the whole mesh (see
-  ``parallel/alltoall.py``): each device owns one open-addressing shard,
-  keys are partitioned ``key % num_shards`` (the reference's modulo shard
-  layout, /root/reference/openembedding/server/EmbeddingPullOperator.cpp:73-78,
-  applied to hashed keys, which are uniform by construction) and routed to
-  their single owner.
-* ``"psum"`` — shards along the model axis only (replicated over data):
-  non-owned keys are masked to the EMPTY sentinel before the local table
-  call (zero pull rows / dropped updates), so a psum over the model axis
-  reconstructs the full batch exactly once.
+Same data planes as ``sharded_table`` but for unbounded key spaces: each
+shard is one open-addressing table, and keys are partitioned ``key %
+num_shards`` (the reference's modulo shard layout,
+/root/reference/openembedding/server/EmbeddingPullOperator.cpp:73-78,
+applied to hashed keys, which are uniform by construction) to their single
+owner. Non-owned keys are masked to the EMPTY sentinel before the local
+table call (zero pull rows / dropped updates), so the masked-local body's
+psum over the model axis reconstructs the full batch exactly once.
 """
 
 from __future__ import annotations
@@ -22,7 +20,6 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -32,78 +29,23 @@ from ..utils import observability
 from ..optim.initializers import make_initializer
 from ..optim.optimizers import SparseOptimizer, make_optimizer
 from .. import hash_table as hash_lib
-from .. import table as table_lib
 from . import alltoall as a2a
-from . import hot_cache
 from . import precision
+from . import sharded
 from . import sharded_table as st
-from .mesh import DATA_AXIS, MODEL_AXIS
 
 
 @dataclasses.dataclass(frozen=True)
-class HashShardingSpec:
+class HashShardingSpec(sharded.PlaneSpec):
     """Static layout of one hash table over the mesh."""
 
-    num_shards: int
     capacity_per_shard: int
     max_probes: int = hash_lib.DEFAULT_MAX_PROBES
-    data_axis: str = DATA_AXIS
-    model_axis: str = MODEL_AXIS
-    plane: str = "a2a"   # sharded_table.PLANES member
-    a2a_capacity: int = 0
-    a2a_slack: float = 2.0
     key_width: int = 32  # 64 = [n, 2] int32 (lo, hi) pairs, x64-off
-    cache_k: int = 0     # hot-row replica slots ("a2a+cache" plane)
-    # compressed-exchange rungs (parallel/precision.py)
-    exchange_precision: str = "f32"   # "f32" | "bf16"
-    push_precision: str = "f32"       # "f32" | "bf16" | "int8_ef"
-
-    @property
-    def is_cached(self) -> bool:
-        return self.plane == "a2a+cache"
-
-    @property
-    def plane_label(self) -> str:
-        """Observable plane token incl. the precision suffix."""
-        return precision.plane_label(self.plane, self.exchange_precision,
-                                     self.push_precision)
-
-    @property
-    def pull_wire_dtype(self):
-        return precision.wire_dtype(self.exchange_precision)
-
-    @property
-    def push_wire_dtype(self):
-        return precision.wire_dtype(self.push_precision) \
-            if self.push_precision == "bf16" else None
-
-    @property
-    def is_int8_ef(self) -> bool:
-        return self.push_precision == "int8_ef"
-
-    @property
-    def is_grouped(self) -> bool:
-        """Collection-level multi-table exchange (``parallel/grouped.py``)."""
-        return self.plane in ("a2a+grouped", "a2a+grouped+pipelined")
-
-    @property
-    def is_pipelined(self) -> bool:
-        """Trainer-level double-buffered exchange schedule
-        (``parallel/pipelined.py``)."""
-        return self.plane in ("a2a+pipelined", "a2a+grouped+pipelined")
-
-    @property
-    def shard_axes(self) -> tuple:
-        if self.plane != "psum":
-            return (self.data_axis, self.model_axis)
-        return (self.model_axis,)
 
     @property
     def wide(self) -> bool:
         return self.key_width == 64
-
-    def row_spec(self) -> P:
-        return P(self.shard_axes)
 
     def owner_shard(self, keys: jnp.ndarray) -> jnp.ndarray:
         if hash_lib.is_wide(keys):
@@ -124,14 +66,11 @@ class HashShardingSpec:
 def make_hash_sharding_spec(mesh: Mesh, total_capacity: int,
                             num_shards: int = -1,
                             max_probes: int = hash_lib.DEFAULT_MAX_PROBES,
-                            plane: str = "a2a",
-                            a2a_capacity: int = 0,
-                            a2a_slack: float = 2.0,
-                            key_width: int = 32,
+                            plane: str = "a2a", a2a_capacity: int = 0,
+                            a2a_slack: float = 2.0, key_width: int = 32,
                             cache_k: int = 0,
                             exchange_precision: str = "f32",
-                            push_precision: str = "f32"
-                            ) -> HashShardingSpec:
+                            push_precision: str = "f32") -> HashShardingSpec:
     """num_shards=-1 => one shard per device ("a2a") / per model slice ("psum").
 
     ``plane="a2a+cache"``: a2a layout plus a ``cache_k``-row hot-row replica
@@ -139,23 +78,11 @@ def make_hash_sharding_spec(mesh: Mesh, total_capacity: int,
     A ``+bf16``/``+int8`` plane suffix selects the compressed-exchange
     rungs (``parallel/precision.py``).
     """
-    plane, exchange_precision, push_precision = st._resolve_precision(
-        plane, exchange_precision, push_precision)
-    if plane not in st.PLANES:
-        raise ValueError(f"unknown plane {plane!r}")
     if key_width not in (32, 64):
         raise ValueError(f"key_width must be 32 or 64, got {key_width}")
-    want = mesh.shape[MODEL_AXIS] if plane == "psum" else mesh.size
-    if num_shards == -1:
-        num_shards = want
-    if num_shards != want:
-        raise ValueError(
-            f"num_shards={num_shards} must equal the {plane}-plane shard "
-            f"count {want} for this mesh (or pass -1)")
-    if plane == "a2a+cache" and cache_k <= 0:
-        cache_k = hot_cache.DEFAULT_CACHE_K
-    if plane != "a2a+cache":
-        cache_k = 0
+    plane, num_shards, cache_k, exchange_precision, push_precision = \
+        st._resolve_plane(mesh, plane, num_shards, cache_k,
+                          exchange_precision, push_precision)
     cap = hash_lib.round_capacity(-(-total_capacity // num_shards))
     return HashShardingSpec(num_shards=num_shards, capacity_per_shard=cap,
                             max_probes=max_probes, plane=plane,
@@ -175,24 +102,13 @@ def table_state_specs(optimizer: SparseOptimizer, dim: int,
 
 
 def state_specs(optimizer: SparseOptimizer, dim: int, spec: HashShardingSpec):
-    table = table_state_specs(optimizer, dim, spec)
-    if spec.is_cached:
-        return hot_cache.CachedState(
-            table=table,
-            cache=hot_cache.HotCacheState(
-                keys=P(), rows=P(),
-                slots={name: P() for name in table.slots}))
-    return table
+    return st.with_cache_specs(table_state_specs(optimizer, dim, spec), spec)
 
 
-def create_sharded_hash_table(meta: EmbeddingVariableMeta,
-                              optimizer: Any,
-                              *,
-                              mesh: Mesh,
-                              spec: HashShardingSpec,
+def create_sharded_hash_table(meta: EmbeddingVariableMeta, optimizer: Any, *,
+                              mesh: Mesh, spec: HashShardingSpec,
                               rng: Optional[jax.Array] = None,
-                              key_dtype=jnp.int32,
-                              wrap_cache: bool = True):
+                              key_dtype=jnp.int32, wrap_cache: bool = True):
     """Allocate per-shard empty hash tables across the mesh.
 
     The per-key deterministic init uses the shared base rng (not folded per
@@ -201,8 +117,6 @@ def create_sharded_hash_table(meta: EmbeddingVariableMeta,
     resharded).
     """
     optimizer = make_optimizer(optimizer)
-    if rng is None:
-        rng = jax.random.PRNGKey(0)
     dim = meta.embedding_dim
 
     def _init(key):
@@ -211,18 +125,8 @@ def create_sharded_hash_table(meta: EmbeddingVariableMeta,
             capacity=spec.capacity_per_shard, rng=key, key_dtype=key_dtype,
             key_width=spec.key_width)
 
-    fn = shard_map(_init, mesh=mesh,
-                   in_specs=(P(),),
-                   out_specs=table_state_specs(optimizer, dim, spec),
-                   check_vma=False)
-    state = jax.jit(fn)(rng)
-    if wrap_cache:
-        # all-pad replica: zero hits (pure-a2a behavior) until the first
-        # admission refresh (hot_cache.HotCacheManager / build_cache).
-        # ``wrap_cache=False`` returns the bare table (callers composing
-        # their own jitted init wrap eagerly afterwards).
-        return hot_cache.attach_empty(state, spec, mesh)
-    return state
+    return st._create(_init, table_state_specs(optimizer, dim, spec),
+                      mesh=mesh, spec=spec, rng=rng, wrap_cache=wrap_cache)
 
 
 def _mask_non_owned(spec: HashShardingSpec, flat: jnp.ndarray,
@@ -264,9 +168,7 @@ def _insert_rows_program(mesh: Mesh, spec: HashShardingSpec,
     program follows the insert."""
 
     def _insert(tkeys, tweights, tslots, failures, init_rng, k, w, srows):
-        local = hash_lib.HashTableState(
-            keys=tkeys, weights=tweights, slots=tslots, init_rng=init_rng,
-            insert_failures=jnp.zeros((), jnp.int32))
+        local = HashStore(spec).local(tkeys, tweights, tslots, init_rng)
         flat = k.reshape(-1, 2) if spec.wide else k.ravel()
         masked = _mask_non_owned(spec, flat, _my_shard(mesh, spec))
         new = hash_lib.insert_rows(local, masked, w, srows or None,
@@ -314,9 +216,8 @@ def insert_rows_sharded(state: hash_lib.HashTableState,
     tkeys, tweights, tslots, failures = fn(
         state.keys, state.weights, state.slots, state.insert_failures,
         state.init_rng, keys, weights, slot_rows)
-    return hash_lib.HashTableState(
-        keys=tkeys, weights=tweights, slots=tslots,
-        init_rng=state.init_rng, insert_failures=failures)
+    return state.replace(keys=tkeys, weights=tweights, slots=tslots,
+                         insert_failures=failures)
 
 
 @functools.lru_cache(maxsize=None)
@@ -339,9 +240,7 @@ def _insert_packed_program(mesh: Mesh, spec: HashShardingSpec,
     of every instruction's ``op_name``."""
 
     def _insert(tkeys, tweights, tslots, failures, init_rng, packed):
-        local = hash_lib.HashTableState(
-            keys=tkeys, weights=tweights, slots=tslots, init_rng=init_rng,
-            insert_failures=jnp.zeros((), jnp.int32))
+        local = HashStore(spec).local(tkeys, tweights, tslots, init_rng)
         n = packed.shape[0]
         k = lax.bitcast_convert_type(packed[:, 0], jnp.int32)
         w = packed[:, 1:1 + dim]
@@ -386,9 +285,8 @@ def insert_rows_sharded_packed(state: hash_lib.HashTableState,
     tkeys, tweights, tslots, failures = fn(
         state.keys, state.weights, state.slots, state.insert_failures,
         state.init_rng, packed)
-    return hash_lib.HashTableState(
-        keys=tkeys, weights=tweights, slots=tslots,
-        init_rng=state.init_rng, insert_failures=failures)
+    return state.replace(keys=tkeys, weights=tweights, slots=tslots,
+                         insert_failures=failures)
 
 
 @functools.lru_cache(maxsize=None)
@@ -436,375 +334,132 @@ def read_rows_sharded(state: hash_lib.HashTableState, keys: jnp.ndarray, *,
         state.keys, state.weights, state.slots, keys)
 
 
-@functools.lru_cache(maxsize=None)
-def _pull_program(mesh: Mesh, spec: HashShardingSpec, initializer: Any,
-                  dim: int, batch_sharded: bool,
-                  record_stats: bool = False):
-    batch_spec = P(spec.data_axis) if batch_sharded else P()
+@dataclasses.dataclass(frozen=True)
+class HashStore:
+    """A hash table behind ``parallel/sharded.py``'s builder (which lists
+    what a store answers): a key's slot is found by probing, fresh keys are
+    inserted while merging, so the carry is the key array and the count of
+    keys no probe window held. A missing-but-valid key pulls its
+    deterministic init row (computed only by the owner shard), an EMPTY one
+    zeros; ``initializer=None`` is the read-only serving contract (missing
+    keys -> zeros). Cached keys (``"a2a+cache"``) are always PRESENT in the
+    table — admission rejects absent ones — so the replica can never shadow
+    the deterministic-init contract."""
 
-    # a grouped-plane table addressed PER TABLE takes the plain a2a
-    # program — grouping only exists at the collection level
-    if (spec.plane != "psum" and spec.num_shards > 1) \
-            or spec.is_cached:
-        grid_axes, grid_sizes, split_axes, split_sizes = a2a.grid_info(
-            mesh, spec.shard_axes, spec.model_axis, batch_sharded)
+    spec: HashShardingSpec
+    initializer: Any = None
+    prefix = "hash_"
 
-        def _pull_core(keys, weights, init_rng, flat):
-            me = a2a.linear_shard_id(grid_axes, grid_sizes)
-            local = hash_lib.HashTableState(
-                keys=keys, weights=weights, slots={}, init_rng=init_rng,
-                insert_failures=jnp.zeros((), jnp.int32))
-            sentinel = hash_lib.empty_key(flat.dtype)
+    @property
+    def key_bytes(self) -> int:
+        return 8 if self.spec.wide else 4
 
-            def resolve(q):
-                masked = _mask_non_owned(spec, q, me)
-                return hash_lib.pull(local, masked, initializer,
-                                     max_probes=spec.max_probes)
+    def operands(self, table):
+        return table.keys, table.weights, table.slots, table.init_rng
 
-            def owner(q):
-                valid = (q[:, 1] if spec.wide else q) != sentinel
-                return jnp.where(valid, spec.owner_shard(q),
-                                 spec.num_shards).astype(jnp.int32)
+    def specs(self, slot_names: tuple):
+        # out of a push the step's failure count sits where init_rng went in
+        row = self.spec.row_spec()
+        return row, row, {name: row for name in slot_names}, P()
 
-            return a2a.exchange_pull(
-                flat, resolve, owner, sentinel=sentinel, dim=dim,
-                num_shards=spec.num_shards, grid_axes=grid_axes,
-                grid_sizes=grid_sizes, split_axes=split_axes,
-                split_sizes=split_sizes, capacity=spec.a2a_capacity,
-                slack=spec.a2a_slack, record_stats=record_stats,
-                wire_dtype=spec.pull_wire_dtype)
+    def local(self, keys, weights, slots, init_rng):
+        return hash_lib.HashTableState(
+            keys=keys, weights=weights, slots=slots, init_rng=init_rng,
+            insert_failures=jnp.zeros((), jnp.int32))
 
-        if spec.is_cached:
-            def _pull(keys, weights, init_rng, ckeys, crows, idx):
-                flat = idx.reshape(-1, 2) if spec.wide else idx.ravel()
-                out_shape = (idx.shape[:-1] if spec.wide else idx.shape) \
-                    + (dim,)
-                sentinel = hash_lib.empty_key(flat.dtype)
-                valid = (flat[:, 1] if spec.wide else flat) != sentinel
-                pos, hit = hot_cache.lookup(ckeys, flat, valid)
-                served = jnp.where(hit[:, None],
-                                   jnp.take(crows, pos, axis=0),
-                                   jnp.zeros((1, dim), crows.dtype))
-                hot_cache.record_cache_stats(
-                    hit, valid,
-                    entry_bytes=dim * crows.dtype.itemsize
-                    + (8 if spec.wide else 4),
-                    split_axes=split_axes, split_sizes=split_sizes,
-                    record=record_stats)
-                resid = hot_cache.mask_hits(flat, hit, sentinel)
-                rows = _pull_core(keys, weights, init_rng, resid)
-                return (rows + served).reshape(out_shape)
-        else:
-            def _pull(keys, weights, init_rng, idx):
-                flat = idx.reshape(-1, 2) if spec.wide else idx.ravel()
-                out_shape = (idx.shape[:-1] if spec.wide else idx.shape) \
-                    + (dim,)
-                return _pull_core(keys, weights, init_rng,
-                                  flat).reshape(out_shape)
-    else:
-        def _pull(keys, weights, init_rng, idx):
-            local = hash_lib.HashTableState(
-                keys=keys, weights=weights, slots={}, init_rng=init_rng,
-                insert_failures=jnp.zeros((), jnp.int32))
-            flat = idx.reshape(-1, 2) if spec.wide else idx.ravel()
-            out_shape = (idx.shape[:-1] if spec.wide else idx.shape) \
-                + (dim,)
-            flat = _mask_non_owned(spec, flat,
-                                   lax.axis_index(spec.model_axis))
-            rows = hash_lib.pull(local, flat, initializer,
-                                 max_probes=spec.max_probes)
-            rows = scope.stage("exchange")(
-                lambda rows: lax.psum(rows, spec.model_axis))(rows)
-            return rows.reshape(out_shape)
+    def rebuild(self, table, outs):
+        keys, weights, slots, failed = outs
+        return table.replace(keys=keys, weights=weights, slots=slots,
+                             insert_failures=table.insert_failures + failed)
 
-    row = spec.row_spec()
-    if spec.is_cached:
-        in_specs = (row, row, P(), P(), P(), batch_spec)
-    else:
-        in_specs = (row, row, P(), batch_spec)
-    # plane-identifiable HLO module name for the contract audits
-    # (analysis/contracts.py): failures name the plane that regressed
-    _pull.__name__ = f"hash_pull_{spec.plane_label.replace('+', '_')}"
-    fn = shard_map(_pull, mesh=mesh,
-                   in_specs=in_specs,
-                   out_specs=batch_spec,
-                   check_vma=False)
-    return jax.jit(fn)
+    def batch_shape(self, shape: tuple) -> tuple:
+        return shape[:-1] if self.spec.wide else shape
+
+    def sentinel(self, dtype):
+        return hash_lib.empty_key(jnp.int32 if self.spec.wide else dtype)
+
+    def valid(self, flat):
+        return (flat[:, 1] if self.spec.wide else flat) \
+            != self.sentinel(flat.dtype)
+
+    def owner(self, keys):
+        return jnp.where(self.valid(keys), self.spec.owner_shard(keys),
+                         self.spec.num_shards).astype(jnp.int32)
+
+    def slot_of(self, carry, keys, me):
+        return hash_lib.find_rows(carry[0],
+                                  _mask_non_owned(self.spec, keys, me),
+                                  self.spec.max_probes)
+
+    def resolve(self, local, keys, me):
+        return hash_lib.pull(local, _mask_non_owned(self.spec, keys, me),
+                             self.initializer,
+                             max_probes=self.spec.max_probes)
+
+    def read_local(self, local, flat):
+        return self.resolve(local, flat,
+                            lax.axis_index(self.spec.model_axis))
+
+    def carry(self, local):
+        return local.keys, jnp.zeros((), jnp.int32)
+
+    def merge(self, local, carry, keys, grads, counts, me, *,
+              dedup_capacity, record_stats):
+        tkeys, fails = carry
+        tkeys, failed, merged = hash_lib.merge_gradients(
+            local.replace(keys=tkeys), self.initializer,
+            _mask_non_owned(self.spec, keys, me), grads,
+            dedup_capacity=dedup_capacity, max_probes=self.spec.max_probes,
+            in_counts=counts, record_stats=record_stats)
+        return (tkeys, fails + failed), merged
+
+    def apply_local(self, local, optimizer, flat, grads, *, dedup_capacity,
+                    record_stats):
+        new = hash_lib.apply_gradients(
+            local, optimizer, self.initializer,
+            _mask_non_owned(self.spec, flat,
+                            lax.axis_index(self.spec.model_axis)), grads,
+            dedup_capacity=dedup_capacity, max_probes=self.spec.max_probes,
+            record_stats=record_stats)
+        return (new.keys, new.insert_failures), new.weights, new.slots
+
+    def outputs(self, carry, weights, slots, axes):
+        keys, fails = carry
+        # per-shard failure deltas -> replicated global total
+        return keys, weights, slots, lax.psum(fails, axes)
+
+    def ef_space(self, table) -> dict:
+        sentinel, key_dtype = precision.ef_key_space(
+            use_hash=True, wide=self.spec.wide, key_dtype=table.keys.dtype)
+        return dict(wide=self.spec.wide, sentinel=sentinel,
+                    key_dtype=key_dtype)
 
 
-def pull_sharded(state,
-                 indices: jnp.ndarray,
-                 initializer: Any,
-                 *,
-                 mesh: Mesh,
-                 spec: HashShardingSpec,
+def _store(spec: HashShardingSpec, initializer: Any) -> HashStore:
+    return HashStore(spec, make_initializer(initializer)
+                     if initializer is not None else None)
+
+
+def pull_sharded(state, indices: jnp.ndarray, initializer: Any, *,
+                 mesh: Mesh, spec: HashShardingSpec,
                  batch_sharded: bool = True) -> jnp.ndarray:
-    """Distributed hash lookup: the owner shard resolves each key.
-
-    Missing-but-valid keys get their deterministic init row (computed only by
-    the owner shard); EMPTY-sentinel keys return zero rows. ``initializer=
-    None`` = read-only serving contract (missing keys -> zeros). On the
-    ``"a2a+cache"`` plane ``state`` is a :class:`hot_cache.CachedState`;
-    hot keys are served from the local replica (cached keys are always
-    PRESENT in the table — admission rejects absent ones — so the replica
-    can never shadow the deterministic-init contract).
-    """
-    record = observability.evaluate_performance()
-    if initializer is not None:
-        initializer = make_initializer(initializer)
-    if spec.is_cached:
-        table = state.table
-        dim = table.weights.shape[-1]
-        fn = _pull_program(mesh, spec, initializer, dim, batch_sharded,
-                           record)
-        return observability.plane_timed(
-            "pull", spec.plane_label, record, fn, table.keys,
-            table.weights, table.init_rng, state.cache.keys,
-            state.cache.rows, indices)
-    state = precision.unwrap(state)
-    dim = state.weights.shape[-1]
-    fn = _pull_program(mesh, spec, initializer, dim, batch_sharded, record)
-    return observability.plane_timed(
-        "pull", spec.plane_label, record, fn, state.keys, state.weights,
-        state.init_rng, indices)
+    """:func:`sharded.pull_sharded` of a hash table (:class:`HashStore`
+    has the contract): the owner shard resolves each key."""
+    return sharded.pull_sharded(state, indices, mesh=mesh,
+                                store=_store(spec, initializer),
+                                batch_sharded=batch_sharded)
 
 
-@functools.lru_cache(maxsize=None)
-def _apply_program(mesh: Mesh, spec: HashShardingSpec,
-                   optimizer: SparseOptimizer, initializer: Any, dim: int,
-                   batch_sharded: bool, dedup_capacity: Optional[int],
-                   slot_names: tuple, record_stats: bool = False):
-    batch_spec = P(spec.data_axis) if batch_sharded else P()
-
-    if (spec.plane != "psum" and spec.num_shards > 1) \
-            or spec.is_cached:
-        grid_axes, grid_sizes, split_axes, split_sizes = a2a.grid_info(
-            mesh, spec.shard_axes, spec.model_axis, batch_sharded)
-
-        def _push_core(keys, weights, slots, init_rng, flat, g2, ef=None):
-            me = a2a.linear_shard_id(grid_axes, grid_sizes)
-            sentinel = hash_lib.empty_key(
-                flat.dtype if not spec.wide else jnp.int32)
-
-            def owner(q):
-                valid = (q[:, 1] if spec.wide else q) != sentinel
-                return jnp.where(valid, spec.owner_shard(q),
-                                 spec.num_shards).astype(jnp.int32)
-
-            def merge_fn(st, q, grads, counts):
-                tkeys, fails = st
-                cur = hash_lib.HashTableState(
-                    keys=tkeys, weights=weights, slots=slots,
-                    init_rng=init_rng,
-                    insert_failures=jnp.zeros((), jnp.int32))
-                tkeys, failed, merged = hash_lib.merge_gradients(
-                    cur, initializer, _mask_non_owned(spec, q, me), grads,
-                    dedup_capacity=dedup_capacity,
-                    max_probes=spec.max_probes, in_counts=counts,
-                    record_stats=record_stats)
-                return (tkeys, fails + failed), merged
-
-            out = a2a.exchange_push(
-                flat, g2, (keys, jnp.zeros((), jnp.int32)), merge_fn, owner,
-                sentinel=sentinel, num_shards=spec.num_shards,
-                grid_axes=grid_axes, grid_sizes=grid_sizes,
-                split_axes=split_axes, split_sizes=split_sizes,
-                capacity=spec.a2a_capacity, slack=spec.a2a_slack,
-                record_stats=record_stats,
-                wire_dtype=spec.push_wire_dtype, ef_state=ef)
-            ((keys, fails), merged), new_ef = \
-                out if ef is not None else (out, None)
-            weights, slots = table_lib.apply_rows(
-                weights, slots, optimizer, *merged,
-                record_stats=record_stats)
-            table = (keys, weights, slots, fails)
-            return table if ef is None else (table, new_ef)
-
-        if spec.is_cached:
-            def _apply(keys, weights, slots, init_rng, ckeys, crows,
-                       cslots, idx, g):
-                me = a2a.linear_shard_id(grid_axes, grid_sizes)
-                flat = idx.reshape(-1, 2) if spec.wide else idx.ravel()
-                g2 = g.reshape(-1, dim)
-                sentinel = hash_lib.empty_key(flat.dtype)
-                valid = (flat[:, 1] if spec.wide else flat) != sentinel
-                pos, hit = hot_cache.lookup(ckeys, flat, valid)
-                k = ckeys.shape[0]
-                summed, counts = hot_cache.cache_pre_reduce(
-                    pos, hit, g2, k, split_axes, split_sizes, grid_axes)
-                hot_cache.record_cache_stats(
-                    hit, valid,
-                    entry_bytes=dim * crows.dtype.itemsize
-                    + (12 if spec.wide else 8),
-                    split_axes=split_axes, split_sizes=split_sizes,
-                    record=record_stats)
-                resid = hot_cache.mask_hits(flat, hit, sentinel)
-                tkeys, tweights, tslots, fails = _push_core(
-                    keys, weights, slots, init_rng, resid, g2)
-                # identical psum'd totals on every device -> identical
-                # replica update everywhere; the owner scatters its rows
-                # back so the table stays authoritative
-                cache = hot_cache.HotCacheState(keys=ckeys, rows=crows,
-                                                slots=cslots)
-                cache = hot_cache.update_replica(optimizer, cache, summed,
-                                                 counts)
-                # owner write-back: admitted keys are PRESENT, so the
-                # probe hits; the scatter drops non-owned / untouched rows
-                mine_keys = _mask_non_owned(spec, ckeys, me)
-                slot = hash_lib.find_rows(tkeys, mine_keys,
-                                          spec.max_probes)
-                touched = (slot >= 0) & (counts > 0)
-                oob = jnp.asarray(tweights.shape[0], jnp.int32)
-                sc = jnp.where(touched, slot, oob)
-                tweights = tweights.at[sc].set(
-                    cache.rows.astype(tweights.dtype), mode="drop")
-                tslots = {name: tslots[name].at[sc].set(
-                    cache.slots[name].astype(tslots[name].dtype),
-                    mode="drop") for name in tslots}
-                return (tkeys, tweights, tslots, cache.rows, cache.slots,
-                        lax.psum(fails, spec.shard_axes))
-        elif spec.is_int8_ef:
-            def _apply(keys, weights, slots, init_rng, ef_keys, ef_resid,
-                       idx, g):
-                flat = idx.reshape(-1, 2) if spec.wide else idx.ravel()
-                res, (nek, ner) = _push_core(
-                    keys, weights, slots, init_rng, flat,
-                    g.reshape(-1, dim), ef=(ef_keys, ef_resid))
-                tkeys, tweights, tslots, fails = res
-                return (tkeys, tweights, tslots,
-                        lax.psum(fails, spec.shard_axes), nek, ner)
-        else:
-            def _apply(keys, weights, slots, init_rng, idx, g):
-                flat = idx.reshape(-1, 2) if spec.wide else idx.ravel()
-                tkeys, tweights, tslots, fails = _push_core(
-                    keys, weights, slots, init_rng, flat,
-                    g.reshape(-1, dim))
-                return (tkeys, tweights, tslots,
-                        lax.psum(fails, spec.shard_axes))
-    else:
-        def _apply(keys, weights, slots, init_rng, idx, g):
-            flat = idx.reshape(-1, 2) if spec.wide else idx.ravel()
-            g2 = g.reshape(-1, dim)
-            if batch_sharded:
-                flat, g2 = scope.stage("exchange")(
-                    lambda *xs: tuple(lax.all_gather(x, spec.data_axis,
-                                                     tiled=True)
-                                      for x in xs))(flat, g2)
-            flat = _mask_non_owned(spec, flat,
-                                   lax.axis_index(spec.model_axis))
-            local = hash_lib.HashTableState(
-                keys=keys, weights=weights, slots=slots, init_rng=init_rng,
-                insert_failures=jnp.zeros((), jnp.int32))
-            new = hash_lib.apply_gradients(
-                local, optimizer, initializer, flat, g2,
-                dedup_capacity=dedup_capacity, max_probes=spec.max_probes,
-                record_stats=record_stats)
-            # per-shard failure deltas -> replicated global total
-            failed = lax.psum(new.insert_failures, spec.model_axis)
-            return new.keys, new.weights, new.slots, failed
-
-    row = spec.row_spec()
-    slot_specs = {name: row for name in slot_names}
-    _apply.__name__ = f"hash_push_{spec.plane_label.replace('+', '_')}"
-    if spec.is_cached:
-        cache_slot_specs = {name: P() for name in slot_names}
-        fn = shard_map(_apply, mesh=mesh,
-                       in_specs=(row, row, slot_specs, P(), P(), P(),
-                                 cache_slot_specs, batch_spec, batch_spec),
-                       out_specs=(row, row, slot_specs, P(),
-                                  cache_slot_specs, P()),
-                       check_vma=False)
-    elif spec.is_int8_ef and spec.num_shards > 1:
-        ef_spec = P(spec.shard_axes)
-        fn = shard_map(_apply, mesh=mesh,
-                       in_specs=(row, row, slot_specs, P(), ef_spec,
-                                 ef_spec, batch_spec, batch_spec),
-                       out_specs=(row, row, slot_specs, P(), ef_spec,
-                                  ef_spec),
-                       check_vma=False)
-    else:
-        fn = shard_map(_apply, mesh=mesh,
-                       in_specs=(row, row, slot_specs, P(),
-                                 batch_spec, batch_spec),
-                       out_specs=(row, row, slot_specs, P()),
-                       check_vma=False)
-    return jax.jit(fn)
-
-
-def apply_gradients_sharded(state,
-                            optimizer: SparseOptimizer,
-                            initializer: Any,
-                            indices: jnp.ndarray,
-                            grads: jnp.ndarray,
-                            *,
-                            mesh: Mesh,
+def apply_gradients_sharded(state, optimizer: SparseOptimizer,
+                            initializer: Any, indices: jnp.ndarray,
+                            grads: jnp.ndarray, *, mesh: Mesh,
                             spec: HashShardingSpec,
                             batch_sharded: bool = True,
                             dedup_capacity: Optional[int] = None):
-    """Distributed push+update: each key's grads reach its single owner
-    shard. On the ``"a2a+cache"`` plane ``state`` is a
-    :class:`hot_cache.CachedState`: hot keys pre-reduce locally + one psum
-    over the K replica rows, and the owner writes the updated rows back."""
-    optimizer = make_optimizer(optimizer)
-    initializer = make_initializer(initializer) if initializer is not None \
-        else None
-    record = observability.evaluate_performance()
-    if spec.is_cached:
-        table = state.table
-        dim = table.weights.shape[-1]
-        fn = _apply_program(mesh, spec, optimizer, initializer, dim,
-                            batch_sharded, dedup_capacity,
-                            tuple(table.slots), record)
-        keys, weights, slots, crows, cslots, failed = \
-            observability.plane_timed(
-                "push", spec.plane_label, record, fn,
-                table.keys, table.weights, table.slots, table.init_rng,
-                state.cache.keys, state.cache.rows, state.cache.slots,
-                indices, grads)
-        new_table = hash_lib.HashTableState(
-            keys=keys, weights=weights, slots=slots,
-            init_rng=table.init_rng,
-            insert_failures=table.insert_failures + failed)
-        return hot_cache.CachedState(
-            table=new_table,
-            cache=hot_cache.HotCacheState(keys=state.cache.keys,
-                                          rows=crows, slots=cslots))
-    if spec.is_int8_ef and spec.num_shards > 1:
-        bare = precision.unwrap(state)
-        dim = bare.weights.shape[-1]
-        sentinel, key_dtype = precision.ef_key_space(
-            use_hash=True, wide=spec.wide, key_dtype=bare.keys.dtype)
-        n_flat = int(np.prod(indices.shape))
-        if spec.wide:
-            n_flat //= 2
-        table, ef_keys, ef_resid = precision.ensure_ef(
-            state, dim=dim, wide=spec.wide, sentinel=sentinel,
-            n_flat=n_flat, data=mesh.shape[spec.data_axis],
-            model=mesh.shape[spec.model_axis],
-            batch_sharded=batch_sharded, key_dtype=key_dtype)
-        fn = _apply_program(mesh, spec, optimizer, initializer, dim,
-                            batch_sharded, dedup_capacity,
-                            tuple(table.slots), record)
-        keys, weights, slots, failed, nek, ner = \
-            observability.plane_timed(
-                "push", spec.plane_label, record, fn,
-                table.keys, table.weights, table.slots, table.init_rng,
-                ef_keys, ef_resid, indices, grads)
-        new_table = hash_lib.HashTableState(
-            keys=keys, weights=weights, slots=slots,
-            init_rng=table.init_rng,
-            insert_failures=table.insert_failures + failed)
-        return precision.EFState(table=new_table, keys=nek, resid=ner)
-    state = precision.unwrap(state)
-    dim = state.weights.shape[-1]
-    fn = _apply_program(mesh, spec, optimizer, initializer, dim,
-                        batch_sharded, dedup_capacity, tuple(state.slots),
-                        record)
-    keys, weights, slots, failed = observability.plane_timed(
-        "push", spec.plane_label, record, fn,
-        state.keys, state.weights, state.slots, state.init_rng,
-        indices, grads)
-    return hash_lib.HashTableState(
-        keys=keys, weights=weights, slots=slots,
-        init_rng=state.init_rng,
-        insert_failures=state.insert_failures + failed)
+    """:func:`sharded.apply_gradients_sharded` of a hash table: each key's
+    grads reach its single owner shard, which inserts the keys it has not
+    seen."""
+    return sharded.apply_gradients_sharded(
+        state, optimizer, indices, grads, mesh=mesh,
+        store=_store(spec, initializer), batch_sharded=batch_sharded,
+        dedup_capacity=dedup_capacity)

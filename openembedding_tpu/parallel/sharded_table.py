@@ -1,23 +1,5 @@
-"""Vocabulary-sharded embedding tables over a device mesh.
-
-TPU-native replacement for the reference's parameter-server data plane:
-
-* The reference shards each variable's key space ``index % global_shard_num``
-  across PS processes and pulls rows by RPC
-  (/root/reference/openembedding/server/EmbeddingPullOperator.cpp:60-112,
-  key stored as ``index / shard_num``). Here the same modulo layout shards
-  rows across TPU devices along the mesh ``model`` axis, and the pull is a
-  shard_map region: local gather of owned rows + ``psum`` over the model
-  axis — XLA collectives over ICI instead of TCP/RDMA round trips.
-* The push + store pipeline (client pre-reduce -> MpscGradientReducer ->
-  EmbeddingStoreOperator commit, EmbeddingPushOperator.cpp:29-161,
-  EmbeddingStoreOperator.cpp:23-81) becomes: ``all_gather`` of (indices,
-  row-grads) over the data axis, then every model shard dedups/combines the
-  global batch, masks ownership, and applies its rows' optimizer update
-  locally — one fused XLA program, synchronous per step (the reference's
-  fake-gradient batch barrier is unnecessary: the SPMD step IS the barrier).
-* ``num_shards`` semantics: the reference's shard-per-server default
-  (WorkerContext.cpp:66-85) corresponds to one shard per mesh model slice.
+"""Vocabulary-sharded array tables: layout, creation, row delivery, and the
+array store of the shared pull/push builder.
 
 Layouts:
 * ``mod``   (default, reference parity): global row r -> shard r % S, local
@@ -25,34 +7,9 @@ Layouts:
 * ``div``   (block): r -> shard r // rows_per_shard. Matches NamedSharding's
   natural blocking; best when keys are pre-hashed (uniform).
 
-Data planes (``ShardingSpec.plane``):
-* ``"a2a"`` (default) — owner-routed all-to-all exchange (see
-  ``parallel/alltoall.py``): tables sharded over the WHOLE mesh (data x
-  model), per-device traffic O(batch_slice * dim). The reference's
-  dedup->shard->request->scatter pipeline, TPU-native.
-* ``"psum"`` — tables sharded over the model axis only (replicated across
-  the data axis); pull = gather + psum, push = all_gather + masked local
-  update. Simpler program, more ICI bytes and D-fold HBM replication; kept
-  as the ablation baseline and for meshes where replicas are wanted.
-* ``"a2a+cache"`` — the a2a layout plus a frequency-tracked top-K hot-row
-  replica in every device's HBM (``parallel/hot_cache.py``): pulls for hot
-  keys are served locally with no exchange round, pushes pre-reduce
-  locally and merge with one psum over the K cached rows — exactly
-  equivalent to ``"a2a"``, built for Zipfian key streams.
-* ``"a2a+grouped"`` — the a2a layout, but the COLLECTION batches all
-  same-shape tables into one exchange per group per step
-  (``parallel/grouped.py``): a T-table model pays O(#groups) collective
-  rounds instead of O(T). Per-table calls on this plane (serving probes,
-  checkpoint paths) behave exactly like ``"a2a"``.
-* ``"a2a+pipelined"`` — the a2a layout, but the TRAINER double-buffers
-  the exchange (``parallel/pipelined.py``): batch N+1's rows are pulled
-  inside step N's jitted program (after step N's push commits, so
-  results stay bit-identical to ``"a2a"``) and the pull's index/key-leg
-  collectives overlap step N's dense compute. Per-table calls behave
-  exactly like ``"a2a"`` — the plane only changes the step schedule.
-* ``"a2a+grouped+pipelined"`` — both: grouped collection-level exchange
-  AND the pipelined step schedule, so the prefetched exchange is one
-  collective round per GROUP.
+The data planes (``ShardingSpec.plane``) and the pull / push programs every
+plane variant runs are ``parallel/sharded.py``'s, shared with the hash
+tables; :class:`ArrayStore` is what an array table puts behind them.
 """
 
 from __future__ import annotations
@@ -60,7 +17,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from functools import partial
 from typing import Any, Optional, Tuple
 
 import jax
@@ -72,14 +28,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..analysis import scope
 from ..meta import EmbeddingVariableMeta
 from ..ops import dedup
-from ..utils import observability
 from ..optim.initializers import make_initializer
 from ..optim.optimizers import SparseOptimizer, make_optimizer
 from .. import table as table_lib
 from . import alltoall as a2a
 from . import hot_cache
 from . import precision
-from .mesh import DATA_AXIS, MODEL_AXIS
+from . import sharded
+from .mesh import MODEL_AXIS
 
 
 # every plane riding the owner-routed exchange layout (tables sharded
@@ -90,71 +46,11 @@ PLANES = A2A_PLANES + ("psum",)
 
 
 @dataclasses.dataclass(frozen=True)
-class ShardingSpec:
+class ShardingSpec(sharded.PlaneSpec):
     """Static description of how one table is laid out on the mesh."""
 
-    num_shards: int
     rows_per_shard: int
     layout: str = "mod"  # "mod" | "div"
-    data_axis: str = DATA_AXIS
-    model_axis: str = MODEL_AXIS
-    plane: str = "a2a"   # "a2a" | "psum" | "a2a+cache" | "a2a+grouped"
-                         # | "a2a+pipelined" | "a2a+grouped+pipelined"
-    a2a_capacity: int = 0    # per-destination bucket rows; 0 = auto
-    a2a_slack: float = 2.0   # auto capacity = slack * mean bucket size
-    cache_k: int = 0         # hot-row replica slots ("a2a+cache" plane)
-    # compressed-exchange rungs (parallel/precision.py): pulled rows /
-    # pushed pre-reduced grads on the wire; master weights + optimizer
-    # slots stay at the table's storage dtype in the shard
-    exchange_precision: str = "f32"   # "f32" | "bf16"
-    push_precision: str = "f32"       # "f32" | "bf16" | "int8_ef"
-
-    @property
-    def is_cached(self) -> bool:
-        return self.plane == "a2a+cache"
-
-    @property
-    def plane_label(self) -> str:
-        """Observable plane token incl. the precision suffix — keys the
-        HLO module names, plane_timed spans, contract registry and the
-        graftscope byte ledger (``precision.plane_label``)."""
-        return precision.plane_label(self.plane, self.exchange_precision,
-                                     self.push_precision)
-
-    @property
-    def pull_wire_dtype(self):
-        return precision.wire_dtype(self.exchange_precision)
-
-    @property
-    def push_wire_dtype(self):
-        # int8_ef carries its own int8 payload inside exchange_push
-        return precision.wire_dtype(self.push_precision) \
-            if self.push_precision == "bf16" else None
-
-    @property
-    def is_int8_ef(self) -> bool:
-        return self.push_precision == "int8_ef"
-
-    @property
-    def is_grouped(self) -> bool:
-        """Collection-level multi-table exchange (``parallel/grouped.py``)."""
-        return self.plane in ("a2a+grouped", "a2a+grouped+pipelined")
-
-    @property
-    def is_pipelined(self) -> bool:
-        """Trainer-level double-buffered exchange schedule
-        (``parallel/pipelined.py``)."""
-        return self.plane in ("a2a+pipelined", "a2a+grouped+pipelined")
-
-    @property
-    def shard_axes(self) -> tuple:
-        """Mesh axes the table's row dimension is sharded over."""
-        if self.plane != "psum":
-            return (self.data_axis, self.model_axis)
-        return (self.model_axis,)
-
-    def row_spec(self) -> P:
-        return P(self.shard_axes)
 
     @property
     def padded_vocab(self) -> int:
@@ -173,12 +69,9 @@ class ShardingSpec:
 
 def make_sharding_spec(meta: EmbeddingVariableMeta, mesh: Mesh,
                        num_shards: int = -1, layout: str = "mod",
-                       capacity: Optional[int] = None,
-                       plane: str = "a2a",
-                       a2a_capacity: int = 0,
-                       a2a_slack: float = 2.0,
-                       cache_k: int = 0,
-                       exchange_precision: str = "f32",
+                       capacity: Optional[int] = None, plane: str = "a2a",
+                       a2a_capacity: int = 0, a2a_slack: float = 2.0,
+                       cache_k: int = 0, exchange_precision: str = "f32",
                        push_precision: str = "f32") -> ShardingSpec:
     """num_shards=-1 => one shard per device ("a2a") / per model slice ("psum").
 
@@ -196,6 +89,24 @@ def make_sharding_spec(meta: EmbeddingVariableMeta, mesh: Mesh,
     """
     if layout not in ("mod", "div"):
         raise ValueError(f"unknown layout {layout!r}")
+    plane, num_shards, cache_k, exchange_precision, push_precision = \
+        _resolve_plane(mesh, plane, num_shards, cache_k, exchange_precision,
+                       push_precision)
+    vocab = capacity if capacity is not None else meta.vocabulary_size
+    rows_per_shard = math.ceil(vocab / num_shards)
+    return ShardingSpec(num_shards=num_shards, rows_per_shard=rows_per_shard,
+                        layout=layout, plane=plane,
+                        a2a_capacity=a2a_capacity, a2a_slack=a2a_slack,
+                        cache_k=cache_k,
+                        exchange_precision=exchange_precision,
+                        push_precision=push_precision)
+
+
+def _resolve_plane(mesh: Mesh, plane: str, num_shards: int, cache_k: int,
+                   exchange_precision: str, push_precision: str):
+    """What the array and hash spec builders settle alike: the base plane
+    and the precision rungs its suffix names, the shard count that plane
+    wants of this mesh, the size of the hot-row replica."""
     plane, exchange_precision, push_precision = _resolve_precision(
         plane, exchange_precision, push_precision)
     if plane not in PLANES:
@@ -207,18 +118,11 @@ def make_sharding_spec(meta: EmbeddingVariableMeta, mesh: Mesh,
         raise ValueError(
             f"num_shards={num_shards} must equal the {plane}-plane shard "
             f"count {want} for this mesh (or pass -1)")
-    if plane == "a2a+cache" and cache_k <= 0:
-        cache_k = hot_cache.DEFAULT_CACHE_K
     if plane != "a2a+cache":
         cache_k = 0
-    vocab = capacity if capacity is not None else meta.vocabulary_size
-    rows_per_shard = math.ceil(vocab / num_shards)
-    return ShardingSpec(num_shards=num_shards, rows_per_shard=rows_per_shard,
-                        layout=layout, plane=plane,
-                        a2a_capacity=a2a_capacity, a2a_slack=a2a_slack,
-                        cache_k=cache_k,
-                        exchange_precision=exchange_precision,
-                        push_precision=push_precision)
+    elif cache_k <= 0:
+        cache_k = hot_cache.DEFAULT_CACHE_K
+    return plane, num_shards, cache_k, exchange_precision, push_precision
 
 
 def _resolve_precision(plane: str, exchange_precision: str,
@@ -241,11 +145,8 @@ def _resolve_precision(plane: str, exchange_precision: str,
     return base, exchange_precision, push_precision
 
 
-def create_sharded_table(meta: EmbeddingVariableMeta,
-                         optimizer: Any,
-                         initializer: Any = None,
-                         *,
-                         mesh: Mesh,
+def create_sharded_table(meta: EmbeddingVariableMeta, optimizer: Any,
+                         initializer: Any = None, *, mesh: Mesh,
                          spec: Optional[ShardingSpec] = None,
                          rng: Optional[jax.Array] = None,
                          wrap_cache: bool = True):
@@ -259,8 +160,6 @@ def create_sharded_table(meta: EmbeddingVariableMeta,
     initializer = make_initializer(initializer or table_lib.DEFAULT_INITIALIZER)
     if spec is None:
         spec = make_sharding_spec(meta, mesh)
-    if rng is None:
-        rng = jax.random.PRNGKey(0)
     dtype = table_lib.resolve_dtype(meta)
     dim = meta.embedding_dim
 
@@ -274,10 +173,17 @@ def create_sharded_table(meta: EmbeddingVariableMeta,
         slots = optimizer.init_slots(spec.rows_per_shard, dim, dtype)
         return table_lib.TableState(weights=weights, slots=slots)
 
-    fn = shard_map(_init, mesh=mesh,
-                   in_specs=(P(),),
-                   out_specs=table_state_specs(optimizer, dim, spec),
-                   check_vma=False)
+    return _create(_init, table_state_specs(optimizer, dim, spec), mesh=mesh,
+                   spec=spec, rng=rng, wrap_cache=wrap_cache)
+
+
+def _create(init_shard, out_specs, *, mesh: Mesh, spec, rng, wrap_cache: bool):
+    """Run ``init_shard(rng)`` on every shard: the state of a new table of
+    either kind."""
+    if rng is None:
+        rng = jax.random.PRNGKey(0)
+    fn = shard_map(init_shard, mesh=mesh, in_specs=(P(),),
+                   out_specs=out_specs, check_vma=False)
     state = jax.jit(fn)(rng)
     if wrap_cache:
         # all-pad replica: zero hits (pure-a2a behavior) until the first
@@ -296,7 +202,12 @@ def table_state_specs(optimizer: SparseOptimizer, dim: int,
 
 
 def state_specs(optimizer: SparseOptimizer, dim: int, spec: ShardingSpec):
-    table = table_state_specs(optimizer, dim, spec)
+    return with_cache_specs(table_state_specs(optimizer, dim, spec), spec)
+
+
+def with_cache_specs(table, spec):
+    """A table's specs (either kind) under those of its hot-row replica,
+    where the plane has one."""
     if spec.is_cached:
         # the replica is replicated on every device
         return hot_cache.CachedState(
@@ -384,335 +295,113 @@ def _masked_local(spec: ShardingSpec, flat: jnp.ndarray):
     return owned, local
 
 
-@functools.lru_cache(maxsize=None)
-def _pull_program(mesh: Mesh, spec: ShardingSpec, dim: int,
-                  batch_sharded: bool, record_stats: bool = False):
-    """Cached jitted pull: eager callers (serving lookups, tests) would
-    otherwise rebuild + retrace the shard_map closure every call."""
-    batch_spec = P(spec.data_axis) if batch_sharded else P()
-
-    # single shard => nothing to route; the masked-local body below (whose
-    # collectives are free over size-1 axes) skips the bucketing machinery
-    # (~25% faster on one chip for the headline config). The cached plane
-    # always routes: its residue masking composes with the exchange. A
-    # grouped-plane table addressed PER TABLE (serving probes, checkpoint
-    # paths) takes the plain a2a program — grouping only exists at the
-    # collection level.
-    if (spec.plane != "psum" and spec.num_shards > 1) \
-            or spec.is_cached:
-        grid_axes, grid_sizes, split_axes, split_sizes = a2a.grid_info(
-            mesh, spec.shard_axes, spec.model_axis, batch_sharded)
-        sentinel = dedup.FILL
-
-        def _pull_core(weights, idx):
-            me = a2a.linear_shard_id(grid_axes, grid_sizes)
-
-            def resolve(keys):
-                shard, local = spec.shard_and_local(keys)
-                mine = ((keys >= 0) & (keys < spec.padded_vocab)
-                        & (shard == me))
-                rows = jnp.take(weights, jnp.where(mine, local, 0), axis=0,
-                                mode="clip")
-                return jnp.where(mine[:, None], rows, jnp.zeros_like(rows))
-
-            def owner(keys):
-                shard, _ = spec.shard_and_local(keys)
-                valid = (keys >= 0) & (keys < spec.padded_vocab)
-                return jnp.where(valid, shard, spec.num_shards).astype(
-                    jnp.int32)
-
-            rows = a2a.exchange_pull(
-                idx.ravel(), resolve, owner, sentinel=sentinel, dim=dim,
-                num_shards=spec.num_shards, grid_axes=grid_axes,
-                grid_sizes=grid_sizes, split_axes=split_axes,
-                split_sizes=split_sizes, capacity=spec.a2a_capacity,
-                slack=spec.a2a_slack, record_stats=record_stats,
-                wire_dtype=spec.pull_wire_dtype)
-            return rows.reshape(idx.shape + (dim,))
-
-        if spec.is_cached:
-            def _pull(weights, ckeys, crows, idx):
-                flat = idx.ravel()
-                valid = (flat >= 0) & (flat < spec.padded_vocab)
-                pos, hit = hot_cache.lookup(ckeys, flat, valid)
-                served = jnp.where(hit[:, None],
-                                   jnp.take(crows, pos, axis=0),
-                                   jnp.zeros((1, dim), crows.dtype))
-                hot_cache.record_cache_stats(
-                    hit, valid,
-                    entry_bytes=dim * crows.dtype.itemsize + 4,
-                    split_axes=split_axes, split_sizes=split_sizes,
-                    record=record_stats)
-                resid = hot_cache.mask_hits(flat, hit, sentinel)
-                rows = _pull_core(weights, resid).reshape(-1, dim)
-                return (rows + served).reshape(idx.shape + (dim,))
-        else:
-            _pull = _pull_core
-    else:
-        def _pull(weights, idx):
-            owned, local = scope.stage("route")(
-                lambda flat: _masked_local(spec, flat))(idx.ravel())
-
-            @scope.stage("resolve")
-            def read(weights, owned, local):
-                rows = jnp.take(weights, jnp.where(owned, local, 0), axis=0,
-                                mode="clip")
-                return jnp.where(owned[:, None], rows, jnp.zeros_like(rows))
-
-            rows = scope.stage("exchange")(
-                lambda rows: lax.psum(rows, spec.model_axis))(
-                    read(weights, owned, local))
-            return rows.reshape(idx.shape + (dim,))
-
-    if spec.is_cached:
-        in_specs = (spec.row_spec(), P(), P(), batch_spec)
-    else:
-        in_specs = (spec.row_spec(), batch_spec)
-    # plane-identifiable HLO module name (jit names the module after the
-    # callable): a contract-audit failure then says WHICH plane's
-    # program regressed (analysis/contracts.py); compressed planes carry
-    # their precision suffix (pull_a2a_bf16, ...)
-    _pull.__name__ = f"pull_{spec.plane_label.replace('+', '_')}"
-    fn = shard_map(_pull, mesh=mesh,
-                   in_specs=in_specs,
-                   out_specs=batch_spec,
-                   check_vma=False)
-    return jax.jit(fn)
+def _take_owned(weights, owned, local):
+    rows = jnp.take(weights, jnp.where(owned, local, 0), axis=0, mode="clip")
+    return jnp.where(owned[:, None], rows, jnp.zeros_like(rows))
 
 
-def pull_sharded(state,
-                 indices: jnp.ndarray,
-                 *,
-                 mesh: Mesh,
-                 spec: ShardingSpec,
-                 batch_sharded: bool = True) -> jnp.ndarray:
-    """Distributed embedding lookup.
+@dataclasses.dataclass(frozen=True)
+class ArrayStore:
+    """An array table behind ``parallel/sharded.py``'s builder (which lists
+    what a store answers): keys are row ids, a key's slot is its local row,
+    and merging changes nothing but the rows, so the carry is empty."""
 
-    ``indices``: any shape, sharded over the data axis on dim 0 when
-    ``batch_sharded`` (the normal training path) else replicated. Returns
-    rows with the same batch sharding. Equivalent to the reference's pull
-    RPC fan-out + response scatter (EmbeddingPullOperator.cpp:40-252), as a
-    gather + one psum over ICI. On the ``"a2a+cache"`` plane ``state`` is a
-    :class:`hot_cache.CachedState`; hot keys are served from the local
-    replica and only the residue rides the exchange.
-    """
-    record = observability.evaluate_performance()
-    if spec.is_cached:
-        dim = state.table.weights.shape[-1]
-        fn = _pull_program(mesh, spec, dim, batch_sharded, record)
-        return observability.plane_timed(
-            "pull", spec.plane_label, record, fn, state.table.weights,
-            state.cache.keys, state.cache.rows, indices)
-    # int8_ef states wrap the table with the push residual; pulls read
-    # through the wrapper (serving restores may hand a bare table)
-    state = precision.unwrap(state)
-    dim = state.weights.shape[-1]
-    fn = _pull_program(mesh, spec, dim, batch_sharded, record)
-    return observability.plane_timed("pull", spec.plane_label, record, fn,
-                                     state.weights, indices)
+    spec: ShardingSpec
+    prefix = ""
+    key_bytes = 4
 
+    def operands(self, table):
+        return table.weights, table.slots
 
-@functools.lru_cache(maxsize=None)
-def _apply_program(mesh: Mesh, spec: ShardingSpec,
-                   optimizer: SparseOptimizer, dim: int,
-                   batch_sharded: bool, dedup_capacity: Optional[int],
-                   slot_names: tuple, record_stats: bool = False):
-    batch_spec = P(spec.data_axis) if batch_sharded else P()
+    def specs(self, slot_names: tuple):
+        row = self.spec.row_spec()
+        return row, {name: row for name in slot_names}
 
-    if (spec.plane != "psum" and spec.num_shards > 1) \
-            or spec.is_cached:
-        grid_axes, grid_sizes, split_axes, split_sizes = a2a.grid_info(
-            mesh, spec.shard_axes, spec.model_axis, batch_sharded)
+    def local(self, weights, slots):
+        return table_lib.TableState(weights=weights, slots=slots)
 
-        def _push_core(weights, slots, flat, g2, ef=None):
-            me = a2a.linear_shard_id(grid_axes, grid_sizes)
+    def rebuild(self, table, outs):
+        return self.local(*outs)
 
-            def owner(keys):
-                shard, _ = spec.shard_and_local(keys)
-                valid = (keys >= 0) & (keys < spec.padded_vocab)
-                return jnp.where(valid, shard, spec.num_shards).astype(
-                    jnp.int32)
+    def batch_shape(self, shape: tuple) -> tuple:
+        return shape
 
-            def merge_fn(st, keys, grads, counts):
-                @scope.stage("route")
-                def mask(keys, me):
-                    shard, local = spec.shard_and_local(keys)
-                    mine = ((keys >= 0) & (keys < spec.padded_vocab)
-                            & (shard == me))
-                    return jnp.where(mine, local, -1)
+    def sentinel(self, dtype):
+        return dedup.FILL
 
-                return st, table_lib.merge_gradients(
-                    mask(keys, me), grads, dedup_capacity=dedup_capacity,
-                    in_counts=counts)
+    def valid(self, flat):
+        return (flat >= 0) & (flat < self.spec.padded_vocab)
 
-            out = a2a.exchange_push(
-                flat, g2, (), merge_fn, owner,
-                sentinel=dedup.FILL, num_shards=spec.num_shards,
-                grid_axes=grid_axes, grid_sizes=grid_sizes,
-                split_axes=split_axes, split_sizes=split_sizes,
-                capacity=spec.a2a_capacity, slack=spec.a2a_slack,
-                record_stats=record_stats,
-                wire_dtype=spec.push_wire_dtype, ef_state=ef)
-            (_, merged), new_ef = out if ef is not None else (out, None)
-            table = table_lib.apply_rows(weights, slots, optimizer, *merged,
-                                         record_stats=record_stats)
-            return table if ef is None else (table, new_ef)
+    def owner(self, keys):
+        shard, _ = self.spec.shard_and_local(keys)
+        return jnp.where(self.valid(keys), shard,
+                         self.spec.num_shards).astype(jnp.int32)
 
-        if spec.is_cached:
-            def _apply(weights, slots, ckeys, crows, cslots, idx, g):
-                me = a2a.linear_shard_id(grid_axes, grid_sizes)
-                flat = idx.ravel()
-                g2 = g.reshape(-1, dim)
-                valid = (flat >= 0) & (flat < spec.padded_vocab)
-                pos, hit = hot_cache.lookup(ckeys, flat, valid)
-                k = ckeys.shape[0]
-                summed, counts = hot_cache.cache_pre_reduce(
-                    pos, hit, g2, k, split_axes, split_sizes, grid_axes)
-                hot_cache.record_cache_stats(
-                    hit, valid,
-                    entry_bytes=dim * crows.dtype.itemsize + 8,
-                    split_axes=split_axes, split_sizes=split_sizes,
-                    record=record_stats)
-                # residue rides the exchange with hits masked invalid
-                resid = hot_cache.mask_hits(flat, hit, dedup.FILL)
-                weights, slots = _push_core(weights, slots, resid, g2)
-                # identical psum'd totals on every device -> identical
-                # replica update everywhere; the owner scatters its rows
-                # back so the table stays authoritative
-                cache = hot_cache.HotCacheState(keys=ckeys, rows=crows,
-                                                slots=cslots)
-                cache = hot_cache.update_replica(optimizer, cache, summed,
-                                                 counts)
-                shard, local = spec.shard_and_local(ckeys)
-                ckv = (ckeys >= 0) & (ckeys < spec.padded_vocab)
-                mine = ckv & (shard == me) & (counts > 0)
-                oob = jnp.asarray(spec.rows_per_shard, local.dtype)
-                sc = jnp.where(mine, local, oob)
-                weights = weights.at[sc].set(
-                    cache.rows.astype(weights.dtype), mode="drop")
-                slots = {name: slots[name].at[sc].set(
-                    cache.slots[name].astype(slots[name].dtype),
-                    mode="drop") for name in slots}
-                return weights, slots, cache.rows, cache.slots
-        elif spec.is_int8_ef:
-            def _apply(weights, slots, ef_keys, ef_resid, idx, g):
-                (weights, slots), (nek, ner) = _push_core(
-                    weights, slots, idx.ravel(), g.reshape(-1, dim),
-                    ef=(ef_keys, ef_resid))
-                return weights, slots, nek, ner
-        else:
-            def _apply(weights, slots, idx, g):
-                return _push_core(weights, slots, idx.ravel(),
-                                  g.reshape(-1, dim))
-    else:
-        def _apply(weights, slots, idx, g):
-            flat = idx.ravel()
-            g2 = g.reshape(-1, dim)
-            if batch_sharded:
-                flat, g2 = scope.stage("exchange")(
-                    lambda *xs: tuple(lax.all_gather(x, spec.data_axis,
-                                                     tiled=True)
-                                      for x in xs))(flat, g2)
+    def _mine(self, keys, me):
+        shard, local = self.spec.shard_and_local(keys)
+        return self.valid(keys) & (shard == me), local
 
-            @scope.stage("route")
-            def mask(flat):
-                owned, local = _masked_local(spec, flat)
-                # non-owned entries become index -1 -> dropped in
-                # apply_gradients
-                return jnp.where(owned, local, -1)
+    def slot_of(self, carry, keys, me):
+        mine, local = self._mine(keys, me)
+        return jnp.where(mine, local, -1)
 
-            masked = mask(flat)
-            local_state = table_lib.TableState(weights=weights, slots=slots)
-            new_state = table_lib.apply_gradients(
-                local_state, optimizer, masked, g2,
-                dedup_capacity=dedup_capacity, record_stats=record_stats)
-            return new_state.weights, new_state.slots
+    def resolve(self, local, keys, me):
+        return _take_owned(local.weights, *self._mine(keys, me))
 
-    slot_specs = {name: spec.row_spec() for name in slot_names}
-    _apply.__name__ = f"push_{spec.plane_label.replace('+', '_')}"
-    if spec.is_cached:
-        cache_slot_specs = {name: P() for name in slot_names}
-        fn = shard_map(_apply, mesh=mesh,
-                       in_specs=(spec.row_spec(), slot_specs, P(), P(),
-                                 cache_slot_specs, batch_spec, batch_spec),
-                       out_specs=(spec.row_spec(), slot_specs, P(),
-                                  cache_slot_specs),
-                       check_vma=False)
-    elif spec.is_int8_ef and spec.num_shards > 1:
-        # the EF residual buffers shard over the exchange grid: each
-        # device owns exactly its sender slice's block
-        ef_spec = P(spec.shard_axes)
-        fn = shard_map(_apply, mesh=mesh,
-                       in_specs=(spec.row_spec(), slot_specs, ef_spec,
-                                 ef_spec, batch_spec, batch_spec),
-                       out_specs=(spec.row_spec(), slot_specs, ef_spec,
-                                  ef_spec),
-                       check_vma=False)
-    else:
-        fn = shard_map(_apply, mesh=mesh,
-                       in_specs=(spec.row_spec(), slot_specs, batch_spec,
-                                 batch_spec),
-                       out_specs=(spec.row_spec(), slot_specs),
-                       check_vma=False)
-    return jax.jit(fn)
+    def read_local(self, local, flat):
+        owned, row = scope.stage("route")(
+            lambda flat: _masked_local(self.spec, flat))(flat)
+        return scope.stage("resolve")(_take_owned)(local.weights, owned, row)
+
+    def carry(self, local):
+        return ()
+
+    def merge(self, local, carry, keys, grads, counts, me, *,
+              dedup_capacity, record_stats):
+        rows = scope.stage("route")(
+            lambda keys, me: self.slot_of(carry, keys, me))(keys, me)
+        return carry, table_lib.merge_gradients(
+            rows, grads, dedup_capacity=dedup_capacity, in_counts=counts)
+
+    def apply_local(self, local, optimizer, flat, grads, *, dedup_capacity,
+                    record_stats):
+        @scope.stage("route")
+        def mask(flat):
+            owned, row = _masked_local(self.spec, flat)
+            # non-owned entries become index -1 -> dropped in
+            # apply_gradients
+            return jnp.where(owned, row, -1)
+
+        new = table_lib.apply_gradients(
+            local, optimizer, mask(flat), grads,
+            dedup_capacity=dedup_capacity, record_stats=record_stats)
+        return (), new.weights, new.slots
+
+    def outputs(self, carry, weights, slots, axes):
+        return weights, slots
+
+    def ef_space(self, table) -> dict:
+        sentinel, key_dtype = precision.ef_key_space(use_hash=False)
+        return dict(wide=False, sentinel=sentinel, key_dtype=key_dtype)
 
 
-def apply_gradients_sharded(state,
-                            optimizer: SparseOptimizer,
-                            indices: jnp.ndarray,
-                            grads: jnp.ndarray,
-                            *,
-                            mesh: Mesh,
-                            spec: ShardingSpec,
+def pull_sharded(state, indices: jnp.ndarray, *, mesh: Mesh,
+                 spec: ShardingSpec, batch_sharded: bool = True
+                 ) -> jnp.ndarray:
+    """:func:`sharded.pull_sharded` of an array table: a gather + one psum
+    over ICI on the masked-local body, the owner-routed exchange
+    elsewhere."""
+    return sharded.pull_sharded(state, indices, mesh=mesh,
+                                store=ArrayStore(spec),
+                                batch_sharded=batch_sharded)
+
+
+def apply_gradients_sharded(state, optimizer: SparseOptimizer,
+                            indices: jnp.ndarray, grads: jnp.ndarray, *,
+                            mesh: Mesh, spec: ShardingSpec,
                             batch_sharded: bool = True,
                             dedup_capacity: Optional[int] = None):
-    """Distributed push+update: every shard applies its owned rows.
-
-    Data-axis devices all_gather the global (indices, grads) so the update is
-    computed identically on every data replica of a model shard — replacing
-    the reference's single-owner store RPC (WorkerContext.cpp:115-123) with
-    deterministic replicated application. On the ``"a2a+cache"`` plane
-    ``state`` is a :class:`hot_cache.CachedState`: hot keys pre-reduce
-    locally + one psum over the K replica rows (no exchange for them), and
-    the owner writes the updated rows back so the table stays authoritative.
-    """
-    optimizer = make_optimizer(optimizer)
-    record = observability.evaluate_performance()
-    if spec.is_cached:
-        table = state.table
-        dim = table.weights.shape[-1]
-        fn = _apply_program(mesh, spec, optimizer, dim, batch_sharded,
-                            dedup_capacity, tuple(table.slots), record)
-        weights, slots, crows, cslots = observability.plane_timed(
-            "push", spec.plane_label, record, fn,
-            table.weights, table.slots, state.cache.keys, state.cache.rows,
-            state.cache.slots, indices, grads)
-        return hot_cache.CachedState(
-            table=table_lib.TableState(weights=weights, slots=slots),
-            cache=hot_cache.HotCacheState(keys=state.cache.keys,
-                                          rows=crows, slots=cslots))
-    if spec.is_int8_ef and spec.num_shards > 1:
-        dim = precision.unwrap(state).weights.shape[-1]
-        sentinel, key_dtype = precision.ef_key_space(use_hash=False)
-        table, ef_keys, ef_resid = precision.ensure_ef(
-            state, dim=dim, wide=False, sentinel=sentinel,
-            n_flat=int(np.prod(indices.shape)),
-            data=mesh.shape[spec.data_axis],
-            model=mesh.shape[spec.model_axis],
-            batch_sharded=batch_sharded, key_dtype=key_dtype)
-        fn = _apply_program(mesh, spec, optimizer, dim, batch_sharded,
-                            dedup_capacity, tuple(table.slots), record)
-        weights, slots, nek, ner = observability.plane_timed(
-            "push", spec.plane_label, record, fn,
-            table.weights, table.slots, ef_keys, ef_resid, indices, grads)
-        return precision.EFState(
-            table=table_lib.TableState(weights=weights, slots=slots),
-            keys=nek, resid=ner)
-    state = precision.unwrap(state)
-    dim = state.weights.shape[-1]
-    fn = _apply_program(mesh, spec, optimizer, dim, batch_sharded,
-                        dedup_capacity, tuple(state.slots), record)
-    weights, slots = observability.plane_timed(
-        "push", spec.plane_label, record, fn,
-        state.weights, state.slots, indices, grads)
-    return table_lib.TableState(weights=weights, slots=slots)
+    """:func:`sharded.apply_gradients_sharded` of an array table."""
+    return sharded.apply_gradients_sharded(
+        state, optimizer, indices, grads, mesh=mesh, store=ArrayStore(spec),
+        batch_sharded=batch_sharded, dedup_capacity=dedup_capacity)

@@ -143,3 +143,93 @@ def test_bfloat16_table_trains_sharded(devices8):
     assert (rows < 0.25 - 0.1).all()
     np.testing.assert_allclose(rows, np.broadcast_to(rows[0], rows.shape),
                                rtol=1e-2)
+
+
+# --- one builder, two stores (parallel/sharded.py) ---------------------------
+
+def _table_of(kind, mesh):
+    """(state, store, the kind's own pull, the kind's own apply, keys) of a
+    small table of ``kind`` on ``mesh``."""
+    from openembedding_tpu import hash_table as hash_lib
+    from openembedding_tpu.optim.initializers import make_initializer
+    from openembedding_tpu.parallel import sharded_hash as sh
+
+    opt = make_optimizer({"category": "adagrad", "learning_rate": 0.1})
+    ids = np.random.RandomState(7).randint(0, VOCAB, size=16)
+    if kind == "array":
+        meta = EmbeddingVariableMeta(embedding_dim=DIM, vocabulary_size=VOCAB)
+        spec = st.make_sharding_spec(meta, mesh)
+        state = st.create_sharded_table(meta, opt, mesh=mesh, spec=spec,
+                                        rng=jax.random.PRNGKey(3))
+
+        def pull(s, k):
+            return st.pull_sharded(s, k, mesh=mesh, spec=spec)
+
+        def apply(s, k, g):
+            return st.apply_gradients_sharded(s, opt, k, g, mesh=mesh,
+                                              spec=spec)
+        return (state, st.ArrayStore(spec), pull, apply,
+                jnp.asarray(ids, jnp.int32))
+
+    wide = kind == "hashwide"
+    init = make_initializer({"category": "uniform", "minval": -1.0,
+                             "maxval": 1.0})
+    meta = EmbeddingVariableMeta(embedding_dim=DIM, vocabulary_size=2**62)
+    spec = sh.make_hash_sharding_spec(mesh, 1024,
+                                      key_width=64 if wide else 32)
+    state = sh.create_sharded_hash_table(meta, opt, mesh=mesh, spec=spec,
+                                         rng=jax.random.PRNGKey(3))
+    keys = ids.astype(np.int64) * 1_000_003 + 17
+    keys = hash_lib.split64(keys * (1 << 20)) if wide \
+        else keys.astype(np.int32)
+
+    def pull(s, k):
+        return sh.pull_sharded(s, k, init, mesh=mesh, spec=spec)
+
+    def apply(s, k, g):
+        return sh.apply_gradients_sharded(s, opt, init, k, g, mesh=mesh,
+                                          spec=spec)
+    return state, sh.HashStore(spec, init), pull, apply, jnp.asarray(keys)
+
+
+@pytest.mark.parametrize("data,model", [(1, 1), (2, 2)],
+                         ids=["masked_local", "routed"])
+@pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
+def test_both_kinds_through_the_one_builder(devices8, kind, data, model):
+    """pull -> apply_gradients -> pull through ``sharded``'s entry equals
+    the per-kind public names bit for bit, and each program is built once
+    per (mesh, store, ...) however the state is wrapped."""
+    from openembedding_tpu.parallel import precision, sharded
+
+    mesh = create_mesh(data, model, devices8[:data * model])
+    state, store, pull_kind, apply_kind, keys = _table_of(kind, mesh)
+    assert store.spec.routes == (data * model > 1)
+    grads = jnp.asarray(np.random.RandomState(8).randn(16, DIM), jnp.float32)
+    opt = make_optimizer({"category": "adagrad", "learning_rate": 0.1})
+    sharded._pull_program.cache_clear()
+    sharded._apply_program.cache_clear()
+
+    def through_builder(s):
+        rows0 = sharded.pull_sharded(s, keys, mesh=mesh, store=store)
+        s = sharded.apply_gradients_sharded(s, opt, keys, grads, mesh=mesh,
+                                            store=store)
+        return rows0, s, sharded.pull_sharded(s, keys, mesh=mesh, store=store)
+
+    def through_kind(s):
+        rows0 = pull_kind(s, keys)
+        s = apply_kind(s, keys, grads)
+        return rows0, s, pull_kind(s, keys)
+
+    got, want = through_builder(state), through_kind(state)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.abs(np.asarray(got[2]) - np.asarray(got[0])).max() > 0
+
+    # a state in the int8-EF wrapper reads through precision.unwrap: the
+    # same table, the same program
+    wrapped = precision.empty_ef(state, dim=DIM, **store.ef_space(state))
+    np.testing.assert_array_equal(
+        np.asarray(sharded.pull_sharded(wrapped, keys, mesh=mesh,
+                                        store=store)), np.asarray(got[0]))
+    assert sharded._pull_program.cache_info().misses == 1
+    assert sharded._apply_program.cache_info().misses == 1
