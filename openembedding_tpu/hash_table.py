@@ -397,7 +397,10 @@ def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
 
     Two phases. *Find*: every key probes its chain for a match, one row
     gather a level and no insert machinery, as the pull's
-    :func:`find_rows` does; a key found there is done. *Insert*: the keys
+    :func:`find_rows` does; a key found there is done. A call wider than
+    ``table.FIND_CHUNK`` walks its keys a chunk a trip and stops after the
+    last valid one, so the find costs what the keys of a step cost, not
+    what the padded unique buffer would. *Insert*: the keys
     that missed, in their original order, are compacted into a buffer of
     :func:`insert_width` keys and only that buffer runs the insert loop
     below; its slots are written back to the keys' positions. When more
@@ -429,8 +432,10 @@ def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
 
     ``record_stats`` (the trace-time gate of ``alltoall.record_stat``)
     counts ``hash_insert_compact`` / ``hash_insert_full``, the calls that
-    ran the loop over the buffer / over every key, and
-    ``hash_insert_missed``, the keys that were not in the table.
+    ran the loop over the buffer / over every key,
+    ``hash_insert_missed``, the keys that were not in the table, and
+    ``hash_find_slots_live`` / ``hash_find_slots_walked``, the valid keys
+    of a call and the keys its find walked.
 
     Returns ``(table_keys, slot [n] (-1 = failed), inserted [n],
     failed [n])``.
@@ -452,7 +457,7 @@ def _find_or_insert(table_keys, new_keys, valid, max_probes, record_stats):
                     jnp.sum(inserted | failed, dtype=jnp.int32), record_stats)
         return out
 
-    found = _find_levels(table_keys, new_keys, valid, max_probes)
+    found, walked = _find_levels(table_keys, new_keys, valid, max_probes)
     miss = valid & (found < 0)
     missed = jnp.sum(miss, dtype=jnp.int32)
     fits = missed <= m
@@ -472,6 +477,9 @@ def _find_or_insert(table_keys, new_keys, valid, max_probes, record_stats):
     record_stat("hash_insert_compact", fits.astype(jnp.int32), record_stats)
     record_stat("hash_insert_full", (~fits).astype(jnp.int32), record_stats)
     record_stat("hash_insert_missed", missed, record_stats)
+    record_stat("hash_find_slots_live", jnp.sum(valid, dtype=jnp.int32),
+                record_stats)
+    record_stat("hash_find_slots_walked", walked, record_stats)
     # a key that missed is in no bucket the loop reads for it: it is placed
     # or it fails, it never hits
     return table_keys, slot, miss & (slot >= 0), miss & (slot < 0)
@@ -492,22 +500,46 @@ def _bucket_masks(keys_arr, query, bkt, max_probes):
 
 
 def _find_levels(table_keys, query, valid, max_probes):
-    """What :func:`find_rows` finds, one chain level at a time: a bulk
-    load's whole-chain gather is a 4 GiB temporary (and as much again for
-    its relayout) beside the insert loop's own; one level of it is what
-    one level of that loop holds."""
+    """What :func:`find_rows` finds for the valid keys, one chain level at
+    a time (a bulk load's whole-chain gather is a 4 GiB temporary, and as
+    much again for its relayout) and, in a call wider than
+    ``table.FIND_CHUNK``, one chunk of keys a trip up to the last valid
+    one: a push hands over the whole unique buffer, whose keys fill a
+    prefix of it (a third, for Criteo-shaped ids), and a bucket row
+    gathered for padding is thrown away. The loop only reads the key array
+    and sits under no conditional, so the array stays where it is.
+    ``(slot [n], keys walked)``."""
     capacity = table_keys.shape[0]
+    n = query.shape[0]
+    chunk = table_lib.FIND_CHUNK
     bsz, _nb, chain = table_layout(capacity, max_probes)
-    b0 = probe_starts(query, capacity, max_probes) // bsz
 
-    def level(j, slot):
-        match, _ = _bucket_masks(table_keys, query, b0 + j, max_probes)
-        hit = valid & (slot < 0) & jnp.any(match, axis=1)
-        first = jnp.argmax(match, axis=1).astype(jnp.int32)
-        return jnp.where(hit, (b0 + j) * bsz + first, slot)
+    def levels(query, valid):
+        b0 = probe_starts(query, capacity, max_probes) // bsz
+        slot = jnp.full(query.shape[:1], -1, jnp.int32)
+        for j in range(chain):
+            match, _ = _bucket_masks(table_keys, query, b0 + j, max_probes)
+            hit = valid & (slot < 0) & jnp.any(match, axis=1)
+            first = jnp.argmax(match, axis=1).astype(jnp.int32)
+            slot = jnp.where(hit, (b0 + j) * bsz + first, slot)
+        return slot
 
-    return lax.fori_loop(0, chain, level,
-                         jnp.full((query.shape[0],), -1, jnp.int32))
+    if n <= chunk:
+        return levels(query, valid), jnp.int32(n)
+    trips = (table_lib.occupied_prefix(valid) + (chunk - 1)) // chunk
+    # whole chunks only, as table.apply_rows pads: the padding is invalid
+    short = -n % chunk
+    query, valid = (jnp.pad(x, [(0, short)] + [(0, 0)] * (x.ndim - 1))
+                    for x in (query, valid))
+
+    def trip(i, slot):
+        part = levels(*(lax.dynamic_slice_in_dim(x, i * chunk, chunk)
+                        for x in (query, valid)))
+        return lax.dynamic_update_slice_in_dim(slot, part, i * chunk, 0)
+
+    slot = lax.fori_loop(0, trips, trip,
+                         jnp.full((n + short,), -1, jnp.int32))
+    return slot[:n], trips * chunk
 
 
 def _insert_levels(table_keys, new_keys, valid, max_probes, slot0=None):

@@ -49,6 +49,19 @@ DEFAULT_INITIALIZER = {"category": "uniform", "minval": -1e-3, "maxval": 1e-3}
 # and scatters (apply_rows). Fixed from runs on a TPU v5e: PERF.md section 6.
 APPLY_CHUNK = 4096
 
+# Keys of the same buffer one trip of the hash push's find probes
+# (hash_table.find_or_insert); fixed the same way.
+FIND_CHUNK = 4096
+
+
+def occupied_prefix(mask: jnp.ndarray) -> jnp.ndarray:
+    """One past the last set position of ``mask`` [n], 0 where none is set:
+    the part of a unique buffer a chunked walk has to visit. Right for any
+    mask; a prefix-shaped one (both dedups leave the live slots in front)
+    makes it the number of live slots."""
+    return jnp.max(jnp.where(
+        mask, jnp.arange(1, mask.shape[0] + 1, dtype=jnp.int32), 0))
+
 
 def resolve_dtype(meta: EmbeddingVariableMeta):
     """Table dtype with the x64 guard (float64 needs jax_enable_x64)."""
@@ -254,9 +267,7 @@ def apply_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
     # apply_update; the trips' gathers and scatters keep their stages
     @scope.stage("apply_update")
     def walk(arrays, *per_slot):
-        bound = jnp.max(jnp.where(
-            per_slot[1], jnp.arange(1, capacity + 1, dtype=jnp.int32), 0))
-        trips = (bound + (chunk - 1)) // chunk
+        trips = (occupied_prefix(per_slot[1]) + (chunk - 1)) // chunk
         # whole chunks only: a last slice clamped backwards would gather a
         # row the trip before it had updated, and apply its gradient twice
         short = -capacity % chunk

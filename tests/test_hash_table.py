@@ -492,29 +492,177 @@ def test_find_or_insert_counts_its_branch(case, compact, full):
         observability.set_evaluate_performance(False)
     got = observability.GLOBAL.snapshot()
     observability.GLOBAL.reset()
+    # the find of a call of one chunk or less walks the call
     assert {k: int(got.get(k, {}).get("count", 0)) for k in (
-        "hash_insert_compact", "hash_insert_full", "hash_insert_missed")
+        "hash_insert_compact", "hash_insert_full", "hash_insert_missed",
+        "hash_find_slots_live", "hash_find_slots_walked")
     } == {"hash_insert_compact": compact, "hash_insert_full": full,
-          "hash_insert_missed": misses}
+          "hash_insert_missed": misses,
+          "hash_find_slots_live": int(valid.sum()),
+          "hash_find_slots_walked": TP_N}
 
 
-def test_find_or_insert_default_program_has_no_host_callback():
+def test_find_or_insert_default_program_has_no_host_callback(monkeypatch):
     from openembedding_tpu.analysis import contracts
     table, keys, valid, _ = _tp_case("five_percent", True)
-    default = jax.jit(ht.find_or_insert).lower(
-        table, keys, valid).compile().as_text()
+
+    def compiled(keys, valid, **kw):
+        return jax.jit(lambda t, k, v: ht.find_or_insert(t, k, v, **kw)
+                       ).lower(table, keys, valid).compile().as_text()
+
+    # the two insert loops, chosen by what they are given to do and by no
+    # conditional (which would copy the key array); a find of one chunk
+    # or less is one pass and no loop
+    default = compiled(keys, valid)
     contracts.check_no_host_transfers(default)
-    # the find and the two insert loops, chosen by what they are given to
-    # do and by no conditional (which would copy the key array)
-    assert default.count(" while(") == 3 and " conditional(" not in default
-    recording = jax.jit(lambda t, k, v: ht.find_or_insert(
-        t, k, v, record_stats=True)).lower(
-            table, keys, valid).compile().as_text()
-    assert contracts.host_transfer_ops(recording) == ["host-callback"] * 3
+    assert default.count(" while(") == 2 and " conditional(" not in default
+    recording = compiled(keys, valid, record_stats=True)
+    assert contracts.host_transfer_ops(recording) == ["host-callback"] * 5
     # a call no wider than the buffer is the loop alone
-    small = jax.jit(ht.find_or_insert).lower(
-        table, keys[:1024], valid[:1024]).compile().as_text()
+    small = compiled(keys[:1024], valid[:1024])
     assert small.count(" while(") == 1
+    # wider than a chunk, the find is a third loop, and records as little
+    monkeypatch.setattr(ht.table_lib, "FIND_CHUNK", FIND_CHUNK)
+    chunked = compiled(keys, valid)
+    contracts.check_no_host_transfers(chunked)
+    assert chunked.count(" while(") == 3 and " conditional(" not in chunked
+
+
+# --- the chunked find (find_or_insert's first phase) -------------------------
+
+FIND_CHUNK = 512                    # table.FIND_CHUNK, set small for these
+FIND_N = 3 * FIND_CHUNK + 100       # three chunks and a remainder
+FIND_MASKS = {"none_valid": 0, "one_valid": 1, "one_chunk": FIND_CHUNK,
+              "one_chunk_and_one": FIND_CHUNK + 1, "all_valid": FIND_N,
+              "holes_and_valid_last_slot": None}
+
+
+def _find_case(mask, wide):
+    """(table keys, the call's keys, valid): a twentieth of the keys are
+    not in the table, and the slots the mask leaves out hold real keys,
+    absent ones among them, which a find must neither report nor place."""
+    table, present, fresh, _crowd = _tp_table(wide)
+    keys = np.asarray(present)[:FIND_N].copy()
+    at = np.random.RandomState(5).permutation(FIND_N)[:FIND_N // 20]
+    keys[at] = np.asarray(fresh)[:len(at)]
+    live = FIND_MASKS[mask]
+    if live is None:
+        valid = np.random.RandomState(9).rand(FIND_N) < 0.5
+        valid[-1] = True
+    else:
+        valid = np.arange(FIND_N) < live
+    return table, jnp.asarray(keys), jnp.asarray(valid)
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["narrow", "wide"])
+@pytest.mark.parametrize("mask", FIND_MASKS)
+def test_chunked_find_equals_level_loop(monkeypatch, mask, wide):
+    """A call wider than a chunk finds in trips over the occupied prefix:
+    the key array, slots, inserted and failed are the full-width loop's
+    bit for bit, whatever the mask."""
+    monkeypatch.setattr(ht.table_lib, "FIND_CHUNK", FIND_CHUNK)
+    table, keys, valid = _find_case(mask, wide)
+    assert ht.insert_width(FIND_N) < FIND_N     # the call has a find phase
+    want = _level_loop_oracle(table, keys, valid, ht.DEFAULT_MAX_PROBES)
+    got = jax.jit(ht.find_or_insert)(table, keys, valid)
+    for name, g, w in zip(("keys", "slot", "inserted", "failed"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    _, slot, inserted, failed = map(np.asarray, got)
+    valid = np.asarray(valid)
+    assert not failed.any() and (slot[~valid] == -1).all()
+    assert (slot[valid] >= 0).all() and not inserted[~valid].any()
+    if mask == "all_valid":
+        assert inserted.sum() == FIND_N // 20
+
+
+@pytest.mark.parametrize("mask", FIND_MASKS)
+def test_chunked_find_counts_the_keys_it_walks(monkeypatch, mask):
+    from test_table import recorded
+    monkeypatch.setattr(ht.table_lib, "FIND_CHUNK", FIND_CHUNK)
+    table, keys, valid = _find_case(mask, True)
+    _, stats = recorded(lambda: jax.jit(
+        lambda t, k, v: ht.find_or_insert(t, k, v, record_stats=True))(
+            table, keys, valid))
+    valid = np.asarray(valid)
+    bound = int(np.flatnonzero(valid).max()) + 1 if valid.any() else 0
+    assert {k: stats.get(k, 0) for k in (
+        "hash_find_slots_live", "hash_find_slots_walked")} == {
+            "hash_find_slots_live": int(valid.sum()),
+            "hash_find_slots_walked": -(-bound // FIND_CHUNK) * FIND_CHUNK}
+
+
+def _one_pass_find_levels(table_keys, query, valid, max_probes):
+    """The find as it was before it walked chunks, over every key of the
+    call in one pass a level: the reference of the test below."""
+    capacity = table_keys.shape[0]
+    bsz, _nb, chain = ht.table_layout(capacity, max_probes)
+    b0 = ht.probe_starts(query, capacity, max_probes) // bsz
+
+    def level(j, slot):
+        match, _ = ht._bucket_masks(table_keys, query, b0 + j, max_probes)
+        hit = valid & (slot < 0) & jnp.any(match, axis=1)
+        first = jnp.argmax(match, axis=1).astype(jnp.int32)
+        return jnp.where(hit, (b0 + j) * bsz + first, slot)
+
+    n = query.shape[0]
+    return (jax.lax.fori_loop(0, chain, level,
+                              jnp.full((n,), -1, jnp.int32)), jnp.int32(n))
+
+
+@pytest.mark.parametrize("key_dtype", ["int32", "wide"])
+@pytest.mark.parametrize("data,model", [(1, 1), (2, 2)],
+                         ids=["1x1", "2x2"])
+def test_three_trainer_steps_equal_the_one_pass_find(monkeypatch, devices8,
+                                                     data, model, key_dtype):
+    """DeepFM over one fused hash table, three steps of ``Trainer``: every
+    leaf of the tables and of the dense model is what the one-pass find
+    leaves, bit for bit, with pushes several chunks wide."""
+    import optax
+    from openembedding_tpu import EmbeddingCollection, Trainer
+    from openembedding_tpu.fused import make_fused_specs
+    from openembedding_tpu.models import deepctr
+    features, batch = ("a", "b", "c", "d"), 1024
+    mesh = create_mesh(data, model, devices8[:data * model])
+    rng = np.random.RandomState(31)
+    raw = [{"label": (rng.rand(batch) > 0.5).astype(np.float32),
+            "dense": rng.randn(batch, 4).astype(np.float32),
+            "sparse": {f: (rng.zipf(1.2, batch) % 50021).astype(np.int32)
+                       for f in features}} for _ in range(3)]
+
+    def three_steps():
+        jax.clear_caches()      # the push programs are cached by their spec
+        specs, mapper = make_fused_specs(
+            features, -1, DIM, hash_capacity=1 << 14, key_dtype=key_dtype,
+            optimizer={"category": "adagrad", "learning_rate": 0.1})
+        trainer = Trainer(deepctr.build_model("deepfm", features),
+                          EmbeddingCollection(specs, mesh), optax.adam(1e-2))
+        batches = [mapper.fuse_batch(b) for b in raw]
+        state = trainer.init(jax.random.PRNGKey(0),
+                             trainer.shard_batch(batches[0]))
+        for b in batches:
+            state, _ = trainer.train_step(state, b)
+        return jax.device_get((state.emb, state.params))
+
+    def traced(find, widths):
+        def spy(table_keys, query, *rest):
+            widths.append(query.shape[0])
+            return find(table_keys, query, *rest)
+        return spy
+
+    chunked, one_pass = [], []
+    monkeypatch.setattr(ht.table_lib, "FIND_CHUNK", 256)
+    monkeypatch.setattr(ht, "_find_levels", traced(ht._find_levels, chunked))
+    got = three_steps()
+    monkeypatch.setattr(ht, "_find_levels",
+                        traced(_one_pass_find_levels, one_pass))
+    want = three_steps()
+    # each form was traced into its own steps, over several chunks a push
+    assert chunked == one_pass and min(chunked) >= 3 * 256, (chunked,
+                                                             one_pass)
+    assert int(want[0]["fields"].num_used()) > 300
+    assert int(want[0]["fields"].insert_failures) == 0
+    jax.tree.map(np.testing.assert_array_equal, got, want)
 
 
 # --- the chunked sparse apply (table.apply_rows) on the hash path -----------

@@ -150,6 +150,58 @@ def test_v5e_hash_push_finds_then_inserts_in_place(v5e, shape):
         assert not copies, copies
 
 
+def test_v5e_hash_push_find_walks_chunks_in_place(v5e):
+    """The find of each table's push is the one loop under ``probe`` that
+    neither sorts nor scatters: its trips gather a chunk of bucket rows,
+    and nothing as large as the unique buffer's worth of them (26 x 4096
+    keys x 128 slots) is left in the program outside the insert loops,
+    whose full-width one keeps it. The key array the find reads is copied
+    nowhere, into the loop or out of it."""
+    mesh = create_mesh(1, 1, v5e[:1])
+    hlo = _compile_deepfm_step(mesh, use_hash=True).as_text()
+    lines = hlo.splitlines()
+    stages = stage_reduce.instruction_stages(hlo)
+    _, comps = contracts.parse_hlo_computations(hlo)
+
+    def reached(names):
+        seen, todo = set(), list(names)
+        while todo:
+            name = todo.pop()
+            if name not in seen and name in comps:
+                seen.add(name)
+                todo += [c for inst in comps[name] for c in inst.calls]
+        return seen
+
+    unique = chip_smoke.FEATURES * chip_smoke.BATCH
+    chunk = hl.table_lib.FIND_CHUNK
+    assert unique > chunk
+
+    def makes(inst, rows):
+        return re.search(rf"= \(?\w+\[{rows},{hl.BUCKET}\b",
+                         lines[inst.line_no])
+
+    finds, inserting = [], set()
+    for inst in (i for body in comps.values() for i in body):
+        if inst.opcode == "while" and stages.get(inst.name) == "probe":
+            inside = reached(inst.calls)
+            if {i.opcode for c in inside for i in comps[c]} & {"sort",
+                                                               "scatter"}:
+                inserting |= inside
+            else:
+                finds.append([i for c in inside for i in comps[c]])
+    assert len(finds) == 2, len(finds)                  # two tables
+    for body in finds:
+        assert any(makes(i, chunk) for i in body)
+        assert not [i.name for i in body if makes(i, unique)]
+    left = [i.name for c, body in comps.items() if c not in inserting
+            for i in body if makes(i, unique)]
+    assert not left, left
+    keys = f"s32[{HASH_CAPACITY},2]"
+    copies = [line.strip()[:120] for line in lines
+              if f"= {keys}" in line and " copy" in line]
+    assert not copies, copies
+
+
 @pytest.mark.parametrize("shape,use_hash", [((1, 1), False), ((1, 1), True),
                                             ((2, 2), False)],
                          ids=["1x1-array", "1x1-hash", "2x2-array"])
