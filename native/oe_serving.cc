@@ -737,6 +737,55 @@ bool verify_chunk_crcs(const DeltaPayload& pl, const Json& chunk_crc,
   return true;
 }
 
+// Mirror checkpoint_delta._verify_array_blocks (delta format 2): one
+// crc32 a block of block_rows payload rows (the last block short), over
+// the field rows in _field_order, against the manifest record's
+// block_crc list. Same contract as verify_chunk_crcs: false on any
+// mismatch or ill-formed geometry, never out of bounds.
+bool verify_block_crcs(const DeltaPayload& pl, const Json& block_crc,
+                       const Json* block_rows, const std::string& what) {
+  int64_t B = 0;
+  NpyArray w;
+  if (!json_i64(block_rows, &B) || B <= 0
+      || !pl.view("weights", &w, what)) {
+    return false;
+  }
+  const int64_t rows = w.rows();
+  if (static_cast<int64_t>(block_crc.arr.size()) != (rows + B - 1) / B) {
+    return false;
+  }
+  std::vector<std::string> order = {"weights"};
+  for (const auto& m : pl.members) {
+    if (m.first.rfind("slot_", 0) == 0 && m.first.size() > 4
+        && m.first.compare(m.first.size() - 4, 4, ".npy") == 0) {
+      order.push_back(m.first.substr(0, m.first.size() - 4));
+    }
+  }
+  std::vector<NpyArray> fields(order.size());
+  for (size_t k = 0; k < order.size(); ++k) {
+    if (!pl.view(order[k], &fields[k], what) || fields[k].rows() != rows
+        || fields[k].row_elems() < 0) {
+      return false;
+    }
+  }
+  for (size_t i = 0; i < block_crc.arr.size(); ++i) {
+    int64_t want = 0;
+    if (!json_i64(&block_crc.arr[i], &want)) return false;
+    const int64_t off = static_cast<int64_t>(i) * B;
+    const int64_t n = std::min(off + B, rows) - off;
+    uint32_t crc = 0;
+    for (const NpyArray& a : fields) {
+      int64_t rowbytes = a.row_elems() * static_cast<int64_t>(a.itemsize);
+      crc = crc32_update(
+          crc,
+          reinterpret_cast<const unsigned char*>(a.data) + off * rowbytes,
+          static_cast<size_t>(n) * static_cast<size_t>(rowbytes));
+    }
+    if (crc != static_cast<uint32_t>(want)) return false;
+  }
+  return true;
+}
+
 // Apply one variable's verified payload newest-wins: its weights become
 // a new part; overlay/index entries redirect the touched keys to it.
 bool apply_delta_payload(oe_variable* var, const DeltaPayload& pl,
@@ -856,7 +905,9 @@ bool replay_delta_chain(oe_model* model, const std::string& root) {
     return false;
   }
   int64_t fmt_num = -1;
-  if (!json_i64(manifest.get("format"), &fmt_num) || fmt_num != 1) {
+  // format 2 adds block_crc records; format 1 manifests read as before
+  if (!json_i64(manifest.get("format"), &fmt_num)
+      || (fmt_num != 1 && fmt_num != 2)) {
     set_error("unknown delta manifest format at " + root);
     return false;
   }
@@ -916,6 +967,15 @@ bool replay_delta_chain(oe_model* model, const std::string& root) {
           && (ccrc->kind != Json::kArr
               || !verify_chunk_crcs(pl, *ccrc, file->str))) {
         bad = true;                      // chunk checksum mismatch
+        break;
+      }
+      const Json* bcrc = kv.second.get("block_crc");
+      if (bcrc && bcrc->kind != Json::kNull
+          && (bcrc->kind != Json::kArr
+              || !verify_block_crcs(pl, *bcrc,
+                                    kv.second.get("block_rows"),
+                                    file->str))) {
+        bad = true;                      // block checksum mismatch
         break;
       }
       maps.push_back(std::move(mf));

@@ -1803,8 +1803,9 @@ def delta_chain(*, commit_order: str = "payload_first",
 
     # -- delta save ---------------------------------------------------------
     def dw_guard(s):
-        # the saver is the trainer's own thread (fit's blocking
-        # autosave): no save from a dead process, and no empty delta —
+        # the saver belongs to the trainer's process (fit's autosave:
+        # the snapshot on the step thread, the write on its writer
+        # thread): no save from a dead process, and no empty delta —
         # a save needs rows the last commit does not cover
         return s["mf"] is not None and s["saver"] == ("idle",) \
             and s["comp"] == ("off",) and s["mf"][1] < max_seq \
@@ -1818,7 +1819,10 @@ def delta_chain(*, commit_order: str = "payload_first",
         s["mf"] = (gen, seq, cseq, chain + (seq,))
         s["truths"] = s["truths"] | {seq}
         # the manifest entry's extra records the trainer cursor at the
-        # save (t_hi cannot move mid-save: fit's autosave is blocking)
+        # save's SNAPSHOT. fit trains on while its writer thread
+        # commits, but what the save holds (rows and cursor) was fixed
+        # when the snapshot was dispatched; the model keeps the save
+        # atomic against trainer_step, which is that instant
         s["cursors"] = s["cursors"] + ((seq, s["t_hi"]),)
 
     def write_branches(s, seq, key):
@@ -2117,8 +2121,10 @@ def delta_chain(*, commit_order: str = "payload_first",
 
     # -- trainer_restart role ----------------------------------------------
     def t_step_guard(s):
-        # fit's loop: one batch at a time, never while its own blocking
-        # autosave is in flight
+        # fit's loop: one batch at a time. Steps do run while the writer
+        # thread commits a save; none of them reaches that save (its
+        # content is its snapshot's), so the model orders each save
+        # whole between two steps
         return s["t_pc"] == "run" and s["saver"] == ("idle",) \
             and s["t_next"] <= trainer_steps
 

@@ -10,10 +10,20 @@ module generalizes it to the WHOLE-MODEL checkpoint
 * a FULL save (``checkpoint._save_checkpoint_impl``, parallel shard
   writers) is the BASE; it arms the chain by writing a fresh manifest
   (:func:`init_manifest`) when the collection's dirty tracking is on;
-* a DELTA save writes, per variable, only the chunks whose
-  ``DirtyTracker`` bit is set (``dirty.py``; pushes mark chunks) — one
-  ``delta_<seq>_<vid>.npz`` per variable, written by the same parallel
-  writer pool, checksummed per chunk;
+* a DELTA save writes, per variable, only what its ``DirtyTracker``
+  marked (``dirty.py``; pushes mark rows of an array table, key chunks
+  of a hash table) — one ``delta_<seq>_<vid>.npz`` per variable,
+  written by the same parallel writer pool, checksummed per block of
+  rows. It has two halves. :func:`begin_delta` is the SNAPSHOT: it
+  claims the dirty set and dispatches, behind whatever step was
+  dispatched last, one gather program a variable that copies the dirty
+  rows of the weights and of every slot array into staging buffers
+  (``sharded_table.snapshot_rows_sharded``, stage ``ckpt_gather``):
+  nothing waits for the device, and steps dispatched after it may
+  donate the tables. :func:`finish_delta` is everything else (the copy
+  to the host, checksums, files, the manifest rename), on any thread:
+  ``Trainer.fit`` runs it on a writer thread, :func:`save_delta` right
+  after the snapshot;
 * the MANIFEST (``delta_manifest``, atomic rename) is the single commit
   point: a kill at ANY instant leaves either the previous chain or the
   new chain — never a manifest referencing a torn file. Torn/corrupt
@@ -39,6 +49,7 @@ never has a manifest and loads exactly as before.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import io
 import json
 import os
@@ -64,7 +75,19 @@ from . import hash_table as hash_lib
 from . import table as table_lib
 
 DELTA_MANIFEST_FILE = "delta_manifest"
-DELTA_FORMAT = 1
+# Format 2 (PR 32): an array record may carry ``block_crc`` (one crc32 a
+# block of ``block_rows`` payload rows) where format 1 has ``chunk_crc``
+# (one a tracker chunk: a Python call a row when the tracker is exact to
+# the row). A manifest is written at the lowest format that holds it, so
+# a chain of chunked deltas stays format 1, byte for byte.
+DELTA_FORMAT = 2
+_CHUNKED_FORMAT = 1
+CRC_BLOCK_ROWS = 1 << 16
+# staging lengths of a snapshot are few and fixed, so that the saves of a
+# steady job reuse one compiled gather a variable: powers of two from here,
+# and from four gather chunks on, four lengths an octave (at most a quarter
+# of padding, whole chunks)
+_MIN_STAGING_ROWS = 256
 # compaction budget: fold the chain into a new base past either bound
 COMPACT_CHAIN_LEN = 8
 COMPACT_BYTES_RATIO = 0.5
@@ -149,10 +172,12 @@ def read_manifest(path: str) -> Optional[Dict[str, Any]]:
         raise DeltaDecodeError(
             f"delta manifest at {path!r} is JSON "
             f"{type(manifest).__name__}, not an object")
-    if manifest.get("format") != DELTA_FORMAT:
+    if manifest.get("format") not in (_CHUNKED_FORMAT, DELTA_FORMAT) \
+            or isinstance(manifest.get("format"), bool):
         raise ValueError(
             f"unknown delta manifest format {manifest.get('format')!r} "
-            f"at {path!r} (this build reads format {DELTA_FORMAT})")
+            f"at {path!r} (this build reads formats {_CHUNKED_FORMAT} "
+            f"and {DELTA_FORMAT})")
     return manifest
 
 
@@ -181,7 +206,7 @@ def init_manifest(path: str, *, step: int, include_optimizer: bool,
     resume channel (``Trainer.fit(autosave_every=)`` records its step/
     epoch/ingest cursor here; ``resume_from`` restores from whatever
     entry the load verifies). JSON-serializable dict."""
-    manifest = {"format": DELTA_FORMAT,
+    manifest = {"format": _CHUNKED_FORMAT,
                 "base_id": uuid.uuid4().hex,
                 "base_step": int(step),
                 "include_optimizer": bool(include_optimizer),
@@ -275,63 +300,99 @@ def _field_order(payload: Dict[str, np.ndarray]) -> List[str]:
     return fields
 
 
-def _array_delta_payload(state, sspec, vocab: int, rows_per_chunk: int,
-                         chunks: np.ndarray, include_optimizer: bool
-                         ) -> Tuple[Dict[str, np.ndarray], List[int]]:
-    """Gather one bounded variable's dirty chunks into a payload dict +
-    per-chunk crc32 list (crc over the chunk's weights+slots bytes, in
-    field order). Contiguous chunk runs gather as one logical window —
-    the same bulk device->host streams as the full save."""
-    from . import checkpoint as ckpt
-    fields: Dict[str, Any] = {"weights": state.weights}
-    if include_optimizer:
-        for sname, sval in state.slots.items():
-            fields[f"slot_{sname}"] = sval
-    shards = {f: ckpt._sorted_shards(a) for f, a in fields.items()}
+def _chunk_rows(chunks: np.ndarray, rows_per_chunk: int,
+                vocab: int) -> np.ndarray:
+    """Logical row ids of array-table chunks, chunk by chunk in the order
+    given (a chunk is the contiguous range ``[c * R, min((c+1) * R,
+    vocab))``); the chunks themselves where a chunk is a row."""
     chunks = np.asarray(chunks, np.int64)
-    parts: Dict[str, list] = {f: [] for f in fields}
-    chunk_crcs: List[int] = []
-    R = int(rows_per_chunk)
-    # group consecutive chunk ids into runs
-    runs: List[Tuple[int, int]] = []
+    if rows_per_chunk == 1:
+        return chunks
+    rows = (chunks[:, None] * rows_per_chunk
+            + np.arange(min(rows_per_chunk, max(int(vocab), 1)),
+                        dtype=np.int64)).ravel()
+    return rows[rows < vocab]
+
+
+@dataclasses.dataclass
+class _StagedRows:
+    """One array variable's dirty rows between snapshot and commit: the
+    staging buffers on the device (weights, then each slot array, rows in
+    the order of ``header["chunks"]``) and the payload's header."""
+    fields: List[str]
+    arrays: List[Any]
+    rows: int
+    header: Dict[str, np.ndarray]
+
+
+def _staging_rows(rows: int) -> int:
+    size = max(_MIN_STAGING_ROWS, 1 << max(rows - 1, 0).bit_length())
+    if size >= 4 * table_lib.APPLY_CHUNK:
+        step = size // 8
+        size = -(-rows // step) * step
+    return size
+
+
+def _stage_array_rows(collection, name: str, state, tracker,
+                      chunks: np.ndarray, include_optimizer: bool
+                      ) -> _StagedRows:
+    """Dispatch the gather of one bounded variable's dirty rows: one
+    program over the weights and every slot array, by row id, into
+    staging buffers of one of a few fixed lengths (the only new device
+    memory of a save; no slice or copy of a table array), and the
+    start of their copy to the host. Returns without waiting."""
+    sspec = collection.sharding_spec(name)
+    vocab = int(collection.specs[name].input_dim)
+    fields, arrays = ["weights"], [state.weights]
+    if include_optimizer:
+        for sname in sorted(state.slots):
+            fields.append(f"slot_{sname}")
+            arrays.append(state.slots[sname])
+    rows = _chunk_rows(chunks, tracker.rows_per_chunk, vocab)
+    if sspec.num_shards > 1:
+        shard, local = sspec.shard_and_local(rows)
+        rows = shard * sspec.rows_per_shard + local
+    phys = np.full(_staging_rows(int(rows.size)), -1, np.int32)
+    phys[:rows.size] = rows
+    staged = st.snapshot_rows_sharded(
+        arrays, jnp.asarray(phys), rows.size, mesh=collection.mesh,
+        spec=sspec)
+    for a in staged:
+        a.copy_to_host_async()
+    return _StagedRows(
+        fields, staged, int(rows.size),
+        {"chunks": np.asarray(chunks, np.int64),
+         "rows_per_chunk": np.int64(tracker.rows_per_chunk),
+         "vocab": np.int64(vocab)})
+
+
+def _row_crcs(payload: Dict[str, np.ndarray], bounds) -> List[int]:
+    """crc32 of the payload rows ``[a, b)`` of each of ``bounds``, over
+    weights then slots in field order: one call a field and range."""
+    order = _field_order(payload)
+    out = []
+    for a, b in bounds:
+        crc = 0
+        for f in order:
+            crc = zlib.crc32(np.ascontiguousarray(payload[f][a:b])
+                             .reshape(-1).view(np.uint8), crc)
+        out.append(crc)
+    return out
+
+
+def _chunk_bounds(chunks, rows_per_chunk: int, vocab: int):
+    """Payload row range of each chunk (the last chunk may be short)."""
+    off = 0
     for c in chunks:
         c = int(c)
-        if runs and runs[-1][1] == c:
-            runs[-1] = (runs[-1][0], c + 1)
-        else:
-            runs.append((c, c + 1))
-    order = _field_order({f: None for f in fields})
-    for c0, c1 in runs:
-        l0 = c0 * R
-        l1 = min(c1 * R, vocab)
-        if l1 <= l0:
-            continue
-        bufs = {}
-        for f, arr in fields.items():
-            bufs[f] = ckpt.gather_logical_window(
-                shards[f], sspec, l0, l1, arr.shape[1:],
-                np.dtype(arr.dtype))
-            parts[f].append(bufs[f])
-        for c in range(c0, c1):
-            a = c * R - l0
-            b = min((c + 1) * R, vocab) - l0
-            if b <= a:
-                continue
-            crc = 0
-            for f in order:
-                crc = zlib.crc32(bufs[f][a:b].tobytes(), crc)
-            chunk_crcs.append(crc)
-    payload = {}
-    for f, arr in fields.items():
-        if parts[f]:
-            payload[f] = np.concatenate(parts[f])
-        else:
-            payload[f] = np.zeros((0,) + arr.shape[1:],
-                                  np.dtype(arr.dtype))
-    payload["chunks"] = chunks
-    payload["rows_per_chunk"] = np.int64(R)
-    payload["vocab"] = np.int64(vocab)
-    return payload, chunk_crcs
+        n = min((c + 1) * rows_per_chunk, vocab) - c * rows_per_chunk
+        yield off, off + n
+        off += n
+
+
+def _block_bounds(rows: int, block_rows: int):
+    return ((a, min(a + block_rows, rows))
+            for a in range(0, rows, block_rows))
 
 
 def _hash_delta_payload(state, tracker, chunks: np.ndarray,
@@ -374,16 +435,60 @@ def _hash_delta_payload(state, tracker, chunks: np.ndarray,
     return payload
 
 
+class _NpzBuffer:
+    """The seekable in-memory file ``np.savez`` writes a payload into,
+    sized up front. Its writes are numpy copies of plain bytes, which
+    release the interpreter lock; ``io.BytesIO`` grows by reallocating
+    under the lock and hands its bytes out as one more copy, which for a
+    276 MB payload held the lock, and the step thread of a training loop
+    with it, for about a second of every save (PERF.md, PR 32)."""
+
+    def __init__(self, capacity: int):
+        self._bytes = np.empty(max(int(capacity), 1), np.uint8)
+        self._at = self._end = 0
+
+    def write(self, data) -> int:
+        data = np.frombuffer(data, np.uint8)
+        end = self._at + data.size
+        if end > self._bytes.size:
+            grown = np.empty(max(end, 2 * self._bytes.size), np.uint8)
+            np.copyto(grown[:self._end], self._bytes[:self._end])
+            self._bytes = grown
+        np.copyto(self._bytes[self._at:end], data)
+        self._at, self._end = end, max(self._end, end)
+        return data.size
+
+    def read(self, size: int = -1) -> bytes:     # what makes it a file
+        end = self._end if size < 0 else min(self._end, self._at + size)
+        data = self._bytes[self._at:end].tobytes()
+        self._at = end
+        return data
+
+    def seek(self, offset: int, whence: int = 0) -> int:
+        self._at = offset + (0, self._at, self._end)[whence]
+        return self._at
+
+    def tell(self) -> int:
+        return self._at
+
+    def flush(self) -> None:
+        pass
+
+    def getbuffer(self) -> memoryview:
+        return memoryview(self._bytes[:self._end])
+
+
 def _serialize_payload(payload: Dict[str, np.ndarray],
-                       compress: str) -> Tuple[bytes, int]:
+                       compress: str) -> Tuple[memoryview, int]:
     """npz bytes + file crc32 (the whole-file checksum the manifest
     records; verified before any byte of the delta is applied)."""
     from .utils import compress as compress_lib
     savez = np.savez_compressed \
         if compress_lib.check_persist_codec(compress) else np.savez
-    bio = io.BytesIO()
-    savez(bio, **payload)
-    raw = bio.getvalue()
+    out = _NpzBuffer(sum(np.asarray(v).nbytes + 4096
+                         for v in payload.values()) + 4096)
+    savez(out, **payload)
+    raw = out.getbuffer()
     return raw, zlib.crc32(raw)
 
 
@@ -417,60 +522,96 @@ def _verify_array_chunks(payload: Dict[str, np.ndarray],
         chunks = np.asarray(payload["chunks"], np.int64)
         R = int(payload["rows_per_chunk"])
         vocab = int(payload["vocab"])
-        order = _field_order(payload)
-        if R <= 0 or vocab < 0 or chunks.ndim != 1:
+        if R <= 0 or vocab < 0 or chunks.ndim != 1 \
+                or len(chunk_crc) != chunks.size:
             return False
-        nchunks = -(-vocab // R)
-        if len(chunk_crc) != chunks.size:
+        if chunks.size and not (0 <= int(chunks.min())
+                                and int(chunks.max()) < -(-vocab // R)):
             return False
-        off = 0
-        for i, c in enumerate(chunks):
-            c = int(c)
-            if c < 0 or c >= nchunks:
-                return False
-            n = min((c + 1) * R, vocab) - c * R
-            crc = 0
-            for f in order:
-                crc = zlib.crc32(payload[f][off:off + n].tobytes(), crc)
-            if crc != int(chunk_crc[i]):
-                return False
-            off += n
-        return all(payload[f].shape[0] == off for f in order)
+        bounds = list(_chunk_bounds(chunks, R, vocab))
+        return _crcs_match(payload, bounds,
+                           bounds[-1][1] if bounds else 0, chunk_crc)
     except (KeyError, TypeError, ValueError, OverflowError):
+        return False
+
+
+def _crcs_match(payload, bounds, rows: int, want) -> bool:
+    """Every field holds ``rows`` rows and the crcs of ``bounds`` are
+    ``want``."""
+    return all(payload[f].shape[0] == rows for f in _field_order(payload)) \
+        and _row_crcs(payload, bounds) == [int(c) for c in want]
+
+
+def _verify_array_blocks(payload: Dict[str, np.ndarray], block_crc,
+                         block_rows) -> bool:
+    """Recompute the per-block crcs of a format-2 array payload (blocks
+    of ``block_rows`` payload rows, the last one short). Never raises;
+    mirrored by the native reader's ``verify_block_crcs``."""
+    try:
+        block_rows = int(block_rows)
+        rows = int(payload["weights"].shape[0])
+        if block_rows <= 0 or isinstance(block_crc, (str, bytes)) \
+                or len(block_crc) != -(-rows // block_rows):
+            return False
+        return _crcs_match(payload, _block_bounds(rows, block_rows), rows,
+                           block_crc)
+    except (KeyError, TypeError, ValueError, OverflowError,
+            AttributeError):
         return False
 
 
 # --- delta save --------------------------------------------------------------
 
-def save_delta(path: str, collection: EmbeddingCollection,
-               states: Dict[str, Any], *, step: int,
-               dense_state: Any = None,
-               include_optimizer: bool = True,
-               compress: str = "",
-               model_sign: str = "",
-               max_workers: Optional[int] = None,
-               compact_chain_len: int = COMPACT_CHAIN_LEN,
-               compact_bytes_ratio: float = COMPACT_BYTES_RATIO,
-               background_compact: bool = True,
-               return_payload: bool = False,
-               extra: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    """One incremental save: dirty chunks since the last save -> one new
-    chain entry. Forces a FULL save when no armed base exists (first
-    save into a directory, or the previous dump predates dirty
-    tracking). See ``checkpoint.save_checkpoint`` for the public entry.
+@dataclasses.dataclass
+class PendingDelta:
+    """A delta save between its snapshot (:func:`begin_delta`) and its
+    commit (:func:`finish_delta`): the claimed dirty sets, the staged
+    rows, the chain entry it will become. At most one per directory at a
+    time: the next :func:`begin_delta` reads the manifest this one
+    commits."""
+    path: str
+    collection: Any
+    manifest: Dict[str, Any]
+    seq: int
+    step: int
+    snaps: Dict[str, np.ndarray]
+    staged: Dict[str, Any]          # _StagedRows, or a hash payload
+    dense: Any
+    options: Dict[str, Any]
+    began: float
+    snapshot_at: float
 
-    ``return_payload=True`` attaches the committed :class:`Delta` to the
-    info dict (``info["delta"]``) straight from memory — the PUBLISH
-    path for serving hot-swap. Prefer it over a post-save
-    :func:`read_delta`: the background compactor may fold the chain
-    (deleting the file) before a disk read lands.
 
-    ``extra``: JSON-serializable caller bookkeeping committed WITH this
-    entry (and carried into the manifest base when the save is forced
-    full) — the elastic-resume channel: ``fit(autosave_every=)`` records
-    ``{"fit": {step, epoch, cursor}}`` here and ``fit(resume_from=)``
-    restores from the entry the load actually verifies, so a torn tail
-    resumes one autosave earlier, never from a half-applied state.
+@functools.lru_cache(maxsize=None)
+def _tree_copy_program():
+    def ckpt_gather_dense(tree):
+        return jax.tree.map(jnp.copy, tree)
+    return jax.jit(ckpt_gather_dense)
+
+
+def begin_delta(path: str, collection: EmbeddingCollection,
+                states: Dict[str, Any], *, step: int,
+                dense_state: Any = None,
+                include_optimizer: bool = True,
+                compress: str = "",
+                model_sign: str = "",
+                max_workers: Optional[int] = None,
+                compact_chain_len: int = COMPACT_CHAIN_LEN,
+                compact_bytes_ratio: float = COMPACT_BYTES_RATIO,
+                background_compact: bool = True,
+                return_payload: bool = False,
+                extra: Optional[Dict[str, Any]] = None):
+    """The snapshot half of a delta save (arguments: :func:`save_delta`).
+
+    Claims every tracker's dirty set and stages what it names as of
+    ``states``: an array variable's rows by one gather program dispatched
+    on the device's stream (:func:`_stage_array_rows`), the dense pytree
+    by a device copy, neither waited for; a hash variable's rows are read
+    to the host here (its tracker is not exact to the key, so its payload
+    is a scan of the table). Returns a :class:`PendingDelta` for
+    :func:`finish_delta`. ``states`` may be donated once this returns.
+    With no armed base in ``path`` nothing can be incremental: the full
+    save runs here, blocking, and its info dict is returned instead.
     """
     from . import checkpoint as ckpt
     from .utils import compress as compress_lib
@@ -511,99 +652,192 @@ def save_delta(path: str, collection: EmbeddingCollection,
             f"(base={manifest.get('include_optimizer')}); re-save full")
     _gc_orphans(path, manifest["chain"])
 
-    # DENSE params ride OUTSIDE the chain protocol: small, replicated,
-    # rewritten whole (atomically) on every save — including a SKIPPED
-    # one, so a dense-only training window still persists its params.
-    # Last-writer-wins; a torn-tail recovery keeps the newest dense
-    # file next to the recovered sparse state (document'd divergence —
-    # chain guarantees cover the sparse tables).
-    if dense_state is not None:
-        from flax import serialization
-        with fs.open_atomic(fs.join(path, ckpt.DENSE_FILE)) as f:
-            f.write(serialization.to_bytes(jax.device_get(dense_state)))
-
     snaps = {name: trackers[name].snapshot_clear() for name in trackers}
-    total_dirty = sum(s.size for s in snaps.values())
-    if total_dirty == 0:
-        return {"mode": "delta", "seq": int(manifest["last_seq"]),
-                "skipped": True, "bytes": 0, "rows": 0,
-                "chain_len": len(manifest["chain"])}
-    seq = int(manifest["last_seq"]) + 1
+    staged: Dict[str, Any] = {}
+    try:
+        for name, chunks in snaps.items():
+            if not chunks.size:
+                continue
+            state = hot_cache.unwrap(states[name])
+            if collection.specs[name].use_hash:
+                staged[name] = _hash_delta_payload(
+                    state, trackers[name], chunks, include_optimizer)
+            else:
+                staged[name] = _stage_array_rows(
+                    collection, name, state, trackers[name], chunks,
+                    include_optimizer)
+        dense = None
+        if dense_state is not None:
+            dense = _tree_copy_program()(dense_state)
+            for leaf in jax.tree.leaves(dense):
+                leaf.copy_to_host_async()
+    except BaseException:
+        for name, chunks in snaps.items():
+            trackers[name].restore(chunks)
+        raise
+    # the snapshot is taken: steps dispatched from here on run behind it,
+    # and a kill from here to the commit leaves the previous chain
+    sync_point("ckpt.delta.snapshot")
+    return PendingDelta(
+        path=path, collection=collection, manifest=manifest,
+        seq=int(manifest["last_seq"]) + 1, step=int(step), snaps=snaps,
+        staged=staged, dense=dense, began=t0,
+        snapshot_at=time.perf_counter(),
+        options=dict(compress=compress, max_workers=max_workers,
+                     compact_chain_len=compact_chain_len,
+                     compact_bytes_ratio=compact_bytes_ratio,
+                     background_compact=background_compact,
+                     return_payload=return_payload,
+                     extra=dict(extra) if extra else None))
+
+
+def finish_delta(pending: PendingDelta) -> Dict[str, Any]:
+    """The commit half of a delta save, on any thread: the staged rows
+    come to the host (span ``ckpt.d2h``: the wait for the steps ahead of
+    the gather, the gather, the copy), are checksummed (``ckpt.checksum``)
+    and written one file a variable (``ckpt.write``), and one manifest
+    rename commits them (``ckpt.commit``). A failure anywhere restores
+    every claimed dirty set to its tracker, so the next save carries
+    those rows."""
+    from . import checkpoint as ckpt
+    from .analysis import scope
+    from .utils import observability
+    path, collection, manifest = \
+        pending.path, pending.collection, pending.manifest
+    opts, seq = pending.options, pending.seq
+    trackers = collection.dirty_trackers
     results: Dict[str, Dict[str, Any]] = {}
-    kept_payloads: Dict[str, Dict[str, np.ndarray]] = {}
-    tasks = []
+    payloads: Dict[str, Dict[str, np.ndarray]] = {}
 
     def _write_var(name: str) -> None:
         sync_point("ckpt.delta.write")
-        spec = collection.specs[name]
-        tracker = trackers[name]
-        state = hot_cache.unwrap(states[name])
-        chunks = snaps[name]
-        if spec.use_hash:
-            payload = _hash_delta_payload(state, tracker, chunks,
-                                          include_optimizer)
-            chunk_crc = None
-        else:
-            payload, chunk_crc = _array_delta_payload(
-                state, collection.sharding_spec(name), spec.input_dim,
-                tracker.rows_per_chunk, chunks, include_optimizer)
-        rows = int(payload["weights"].shape[0])
-        raw, crc = _serialize_payload(payload, compress)
-        fname = _delta_fname(seq, collection.variable_id(name))
-        with fs.open_atomic(fs.join(path, fname)) as f:
-            f.write(raw)
-        info = {"file": fname, "bytes": len(raw), "crc32": int(crc),
-                "kind": "hash" if spec.use_hash else "array",
-                "rows": rows, "dirty_chunks": int(chunks.size)}
-        if chunk_crc is not None:
-            info["chunk_crc"] = [int(c) for c in chunk_crc]
-        results[name] = info
-        if return_payload:
-            kept_payloads[name] = payload
+        payload = payloads[name]
+        info = {"kind": "array" if "rows_per_chunk" in payload else "hash",
+                "rows": int(payload["weights"].shape[0]),
+                "dirty_chunks": int(payload["chunks"].size)}
+        if info["kind"] == "array":
+            with scope.span("ckpt.checksum"):
+                R = int(payload["rows_per_chunk"])
+                if R == 1:
+                    info["block_rows"] = CRC_BLOCK_ROWS
+                    info["block_crc"] = _row_crcs(payload, _block_bounds(
+                        info["rows"], CRC_BLOCK_ROWS))
+                else:
+                    info["chunk_crc"] = _row_crcs(payload, _chunk_bounds(
+                        payload["chunks"], R, int(payload["vocab"])))
+        with scope.span("ckpt.write"):
+            raw, crc = _serialize_payload(payload, opts["compress"])
+            fname = _delta_fname(seq, collection.variable_id(name))
+            with fs.open_atomic(fs.join(path, fname)) as f:
+                f.write(raw)
+        results[name] = {"file": fname, "bytes": len(raw),
+                         "crc32": int(crc), **info}
 
-    for name in trackers:
-        if snaps[name].size:
-            tasks.append(lambda n=name: _write_var(n))
     try:
-        ckpt._run_writers(tasks, max_workers=max_workers)
-
-        entry = {"seq": seq, "step": int(step),
+        # DENSE params ride OUTSIDE the chain protocol: small, replicated,
+        # rewritten whole (atomically) on every save — including a SKIPPED
+        # one, so a dense-only training window still persists its params.
+        # Last-writer-wins; a torn-tail recovery keeps the newest dense
+        # file next to the recovered sparse state (document'd divergence —
+        # chain guarantees cover the sparse tables).
+        with scope.span("ckpt.d2h"):
+            dense = jax.device_get(pending.dense)
+            for name, rows in pending.staged.items():
+                if isinstance(rows, _StagedRows):
+                    payloads[name] = dict(rows.header, **{
+                        f: np.asarray(a)[:rows.rows]
+                        for f, a in zip(rows.fields, rows.arrays)})
+                else:
+                    payloads[name] = rows
+        pending.staged = {}             # the staging buffers are free
+        if dense is not None:
+            from flax import serialization
+            with fs.open_atomic(fs.join(path, ckpt.DENSE_FILE)) as f:
+                f.write(serialization.to_bytes(dense))
+        if not payloads:
+            return {"mode": "delta", "seq": int(manifest["last_seq"]),
+                    "skipped": True, "bytes": 0, "rows": 0,
+                    "chain_len": len(manifest["chain"])}
+        ckpt._run_writers([lambda n=name: _write_var(n)
+                           for name in payloads],
+                          max_workers=opts["max_workers"])
+        entry = {"seq": seq, "step": pending.step,
                  "bytes": sum(i["bytes"] for i in results.values()),
                  "rows": sum(i["rows"] for i in results.values()),
-                 "vars": results}
-        if extra:
-            entry["extra"] = dict(extra)
+                 "vars": {name: results[name] for name in payloads}}
+        if opts["extra"]:
+            entry["extra"] = opts["extra"]
         manifest["chain"].append(entry)
         manifest["last_seq"] = seq
+        if any("block_crc" in i for i in results.values()):
+            manifest["format"] = DELTA_FORMAT
         # the commit point: before this rename readers replay the old
         # chain
         sync_point("ckpt.delta.commit")
-        _write_manifest(path, manifest)
+        with scope.span("ckpt.commit"):
+            _write_manifest(path, manifest)
     except BaseException:
         # failed write OR failed commit: restore every claim so the next
         # save re-covers it (completed-but-uncommitted files are
         # orphans, GC'd next save); marks that landed during the attempt
         # are preserved either way
-        for name, chunks in snaps.items():
+        for name, chunks in pending.snaps.items():
             trackers[name].restore(chunks)
         raise
-    dt = time.perf_counter() - t0
+    now = time.perf_counter()
+    dt = now - pending.began
+    scope.HISTOGRAMS.observe("ckpt_commit_lag_s", now - pending.snapshot_at)
     observability.record_ckpt_save("delta", entry["bytes"], dt,
-                                   chain_len=len(manifest["chain"]))
-    info = {"mode": "delta", "seq": seq, "step": int(step),
+                                   chain_len=len(manifest["chain"]),
+                                   rows=entry["rows"])
+    info = {"mode": "delta", "seq": seq, "step": pending.step,
             "bytes": int(entry["bytes"]), "rows": int(entry["rows"]),
             "seconds": dt, "chain_len": len(manifest["chain"]),
             "skipped": False}
-    if return_payload:
-        info["delta"] = Delta(seq=seq, step=int(step), vars=kept_payloads)
+    if opts["return_payload"]:
+        info["delta"] = Delta(seq=seq, step=pending.step, vars=payloads)
     if compact_due(manifest, _base_bytes(path),
-                   chain_len=compact_chain_len,
-                   bytes_ratio=compact_bytes_ratio):
-        compact(path, background=background_compact,
-                max_workers=max_workers)
-        info["compaction"] = "background" if background_compact \
+                   chain_len=opts["compact_chain_len"],
+                   bytes_ratio=opts["compact_bytes_ratio"]):
+        compact(path, background=opts["background_compact"],
+                max_workers=opts["max_workers"])
+        info["compaction"] = "background" if opts["background_compact"] \
             else "done"
     return info
+
+
+def save_delta(path: str, collection: EmbeddingCollection,
+               states: Dict[str, Any], *, step: int, **options
+               ) -> Dict[str, Any]:
+    """One incremental save: what was marked dirty since the last save ->
+    one new chain entry: :func:`begin_delta`, then :func:`finish_delta`
+    on the caller's thread. Forces a FULL save when no armed base exists
+    (first save into a directory, or the previous dump predates dirty
+    tracking). See ``checkpoint.save_checkpoint`` for the public entry.
+
+    ``dense_state``, ``include_optimizer``, ``compress``, ``model_sign``,
+    ``max_workers``: as ``checkpoint.save_checkpoint``.
+    ``compact_chain_len`` / ``compact_bytes_ratio`` /
+    ``background_compact``: the chain budget (:func:`compact_due`) and
+    whether the fold it triggers runs on a background thread.
+
+    ``return_payload=True`` attaches the committed :class:`Delta` to the
+    info dict (``info["delta"]``) straight from memory — the PUBLISH
+    path for serving hot-swap. Prefer it over a post-save
+    :func:`read_delta`: the background compactor may fold the chain
+    (deleting the file) before a disk read lands.
+
+    ``extra``: JSON-serializable caller bookkeeping committed WITH this
+    entry (and carried into the manifest base when the save is forced
+    full) — the elastic-resume channel: ``fit(autosave_every=)`` records
+    ``{"fit": {step, epoch, cursor}}`` here and ``fit(resume_from=)``
+    restores from the entry the load actually verifies, so a torn tail
+    resumes one autosave earlier, never from a half-applied state.
+    """
+    pending = begin_delta(path, collection, states, step=step, **options)
+    if isinstance(pending, dict):
+        return pending
+    return finish_delta(pending)
 
 
 def _base_bytes(path: str) -> int:
@@ -692,6 +926,12 @@ def verify_chain(path: str, manifest: Dict[str, Any],
                     and not _verify_array_chunks(payload,
                                                  info["chunk_crc"]):
                 bad = f"{info['file']}: chunk checksum mismatch"
+                break
+            if info.get("block_crc") is not None \
+                    and not _verify_array_blocks(payload,
+                                                 info["block_crc"],
+                                                 info.get("block_rows")):
+                bad = f"{info['file']}: block checksum mismatch"
                 break
             if keep_payloads:
                 payloads[name] = payload
@@ -873,9 +1113,7 @@ def _payload_ids(payload: Dict[str, np.ndarray]) -> np.ndarray:
         raise DeltaDecodeError(
             f"array delta chunk id out of range: [{lo}, {hi}] outside "
             f"[0, {nchunks})")
-    return np.concatenate([
-        np.arange(int(c) * R, min((int(c) + 1) * R, vocab),
-                  dtype=np.int64) for c in chunks])
+    return _chunk_rows(chunks, R, vocab)
 
 
 def _apply_array_payload(collection, name, state, payload, *,
@@ -1307,7 +1545,7 @@ def _compact_impl(path: str, *,
         else:
             _fold_array_var(vdir, path, has, name,
                             max_workers=max_workers)
-    new_manifest = {"format": DELTA_FORMAT,
+    new_manifest = {"format": _CHUNKED_FORMAT,
                     "base_id": uuid.uuid4().hex,
                     "base_step": int(folded_steps[-1]) if folded_steps
                     else manifest["base_step"],

@@ -8,12 +8,16 @@ that changed since the last save — the reference's ICDE'23 incremental
 checkpoints from dirty tracking (PmemEmbeddingTable.h:285-328), lifted
 out of the PMem tier into the whole-model checkpoint plane.
 
-Granularity is a CHUNK of rows, not a row: at north-star vocab a per-row
-bitmap is GBs and a per-row delta file is an id-per-row index; chunks
-keep the bitmap O(vocab / rows_per_chunk) and make every delta file a
-run of contiguous row ranges (sequential IO on both ends). The offload
-tier uses ``rows_per_chunk=1`` (its writeback scatter is already
-row-exact and its bitmap already row-sized).
+Granularity: an array table is tracked to the ROW wherever one flag a
+row fits (:data:`MAX_ROW_FLAGS`): hashed ids land everywhere, so a step's
+~35k distinct rows dirty every one of a thousand contiguous chunks of an
+81.8M-row table and a chunked "delta" is the whole table; to the row it
+is the 4% that changed (PERF.md, PR 32). :class:`RowTracker` also keeps
+the fresh marks in arrival order, so a snapshot costs by the rows
+marked, not by a scan of the table's flags. Callers that pass
+``target_chunks`` keep chunks of contiguous rows (a delta file is then a
+run of row ranges), hash tables keep ``key % n`` chunks, and the offload
+tier uses ``rows_per_chunk=1`` over its own book.
 
 Mapping:
 
@@ -116,6 +120,10 @@ class DirtyTracker:
                 fresh = np.unique(fresh)
                 self._bits[fresh] = True
                 self._count += int(fresh.size)
+                self._note_fresh(fresh)
+
+    def _note_fresh(self, fresh: np.ndarray) -> None:
+        """Under the lock: chunks a mark has just set (distinct)."""
 
     def mark_all(self) -> None:
         with self._lock:
@@ -201,21 +209,99 @@ class DirtyTracker:
                 f"dirty={self.dirty_count})")
 
 
+# One flag a row up to here (2 GiB of flags); a larger table is tracked in
+# chunks of the fewest contiguous rows that keep the flags under it.
+MAX_ROW_FLAGS = 1 << 31
+_LOG_START = 1 << 16
+
+
+class RowTracker(DirtyTracker):
+    """Row-exact tracker of an array table that also logs its fresh marks.
+
+    A mark already finds the rows that were clean (it counts them), so it
+    appends them to a log; :meth:`snapshot_clear` hands that log out
+    instead of scanning ``num_chunks`` flags: at 81.8M rows the scan is
+    0.1-0.3 s on the step thread, the log is there already. The snapshot
+    is in ARRIVAL order, each row once. ``mark_all`` / ``clear_chunks``
+    (nothing on the save path calls them) drop the log, and the next
+    snapshot scans and starts a new one.
+    """
+
+    def __init__(self, vocab: int, *, name: str = "", lock=None):
+        super().__init__(vocab, rows_per_chunk=1, name=name, lock=lock)
+        self._log: Optional[np.ndarray] = np.empty(_LOG_START, np.int64)
+        self._logged = 0
+
+    def _note_fresh(self, fresh: np.ndarray) -> None:
+        if self._log is None:
+            return
+        end = self._logged + fresh.size
+        if end > self._log.size:
+            grown = np.empty(max(end, 2 * self._log.size), np.int64)
+            grown[:self._logged] = self._log[:self._logged]
+            self._log = grown
+        self._log[self._logged:end] = fresh
+        self._logged = end
+
+    def mark_all(self) -> None:
+        with self._lock:
+            self._bits[:] = True
+            self._count = self.num_chunks
+            self._log = None
+
+    def clear_chunks(self, chunks) -> None:
+        super().clear_chunks(chunks)
+        with self._lock:
+            self._log = None
+
+    def clear_all(self) -> None:
+        with self._lock:
+            self._bits[:] = False
+            self._count = 0
+            self._log, self._logged = np.empty(_LOG_START, np.int64), 0
+
+    def snapshot_clear(self) -> np.ndarray:
+        with self._lock:
+            if self._log is None:
+                rows = np.nonzero(self._bits)[0]
+                size = _LOG_START
+            else:
+                # the log's buffer leaves with the snapshot; the next one
+                # is as large, untouched until marks fill it
+                rows, size = self._log[:self._logged], self._log.size
+            if rows.size * 8 < self.num_chunks:
+                self._bits[rows] = False
+            else:
+                self._bits[:] = False
+            self._count = 0
+            self._log, self._logged = np.empty(size, np.int64), 0
+        sync_point("dirty.snapshot")
+        return rows
+
+
 def make_array_tracker(name: str, vocab: int,
-                       target_chunks: int = 1024,
+                       target_chunks: Optional[int] = None,
                        lock=None) -> DirtyTracker:
-    """Tracker for a bounded (array) variable: ~``target_chunks`` chunks
-    of contiguous logical rows (at least one row per chunk)."""
+    """Tracker for a bounded (array) variable. The granularity comes from
+    the table: to the row (:class:`RowTracker`) up to
+    :data:`MAX_ROW_FLAGS` rows. ``target_chunks`` asks for ~that many
+    chunks of contiguous logical rows instead (at least one row each)."""
     vocab = max(1, int(vocab))
-    rows = max(1, -(-vocab // max(1, int(target_chunks))))
+    if target_chunks is None:
+        rows = -(-vocab // MAX_ROW_FLAGS)
+        if rows == 1:
+            return RowTracker(vocab, name=name, lock=lock)
+    else:
+        rows = max(1, -(-vocab // max(1, int(target_chunks))))
     return DirtyTracker(-(-vocab // rows), rows_per_chunk=rows,
                         name=name, lock=lock)
 
 
 def make_hash_tracker(name: str, capacity: int,
-                      target_chunks: int = 1024,
+                      target_chunks: Optional[int] = None,
                       lock=None) -> DirtyTracker:
     """Tracker for a hash variable: key-space partitioned into
-    ``min(target_chunks, capacity)`` chunks by ``key % n``."""
-    n = max(1, min(int(target_chunks), max(1, int(capacity))))
+    ``min(target_chunks, capacity)`` chunks by ``key % n`` (1,024 where
+    the caller names no count)."""
+    n = max(1, min(int(target_chunks or 1024), max(1, int(capacity))))
     return DirtyTracker(n, name=name, lock=lock)

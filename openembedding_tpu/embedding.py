@@ -201,9 +201,12 @@ class EmbeddingCollection:
                 self._serving_stores[spec.name] = store
 
     # --- dirty tracking (delta checkpoints, checkpoint.py mode="delta") ----
-    def enable_dirty_tracking(self, *, target_chunks: int = 1024,
+    def enable_dirty_tracking(self, *, target_chunks: Optional[int] = None,
                               names=None) -> None:
-        """Arm chunk-level dirty bitmaps for every variable (idempotent).
+        """Arm dirty tracking for every variable (idempotent): array
+        tables to the row, hash tables in 1,024 ``key % n`` chunks;
+        ``target_chunks`` asks for ~that many chunks of either instead
+        (``dirty.py``).
 
         ``names``: restrict tracking to a subset of variables. ONLY for
         variables whose rows persist through their own path — the
@@ -268,15 +271,19 @@ class EmbeddingCollection:
         if not self._dirty_trackers:
             return
         from . import hash_table as hash_lib
-        for name, idx in sparse_inputs.items():
+        rows_of = {}    # tables fed one array (a fused table and its
+        for name, idx in sparse_inputs.items():   # linear twin): one sort
             tracker = self._dirty_trackers.get(name)
             if tracker is None or idx is None:
                 continue
             if isinstance(idx, jax.core.Tracer):
                 continue
+            spec = self.specs[name]
+            if not spec.use_hash and (id(idx), spec.input_dim) in rows_of:
+                tracker.mark_rows(rows_of[id(idx), spec.input_dim])
+                continue
             arr = np.asarray(jax.device_get(idx)) \
                 if isinstance(idx, jax.Array) else np.asarray(idx)
-            spec = self.specs[name]
             if spec.use_hash:
                 if spec.key_dtype == "wide" and arr.ndim >= 2 \
                         and arr.shape[-1] == 2:
@@ -286,7 +293,9 @@ class EmbeddingCollection:
                 tracker.mark_keys(keys)
             else:
                 ids = arr.astype(np.int64).ravel()
-                tracker.mark_rows(ids[(ids >= 0) & (ids < spec.input_dim)])
+                ids = np.unique(ids[(ids >= 0) & (ids < spec.input_dim)])
+                rows_of[id(idx), spec.input_dim] = ids
+                tracker.mark_rows(ids)
 
     # --- introspection -----------------------------------------------------
     def variable_id(self, name: str) -> int:

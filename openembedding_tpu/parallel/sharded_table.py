@@ -284,6 +284,45 @@ def deliver_rows_sharded(arr: jnp.ndarray, phys: jnp.ndarray,
     return fn(arr, phys, rows)
 
 
+@functools.lru_cache(maxsize=None)
+def _snapshot_program(mesh: Mesh, spec: ShardingSpec, arrays: int):
+    """Cached gather program of :func:`snapshot_rows_sharded`; one
+    compile a staging length."""
+    rps = spec.rows_per_shard
+    axes = spec.shard_axes
+    sizes = tuple(mesh.shape[a] for a in axes)
+
+    def ckpt_gather(arrays, phys, count):
+        me = a2a.linear_shard_id(axes, sizes)
+        loc = phys - me * rps
+        ok = (phys >= 0) & (loc >= 0) & (loc < rps)
+        # a row another shard owns reads as zeros here: index rps is
+        # past this shard's end
+        staged = table_lib.snapshot_rows(
+            arrays, jnp.where(ok, loc, rps).astype(jnp.int32), count)
+        if spec.num_shards > 1:
+            staged = [lax.psum(s, axes) for s in staged]
+        return staged
+
+    row = spec.row_spec()
+    fn = shard_map(ckpt_gather, mesh=mesh,
+                   in_specs=([row] * arrays, P(), P()),
+                   out_specs=[P()] * arrays, check_vma=False)
+    return jax.jit(fn)
+
+
+def snapshot_rows_sharded(arrays, phys: jnp.ndarray, count, *, mesh: Mesh,
+                          spec: ShardingSpec):
+    """Gather the rows at PHYSICAL positions ``phys[:count]`` of every
+    sharded array of ``arrays`` into replicated staging buffers of
+    ``phys``'s length: the read twin of :func:`deliver_rows_sharded`, and
+    a delta checkpoint's snapshot (``table.snapshot_rows``). Nothing is
+    donated and nothing waits: the program runs behind whatever was
+    dispatched before it."""
+    return _snapshot_program(mesh, spec, len(arrays))(
+        list(arrays), phys, jnp.asarray(count, jnp.int32))
+
+
 def _masked_local(spec: ShardingSpec, flat: jnp.ndarray):
     """``(owned [n], local row [n])`` of ``flat`` on this model-axis shard
     (the masked-local body of the psum plane). Invalid indices (negative

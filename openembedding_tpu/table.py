@@ -320,6 +320,32 @@ def gather_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
     return gather(weights, slots, at)
 
 
+def snapshot_rows(arrays, at: jnp.ndarray, count: jnp.ndarray):
+    """Copies of the rows ``at[:count]`` of every array of ``arrays``, in
+    staging buffers of ``at``'s length (the rest zero): what a delta
+    checkpoint takes of a table in the step's stream. Read as the sparse
+    apply reads rows, :data:`APPLY_CHUNK` a trip, so the cost follows
+    ``count`` and nothing as long as a table array is made; an index
+    past an array's end reads a zero row. ``at``'s length is a chunk or
+    less, or a multiple of it."""
+    capacity = at.shape[0]
+    chunk = min(APPLY_CHUNK, capacity)
+
+    @scope.stage("ckpt_gather")
+    def gather(arrays, at, count):
+        def trip(i, staged):
+            part = lax.dynamic_slice_in_dim(at, i * chunk, chunk)
+            return [lax.dynamic_update_slice_in_dim(
+                s, jnp.take(x, part, axis=0, mode="fill", fill_value=0),
+                i * chunk, 0) for s, x in zip(staged, arrays)]
+
+        return lax.fori_loop(
+            0, (count + (chunk - 1)) // chunk, trip,
+            [jnp.zeros((capacity,) + x.shape[1:], x.dtype) for x in arrays])
+
+    return gather(list(arrays), at, count)
+
+
 def scatter_rows(arrays, at: jnp.ndarray, new):
     """Write the updated rows ``new`` back at ``at``, array by array;
     out-of-range entries (padding) are dropped. The write half of a sparse

@@ -68,6 +68,33 @@ def binary_logloss(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     return jnp.mean(optax.sigmoid_binary_cross_entropy(logits, labels))
 
 
+class _BackgroundSave:
+    """The writer thread of one autosave: ``checkpoint_delta.finish_delta``
+    of a snapshot ``fit`` has taken. Owned by the step thread, which joins
+    it before the next snapshot, on ``fit``'s return and on its unwind."""
+
+    def __init__(self, pending):
+        self.err: Optional[BaseException] = None
+        self._thread = threading.Thread(
+            target=self._run, args=(pending,), daemon=False,
+            name="oe-ckpt-autosave")
+        self._thread.start()
+
+    def _run(self, pending) -> None:
+        from . import checkpoint_delta as cd
+        try:
+            cd.finish_delta(pending)
+        except BaseException as e:  # noqa: BLE001 — re-raised at join
+            self.err = e
+
+    def join(self) -> None:
+        self._thread.join()
+        if self.err is not None:
+            raise RuntimeError(
+                "autosave failed; its rows are marked dirty again and "
+                "ride the next save") from self.err
+
+
 class Trainer:
     """Builds jitted train/eval steps for (flax module + EmbeddingCollection).
 
@@ -178,6 +205,7 @@ class Trainer:
         # state.step is a device array — reading it back per step would
         # add a sync round trip to every step)
         self._host_step = 0
+        self._autosave: Optional[_BackgroundSave] = None
 
     # --- initialization ----------------------------------------------------
     def _split_sparse(self, sparse: Dict[str, Any]):
@@ -705,15 +733,25 @@ class Trainer:
         ``trainer_restart`` role, made real):
 
         * ``autosave_every=N`` with ``autosave_dir``: every N steps the
-          loop BLOCKS and writes a delta autosave of the full
-          TrainState (embedding states + dense params/opt_state) into
-          ``autosave_dir``, recording ``{"fit": {step, epoch, cursor}}``
-          in the manifest extra — ``cursor`` is the count of batches
-          TRAINED so far (epoch-absolute; batches prefetched into the
-          lookahead window but not yet stepped are deliberately NOT
-          counted). Blocking matters: the model's ``trainer_step`` is
-          gated on the saver being idle, so a kill at any sync point
-          can never interleave a step with a half-written autosave.
+          loop takes a delta autosave of the full TrainState (embedding
+          states + dense params/opt_state) into ``autosave_dir``,
+          recording ``{"fit": {step, epoch, cursor}}`` in the manifest
+          extra — ``cursor`` is the count of batches TRAINED so far
+          (epoch-absolute; batches prefetched into the lookahead window
+          but not yet stepped are deliberately NOT counted). The loop
+          pays for the SNAPSHOT only (``checkpoint_delta.begin_delta``:
+          the dirty sets claimed, one gather program a variable
+          dispatched behind the step the save names, no wait for the
+          device); the copy to the host, the files and the manifest
+          rename run on one writer thread while later steps train. At
+          most one save is in flight: the next snapshot waits for the
+          commit before it, and ``fit`` does not return (or unwind)
+          before the last is committed; a failed save re-marks its rows
+          and raises from that wait. What a save holds is fixed at its
+          snapshot (the model's ``trainer_step`` gated on an idle saver
+          is that instant), so a kill at any sync point leaves the
+          previous chain or the new one, never a mix of steps. The
+          first save into an empty directory is a full save, blocking.
         * ``resume_from=DIR``: before the loop, restore TrainState from
           the newest committed version of the delta chain under DIR and
           advance ``batches`` to the recorded cursor —
@@ -747,6 +785,7 @@ class Trainer:
         last = None
         it = iter(batches)
         base_cursor = 0
+        self._join_autosave()
         if resume_from is not None:
             state, base_cursor = self._restore_fit(state, resume_from)
             if base_cursor:
@@ -785,6 +824,9 @@ class Trainer:
         refill()   # window prime: warmup, deliberately unrecorded
         i = 0
         guard = None
+        # the step a save names, kept on the host: reading state.step at
+        # a save would wait for every step in flight
+        step0 = int(jax.device_get(state.step)) if autosave_every else 0
         try:
             while window:
                 # prepare the whole window through the chain — head
@@ -830,7 +872,8 @@ class Trainer:
                                 log_fn(f"persisted {name}: {info}")
                 if autosave_every and (i + 1) % autosave_every == 0:
                     self._autosave_fit(state, autosave_dir,
-                                       base_cursor + i + 1)
+                                       base_cursor + i + 1,
+                                       step0 + i + 1)
                 if log_every and (i + 1) % log_every == 0:
                     log_fn(
                         f"step {i + 1}: loss={float(metrics['loss']):.5f}")
@@ -859,6 +902,11 @@ class Trainer:
         self._cancel_preps()
         for table in self.offload.values():
             table.finish()
+        try:
+            self._join_autosave()
+        except BaseException:
+            self._drain_suppressed()
+            raise
         return state, last
 
     def _restore_fit(self, state: TrainState, path: str):
@@ -894,22 +942,34 @@ class Trainer:
                              emb=states, pipe=None), cursor
 
     def _autosave_fit(self, state: TrainState, path: str,
-                      cursor: int) -> None:
-        """One BLOCKING delta autosave of the full TrainState with the
-        elastic-resume extra ``{"fit": {step, epoch, cursor}}`` in the
-        manifest. ``cursor`` is epoch-absolute (it spans epochs of the
-        deterministic batch sequence), so ``epoch`` is informational.
+                      cursor: int, step: int) -> None:
+        """One delta autosave of the full TrainState as it stands after
+        step ``step``, with the elastic-resume extra ``{"fit": {step,
+        epoch, cursor}}`` in the manifest. ``cursor`` is epoch-absolute
+        (it spans epochs of the deterministic batch sequence), so
+        ``epoch`` is informational. Returns once the snapshot is
+        dispatched; the rest runs on the writer thread. The span is what
+        a save costs the step loop: the wait for the save before (at
+        most one is in flight), the dirty sets' snapshot, the dispatch.
         The first save into an empty dir is a forced full (no manifest
-        yet) — the extra rides the manifest either way."""
-        from . import checkpoint as ckpt_mod
-        step = int(jax.device_get(state.step))
-        extra = {"fit": {"step": step, "epoch": 0,
+        yet), blocking — the extra rides the manifest either way."""
+        from . import checkpoint_delta as cd
+        extra = {"fit": {"step": int(step), "epoch": 0,
                          "cursor": int(cursor)}}
-        with scope.span("trainer.autosave", step=str(step)):
-            ckpt_mod.save_checkpoint(
+        with scope.span("trainer.autosave", detail={"step": int(step)}):
+            self._join_autosave()
+            pending = cd.begin_delta(
                 path, self.collection, state.emb,
                 dense_state=(state.params, state.opt_state),
-                mode="delta", step=step, extra=extra)
+                step=int(step), extra=extra)
+            if isinstance(pending, cd.PendingDelta):
+                self._autosave = _BackgroundSave(pending)
+
+    def _join_autosave(self) -> None:
+        """Wait for the autosave in flight, if any, and raise its error."""
+        saver, self._autosave = self._autosave, None
+        if saver is not None:
+            saver.join()
 
     def _drain_suppressed(self) -> None:
         """Unwind-path drain: join lookahead/persister threads and flush
@@ -917,6 +977,10 @@ class Trainer:
         is already raising the story)."""
         try:
             self._cancel_preps()
+        except Exception:  # noqa: BLE001 — unwinding
+            pass
+        try:
+            self._join_autosave()
         except Exception:  # noqa: BLE001 — unwinding
             pass
         for table in self.offload.values():

@@ -394,16 +394,20 @@ def add_labeled(name: str, value: float = 1.0, **labels) -> None:
 
 def record_ckpt_save(mode: str, nbytes: int, seconds: float, *,
                      chain_len: Optional[int] = None,
+                     rows: Optional[int] = None,
                      accumulator: Optional[Accumulator] = None) -> None:
     """One checkpoint save's ledger entry (``checkpoint.save_checkpoint``):
     ``ckpt_full_bytes``/``ckpt_delta_bytes`` counters accumulate bytes
     moved per mode — the delta plane's headline claim (a ≤5%-dirty delta
     moves ≥10x fewer bytes than a full save) is asserted against exactly
-    these counters — plus ``ckpt_write_gbps``/``ckpt_chain_len`` gauges
+    these counters (``ckpt_delta_rows`` beside them: the rows a delta
+    carried) — plus ``ckpt_write_gbps``/``ckpt_chain_len`` gauges
     and a per-mode write-rate histogram for /metrics."""
     acc = accumulator or GLOBAL
     acc.add(f"ckpt_{mode}_bytes", float(nbytes))
     acc.add(f"ckpt_{mode}_saves", 1.0)
+    if rows is not None:
+        acc.add(f"ckpt_{mode}_rows", float(rows))
     gbps = nbytes / max(seconds, 1e-9) / 1e9
     set_gauge("ckpt_write_gbps", gbps)
     if chain_len is not None:

@@ -358,13 +358,17 @@ def test_thread_spawning_inventory_is_pinned():
             ("_lock",),
         ("openembedding_tpu/serving/rest.py", "ControllerServer"): (),
         ("openembedding_tpu/training.py", "Trainer"): (),
+        # PR 32: one autosave's writer thread; its ``err`` is written by
+        # the thread and read after ``join``, so it needs no lock
+        ("openembedding_tpu/training.py", "_BackgroundSave"): (),
         ("openembedding_tpu/utils/observability.py", "Reporter"):
             ("_lock",),
     }
     # every thread in the package is named (chaos pins faults to
     # thread-name patterns; an anonymous thread is untargetable)
     assert names == {
-        "oe-ckpt-writer-*", "oe-ckpt-compact", "oe-writeback-*",
+        "oe-ckpt-writer-*", "oe-ckpt-compact", "oe-ckpt-autosave",
+        "oe-writeback-*",
         "oe-persist-*", "oe-prep", "oe-ingest-*", "oe-batcher-*",
         "oe-plan-*", "oe-model-load-*", "oe-rest-*", "oe-reporter",
     }

@@ -58,14 +58,8 @@ ROWS_PER_FEATURE = 3 << 20
 HASH_CAPACITY = 1 << 26
 
 
-@functools.lru_cache(maxsize=None)      # several tests read each program
-def _compile_deepfm_step(mesh, *, use_hash):
-    """The step program of chip_smoke.py's training phases, from shapes
-    alone."""
-    rows = ROWS_PER_FEATURE * mesh.size
-    coll, trainer, mapper = chip_smoke.build_deepfm(
-        mesh, use_hash=use_hash, rows_per_feature=rows,
-        hash_capacity=HASH_CAPACITY)
+def _abstract_step(mesh, coll, trainer, mapper, rows):
+    """(state, batch) of the step as shapes and shardings alone."""
     batch = mapper.fuse_batch(next(iter(criteo.synthetic_criteo(
         chip_smoke.BATCH, num_buckets=rows, num_batches=1))))
     state = jax.eval_shape(trainer.init, jax.random.PRNGKey(0), batch)
@@ -80,7 +74,19 @@ def _compile_deepfm_step(mesh, *, use_hash):
         lambda x: jax.ShapeDtypeStruct(
             x.shape, jax.dtypes.canonicalize_dtype(x.dtype),
             sharding=by_batch), batch)
-    return trainer.lower_train_step(state, batch).compile()
+    return state, batch
+
+
+@functools.lru_cache(maxsize=None)      # several tests read each program
+def _compile_deepfm_step(mesh, *, use_hash):
+    """The step program of chip_smoke.py's training phases, from shapes
+    alone."""
+    rows = ROWS_PER_FEATURE * mesh.size
+    coll, trainer, mapper = chip_smoke.build_deepfm(
+        mesh, use_hash=use_hash, rows_per_feature=rows,
+        hash_capacity=HASH_CAPACITY)
+    return trainer.lower_train_step(
+        *_abstract_step(mesh, coll, trainer, mapper, rows)).compile()
 
 
 @pytest.mark.parametrize("use_hash", [False, True], ids=["array", "hash"])
@@ -305,3 +311,65 @@ def test_v5e_offload_insert_updates_the_cache_in_place(v5e, keys):
     assert memory.alias_size_in_bytes >= capacity * (4 + 2 * dim * 4)
     assert memory.temp_size_in_bytes < (5 << 30 if keys > 1 << 13
                                         else 1 << 30), memory
+
+
+SNAPSHOT_ROWS = 6 << 19     # the staging length of the autosave cell's saves
+
+
+def test_v5e_snapshot_gathers_rows_beside_the_table(v5e):
+    """A delta save's snapshot at the autosave cell's size: the dim-9
+    table and its accumulator (81.8M rows each, 9.8 GiB of arguments),
+    3,145,728 staged rows. The program reads rows in trips of the apply's
+    chunk under ``ckpt_gather``, donates nothing, and holds neither a copy
+    nor a slice of a table array nor a temporary wider than its staging
+    buffers: beside the tables it costs what it returns."""
+    from openembedding_tpu import table as table_lib
+    from openembedding_tpu.parallel import sharded_table as st
+    mesh = create_mesh(1, 1, v5e[:1])
+    coll, _, mapper = chip_smoke.build_deepfm(
+        mesh, use_hash=False, rows_per_feature=ROWS_PER_FEATURE,
+        hash_capacity=HASH_CAPACITY)
+    spec = coll.sharding_spec(mapper.name)
+    rows, dim = spec.padded_vocab, coll.specs[mapper.name].output_dim
+    row = NamedSharding(mesh, spec.row_spec())
+    whole = NamedSharding(mesh, P())
+    table = [jax.ShapeDtypeStruct((rows, dim), jnp.float32, sharding=row)] * 2
+    compiled = st._snapshot_program(mesh, spec, 2).lower(
+        table, jax.ShapeDtypeStruct((SNAPSHOT_ROWS,), jnp.int32,
+                                    sharding=whole),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)).compile()
+    hlo = compiled.as_text()
+    header = next(line for line in hlo.splitlines()
+                  if line.startswith("HloModule"))
+    assert "ckpt_gather" in header and "alias" not in header, header
+    paths = trace_reduce.scope_names(hlo)
+    found = [m.groups() for m in map(_OPCODE.match, hlo.splitlines()) if m]
+    loops = [inst for inst, op in found if op == "while"]
+    assert len(loops) == 1 and "ckpt_gather" in paths[loops[0]], loops
+    chunk = table_lib.APPLY_CHUNK
+    gathers = [line for line in hlo.splitlines() if " gather(" in line]
+    assert gathers and all(f"f32[{chunk},{dim}]" in g for g in gathers)
+    wide = [line.strip()[:120] for line in hlo.splitlines()
+            if re.search(r" (copy|copy-start|slice|dynamic-slice)\(", line)
+            and int((re.search(r"= \(?\w+\[(\d+)", line) or [0, 0])[1])
+            > SNAPSHOT_ROWS]
+    assert not wide, wide
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 9 << 30
+    assert memory.temp_size_in_bytes <= memory.output_size_in_bytes
+
+
+def test_step_lowers_the_same_with_dirty_tracking_armed(v5e):
+    """The marks stay on the host: arming the tracking changes nothing of
+    the step's program (the array cell's step is the autosave cell's)."""
+    def lowered(armed):
+        mesh = create_mesh(1, 1, v5e[:1])
+        coll, trainer, mapper = chip_smoke.build_deepfm(
+            mesh, use_hash=False, rows_per_feature=1 << 12,
+            hash_capacity=1 << 12)
+        if armed:
+            coll.enable_dirty_tracking()
+        return trainer.lower_train_step(*_abstract_step(
+            mesh, coll, trainer, mapper, 1 << 12)).as_text()
+
+    assert lowered(True) == lowered(False)
