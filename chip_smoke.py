@@ -205,7 +205,7 @@ def check_layout(mesh, coll, emb):
 
 
 def build_deepfm(mesh, *, use_hash, rows_per_feature=ROWS_PER_FEATURE,
-                 hash_capacity=HASH_CAPACITY):
+                 hash_capacity=HASH_CAPACITY, key_dtype="wide"):
     """(collection, trainer, mapper) for DeepFM at full Criteo width, as
     examples/criteo_deepctr.py builds it (tests/test_tpu_lowering.py
     compiles the same for a described v5e topology)."""
@@ -220,7 +220,7 @@ def build_deepfm(mesh, *, use_hash, rows_per_feature=ROWS_PER_FEATURE,
     specs, mapper = make_fused_specs(
         features, -1 if use_hash else rows_per_feature, DIM,
         optimizer={"category": "adagrad", "learning_rate": 0.01},
-        hash_capacity=hash_capacity)          # key_dtype defaults to "wide"
+        hash_capacity=hash_capacity, key_dtype=key_dtype)
     coll = EmbeddingCollection(specs, mesh)
     trainer = Trainer(deepctr.build_model("deepfm", features), coll,
                       optax.adam(1e-3))

@@ -443,10 +443,34 @@ class EmbeddingCollection:
         return out
 
     # --- data plane --------------------------------------------------------
+    def plan(self, inputs: Dict[str, jnp.ndarray], *,
+             batch_sharded: bool = True) -> Dict[str, Any]:
+        """One step's dedup of every input column whose table's pull and
+        push can share it (``sharded.shares_plan``: the masked-local body,
+        which is every plane on one chip but the cached one, on a mesh
+        with no data axis to gather over): name -> ``dedup.Plan``, for
+        :meth:`pull` and :meth:`apply_gradients` of the SAME ``inputs``.
+        The pull then resolves each distinct key once and the push
+        deduplicates nothing again; rows and updates are what they are
+        without. A column left out (the routed planes, which dedup their
+        own sender slice; the grouped ones) runs as it does without."""
+        plans = {}
+        for name, idx in inputs.items():
+            sspec = self._shardings[name]
+            if sspec.is_grouped or not sharded.shares_plan(
+                    sspec, self.mesh, batch_sharded):
+                continue
+            plans[name] = sharded.plan_sharded(
+                self._widen(self.specs[name], idx), mesh=self.mesh,
+                store=self._stores[name], batch_sharded=batch_sharded)
+        return plans
+
     def pull(self, states: Dict[str, Any], inputs: Dict[str, jnp.ndarray],
              *, batch_sharded: bool = True,
              read_only: bool = False,
-             serving_rows: bool = False) -> Dict[str, jnp.ndarray]:
+             serving_rows: bool = False,
+             plan: Optional[Dict[str, Any]] = None
+             ) -> Dict[str, jnp.ndarray]:
         """Lookup rows for every (present) input column.
 
         ``inputs``: name -> integer indices of any shape; returns name ->
@@ -460,8 +484,10 @@ class EmbeddingCollection:
         one row per index (pair), no pooling, and any trailing dim of 2 on a
         wide spec IS a pair axis — the shape a routing client fans out is
         always a flat pair list, never a ``[B, L=2]`` sequence (a pooled
-        spec's training-side heuristic would misread it).
+        spec's training-side heuristic would misread it). ``plan`` is
+        :meth:`plan`'s of these ``inputs``.
         """
+        plan = plan or {}
         widened = {
             name: self._widen(self.specs[name], idx,
                               pair_ndim=2 if serving_rows else None)
@@ -486,7 +512,7 @@ class EmbeddingCollection:
                 stores = self._serving_stores if read_only else self._stores
                 r = sharded.pull_sharded(
                     states[name], idx, mesh=self.mesh, store=stores[name],
-                    batch_sharded=batch_sharded)
+                    batch_sharded=batch_sharded, plan=plan.get(name))
             if spec.pooling and not serving_rows:
                 # wide sequence features carry [B, L, 2] pair ids; the
                 # combiner counts validity on the hi word (ragged.py)
@@ -550,12 +576,16 @@ class EmbeddingCollection:
     def apply_gradients(self, states: Dict[str, Any],
                         inputs: Dict[str, jnp.ndarray],
                         row_grads: Dict[str, jnp.ndarray],
-                        *, batch_sharded: bool = True) -> Dict[str, Any]:
+                        *, batch_sharded: bool = True,
+                        plan: Optional[Dict[str, Any]] = None
+                        ) -> Dict[str, Any]:
         """Push+update for every column present in ``row_grads``.
 
         ``row_grads[name]`` has the shape of the pulled rows. Untouched
-        variables keep their state object unchanged.
+        variables keep their state object unchanged. ``plan`` is
+        :meth:`plan`'s of these ``inputs``, the one their pull ran on.
         """
+        plan = plan or {}
         # delta-checkpoint dirty marks for EAGER pushes (tracer inputs —
         # the jitted Trainer step — skip; the Trainer marks host-side)
         self.mark_dirty({n: inputs.get(n) for n in row_grads})
@@ -582,7 +612,7 @@ class EmbeddingCollection:
             new_states[name] = sharded.apply_gradients_sharded(
                 states[name], self._optimizers[name], idx_in, g,
                 mesh=self.mesh, store=self._stores[name],
-                batch_sharded=batch_sharded)
+                batch_sharded=batch_sharded, plan=plan.get(name))
         if grouped_idx:
             from .parallel import grouped
             new_states.update(grouped.apply_gradients_grouped(

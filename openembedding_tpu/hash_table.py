@@ -672,16 +672,51 @@ def pull(state: HashTableState, indices: jnp.ndarray,
         hit = slot >= 0
         rows = jnp.take(weights, jnp.where(hit, slot, 0), axis=0,
                         mode="clip")
-        if initializer is None:
-            fresh = jnp.zeros_like(rows)
-        else:
-            fresh = init_rows(initializer, init_rng, flat, state.dim,
-                              weights.dtype)
-        rows = jnp.where(hit[:, None], rows, fresh)
-        return jnp.where(invalid[:, None], jnp.zeros_like(rows), rows)
+        return _or_fresh(initializer, init_rng, flat, rows, hit, invalid)
 
     return read(state.keys, state.weights, state.init_rng, flat,
                 invalid).reshape(out_shape)
+
+
+def _or_fresh(initializer, init_rng, keys, rows, hit, invalid):
+    """``rows`` where ``hit``, a key's deterministic init row (zeros under
+    the read-only contract) where not, zeros for an ``invalid`` key."""
+    if initializer is None:
+        fresh = jnp.zeros_like(rows)
+    else:
+        fresh = init_rows(initializer, init_rng, keys, rows.shape[1],
+                          rows.dtype)
+    rows = jnp.where(hit[:, None], rows, fresh)
+    return jnp.where(invalid[:, None], jnp.zeros_like(rows), rows)
+
+
+def pull_distinct(state: HashTableState, keys: jnp.ndarray,
+                  valid: jnp.ndarray, initializer: Any,
+                  max_probes: int = DEFAULT_MAX_PROBES, *,
+                  positions: int, record_stats: bool = False) -> jnp.ndarray:
+    """:func:`pull` for the distinct keys of a step's plan
+    (``dedup.Plan.uniq`` with its ``valid``, a prefix of the buffer): one
+    row a slot, each key resolved once. The find and the row read walk
+    the valid prefix in chunks (the push's find, :func:`_find_levels`, and
+    ``table.read_distinct``), so a pull costs what the distinct keys of
+    the batch cost; the caller expands by the plan's ``inverse``, over
+    ``positions`` keys (``table.record_pull``'s count)."""
+    if initializer is not None:
+        initializer = make_initializer(initializer)
+    keys = check_key_dtype(state.keys, keys)
+    find = scope.stage("probe")(
+        lambda tkeys, keys, valid: _find_levels(tkeys, keys, valid,
+                                                max_probes))
+
+    @scope.stage("resolve")
+    def read(tkeys, weights, init_rng, keys, valid):
+        slot, walked = find(tkeys, keys, valid)
+        hit = slot >= 0
+        rows, _ = table_lib.read_distinct(weights, slot, hit)
+        table_lib.record_pull(valid, walked, positions, record_stats)
+        return _or_fresh(initializer, init_rng, keys, rows, hit, ~valid)
+
+    return read(state.keys, state.weights, state.init_rng, keys, valid)
 
 
 def merge_gradients(state: HashTableState,
@@ -692,28 +727,34 @@ def merge_gradients(state: HashTableState,
                     dedup_capacity: Optional[int] = None,
                     max_probes: int = DEFAULT_MAX_PROBES,
                     in_counts: Optional[jnp.ndarray] = None,
-                    record_stats: bool = False):
+                    record_stats: bool = False,
+                    plan: Optional[dedup.Plan] = None):
     """The first half of :func:`apply_gradients`, which touches the key
     array alone: deduplicate the keys and combine their gradients into a
     buffer of ``dedup_capacity`` (default ``n``) slots, find or insert each
     key. Returns ``(keys, failed, merged)``: the new key array, the number
     of keys no window held, and ``table.apply_rows``'s ``(rows, live,
-    summed, counts, fresh, inserted)``."""
+    summed, counts, fresh, inserted)``. ``plan`` is the dedup of
+    ``indices`` where the step has made it already, in front of its pull:
+    its slots are the buffer and nothing is deduplicated again."""
     initializer = make_initializer(initializer)
     dim = state.dim
     empty = empty_key(state.keys.dtype)
-    if state.wide:
+    if plan is not None:
+        uniq = check_key_dtype(state.keys, plan.uniq)
+        inverse, valid = plan.inverse, plan.valid
+        capacity = uniq.shape[0]
+    elif state.wide:
         flat_idx = _wide_query(state.keys, indices)
         capacity = dedup_capacity or flat_idx.shape[0]
         uniq, inverse, valid = dedup.unique_pairs(
             flat_idx, capacity, fill_value=empty)
-        valid = valid & (uniq[:, 1] != empty)
     else:
         flat_idx = check_key_dtype(state.keys, indices.ravel())
         capacity = dedup_capacity or flat_idx.shape[0]
         uniq, inverse, valid = dedup.unique_indices(
             flat_idx, capacity, fill_value=empty)
-        valid = valid & (uniq != empty)
+    valid = valid & ((uniq[:, 1] if state.wide else uniq) != empty)
     summed, counts = dedup.combine_gradients(grads.reshape(-1, dim), inverse,
                                              capacity, in_counts)
     keys_arr, slot, inserted, failed = find_or_insert(
@@ -733,7 +774,8 @@ def apply_gradients(state: HashTableState,
                     dedup_capacity: Optional[int] = None,
                     max_probes: int = DEFAULT_MAX_PROBES,
                     in_counts: Optional[jnp.ndarray] = None,
-                    record_stats: bool = False) -> HashTableState:
+                    record_stats: bool = False,
+                    plan: Optional[dedup.Plan] = None) -> HashTableState:
     """Combine duplicate grads, insert missing keys, update touched rows.
 
     The hash-table analogue of ``table.apply_gradients``: dedup -> claim/probe
@@ -745,12 +787,13 @@ def apply_gradients(state: HashTableState,
     The dedup, the combine and the find (:func:`merge_gradients`) run over
     ``dedup_capacity`` (default ``n``) slots; the gather, the optimizer and
     the scatter are ``table.apply_rows``, whose cost follows the distinct
-    keys of the batch and not ``dedup_capacity``.
+    keys of the batch and not ``dedup_capacity``. ``plan`` is
+    :func:`merge_gradients`'s.
     """
     keys_arr, failed, merged = merge_gradients(
         state, initializer, indices, grads, dedup_capacity=dedup_capacity,
         max_probes=max_probes, in_counts=in_counts,
-        record_stats=record_stats)
+        record_stats=record_stats, plan=plan)
     weights, slots = table_lib.apply_rows(
         state.weights, state.slots, make_optimizer(optimizer), *merged,
         record_stats=record_stats)

@@ -16,12 +16,35 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax.numpy as jnp
+from flax import struct
 
 from ..analysis import scope
 
 # Padding sentinel for empty unique slots. Indices/keys are remapped away from
 # this value by callers when the key space could include it.
 FILL = jnp.iinfo(jnp.int32).min
+
+
+@struct.dataclass
+class Plan:
+    """One table's keys of one train step, deduplicated once, in front of
+    the pull: what :func:`unique_indices` / :func:`unique_pairs` return,
+    kept so that the pull resolves each distinct key once and expands by
+    ``inverse``, and the push that follows combines its gradients by the
+    same ``inverse`` into the same slots (``table.merge_gradients``,
+    ``hash_table.merge_gradients``). The capacity is the number of keys,
+    so no key overflows."""
+
+    uniq: jnp.ndarray       # [n] keys, [n, 2] wide ones; fill past the last
+    inverse: jnp.ndarray    # [n]: uniq[inverse[i]] is key i
+    valid: jnp.ndarray      # [n]: the slot holds a key, and not the fill
+
+
+def plan_keys(keys: jnp.ndarray, fill_value: int = FILL) -> Plan:
+    """The :class:`Plan` of a flat key stream ([n], or [n, 2] wide
+    pairs)."""
+    unique = unique_pairs if keys.ndim == 2 else unique_indices
+    return Plan(*unique(keys, fill_value=fill_value))
 
 
 def unique_indices(indices: jnp.ndarray, capacity: int | None = None,

@@ -65,16 +65,27 @@ answers:
 * ``batch_shape(idx.shape)``, ``sentinel(dtype)``, ``valid(flat)``,
   ``owner(keys)`` — the key space;
 * ``resolve(local, keys, me)`` / ``read_local(local, flat)`` — the pull's
-  owner side behind the exchange / on the masked-local body;
+  owner side behind the exchange / on the masked-local body, where
+  ``read_plan(local, plan, record_stats)`` reads one row a distinct key
+  of the step's plan (below);
 * ``carry(local)``, ``merge(local, carry, keys, grads, counts, me, ...)``
-  / ``apply_local(local, optimizer, flat, grads, ...)`` — the push's owner
-  side likewise, ``outputs(carry, weights, slots, axes)`` — what leaves
+  / ``apply_local(local, optimizer, flat, grads, ..., plan=)`` — the
+  push's owner side likewise, ``outputs(carry, weights, slots, axes)`` — what leaves
   the program, ``slot_of(carry, keys, me)`` — a key's slot in this shard
   (-1: not here), where the owner writes a cached key's row back;
 * ``ef_space(table)`` — the int8-EF residual's key space.
 
 No code in this file asks which kind it holds: a step that needs to is a
 method the store lacks.
+
+One dedup a table a step. The masked-local body looks every position of a
+batch up, and the push behind it dedups the same keys; the train step
+(``Trainer``) therefore builds a table's :class:`dedup.Plan` once
+(:func:`plan_sharded`) and hands it to the pull, which resolves the distinct
+keys over their occupied prefix and expands by ``inverse``, and to the
+push, whose unique buffer it is. It serves both where both see the same
+keys (:func:`shares_plan`). The routed body dedups its sender slice itself
+(``alltoall.exchange_pull``), and any call without a plan runs as it did.
 """
 
 from __future__ import annotations
@@ -90,6 +101,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..analysis import scope
+from ..ops import dedup
 from ..optim.optimizers import SparseOptimizer, make_optimizer
 from ..utils import observability
 from .. import table as table_lib
@@ -209,14 +221,60 @@ def _my_shard(grid: dict) -> jnp.ndarray:
     return a2a.linear_shard_id(grid["grid_axes"], grid["grid_sizes"])
 
 
+def shares_plan(spec: PlaneSpec, mesh: Mesh, batch_sharded: bool) -> bool:
+    """A table's pull and push of one step can share one :class:`dedup.Plan`:
+    both run the masked-local body, and a device pushes the keys it pulled
+    (its push gathers no other device's slice of the batch)."""
+    return not spec.routes and (
+        not batch_sharded or mesh.shape[spec.data_axis] == 1)
+
+
+def _require_shared(spec: PlaneSpec, mesh: Mesh, batch_sharded: bool):
+    if not shares_plan(spec, mesh, batch_sharded):
+        raise ValueError(
+            f"plane {spec.plane_label!r} over {spec.num_shards} shard(s): "
+            "only the masked-local body, on a mesh with no data axis to "
+            "gather the batch over, has a plan (sharded.shares_plan)")
+
+
+def _plan_specs(batch_spec: P) -> dedup.Plan:
+    return dedup.Plan(uniq=P(), inverse=batch_spec, valid=P())
+
+
+@functools.lru_cache(maxsize=None)
+def _plan_program(mesh: Mesh, store, batch_sharded: bool):
+    batch_spec = P(store.spec.data_axis) if batch_sharded else P()
+
+    def _plan(idx):
+        flat, _ = _flat_keys(store, idx, 0)
+        return dedup.plan_keys(flat, store.sentinel(flat.dtype))
+
+    _plan.__name__ = _program_name(store, "plan")
+    return jax.jit(shard_map(_plan, mesh=mesh, in_specs=(batch_spec,),
+                             out_specs=_plan_specs(batch_spec),
+                             check_vma=False))
+
+
+def plan_sharded(indices: jnp.ndarray, *, mesh: Mesh, store,
+                 batch_sharded: bool = True) -> dedup.Plan:
+    """The :class:`dedup.Plan` of ``indices`` for one step's
+    :func:`pull_sharded` and :func:`apply_gradients_sharded` through
+    ``store``'s table, where :func:`shares_plan` holds: the keys as they
+    come (no ownership mask: each store lays its own over the distinct
+    keys), deduplicated at full capacity."""
+    _require_shared(store.spec, mesh, batch_sharded)
+    return _plan_program(mesh, store, batch_sharded)(indices)
+
+
 @functools.lru_cache(maxsize=None)
 def _pull_program(mesh: Mesh, store, dim: int, batch_sharded: bool,
-                  record_stats: bool = False):
+                  record_stats: bool = False, planned: bool = False):
     """Cached jitted pull: eager callers (serving lookups, tests) would
     otherwise rebuild + retrace the shard_map closure every call."""
     spec = store.spec
     batch_spec = P(spec.data_axis) if batch_sharded else P()
     cache_specs = ()
+    plan_specs = (_plan_specs(batch_spec),) if planned else ()
 
     if spec.routes:
         grid = _exchange_args(mesh, spec, batch_sharded, record_stats)
@@ -253,23 +311,45 @@ def _pull_program(mesh: Mesh, store, dim: int, batch_sharded: bool,
                 flat, out_shape = _flat_keys(store, idx, dim)
                 return _pull_core(arrays, flat, me).reshape(out_shape)
     else:
-        def _pull(arrays, idx):
+        def _pull(arrays, idx, *plan):
             flat, out_shape = _flat_keys(store, idx, dim)
+            local = store.local(*arrays)
+            # with the step's plan a distinct key is read once: the psum
+            # carries the distinct rows, and expand hands every position
+            # its row, as exchange_pull's does
             rows = scope.stage("exchange")(
                 lambda rows: lax.psum(rows, spec.model_axis))(
-                    store.read_local(store.local(*arrays), flat))
+                    store.read_plan(local, plan[0], record_stats) if plan
+                    else store.read_local(local, flat))
+            if plan:
+                # the plan's capacity is its positions: no inverse is out
+                # of range, and clip spares the fill mode's select
+                rows = scope.stage("expand")(
+                    lambda rows, inverse: jnp.take(rows, inverse, axis=0,
+                                                   mode="clip"))(
+                        rows, plan[0].inverse)
             return rows.reshape(out_shape)
 
     _pull.__name__ = _program_name(store, "pull")
     fn = shard_map(_pull, mesh=mesh,
-                   in_specs=(store.specs(()),) + cache_specs + (batch_spec,),
+                   in_specs=(store.specs(()),) + cache_specs + (batch_spec,)
+                   + plan_specs,
                    out_specs=batch_spec,
                    check_vma=False)
     return jax.jit(fn)
 
 
+def _planned(plan, spec: PlaneSpec, mesh: Mesh, batch_sharded: bool) -> tuple:
+    """``plan`` as a program's trailing operands: none without one."""
+    if plan is None:
+        return ()
+    _require_shared(spec, mesh, batch_sharded)
+    return (plan,)
+
+
 def pull_sharded(state, indices: jnp.ndarray, *, mesh: Mesh, store,
-                 batch_sharded: bool = True) -> jnp.ndarray:
+                 batch_sharded: bool = True,
+                 plan: Optional[dedup.Plan] = None) -> jnp.ndarray:
     """Distributed embedding lookup through ``store``'s table: the
     reference's pull RPC fan-out + response scatter
     (EmbeddingPullOperator.cpp:40-252).
@@ -278,9 +358,12 @@ def pull_sharded(state, indices: jnp.ndarray, *, mesh: Mesh, store,
     over the data axis on dim 0 when ``batch_sharded`` (the normal training
     path) else replicated. Returns rows with the same batch sharding. On the
     ``"a2a+cache"`` plane ``state`` is a :class:`hot_cache.CachedState`.
+    ``plan`` is :func:`plan_sharded`'s of the same ``indices``: the same
+    rows, each distinct key resolved once.
     """
     spec = store.spec
     record = observability.evaluate_performance()
+    plan = _planned(plan, spec, mesh, batch_sharded)
     if spec.is_cached:
         table, cache = state.table, (state.cache.keys, state.cache.rows)
     else:
@@ -288,20 +371,22 @@ def pull_sharded(state, indices: jnp.ndarray, *, mesh: Mesh, store,
         # through the wrapper (serving restores may hand a bare table)
         table, cache = precision.unwrap(state), ()
     fn = _pull_program(mesh, store, table.weights.shape[-1], batch_sharded,
-                       record)
+                       record, bool(plan))
     return observability.plane_timed(
         "pull", spec.plane_label, record, fn,
-        store.operands(table.replace(slots={})), *cache, indices)
+        store.operands(table.replace(slots={})), *cache, indices, *plan)
 
 
 @functools.lru_cache(maxsize=None)
 def _apply_program(mesh: Mesh, store, optimizer: SparseOptimizer, dim: int,
                    batch_sharded: bool, dedup_capacity: Optional[int],
-                   slot_names: tuple, record_stats: bool = False):
+                   slot_names: tuple, record_stats: bool = False,
+                   planned: bool = False):
     spec = store.spec
     batch_spec = P(spec.data_axis) if batch_sharded else P()
     table_specs = store.specs(slot_names)
     extra_in = extra_out = ()
+    plan_specs = (_plan_specs(batch_spec),) if planned else ()
 
     if spec.routes:
         grid = _exchange_args(mesh, spec, batch_sharded, record_stats)
@@ -380,7 +465,7 @@ def _apply_program(mesh: Mesh, store, optimizer: SparseOptimizer, dim: int,
                 return (store.outputs(carry, weights, slots,
                                       spec.shard_axes), new_ef)
     else:
-        def _apply(arrays, idx, g):
+        def _apply(arrays, idx, g, *plan):
             flat, _ = _flat_keys(store, idx, dim)
             g2 = g.reshape(-1, dim)
             if batch_sharded:
@@ -390,13 +475,14 @@ def _apply_program(mesh: Mesh, store, optimizer: SparseOptimizer, dim: int,
                                       for x in xs))(flat, g2)
             carry, weights, slots = store.apply_local(
                 store.local(*arrays), optimizer, flat, g2,
-                dedup_capacity=dedup_capacity, record_stats=record_stats)
+                dedup_capacity=dedup_capacity, record_stats=record_stats,
+                plan=plan[0] if plan else None)
             return store.outputs(carry, weights, slots, spec.model_axis), ()
 
     _apply.__name__ = _program_name(store, "push")
     fn = shard_map(_apply, mesh=mesh,
                    in_specs=(table_specs,) + extra_in
-                   + (batch_spec, batch_spec),
+                   + (batch_spec, batch_spec) + plan_specs,
                    out_specs=(table_specs, extra_out),
                    check_vma=False)
     return jax.jit(fn)
@@ -405,7 +491,8 @@ def _apply_program(mesh: Mesh, store, optimizer: SparseOptimizer, dim: int,
 def apply_gradients_sharded(state, optimizer: SparseOptimizer,
                             indices: jnp.ndarray, grads: jnp.ndarray, *,
                             mesh: Mesh, store, batch_sharded: bool = True,
-                            dedup_capacity: Optional[int] = None):
+                            dedup_capacity: Optional[int] = None,
+                            plan: Optional[dedup.Plan] = None):
     """Distributed push+update: every shard applies its owned rows.
 
     On the routed planes each key's pre-reduced grads reach its single
@@ -414,11 +501,14 @@ def apply_gradients_sharded(state, optimizer: SparseOptimizer,
     data replica of a model shard — replacing the reference's single-owner
     store RPC (WorkerContext.cpp:115-123) with deterministic replicated
     application. On the ``"a2a+cache"`` plane ``state`` is a
-    :class:`hot_cache.CachedState`.
+    :class:`hot_cache.CachedState`. ``plan`` is :func:`plan_sharded`'s of
+    the same ``indices``, the one the step's pull ran on: its slots are the
+    push's unique buffer, and the same rows get the same update.
     """
     spec = store.spec
     optimizer = make_optimizer(optimizer)
     record = observability.evaluate_performance()
+    plan = _planned(plan, spec, mesh, batch_sharded)
     extra = ()
     if spec.is_cached:
         table = state.table
@@ -437,10 +527,10 @@ def apply_gradients_sharded(state, optimizer: SparseOptimizer,
         table = precision.unwrap(state)
     fn = _apply_program(mesh, store, optimizer, table.weights.shape[-1],
                         batch_sharded, dedup_capacity, tuple(table.slots),
-                        record)
+                        record, bool(plan))
     outs, new_extra = observability.plane_timed(
         "push", spec.plane_label, record, fn,
-        store.operands(table), *extra, indices, grads)
+        store.operands(table), *extra, indices, grads, *plan)
     table = store.rebuild(table, outs)
     if spec.is_cached:
         rows, slots = new_extra

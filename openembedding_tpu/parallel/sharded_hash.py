@@ -400,6 +400,19 @@ class HashStore:
         return self.resolve(local, flat,
                             lax.axis_index(self.spec.model_axis))
 
+    def own(self, plan, me):
+        """``plan`` as shard ``me`` sees it: a key another shard owns is
+        EMPTY and not valid."""
+        keys = _mask_non_owned(self.spec, plan.uniq, me)
+        return plan.replace(uniq=keys, valid=plan.valid & self.valid(keys))
+
+    def read_plan(self, local, plan, record_stats):
+        mine = self.own(plan, lax.axis_index(self.spec.model_axis))
+        return hash_lib.pull_distinct(
+            local, mine.uniq, mine.valid, self.initializer,
+            self.spec.max_probes, positions=plan.inverse.shape[0],
+            record_stats=record_stats)
+
     def carry(self, local):
         return local.keys, jnp.zeros((), jnp.int32)
 
@@ -414,13 +427,16 @@ class HashStore:
         return (tkeys, fails + failed), merged
 
     def apply_local(self, local, optimizer, flat, grads, *, dedup_capacity,
-                    record_stats):
+                    record_stats, plan=None):
+        me = lax.axis_index(self.spec.model_axis)
+        # with the step's plan the mask falls on its distinct keys, and
+        # the plan's slots are the unique buffer
         new = hash_lib.apply_gradients(
             local, optimizer, self.initializer,
-            _mask_non_owned(self.spec, flat,
-                            lax.axis_index(self.spec.model_axis)), grads,
-            dedup_capacity=dedup_capacity, max_probes=self.spec.max_probes,
-            record_stats=record_stats)
+            _mask_non_owned(self.spec, flat, me) if plan is None else None,
+            grads, dedup_capacity=dedup_capacity,
+            max_probes=self.spec.max_probes, record_stats=record_stats,
+            plan=None if plan is None else self.own(plan, me))
         return (new.keys, new.insert_failures), new.weights, new.slots
 
     def outputs(self, carry, weights, slots, axes):

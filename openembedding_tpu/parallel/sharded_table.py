@@ -392,6 +392,29 @@ class ArrayStore:
             lambda flat: _masked_local(self.spec, flat))(flat)
         return scope.stage("resolve")(_take_owned)(local.weights, owned, row)
 
+    def own(self, plan: dedup.Plan) -> dedup.Plan:
+        """``plan`` as this model-axis shard sees it: a key it owns is its
+        local row, any other -1 and not valid."""
+        @scope.stage("route")
+        def mask(uniq, valid):
+            owned, row = _masked_local(self.spec, uniq)
+            return jnp.where(owned, row, -1), valid & owned
+
+        uniq, valid = mask(plan.uniq, plan.valid)
+        return plan.replace(uniq=uniq, valid=valid)
+
+    def read_plan(self, local, plan, record_stats):
+        mine = self.own(plan)
+
+        @scope.stage("resolve")
+        def read(weights, row, live):
+            rows, walked = table_lib.read_distinct(weights, row, live)
+            table_lib.record_pull(live, walked, plan.inverse.shape[0],
+                                  record_stats)
+            return rows
+
+        return read(local.weights, mine.uniq, mine.valid)
+
     def carry(self, local):
         return ()
 
@@ -403,7 +426,7 @@ class ArrayStore:
             rows, grads, dedup_capacity=dedup_capacity, in_counts=counts)
 
     def apply_local(self, local, optimizer, flat, grads, *, dedup_capacity,
-                    record_stats):
+                    record_stats, plan=None):
         @scope.stage("route")
         def mask(flat):
             owned, row = _masked_local(self.spec, flat)
@@ -411,9 +434,12 @@ class ArrayStore:
             # apply_gradients
             return jnp.where(owned, row, -1)
 
+        # with the step's plan the mask falls on its distinct keys, and
+        # the plan's slots are the unique buffer
         new = table_lib.apply_gradients(
-            local, optimizer, mask(flat), grads,
-            dedup_capacity=dedup_capacity, record_stats=record_stats)
+            local, optimizer, mask(flat) if plan is None else None, grads,
+            dedup_capacity=dedup_capacity, record_stats=record_stats,
+            plan=None if plan is None else self.own(plan))
         return (), new.weights, new.slots
 
     def outputs(self, carry, weights, slots, axes):

@@ -157,15 +157,22 @@ def optimizer_block_update(optimizer: SparseOptimizer,
 
 def merge_gradients(indices: jnp.ndarray, grads: jnp.ndarray, *,
                     dedup_capacity: Optional[int] = None,
-                    in_counts: Optional[jnp.ndarray] = None):
+                    in_counts: Optional[jnp.ndarray] = None,
+                    plan: Optional[dedup.Plan] = None):
     """The first half of :func:`apply_gradients`, which needs no table:
     deduplicate ``indices`` and combine their gradients into a buffer of
     ``dedup_capacity`` (default ``n``) slots. Returns :func:`apply_rows`'s
-    ``(rows, live, summed, counts)``."""
-    flat_idx = indices.ravel()
+    ``(rows, live, summed, counts)``. ``plan`` is the dedup of ``indices``
+    where the step has made it already, in front of its pull: its slots
+    are the buffer and nothing is deduplicated again."""
     flat_grads = grads.reshape(-1, grads.shape[-1])
-    capacity = dedup_capacity or flat_idx.shape[0]
-    uniq, inverse, valid = dedup.unique_indices(flat_idx, capacity)
+    if plan is None:
+        flat_idx = indices.ravel()
+        capacity = dedup_capacity or flat_idx.shape[0]
+        uniq, inverse, valid = dedup.unique_indices(flat_idx, capacity)
+    else:
+        uniq, inverse, valid = plan.uniq, plan.inverse, plan.valid
+        capacity = uniq.shape[0]
     # negative indices are invalid keys: pull clamps them to row 0, the
     # update must NOT let them wrap around onto a real row.
     valid = valid & (uniq >= 0)
@@ -181,7 +188,8 @@ def apply_gradients(state: TableState,
                     *,
                     dedup_capacity: Optional[int] = None,
                     in_counts: Optional[jnp.ndarray] = None,
-                    record_stats: bool = False) -> TableState:
+                    record_stats: bool = False,
+                    plan: Optional[dedup.Plan] = None) -> TableState:
     """Push + update in one step: combine duplicate grads, update touched rows.
 
     ``indices`` is [n] (or any shape), ``grads`` matches with a trailing
@@ -194,10 +202,10 @@ def apply_gradients(state: TableState,
     ``dedup_capacity`` slots; the gather, the optimizer and the scatter run
     over the distinct rows of the batch (:func:`apply_rows`), so their cost
     follows those and not ``dedup_capacity``. ``record_stats`` is
-    :func:`apply_rows`'s.
+    :func:`apply_rows`'s, ``plan`` :func:`merge_gradients`'s.
     """
     merged = merge_gradients(indices, grads, dedup_capacity=dedup_capacity,
-                             in_counts=in_counts)
+                             in_counts=in_counts, plan=plan)
     weights, slots = apply_rows(state.weights, state.slots, optimizer,
                                 *merged, record_stats=record_stats)
     return TableState(weights=weights, slots=slots)
@@ -320,30 +328,62 @@ def gather_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
     return gather(weights, slots, at)
 
 
-def snapshot_rows(arrays, at: jnp.ndarray, count: jnp.ndarray):
+def read_rows(arrays, at: jnp.ndarray, count: jnp.ndarray):
     """Copies of the rows ``at[:count]`` of every array of ``arrays``, in
-    staging buffers of ``at``'s length (the rest zero): what a delta
-    checkpoint takes of a table in the step's stream. Read as the sparse
-    apply reads rows, :data:`APPLY_CHUNK` a trip, so the cost follows
-    ``count`` and nothing as long as a table array is made; an index
-    past an array's end reads a zero row. ``at``'s length is a chunk or
-    less, or a multiple of it."""
+    buffers of ``at``'s length whose rest is zero. Read as the sparse apply
+    reads rows, :data:`APPLY_CHUNK` a trip, so the cost follows ``count``
+    and not ``at``'s length; an index past an array's end reads a zero
+    row."""
     capacity = at.shape[0]
     chunk = min(APPLY_CHUNK, capacity)
+    short = -capacity % chunk       # whole chunks only, as apply_rows pads
+    if short:
+        at = jnp.pad(at, (0, short))
 
-    @scope.stage("ckpt_gather")
-    def gather(arrays, at, count):
-        def trip(i, staged):
-            part = lax.dynamic_slice_in_dim(at, i * chunk, chunk)
-            return [lax.dynamic_update_slice_in_dim(
-                s, jnp.take(x, part, axis=0, mode="fill", fill_value=0),
-                i * chunk, 0) for s, x in zip(staged, arrays)]
+    def trip(i, staged):
+        part = lax.dynamic_slice_in_dim(at, i * chunk, chunk)
+        return [lax.dynamic_update_slice_in_dim(
+            s, jnp.take(x, part, axis=0, mode="fill", fill_value=0),
+            i * chunk, 0) for s, x in zip(staged, arrays)]
 
-        return lax.fori_loop(
-            0, (count + (chunk - 1)) // chunk, trip,
-            [jnp.zeros((capacity,) + x.shape[1:], x.dtype) for x in arrays])
+    staged = lax.fori_loop(
+        0, (count + (chunk - 1)) // chunk, trip,
+        [jnp.zeros((capacity + short,) + x.shape[1:], x.dtype)
+         for x in arrays])
+    return [s[:capacity] for s in staged] if short else staged
 
-    return gather(list(arrays), at, count)
+
+def snapshot_rows(arrays, at: jnp.ndarray, count: jnp.ndarray):
+    """What a delta checkpoint takes of a table in the step's stream:
+    :func:`read_rows` under its own stage, so nothing as long as a table
+    array is made. ``at``'s length is a chunk or less, or a multiple of
+    it."""
+    return scope.stage("ckpt_gather")(read_rows)(list(arrays), at, count)
+
+
+def read_distinct(weights: jnp.ndarray, at: jnp.ndarray, live: jnp.ndarray):
+    """The row read of a pull that has its step's plan (``dedup.Plan``):
+    ``weights[at]`` for the live slots of the distinct keys' buffer, zero
+    rows for the others, by :func:`read_rows` over the buffer's occupied
+    prefix. A batch's distinct keys fill a third of it (Criteo-shaped
+    ids), and a duplicate is read once. ``(rows, slots walked)``."""
+    oob = jnp.asarray(weights.shape[0], at.dtype)
+    count = occupied_prefix(live)
+    rows, = read_rows([weights], jnp.where(live, at, oob), count)
+    chunk = min(APPLY_CHUNK, at.shape[0])
+    return rows, (count + (chunk - 1)) // chunk * chunk
+
+
+def record_pull(valid: jnp.ndarray, walked: jnp.ndarray, positions: int,
+                record_stats: bool) -> None:
+    """``pull_keys_live`` / ``pull_keys_walked`` / ``pull_positions`` of a
+    pull that has a plan, under ``record_stats`` (the trace-time gate of
+    ``alltoall.record_stat``): the distinct keys this shard resolved, the
+    keys its chunks walked, the positions they were asked for."""
+    record_stat("pull_keys_live", jnp.sum(valid, dtype=jnp.int32),
+                record_stats)
+    record_stat("pull_keys_walked", walked, record_stats)
+    record_stat("pull_positions", jnp.int32(positions), record_stats)
 
 
 def scatter_rows(arrays, at: jnp.ndarray, new):

@@ -251,11 +251,13 @@ class Trainer:
 
     # --- steps ---------------------------------------------------------------
     def _dense_update_and_push(self, state: TrainState, batch, rows,
-                               pull_inputs, dense_ids):
+                               pull_inputs, dense_ids, plan=None):
         """Shared core of the serial AND pipelined step programs: loss
         + grads on ``rows``, dense optimizer update, sparse push. ONE
         definition traced by both schedules — the pipelined plane's
-        exact-equivalence guarantee rests on them never diverging."""
+        exact-equivalence guarantee rests on them never diverging.
+        ``plan`` is the serial step's ``collection.plan`` of
+        ``pull_inputs``, the one ``rows`` were pulled with."""
         def lfn(params, rows):
             logits = self._apply(params, batch.get("dense"), rows,
                                  dense_ids)
@@ -277,7 +279,7 @@ class Trainer:
 
         params, opt_state = update(dense_g, state.opt_state, state.params)
         emb = self.collection.apply_gradients(state.emb, pull_inputs,
-                                              row_g)
+                                              row_g, plan=plan)
         return params, opt_state, emb, loss
 
     def lower_train_step(self, state: TrainState, batch):
@@ -296,9 +298,19 @@ class Trainer:
 
         def step_fn(state: TrainState, batch) -> tuple:
             pull_inputs, dense_ids = self._split_sparse(batch["sparse"])
-            rows = collection.pull(state.emb, pull_inputs)
+            # a table's ids are deduplicated once a step, in front of the
+            # pull: pull and push both work on the distinct keys
+            plan = collection.plan(pull_inputs)
+            rows = collection.pull(state.emb, pull_inputs, plan=plan)
+            # The push's find and insert need nothing of the dense pass:
+            # only the plan and the key array the pull's find reads. This
+            # edge says the push follows the pull; left to order the two
+            # itself the v5e compiler copies an int32 key array (256 MiB
+            # a table) into the insert loop (tests/test_tpu_lowering.py).
+            if plan:
+                rows, plan = jax.lax.optimization_barrier((rows, plan))
             params, opt_state, emb, loss = self._dense_update_and_push(
-                state, batch, rows, pull_inputs, dense_ids)
+                state, batch, rows, pull_inputs, dense_ids, plan)
             new_state = TrainState(step=state.step + 1, params=params,
                                    opt_state=opt_state, emb=emb)
             return new_state, {"loss": loss}

@@ -29,13 +29,15 @@ from openembedding_tpu.parallel.mesh import create_mesh
 CONFIGS = os.path.join(os.path.dirname(system_lib.__file__), "configs")
 EVERYWHERE = {"dedup", "route", "resolve", "apply_gather", "apply_update",
               "apply_scatter", "dense_fwd", "dense_bwd", "dense_update"}
-# one chip takes the masked-local body: nothing is bucketed, so nothing is
-# expanded and no push branches; its psum over one device is lowered and
-# then compiled away, so ``exchange`` is a symbol only
+# one chip takes the masked-local body: nothing is bucketed and no push
+# branches; its pull reads the distinct keys of the step's plan and expands
+# them (``dedup.Plan``); its psum over one device is lowered and then
+# compiled away, so ``exchange`` is a symbol only
 STAGES = {
-    "tiny_array": (EVERYWHERE | {"exchange"}, EVERYWHERE),
-    "tiny_hash": (EVERYWHERE | {"exchange", "probe", "init_rows"},
-                  EVERYWHERE | {"probe", "init_rows"}),
+    "tiny_array": (EVERYWHERE | {"exchange", "expand"},
+                   EVERYWHERE | {"expand"}),
+    "tiny_hash": (EVERYWHERE | {"exchange", "expand", "probe", "init_rows"},
+                  EVERYWHERE | {"expand", "probe", "init_rows"}),
     "tiny_array_x4": (EVERYWHERE | {"exchange", "expand", "push_routed",
                                     "push_spilled"},) * 2,
 }

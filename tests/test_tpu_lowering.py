@@ -78,13 +78,13 @@ def _abstract_step(mesh, coll, trainer, mapper, rows):
 
 
 @functools.lru_cache(maxsize=None)      # several tests read each program
-def _compile_deepfm_step(mesh, *, use_hash):
+def _compile_deepfm_step(mesh, *, use_hash, key_dtype="wide"):
     """The step program of chip_smoke.py's training phases, from shapes
     alone."""
     rows = ROWS_PER_FEATURE * mesh.size
     coll, trainer, mapper = chip_smoke.build_deepfm(
         mesh, use_hash=use_hash, rows_per_feature=rows,
-        hash_capacity=HASH_CAPACITY)
+        hash_capacity=HASH_CAPACITY, key_dtype=key_dtype)
     return trainer.lower_train_step(
         *_abstract_step(mesh, coll, trainer, mapper, rows)).compile()
 
@@ -137,32 +137,36 @@ def test_v5e_hash_push_finds_then_inserts_in_place(v5e, shape):
     insert loop over the buffer of misses and the one over the whole call.
     Which of the two places keys is decided by what each is given, under
     no conditional of the probe's own: through one the chip's compiler
-    copies the key array, and on one chip no copy of it is left."""
+    copies the key array, and on one chip no copy of it is left. On one
+    chip the pull's find, over the distinct keys of the step's plan, is a
+    fourth loop a table; behind the exchange it is one pass."""
     data, model = shape
     mesh = create_mesh(data, model, v5e[:data * model])
     hlo = _compile_deepfm_step(mesh, use_hash=True).as_text()
-    stages = stage_reduce.instruction_stages(hlo)
+    paths = trace_reduce.scope_names(hlo)
+    stages = stage_reduce.instruction_stages(hlo, paths)
     found = [m.groups() for m in map(_OPCODE.match, hlo.splitlines()) if m]
     loops = [inst for inst, op in found
              if op == "while" and stages.get(inst) == "probe"]
-    assert loops and len(loops) % 6 == 0, loops     # two tables
+    pushing = [inst for inst in loops if "hash_push_a2a" in paths[inst]]
+    assert pushing and len(pushing) % 6 == 0, loops     # two tables
     assert not re.search(r'conditional\(.*op_name="[^"]*jit\(probe\)/cond',
                          hlo)
     if mesh.size == 1:
-        assert len(loops) == 6, loops
+        assert len(pushing) == 6 and len(loops) == 8, loops
         keys = f"s32[{HASH_CAPACITY},2]"
         copies = [line.strip()[:120] for line in hlo.splitlines()
                   if f"= {keys}" in line and " copy(" in line]
         assert not copies, copies
 
 
-def test_v5e_hash_push_find_walks_chunks_in_place(v5e):
-    """The find of each table's push is the one loop under ``probe`` that
-    neither sorts nor scatters: its trips gather a chunk of bucket rows,
-    and nothing as large as the unique buffer's worth of them (26 x 4096
-    keys x 128 slots) is left in the program outside the insert loops,
-    whose full-width one keeps it. The key array the find reads is copied
-    nowhere, into the loop or out of it."""
+def test_v5e_hash_finds_walk_chunks_in_place(v5e):
+    """The finds of each table, its pull's and its push's, are the loops
+    under ``probe`` that neither sort nor scatter: their trips gather a
+    chunk of bucket rows, and nothing as large as the unique buffer's
+    worth of them (26 x 4096 keys x 128 slots) is left in the program
+    outside the insert loops, whose full-width one keeps it. The key array
+    the finds read is copied nowhere, into a loop or out of it."""
     mesh = create_mesh(1, 1, v5e[:1])
     hlo = _compile_deepfm_step(mesh, use_hash=True).as_text()
     lines = hlo.splitlines()
@@ -195,7 +199,7 @@ def test_v5e_hash_push_find_walks_chunks_in_place(v5e):
                 inserting |= inside
             else:
                 finds.append([i for c in inside for i in comps[c]])
-    assert len(finds) == 2, len(finds)                  # two tables
+    assert len(finds) == 4, len(finds)          # two tables, pull and push
     for body in finds:
         assert any(makes(i, chunk) for i in body)
         assert not [i.name for i in body if makes(i, unique)]
@@ -234,6 +238,63 @@ def test_v5e_apply_walks_its_buffer_in_place(v5e, shape, use_hash):
               and int((re.search(r"= \(?\w+\[(\d+)", line) or [0, 0])[1])
               >= rows]
     assert not copied, copied
+
+
+@pytest.mark.parametrize("use_hash", [False, True], ids=["array", "hash"])
+def test_v5e_one_chip_step_dedups_once_and_pulls_distinct_keys(v5e, use_hash):
+    """The step's plan (``dedup.Plan``) is built once a table, for pull and
+    push both: the one-chip step sorts as often as it did when the push
+    alone deduplicated (a ``lexsort`` a table for ids, an ``argsort`` a
+    key word a table for wide keys), all under the plan's program. The
+    pull reads the distinct keys' rows in a loop of chunk-sized gathers
+    under ``resolve`` and hands every position its row in one ``expand``
+    gather a table; no gather of the pull is as long as the batch's
+    positions but that one, and none of it sits under a conditional."""
+    mesh = create_mesh(1, 1, v5e[:1])
+    hlo = _compile_deepfm_step(mesh, use_hash=use_hash).as_text()
+    paths = trace_reduce.scope_names(hlo)
+    stages = stage_reduce.instruction_stages(hlo, paths)
+    found = [m.groups() for m in map(_OPCODE.match, hlo.splitlines()) if m]
+    sorts = [paths[inst] for inst, op in found
+             if op == "sort" and stages.get(inst) == "dedup"
+             and ("argsort" if use_hash else "lexsort") in paths[inst]]
+    assert len(sorts) == (4 if use_hash else 2), sorts
+    assert all("plan_a2a" in path for path in sorts), sorts
+
+    pull = "hash_pull_a2a" if use_hash else "pull_a2a"
+    pulling = [(inst, op) for inst, op in found
+               if pull in paths.get(inst, "").split("/")]
+    assert not [inst for inst, _ in pulling if "/cond/" in paths[inst]]
+    reads = [inst for inst, op in pulling
+             if op == "while" and stages.get(inst) == "resolve"]
+    assert len(reads) == 2, reads                       # two tables
+    positions = chip_smoke.FEATURES * chip_smoke.BATCH
+    chunk = hl.table_lib.APPLY_CHUNK
+    lines = {m.group(1): line for line in hlo.splitlines()
+             if (m := _OPCODE.match(line))}
+    gathers = {inst: (stages.get(inst),
+                      int(re.search(r"= \(?\w+\[(\d+)", lines[inst])[1]))
+               for inst, op in pulling if op == "gather"}
+    assert sorted(g for g in gathers.values() if g[1] >= positions) == \
+        [("expand", positions)] * 2, gathers
+    assert {g for g in gathers.values() if g[0] == "resolve"} == \
+        {("resolve", chunk)}, gathers
+
+
+def test_v5e_one_chip_push_follows_the_pull_and_copies_no_key_array(v5e):
+    """int32 keys, the offload cell's caches (2**26 slots, 256 MiB of keys
+    a table). The push's find and insert read the plan and the key array
+    and nothing of the dense pass, so no operand orders them behind the
+    pull's find, which reads the same array; free to order the two, the
+    chip's compiler copied the array into the insert loop. The step ties
+    plan and rows together after the pull (``Trainer._build_train_step``),
+    and no copy of a key array is left."""
+    mesh = create_mesh(1, 1, v5e[:1])
+    hlo = _compile_deepfm_step(mesh, use_hash=True,
+                               key_dtype="int32").as_text()
+    copies = [line.strip()[:120] for line in hlo.splitlines()
+              if f"= s32[{HASH_CAPACITY}]" in line and " copy" in line]
+    assert not copies, copies
 
 
 def _on(dev, shape, dtype):
