@@ -905,9 +905,11 @@ bool replay_delta_chain(oe_model* model, const std::string& root) {
     return false;
   }
   int64_t fmt_num = -1;
-  // format 2 adds block_crc records; format 1 manifests read as before
+  // format 2 adds block_crc records, format 3 hash records exact to the
+  // key (keys + weights + slots, no chunk members: what a hash payload
+  // is read by here anyway); older manifests read as before
   if (!json_i64(manifest.get("format"), &fmt_num)
-      || (fmt_num != 1 && fmt_num != 2)) {
+      || fmt_num < 1 || fmt_num > 3) {
     set_error("unknown delta manifest format at " + root);
     return false;
   }

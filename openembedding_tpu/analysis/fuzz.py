@@ -502,7 +502,7 @@ def _m_manifest_json_garbage(rng: random.Random, d: str) -> str:
     else:
         m = json.loads(raw)
         if variant == "format":
-            m["format"] = rng.choice([3, "one", None])
+            m["format"] = rng.choice([4, "one", None])
         elif variant == "chain_scalar":
             m["chain"] = rng.choice([7, "x", {"a": 1}])
         elif variant == "entry_scalar":

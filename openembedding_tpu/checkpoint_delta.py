@@ -10,18 +10,20 @@ module generalizes it to the WHOLE-MODEL checkpoint
 * a FULL save (``checkpoint._save_checkpoint_impl``, parallel shard
   writers) is the BASE; it arms the chain by writing a fresh manifest
   (:func:`init_manifest`) when the collection's dirty tracking is on;
-* a DELTA save writes, per variable, only what its ``DirtyTracker``
-  marked (``dirty.py``; pushes mark rows of an array table, key chunks
-  of a hash table) — one ``delta_<seq>_<vid>.npz`` per variable,
+* a DELTA save writes, per variable, only what its tracker marked
+  (``dirty.py``; pushes mark rows of an array table, keys of a hash
+  table) — one ``delta_<seq>_<vid>.npz`` per variable,
   written by the same parallel writer pool, checksummed per block of
   rows. It has two halves. :func:`begin_delta` is the SNAPSHOT: it
   claims the dirty set and dispatches, behind whatever step was
   dispatched last, one gather program a variable that copies the dirty
   rows of the weights and of every slot array into staging buffers
-  (``sharded_table.snapshot_rows_sharded``, stage ``ckpt_gather``):
-  nothing waits for the device, and steps dispatched after it may
-  donate the tables. :func:`finish_delta` is everything else (the copy
-  to the host, checksums, files, the manifest rename), on any thread:
+  (``sharded_table.snapshot_rows_sharded``, stage ``ckpt_gather``; for
+  a hash table ``sharded_hash.snapshot_keys_sharded``, which first
+  finds the dirty keys' slots, stage ``ckpt_find``): nothing waits for
+  the device, and steps dispatched after it may donate the tables.
+  :func:`finish_delta` is everything else (the copy to the host,
+  checksums, files, the manifest rename), on any thread:
   ``Trainer.fit`` runs it on a writer thread, :func:`save_delta` right
   after the snapshot;
 * the MANIFEST (``delta_manifest``, atomic rename) is the single commit
@@ -54,6 +56,7 @@ import io
 import json
 import os
 import re
+import sys
 import threading
 import time
 import uuid
@@ -66,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .analysis.concurrency import make_lock, sync_point
+from .dirty import KeyTracker
 from .embedding import EmbeddingCollection
 from .parallel import hot_cache
 from .parallel import sharded_hash as sh
@@ -80,8 +84,14 @@ DELTA_MANIFEST_FILE = "delta_manifest"
 # (one a tracker chunk: a Python call a row when the tracker is exact to
 # the row). A manifest is written at the lowest format that holds it, so
 # a chain of chunked deltas stays format 1, byte for byte.
-DELTA_FORMAT = 2
+# Format 3 (PR 34): a hash record may be exact to the key
+# (``"keys_exact": true``): its payload holds ``keys``, ``weights`` and
+# ``slot_*`` of the keys pushed since the snapshot before, in the order
+# they were first pushed, and neither ``chunks`` nor ``num_chunks``.
+DELTA_FORMAT = 3
+_BLOCKED_FORMAT = 2
 _CHUNKED_FORMAT = 1
+_FORMATS = (_CHUNKED_FORMAT, _BLOCKED_FORMAT, DELTA_FORMAT)
 CRC_BLOCK_ROWS = 1 << 16
 # staging lengths of a snapshot are few and fixed, so that the saves of a
 # steady job reuse one compiled gather a variable: powers of two from here,
@@ -172,12 +182,11 @@ def read_manifest(path: str) -> Optional[Dict[str, Any]]:
         raise DeltaDecodeError(
             f"delta manifest at {path!r} is JSON "
             f"{type(manifest).__name__}, not an object")
-    if manifest.get("format") not in (_CHUNKED_FORMAT, DELTA_FORMAT) \
+    if manifest.get("format") not in _FORMATS \
             or isinstance(manifest.get("format"), bool):
         raise ValueError(
             f"unknown delta manifest format {manifest.get('format')!r} "
-            f"at {path!r} (this build reads formats {_CHUNKED_FORMAT} "
-            f"and {DELTA_FORMAT})")
+            f"at {path!r} (this build reads formats {_FORMATS})")
     return manifest
 
 
@@ -333,6 +342,17 @@ def _staging_rows(rows: int) -> int:
     return size
 
 
+def _staged_fields(state, include_optimizer: bool):
+    """(payload field names, table arrays) a snapshot stages: the weights,
+    then each slot array by name."""
+    fields, arrays = ["weights"], [state.weights]
+    if include_optimizer:
+        for sname in sorted(state.slots):
+            fields.append(f"slot_{sname}")
+            arrays.append(state.slots[sname])
+    return fields, arrays
+
+
 def _stage_array_rows(collection, name: str, state, tracker,
                       chunks: np.ndarray, include_optimizer: bool
                       ) -> _StagedRows:
@@ -343,11 +363,7 @@ def _stage_array_rows(collection, name: str, state, tracker,
     start of their copy to the host. Returns without waiting."""
     sspec = collection.sharding_spec(name)
     vocab = int(collection.specs[name].input_dim)
-    fields, arrays = ["weights"], [state.weights]
-    if include_optimizer:
-        for sname in sorted(state.slots):
-            fields.append(f"slot_{sname}")
-            arrays.append(state.slots[sname])
+    fields, arrays = _staged_fields(state, include_optimizer)
     rows = _chunk_rows(chunks, tracker.rows_per_chunk, vocab)
     if sspec.num_shards > 1:
         shard, local = sspec.shard_and_local(rows)
@@ -364,6 +380,50 @@ def _stage_array_rows(collection, name: str, state, tracker,
         {"chunks": np.asarray(chunks, np.int64),
          "rows_per_chunk": np.int64(tracker.rows_per_chunk),
          "vocab": np.int64(vocab)})
+
+
+@dataclasses.dataclass
+class _StagedKeys:
+    """One hash variable's dirty keys between snapshot and commit: the
+    keys on the host in the table's key form, in the order they were
+    first marked, and on the device which of them the table held and the
+    staging buffers (weights, then each slot array, a row a key)."""
+    fields: List[str]
+    arrays: List[Any]
+    keys: np.ndarray
+    found: Any
+
+
+def _stage_hash_keys(collection, name: str, state, keys64: np.ndarray,
+                     include_optimizer: bool) -> _StagedKeys:
+    """Dispatch the snapshot of one hash variable's dirty keys: one
+    program that finds each key's slot and gathers its row of the weights
+    and of every slot array into staging buffers of one of a few fixed
+    lengths (:func:`_stage_array_rows`' lengths), and the start of their
+    copy to the host. The key array is read where it is; a key the table
+    does not hold (marked and never inserted) comes back not found.
+    Returns without waiting."""
+    fields, arrays = _staged_fields(state, include_optimizer)
+    key_dtype = np.dtype(state.keys.dtype)
+    n = int(keys64.size)
+    if not hash_lib.is_wide(state.keys):
+        keys = keys64.astype(key_dtype)
+    elif sys.byteorder == "little":
+        # an int64 read as two int32 is its (low, high) pair: no pass
+        # over 2.9M keys on the step thread (hash_table.split64's takes
+        # 20-40 ms a table there)
+        keys = np.ascontiguousarray(keys64).view(np.int32).reshape(n, 2)
+    else:
+        keys = hash_lib.split64(keys64)
+    query = np.full((_staging_rows(n),) + keys.shape[1:],
+                    hash_lib.empty_key(key_dtype), key_dtype)
+    query[:n] = keys
+    found, staged = sh.snapshot_keys_sharded(
+        state.keys, arrays, jnp.asarray(query), n, mesh=collection.mesh,
+        spec=collection.sharding_spec(name))
+    for a in (found, *staged):
+        a.copy_to_host_async()
+    return _StagedKeys(fields, staged, keys, found)
 
 
 def _row_crcs(payload: Dict[str, np.ndarray], bounds) -> List[int]:
@@ -432,6 +492,24 @@ def _hash_delta_payload(state, tracker, chunks: np.ndarray,
                                   np.dtype(arr.dtype))
     payload["chunks"] = np.asarray(chunks, np.int64)
     payload["num_chunks"] = np.int64(tracker.num_chunks)
+    return payload
+
+
+def _keys_payload(staged: _StagedKeys) -> Dict[str, np.ndarray]:
+    """The payload of a hash variable tracked to the key, once its staged
+    rows are on the host: the keys the table held at the snapshot, each
+    with its row of every field. A marked key it did not hold has no row
+    and is left out (counter ``ckpt_delta_keys_absent``): marks laid
+    ahead of their push, or an insert no probe window held."""
+    from .utils import observability
+    n = staged.keys.shape[0]
+    found = np.asarray(staged.found)[:n]
+    absent = n - int(np.count_nonzero(found))
+    observability.GLOBAL.add("ckpt_delta_keys_absent", float(absent))
+    take = (lambda a: a[:n][found]) if absent else (lambda a: a[:n])
+    payload = {"keys": take(staged.keys)}
+    for f, a in zip(staged.fields, staged.arrays):
+        payload[f] = take(np.asarray(a))
     return payload
 
 
@@ -575,7 +653,7 @@ class PendingDelta:
     seq: int
     step: int
     snaps: Dict[str, np.ndarray]
-    staged: Dict[str, Any]          # _StagedRows, or a hash payload
+    staged: Dict[str, Any]     # _StagedRows, _StagedKeys or a hash payload
     dense: Any
     options: Dict[str, Any]
     began: float
@@ -604,11 +682,15 @@ def begin_delta(path: str, collection: EmbeddingCollection,
     """The snapshot half of a delta save (arguments: :func:`save_delta`).
 
     Claims every tracker's dirty set and stages what it names as of
-    ``states``: an array variable's rows by one gather program dispatched
-    on the device's stream (:func:`_stage_array_rows`), the dense pytree
-    by a device copy, neither waited for; a hash variable's rows are read
-    to the host here (its tracker is not exact to the key, so its payload
-    is a scan of the table). Returns a :class:`PendingDelta` for
+    ``states``: a variable's rows by one gather program dispatched on the
+    device's stream (:func:`_stage_array_rows`; :func:`_stage_hash_keys`
+    for a hash variable tracked to the key), the dense pytree by a device
+    copy, none waited for; only a hash variable tracked in ``key % n``
+    chunks (``target_chunks``) is read to the host here, by a scan of the
+    table. What this costs the calling thread reads under two spans:
+    ``ckpt.claim`` (the trackers hand out their dirty sets) and
+    ``ckpt.stage`` (a variable's ids to the device and its program's
+    dispatch). Returns a :class:`PendingDelta` for
     :func:`finish_delta`. ``states`` may be donated once this returns.
     With no armed base in ``path`` nothing can be incremental: the full
     save runs here, blocking, and its info dict is returned instead.
@@ -652,20 +734,26 @@ def begin_delta(path: str, collection: EmbeddingCollection,
             f"(base={manifest.get('include_optimizer')}); re-save full")
     _gc_orphans(path, manifest["chain"])
 
-    snaps = {name: trackers[name].snapshot_clear() for name in trackers}
+    from .analysis import scope
+    with scope.span("ckpt.claim"):
+        snaps = {name: trackers[name].snapshot_clear() for name in trackers}
     staged: Dict[str, Any] = {}
     try:
         for name, chunks in snaps.items():
             if not chunks.size:
                 continue
             state = hot_cache.unwrap(states[name])
-            if collection.specs[name].use_hash:
-                staged[name] = _hash_delta_payload(
-                    state, trackers[name], chunks, include_optimizer)
-            else:
-                staged[name] = _stage_array_rows(
-                    collection, name, state, trackers[name], chunks,
-                    include_optimizer)
+            with scope.span("ckpt.stage"):
+                if isinstance(trackers[name], KeyTracker):
+                    staged[name] = _stage_hash_keys(
+                        collection, name, state, chunks, include_optimizer)
+                elif collection.specs[name].use_hash:
+                    staged[name] = _hash_delta_payload(
+                        state, trackers[name], chunks, include_optimizer)
+                else:
+                    staged[name] = _stage_array_rows(
+                        collection, name, state, trackers[name], chunks,
+                        include_optimizer)
         dense = None
         if dense_state is not None:
             dense = _tree_copy_program()(dense_state)
@@ -713,8 +801,11 @@ def finish_delta(pending: PendingDelta) -> Dict[str, Any]:
         sync_point("ckpt.delta.write")
         payload = payloads[name]
         info = {"kind": "array" if "rows_per_chunk" in payload else "hash",
-                "rows": int(payload["weights"].shape[0]),
-                "dirty_chunks": int(payload["chunks"].size)}
+                "rows": int(payload["weights"].shape[0])}
+        if "chunks" in payload:
+            info["dirty_chunks"] = int(payload["chunks"].size)
+        else:
+            info["keys_exact"] = True
         if info["kind"] == "array":
             with scope.span("ckpt.checksum"):
                 R = int(payload["rows_per_chunk"])
@@ -747,6 +838,8 @@ def finish_delta(pending: PendingDelta) -> Dict[str, Any]:
                     payloads[name] = dict(rows.header, **{
                         f: np.asarray(a)[:rows.rows]
                         for f, a in zip(rows.fields, rows.arrays)})
+                elif isinstance(rows, _StagedKeys):
+                    payloads[name] = _keys_payload(rows)
                 else:
                     payloads[name] = rows
         pending.staged = {}             # the staging buffers are free
@@ -769,8 +862,10 @@ def finish_delta(pending: PendingDelta) -> Dict[str, Any]:
             entry["extra"] = opts["extra"]
         manifest["chain"].append(entry)
         manifest["last_seq"] = seq
-        if any("block_crc" in i for i in results.values()):
-            manifest["format"] = DELTA_FORMAT
+        manifest["format"] = max(int(manifest["format"]), *(
+            DELTA_FORMAT if "keys_exact" in i
+            else _BLOCKED_FORMAT if "block_crc" in i else _CHUNKED_FORMAT
+            for i in results.values()))
         # the commit point: before this rename readers replay the old
         # chain
         sync_point("ckpt.delta.commit")

@@ -16,16 +16,19 @@ is the 4% that changed (PERF.md, PR 32). :class:`RowTracker` also keeps
 the fresh marks in arrival order, so a snapshot costs by the rows
 marked, not by a scan of the table's flags. Callers that pass
 ``target_chunks`` keep chunks of contiguous rows (a delta file is then a
-run of row ranges), hash tables keep ``key % n`` chunks, and the offload
-tier uses ``rows_per_chunk=1`` over its own book.
+run of row ranges) and ``key % n`` chunks of a hash table; without it a
+hash table is tracked to the KEY (:class:`KeyTracker`: the same hashed
+keys land in every ``key % n`` chunk too). The offload tier uses
+``rows_per_chunk=1`` over its own book.
 
 Mapping:
 
 * array tables: logical row id -> chunk ``id // rows_per_chunk``
   (:meth:`DirtyTracker.mark_rows`); a delta chunk is the contiguous
   logical range ``[c * R, min((c+1) * R, vocab))``.
-* hash tables: 64-bit key -> chunk ``key % num_chunks``
-  (:meth:`DirtyTracker.mark_keys`); a delta chunk is the set of live
+* hash tables: the 64-bit key itself (:class:`KeyTracker`); with
+  ``target_chunks``, 64-bit key -> chunk ``key % num_chunks``
+  (:meth:`DirtyTracker.mark_keys`), a delta chunk being the set of live
   keys whose joined 64-bit value falls in it. Stable across key-width
   migrations (the owner rule uses the same joined value).
 * optimizer slots are co-indexed with their weights — the same chunk
@@ -46,6 +49,7 @@ from typing import Optional
 import numpy as np
 
 from .analysis.concurrency import make_lock, sync_point
+from .utils.hashing import mix64
 
 
 class DirtyTracker:
@@ -297,11 +301,121 @@ def make_array_tracker(name: str, vocab: int,
                         name=name, lock=lock)
 
 
+class KeyTracker:
+    """Dirty set of a hash table, exact to the key: the 64-bit keys marked
+    since the last snapshot, each once, in arrival order.
+
+    Hashed keys have no row id to flag, and ``key % n`` chunks say nothing
+    about them: a step's ~35k distinct 62-bit keys land in every one of a
+    thousand chunks, so a chunked delta is the whole table. The set is an
+    open-addressing table of int64 on the host (linear probing, at most
+    half full, never deleted from between two snapshots) beside a log of
+    the keys it took in: a mark costs one ``np.unique`` of the batch and a
+    few vectorized probes of its distinct keys, a snapshot hands the log
+    out and wipes the table. Keys equal to the table's EMPTY sentinel
+    (what padding joins to) are the caller's to drop.
+
+    The surface a delta save uses is :class:`DirtyTracker`'s
+    (``mark_keys``, ``snapshot_clear``, ``restore``, ``dirty_count``), with
+    keys where that has chunk ids; the same lock and sync points.
+    """
+
+    EMPTY = np.int64(np.iinfo(np.int64).min)
+
+    def __init__(self, *, name: str = "", lock=None):
+        self.name = name
+        self._tab = np.full(_LOG_START, self.EMPTY, np.int64)
+        self._log = np.empty(_LOG_START, np.int64)
+        self._logged = 0
+        self._lock = lock if lock is not None \
+            else make_lock(f"dirty.{name or 'tracker'}")
+
+    def _place(self, keys: np.ndarray) -> np.ndarray:
+        """Under the lock: put DISTINCT ``keys`` into the table; the ones
+        it did not hold. Contenders for one free slot all write, one stays,
+        the others go on to the next slot with the keys that met another."""
+        tab, mask = self._tab, np.int64(self._tab.size - 1)
+        at = (mix64(keys) & np.uint64(mask)).astype(np.int64)
+        fresh = []
+        while keys.size:
+            cur = tab[at]
+            free = cur == self.EMPTY
+            if free.any():
+                tab[at[free]] = keys[free]
+                cur = tab[at]
+                fresh.append(keys[free & (cur == keys)])
+            left = cur != keys
+            keys, at = keys[left], (at[left] + 1) & mask
+        return np.concatenate(fresh) if fresh else keys
+
+    def mark_keys(self, keys64, *, distinct: bool = False) -> None:
+        """Mark 64-bit keys (``distinct``: the caller has made them so)."""
+        keys = np.asarray(keys64, np.int64).ravel()
+        if not distinct:
+            keys = np.unique(keys)
+        if not keys.size:
+            return
+        sync_point("dirty.mark")
+        with self._lock:
+            fresh = self._place(keys)
+            end = self._logged + fresh.size
+            if end > self._log.size:
+                grown = np.empty(max(end, 2 * self._log.size), np.int64)
+                grown[:self._logged] = self._log[:self._logged]
+                self._log = grown
+            self._log[self._logged:end] = fresh
+            self._logged = end
+            if 2 * end > self._tab.size:
+                size = self._tab.size
+                while 2 * end > size:
+                    size *= 2
+                self._tab = np.full(size, self.EMPTY, np.int64)
+                self._place(self._log[:end])
+
+    def snapshot_clear(self) -> np.ndarray:
+        """Atomically take the dirty keys and clear the set: the delta
+        writer's claim (a failed write :meth:`restore`s it)."""
+        with self._lock:
+            keys, size = self._log[:self._logged], self._log.size
+            if keys.size:
+                self._tab.fill(self.EMPTY)
+            self._log, self._logged = np.empty(size, np.int64), 0
+        sync_point("dirty.snapshot")
+        return keys
+
+    def restore(self, keys) -> None:
+        """Re-mark a failed writer's snapshot."""
+        sync_point("dirty.restore")
+        self.mark_keys(keys)
+
+    def dirty_keys(self) -> np.ndarray:
+        """The dirty keys in arrival order (a copy; they stay marked)."""
+        with self._lock:
+            return self._log[:self._logged].copy()
+
+    @property
+    def dirty_count(self) -> int:
+        with self._lock:
+            return self._logged
+
+    @property
+    def nbytes(self) -> int:
+        """Set and log bytes (graftwatch host-memory ledger)."""
+        with self._lock:
+            return int(self._tab.nbytes + self._log.nbytes)
+
+    def __repr__(self) -> str:  # pragma: no cover — debugging aid
+        return f"KeyTracker({self.name!r}, dirty={self.dirty_count})"
+
+
 def make_hash_tracker(name: str, capacity: int,
                       target_chunks: Optional[int] = None,
-                      lock=None) -> DirtyTracker:
-    """Tracker for a hash variable: key-space partitioned into
-    ``min(target_chunks, capacity)`` chunks by ``key % n`` (1,024 where
-    the caller names no count)."""
-    n = max(1, min(int(target_chunks or 1024), max(1, int(capacity))))
+                      lock=None):
+    """Tracker for a hash variable: exact to the key
+    (:class:`KeyTracker`). ``target_chunks`` asks for the key space
+    partitioned into ``min(target_chunks, capacity)`` chunks by ``key % n``
+    instead: a delta then ships every live key of a dirty chunk."""
+    if target_chunks is None:
+        return KeyTracker(name=name, lock=lock)
+    n = max(1, min(int(target_chunks), max(1, int(capacity))))
     return DirtyTracker(n, name=name, lock=lock)

@@ -204,9 +204,9 @@ class EmbeddingCollection:
     def enable_dirty_tracking(self, *, target_chunks: Optional[int] = None,
                               names=None) -> None:
         """Arm dirty tracking for every variable (idempotent): array
-        tables to the row, hash tables in 1,024 ``key % n`` chunks;
-        ``target_chunks`` asks for ~that many chunks of either instead
-        (``dirty.py``).
+        tables to the row, hash tables to the key; ``target_chunks`` asks
+        for ~that many chunks of either instead (contiguous rows, ``key %
+        n``: ``dirty.py``).
 
         ``names``: restrict tracking to a subset of variables. ONLY for
         variables whose rows persist through their own path — the
@@ -271,7 +271,8 @@ class EmbeddingCollection:
         if not self._dirty_trackers:
             return
         from . import hash_table as hash_lib
-        rows_of = {}    # tables fed one array (a fused table and its
+        from .dirty import KeyTracker
+        done = {}       # tables fed one array (a fused table and its
         for name, idx in sparse_inputs.items():   # linear twin): one sort
             tracker = self._dirty_trackers.get(name)
             if tracker is None or idx is None:
@@ -279,22 +280,35 @@ class EmbeddingCollection:
             if isinstance(idx, jax.core.Tracer):
                 continue
             spec = self.specs[name]
-            if not spec.use_hash and (id(idx), spec.input_dim) in rows_of:
-                tracker.mark_rows(rows_of[id(idx), spec.input_dim])
+            exact = isinstance(tracker, KeyTracker)
+            kind = (id(idx), "keys" if exact else spec.input_dim)
+            if kind in done:    # (key % n chunks keep nothing here)
+                if exact:
+                    tracker.mark_keys(done[kind], distinct=True)
+                else:
+                    tracker.mark_rows(done[kind])
                 continue
             arr = np.asarray(jax.device_get(idx)) \
                 if isinstance(idx, jax.Array) else np.asarray(idx)
             if spec.use_hash:
                 if spec.key_dtype == "wide" and arr.ndim >= 2 \
                         and arr.shape[-1] == 2:
-                    keys = hash_lib.join64(arr.reshape(-1, 2))
+                    pairs = arr.reshape(-1, 2)
+                    keys = hash_lib.join64(pairs)
+                    valid = pairs[:, 1] != hash_lib.empty_key(pairs.dtype)
                 else:
+                    valid = arr.ravel() != hash_lib.empty_key(arr.dtype)
                     keys = arr.astype(np.int64).ravel()
-                tracker.mark_keys(keys)
+                if not exact:
+                    tracker.mark_keys(keys)
+                    continue
+                # exact to the key: padding marks nothing
+                done[kind] = np.unique(keys if valid.all() else keys[valid])
+                tracker.mark_keys(done[kind], distinct=True)
             else:
                 ids = arr.astype(np.int64).ravel()
                 ids = np.unique(ids[(ids >= 0) & (ids < spec.input_dim)])
-                rows_of[id(idx), spec.input_dim] = ids
+                done[kind] = ids
                 tracker.mark_rows(ids)
 
     # --- introspection -----------------------------------------------------

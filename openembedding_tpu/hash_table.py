@@ -719,6 +719,32 @@ def pull_distinct(state: HashTableState, keys: jnp.ndarray,
     return read(state.keys, state.weights, state.init_rng, keys, valid)
 
 
+def snapshot_keys(table_keys: jnp.ndarray, arrays, query: jnp.ndarray,
+                  count: jnp.ndarray,
+                  max_probes: int = DEFAULT_MAX_PROBES):
+    """What a delta checkpoint takes of a hash table in the step's stream:
+    the rows of the keys ``query[:count]`` in every array of ``arrays``
+    (the weights, the optimizer's slots), in buffers of ``query``'s
+    length. The find is the push's (:func:`_find_levels`: a chunk of keys
+    a trip up to the last valid one, the key array read where it is)
+    under a stage of its own, ``ckpt_find``; the read by slot is
+    ``table.snapshot_rows``, stage ``ckpt_gather``. A key the table does
+    not hold reads ``found`` false and a zero row; nothing is inserted.
+    ``(found [n], rows of each array)``."""
+    query = check_key_dtype(table_keys, query)
+    empty = empty_key(table_keys.dtype)
+    valid = ((query[:, 1] if is_wide(query) else query) != empty) \
+        & (jnp.arange(query.shape[0], dtype=jnp.int32) < count)
+    find = scope.stage("ckpt_find")(
+        lambda tkeys, query, valid: _find_levels(tkeys, query, valid,
+                                                 max_probes)[0])
+    slot = find(table_keys, query, valid)
+    found = slot >= 0
+    oob = jnp.asarray(table_keys.shape[0], jnp.int32)
+    return found, table_lib.snapshot_rows(
+        arrays, jnp.where(found, slot, oob), count)
+
+
 def merge_gradients(state: HashTableState,
                     initializer: Any,
                     indices: jnp.ndarray,

@@ -334,6 +334,43 @@ def read_rows_sharded(state: hash_lib.HashTableState, keys: jnp.ndarray, *,
         state.keys, state.weights, state.slots, keys)
 
 
+@functools.lru_cache(maxsize=None)
+def _snapshot_keys_program(mesh: Mesh, spec: HashShardingSpec, arrays: int):
+    """Cached find-and-gather program of :func:`snapshot_keys_sharded`;
+    one compile a staging length."""
+
+    def ckpt_gather_keys(tkeys, arrays, k, count):
+        flat = k.reshape(-1, 2) if spec.wide else k.ravel()
+        if spec.num_shards > 1:     # a key another shard owns: not found,
+            flat = _mask_non_owned(spec, flat, _my_shard(mesh, spec))
+        found, staged = hash_lib.snapshot_keys(
+            tkeys, arrays, flat, count, max_probes=spec.max_probes)
+        if spec.num_shards > 1:     # a zero row here; the owner's in the sum
+            found = lax.psum(found.astype(jnp.int32), spec.shard_axes) > 0
+            staged = [lax.psum(s, spec.shard_axes) for s in staged]
+        return found, staged
+
+    row = spec.row_spec()
+    fn = shard_map(ckpt_gather_keys, mesh=mesh,
+                   in_specs=(row, [row] * arrays, P(), P()),
+                   out_specs=(P(), [P()] * arrays), check_vma=False)
+    return jax.jit(fn)
+
+
+def snapshot_keys_sharded(table_keys, arrays, keys: jnp.ndarray, count, *,
+                          mesh: Mesh, spec: HashShardingSpec):
+    """Find the keys ``keys[:count]`` (replicated, the table's key form,
+    EMPTY past ``count``) and gather their rows of every sharded array of
+    ``arrays`` into replicated staging buffers of ``keys``'s length: a
+    delta checkpoint's snapshot of a hash table
+    (``hash_table.snapshot_keys``), the twin of
+    ``sharded_table.snapshot_rows_sharded``. ``(found [n], rows)``.
+    Nothing is donated, inserted or waited for: the program runs behind
+    whatever was dispatched before it."""
+    return _snapshot_keys_program(mesh, spec, len(arrays))(
+        table_keys, list(arrays), keys, jnp.asarray(count, jnp.int32))
+
+
 @dataclasses.dataclass(frozen=True)
 class HashStore:
     """A hash table behind ``parallel/sharded.py``'s builder (which lists

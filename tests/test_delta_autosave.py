@@ -189,7 +189,7 @@ def test_entry_holds_the_table_at_its_step_not_what_the_rows_became(
         clear_schedule()
     assert hold.wrote_after >= 6    # written once steps 4 and 5 had run
     manifest = cd.read_manifest(path)
-    assert manifest["format"] == cd.DELTA_FORMAT
+    assert manifest["format"] == 2      # array rows: block checksums
     assert [e["step"] for e in manifest["chain"]] == [3, 6]
     assert [e["extra"]["fit"]["cursor"] for e in manifest["chain"]] == [3, 6]
 
@@ -320,7 +320,7 @@ def test_failed_write_marks_its_rows_again(mesh, tmp_path):
 def test_chunked_chains_stay_format_1_and_both_formats_load(mesh, tmp_path):
     batches = _batches(4, seed=5)
     for tracking, fmt, crc in (({"target_chunks": 8}, 1, "chunk_crc"),
-                               ({}, cd.DELTA_FORMAT, "block_crc")):
+                               ({}, 2, "block_crc")):
         path = str(tmp_path / f"format{fmt}")
         tr, state = _start(mesh, batches, **tracking)
         ckpt.save_checkpoint(path, tr.collection, state.emb, mode="delta")
@@ -371,3 +371,372 @@ def test_native_reader_takes_a_row_exact_chain(mesh, tmp_path):
     cd._write_manifest(path, manifest)
     with NativeModel(path, native_lib) as m:      # torn tail: one back
         assert m.version == 1
+
+
+# --- hash tables: a dirty set exact to the key --------------------------------
+
+from benchmark import reference_chain_keys  # noqa: E402
+from openembedding_tpu import hash_table as hash_lib  # noqa: E402
+from openembedding_tpu.dirty import KeyTracker, make_hash_tracker  # noqa: E402
+
+HASH_CAPACITY = 8192
+KEYED = [("int32", (1, 1)), ("wide", (1, 1)), ("int32", (1, 2)),
+         ("wide", (1, 2))]
+KEYED_IDS = [f"{k}-{d}x{m}" for k, (d, m) in KEYED]
+
+
+def _hash_trainer(devices, key_dtype, shape):
+    import optax
+    from openembedding_tpu import EmbeddingCollection, Trainer
+    from openembedding_tpu.fused import make_fused_specs
+    from openembedding_tpu.models import deepctr
+    mesh = create_mesh(*shape, devices[:shape[0] * shape[1]])
+    specs, mapper = make_fused_specs(
+        FEATURES, -1, DIM,
+        optimizer={"category": "adagrad", "learning_rate": 0.1},
+        hash_capacity=HASH_CAPACITY, key_dtype=key_dtype)
+    coll = EmbeddingCollection(specs, mesh)
+    coll.enable_dirty_tracking()
+    return Trainer(deepctr.build_model("deepfm", FEATURES), coll,
+                   optax.adam(1e-2)), mapper
+
+
+def _hash_batches(mapper, n, key_dtype, seed=0, batch=B):
+    """Batches whose id range grows with the step, so that every period
+    re-touches earlier keys and inserts keys no step before it pushed."""
+    rng = np.random.RandomState(seed)
+    dtype = np.int32 if key_dtype == "int32" else np.int64
+    scale = 1 if key_dtype == "int32" else 10 ** 9 + 7
+    out = []
+    for t in range(n):
+        ids = rng.randint(0, 24 + 8 * t, size=batch).astype(dtype) * scale
+        out.append(mapper.fuse_batch({
+            "label": (ids % 2).astype(np.float32),
+            "dense": rng.randn(batch, 4).astype(np.float32),
+            "sparse": {f: ids for f in FEATURES}}))
+    return out
+
+
+def _prefilled(tr, mapper, batches, key_dtype):
+    """A state whose tables hold a thousand keys a feature before anything
+    is saved: the base then stays well above twice the chain, and the
+    compactor leaves the entries these tests read on disk."""
+    dtype = np.int32 if key_dtype == "int32" else np.int64
+    ids = (np.arange(1024) + 5000).astype(dtype)
+    fill = mapper.fuse_batch({
+        "label": np.zeros(1024, np.float32),
+        "dense": np.zeros((1024, 4), np.float32),
+        "sparse": {f: ids for f in FEATURES}})
+    state = tr.init(jax.random.PRNGKey(0), tr.shard_batch(batches[0]))
+    return tr.fit(state, [fill])[0]
+
+
+def _keys_of(batches, name):
+    """Distinct 64-bit keys a run of fused batches pushes to ``name``."""
+    keys = np.concatenate([np.asarray(b["sparse"][name]) for b in batches])
+    keys = hash_lib.join64(keys.reshape(-1, 2)) if keys.shape[-1] == 2 \
+        and keys.ndim == 3 else keys.astype(np.int64).ravel()
+    return np.unique(keys)
+
+
+def _held(state):
+    """(keys sorted, weights, {slot: rows}) of a hash table's live keys."""
+    keys = np.asarray(state.keys)
+    live = reference_chain_keys.is_live(keys)
+    k64 = reference_chain_keys.keys64(keys[live])
+    order = np.argsort(k64)
+    return (k64[order], np.asarray(state.weights)[live][order],
+            {s: np.asarray(v)[live][order] for s, v in state.slots.items()})
+
+
+def _assert_same_table(a, b):
+    ka, wa, sa = _held(a)
+    kb, wb, sb = _held(b)
+    np.testing.assert_array_equal(ka, kb)
+    np.testing.assert_array_equal(wa.view(np.uint32), wb.view(np.uint32))
+    for s in sa:
+        np.testing.assert_array_equal(sa[s].view(np.uint32),
+                                      sb[s].view(np.uint32))
+
+
+def _hash_live(tr, emb):
+    names = {tr.collection.variable_id(n): n for n in tr.collection.specs}
+
+    def live(vid, field, lo, hi):
+        state = emb[names[vid]]
+        array = state.keys if field == "keys" \
+            else state.weights if field == "weights" \
+            else state.slots[field[len("slot_"):]]
+        return np.asarray(array)[lo:hi]
+    return live
+
+
+def _chain_faults(tr, path, emb):
+    cd.join_compactor(path)     # a tiny base: the last save may fold
+    found = reference_chain_keys.compare(
+        path, _hash_live(tr, emb),
+        next(iter(emb.values())).keys.shape[0])
+    return {k: found[k] for k in ("mismatch_rows", "missing_keys",
+                                  "extra_keys")}, found["new_keys"]
+
+
+NO_FAULT = {"mismatch_rows": 0, "missing_keys": 0, "extra_keys": 0}
+
+
+def test_key_tracker_is_exact_to_the_key_and_keeps_arrival_order():
+    t = make_hash_tracker("h", 1 << 20)
+    assert isinstance(t, KeyTracker)
+    assert isinstance(make_hash_tracker("h", 1 << 20, 64), DirtyTracker)
+    a = np.array([5, 5 + 1024, -7, 1 << 61, 5], np.int64)
+    t.mark_keys(a)                       # key % 1024 would fold two of them
+    t.mark_keys(np.array([9, 5], np.int64))
+    assert t.dirty_count == 5
+    snap = t.snapshot_clear()
+    assert sorted(snap[:4]) == sorted({5, 5 + 1024, -7, 1 << 61})
+    assert snap[4] == 9 and t.dirty_count == 0
+    t.mark_keys(np.array([9], np.int64))
+    t.restore(snap)                      # a failed writer's claim comes back
+    assert sorted(t.snapshot_clear()) == sorted(set(snap.tolist()))
+    # the set grows past its first table and stays exact
+    rng = np.random.RandomState(1)
+    many = rng.randint(-2 ** 62, 2 ** 62, size=300_000).astype(np.int64)
+    for part in np.array_split(many, 7):
+        t.mark_keys(part)
+    assert t.dirty_count == np.unique(many).size
+    assert np.array_equal(np.sort(t.dirty_keys()), np.unique(many))
+    assert t.nbytes > 0 and t.snapshot_clear().size == t.dirty_count + \
+        np.unique(many).size and not t.snapshot_clear().size
+
+
+@pytest.mark.parametrize("key_dtype,shape", KEYED, ids=KEYED_IDS)
+def test_hash_chain_restores_the_live_table_key_for_key(
+        devices8, tmp_path, key_dtype, shape):
+    """N steps with K saves through ``fit``: base and chain restore, into
+    a fresh collection, exactly the live table's keys, weights and
+    accumulators, the keys inserted between saves among them; each entry
+    holds exactly the distinct keys pushed since the entry before."""
+    tr, mapper = _hash_trainer(devices8, key_dtype, shape)
+    batches = _hash_batches(mapper, 9, key_dtype)
+    state = _prefilled(tr, mapper, batches, key_dtype)
+    path = str(tmp_path / "auto")
+    state, _ = tr.fit(state, batches[:3])
+    info = ckpt.save_checkpoint(path, tr.collection, state.emb,
+                                mode="delta")
+    assert info["forced_full"]
+    state, _ = tr.fit(state, batches[3:], autosave_every=2,
+                      autosave_dir=path)
+    manifest = cd.read_manifest(path)
+    assert manifest["format"] == cd.DELTA_FORMAT == 3
+    assert [e["extra"]["fit"]["cursor"]
+            for e in manifest["chain"]] == [2, 4, 6]
+    held = reference_chain_keys.entry_keys(path)
+    for i, entry in enumerate(manifest["chain"]):
+        period = batches[3 + 2 * i:5 + 2 * i]
+        for name, record in entry["vars"].items():
+            want = _keys_of(period, name)
+            assert record["keys_exact"] and record["rows"] == want.size
+            assert "dirty_chunks" not in record
+            payload = cd._entry_payload(path, entry, name)
+            assert sorted(payload) == ["keys", "slot_accum", "weights"]
+            assert np.array_equal(np.sort(
+                reference_chain_keys.keys64(payload["keys"])), want)
+            assert held[i][tr.collection.variable_id(name)] == want.size
+    tr2, _ = _hash_trainer(devices8, key_dtype, shape)
+    loaded = ckpt.load_checkpoint(path, tr2.collection)
+    for name in tr.collection.specs:
+        _assert_same_table(loaded[name], state.emb[name])
+        assert int(loaded[name].insert_failures) == 0
+    # the plain reference agrees, and every entry brought new keys
+    faults, new_keys = _chain_faults(tr, path, state.emb)
+    assert faults == NO_FAULT
+    assert all(n > 0 for per_var in new_keys.values() for n in per_var)
+    assert _chain_faults(tr2, path, loaded)[0] == NO_FAULT
+
+
+@pytest.mark.parametrize("key_dtype,shape", KEYED[1::2], ids=KEYED_IDS[1::2])
+def test_entry_of_a_hash_table_holds_it_at_its_step(devices8, tmp_path,
+                                                    key_dtype, shape):
+    """Written while later steps push the same keys again and insert
+    others, an entry equals the table at the entry's own step."""
+    tr, mapper = _hash_trainer(devices8, key_dtype, shape)
+    batches = _hash_batches(mapper, 6, key_dtype, seed=2)
+    state = _prefilled(tr, mapper, batches, key_dtype)
+    path = str(tmp_path / "auto")
+    ckpt.save_checkpoint(path, tr.collection, state.emb, mode="delta")
+    hold = HoldWriter(until=6)
+    install_schedule(hold)
+    try:
+        state, _ = tr.fit(state, list(batches), autosave_every=3,
+                          autosave_dir=path)
+    finally:
+        clear_schedule()
+    assert hold.wrote_after >= 6
+    tr2, mapper2 = _hash_trainer(devices8, key_dtype, shape)
+    s2 = _prefilled(tr2, mapper2, batches, key_dtype)
+    s2, _ = tr2.fit(s2, list(batches[:3]))
+    found = reference_chain_keys.compare(
+        path, _hash_live(tr2, s2.emb),
+        next(iter(s2.emb.values())).keys.shape[0], entries=1)
+    assert {k: found[k] for k in NO_FAULT} == NO_FAULT
+
+
+@pytest.mark.parametrize("key_dtype", ["int32", "wide"])
+def test_marked_key_the_table_lacks_is_skipped_and_counted(
+        devices8, tmp_path, key_dtype):
+    from openembedding_tpu.utils import observability as obs
+    tr, mapper = _hash_trainer(devices8, key_dtype, (1, 1))
+    batches = _hash_batches(mapper, 3, key_dtype, seed=3)
+    state = tr.init(jax.random.PRNGKey(0), tr.shard_batch(batches[0]))
+    path = str(tmp_path / "m")
+    state, _ = tr.fit(state, batches[:1])
+    ckpt.save_checkpoint(path, tr.collection, state.emb, mode="delta")
+    state, _ = tr.fit(state, batches[1:2])
+    tr.collection.mark_dirty(batches[2]["sparse"])  # marked, never pushed
+    absent = {n: np.setdiff1d(_keys_of(batches[2:], n),
+                              _keys_of(batches[:2], n)).size
+              for n in tr.collection.specs}
+    assert all(absent.values())
+    before = obs.GLOBAL.snapshot().get("ckpt_delta_keys_absent",
+                                       {}).get("count", 0.0)
+    info = cd.save_delta(path, tr.collection, state.emb, step=2,
+                         return_payload=True, background_compact=False,
+                         compact_bytes_ratio=1e18)
+    after = obs.GLOBAL.snapshot()["ckpt_delta_keys_absent"]["count"]
+    assert after - before == sum(absent.values())
+    for name, payload in info["delta"].vars.items():
+        want = np.intersect1d(_keys_of(batches[1:], name),
+                              _keys_of(batches[:2], name))
+        assert np.array_equal(np.sort(reference_chain_keys.keys64(
+            payload["keys"])), want)
+    assert _chain_faults(tr, path, state.emb)[0] == NO_FAULT
+    # nothing was inserted by the snapshot's find
+    assert int(state.emb[mapper.name].num_used()) \
+        == _keys_of(batches[:2], mapper.name).size
+
+
+def test_failed_write_puts_a_hash_tables_keys_back(devices8, tmp_path):
+    tr, mapper = _hash_trainer(devices8, "wide", (1, 1))
+    batches = _hash_batches(mapper, 2, "wide", seed=4)
+    state = tr.init(jax.random.PRNGKey(0), tr.shard_batch(batches[0]))
+    path = str(tmp_path / "auto")
+    ckpt.save_checkpoint(path, tr.collection, state.emb, mode="delta")
+    plan = chaos.FaultPlan([chaos.FaultSpec(
+        point="ckpt.delta.write", action="raise", hit=1)])
+    with chaos.active_plan(plan):
+        with pytest.raises(RuntimeError, match="autosave failed"):
+            tr.fit(state, list(batches), autosave_every=2,
+                   autosave_dir=path)
+    assert not cd.read_manifest(path)["chain"]
+    want = _keys_of(batches, mapper.name)
+    for tracker in tr.collection.dirty_trackers.values():
+        assert np.array_equal(np.sort(tracker.dirty_keys()), want)
+    tr2, _ = _hash_trainer(devices8, "wide", (1, 1))
+    s2 = tr2.init(jax.random.PRNGKey(0), tr2.shard_batch(batches[0]))
+    ckpt.save_checkpoint(path, tr2.collection, s2.emb, mode="delta")
+    s2, _ = tr2.fit(s2, list(batches))
+    info = ckpt.save_checkpoint(path, tr2.collection, s2.emb, mode="delta",
+                                step=2)
+    assert info["rows"] == want.size * len(tr2.collection.specs)
+    assert _chain_faults(tr2, path, s2.emb)[0] == NO_FAULT
+
+
+def test_format_2_chain_with_chunked_hash_records_still_loads(devices8,
+                                                              tmp_path):
+    """What PR 32's build wrote for a collection of both kinds: array
+    rows to the row (``block_crc``: format 2), hash keys in ``key % n``
+    chunks (``chunks`` / ``num_chunks`` members)."""
+    from test_delta_checkpoint import assert_states_equal, make_coll, train
+    mesh = create_mesh(2, 4, devices8)
+    coll = make_coll(mesh, track=False)
+    coll.enable_dirty_tracking(names={"arr"})
+    coll.enable_dirty_tracking(target_chunks=16, names={"hsh"})
+    states = coll.init(jax.random.PRNGKey(0))
+    path = str(tmp_path / "m")
+    ckpt.save_checkpoint(path, coll, states, mode="delta", step=0)
+    probes = []
+    for seed in (1, 2):
+        states, idx = train(coll, states, seed)
+        probes.append(np.asarray(idx["hsh"]))
+        cd.save_delta(path, coll, states, step=seed,
+                      background_compact=False, compact_bytes_ratio=1e18)
+    manifest = cd.read_manifest(path)
+    assert manifest["format"] == 2
+    record = manifest["chain"][-1]["vars"]["hsh"]
+    assert "dirty_chunks" in record and "keys_exact" not in record
+    payload = cd._entry_payload(path, manifest["chain"][-1], "hsh")
+    assert {"chunks", "num_chunks", "keys"} <= set(payload)
+    coll2 = make_coll(mesh, track=False)
+    loaded = ckpt.load_checkpoint(path, coll2)
+    assert_states_equal(coll, states, loaded,
+                        probe_keys=np.concatenate(probes))
+    # the same directory takes an entry exact to the key next
+    coll3 = make_coll(mesh, track=False)
+    coll3.enable_dirty_tracking()
+    states3 = ckpt.load_checkpoint(path, coll3)
+    states3, idx = train(coll3, states3, 3)
+    info = cd.save_delta(path, coll3, states3, step=3,
+                         background_compact=False, compact_bytes_ratio=1e18)
+    assert not info["skipped"]
+    assert cd.read_manifest(path)["format"] == 3
+    coll4 = make_coll(mesh, track=False)
+    assert_states_equal(coll3, states3, ckpt.load_checkpoint(path, coll4),
+                        probe_keys=np.concatenate(
+                            probes + [np.asarray(idx["hsh"])]))
+
+
+@pytest.mark.parametrize("compacted", [False, True],
+                         ids=["chain", "folded"])
+def test_serving_and_the_native_reader_take_keyed_entries(
+        devices8, tmp_path, compacted):
+    """``ModelRegistry.apply_delta``, ``read_delta`` / ``encode_delta``,
+    the compactor's fold and ``native/oe_serving.cc`` read entries that
+    are exact to the key, new keys among them."""
+    from test_delta_checkpoint import make_coll, train
+    from openembedding_tpu.serving.registry import ModelRegistry
+    mesh = create_mesh(2, 4, devices8)
+    coll = make_coll(mesh, track=False)
+    coll.enable_dirty_tracking()
+    states = coll.init(jax.random.PRNGKey(0))
+    path = str(tmp_path / "m")
+    states, idx0 = train(coll, states, 0)
+    ckpt.save_checkpoint(path, coll, states, mode="delta", step=0,
+                         model_sign="keyed")
+    reg = ModelRegistry(mesh, default_hash_capacity=2048)
+    sign = reg.create_model(path, block=True)
+    model = reg.find_model(sign)
+    probes = [np.asarray(idx0["hsh"])]
+    for seed in (1, 2):
+        states, idx = train(coll, states, seed)
+        probes.append(np.asarray(idx["hsh"]))
+        info = cd.save_delta(path, coll, states, step=seed,
+                             return_payload=True, background_compact=False,
+                             compact_bytes_ratio=1e18)
+        assert "chunks" not in info["delta"].vars["hsh"]
+        wire = cd.decode_delta(cd.encode_delta(cd.read_delta(path)))
+        assert np.array_equal(wire.vars["hsh"]["keys"],
+                              info["delta"].vars["hsh"]["keys"])
+        out = reg.apply_delta(sign, wire)
+        assert out["applied"] and model.version == seed
+    keys = np.concatenate(probes)
+    want = np.asarray(coll.pull(states, {"hsh": jnp.asarray(keys)},
+                                batch_sharded=False,
+                                read_only=True)["hsh"])
+    np.testing.assert_array_equal(
+        want, np.asarray(model.lookup("hsh", keys)))
+    if compacted:
+        assert cd.compact(path)["compacted"]
+        assert not cd.read_manifest(path)["chain"]
+    coll2 = make_coll(mesh, track=False)
+    loaded = ckpt.load_checkpoint(path, coll2)
+    np.testing.assert_array_equal(want, np.asarray(coll2.pull(
+        loaded, {"hsh": jnp.asarray(keys)}, batch_sharded=False,
+        read_only=True)["hsh"]))
+    from openembedding_tpu.serving import native
+    lib = native.build_library()
+    if lib is None:
+        pytest.skip("no C++ toolchain")
+    with native.NativeModel(path, lib) as m:
+        assert m.version == 2
+        np.testing.assert_array_equal(
+            m.lookup("hsh", keys.astype(np.int64)).astype(np.float32),
+            want.astype(np.float32))

@@ -420,6 +420,66 @@ def test_v5e_snapshot_gathers_rows_beside_the_table(v5e):
     assert memory.temp_size_in_bytes <= memory.output_size_in_bytes
 
 
+def test_v5e_snapshot_of_hash_keys_finds_and_gathers_beside_the_table(v5e):
+    """A delta save's snapshot at the hash autosave cell's size: the dim-9
+    wide-key table (2**26 slots: 512 MiB of keys, 4.5 GiB of rows and
+    accumulators as the compiler lays them out), 3,145,728 staged keys.
+    One program, named for its gather: a loop under ``ckpt_find`` that
+    reads bucket rows a chunk of keys a trip, a loop under ``ckpt_gather``
+    that reads rows a chunk a trip. It donates nothing, inserts nothing
+    (no scatter into, no output of, the key array) and holds no copy or
+    slice of the key array or of a table array: beside the tables it
+    costs what it returns and the slots it found."""
+    from openembedding_tpu import checkpoint_delta as cd
+    from openembedding_tpu import table as table_lib
+    from openembedding_tpu.parallel import sharded_hash as sh
+    mesh = create_mesh(1, 1, v5e[:1])
+    coll, _, mapper = chip_smoke.build_deepfm(
+        mesh, use_hash=True, rows_per_feature=ROWS_PER_FEATURE,
+        hash_capacity=HASH_CAPACITY)
+    spec = coll.sharding_spec(mapper.name)
+    dim = coll.specs[mapper.name].output_dim
+    assert cd._staging_rows(2_870_000) == SNAPSHOT_ROWS
+    row = NamedSharding(mesh, spec.row_spec())
+    whole = NamedSharding(mesh, P())
+    keys = jax.ShapeDtypeStruct((HASH_CAPACITY, 2), jnp.int32, sharding=row)
+    table = [jax.ShapeDtypeStruct((HASH_CAPACITY, dim), jnp.float32,
+                                  sharding=row)] * 2
+    compiled = sh._snapshot_keys_program(mesh, spec, 2).lower(
+        keys, table,
+        jax.ShapeDtypeStruct((SNAPSHOT_ROWS, 2), jnp.int32, sharding=whole),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)).compile()
+    hlo = compiled.as_text()
+    header = next(line for line in hlo.splitlines()
+                  if line.startswith("HloModule"))
+    assert "ckpt_gather" in header and "alias" not in header, header
+    paths = trace_reduce.scope_names(hlo)
+    found = [m.groups() for m in map(_OPCODE.match, hlo.splitlines()) if m]
+    loops = [inst for inst, op in found if op == "while"]
+    assert len(loops) == 2, loops
+    assert sorted(("ckpt_find" in paths[loop].split("/"),
+                   "ckpt_gather" in paths[loop].split("/"))
+                  for loop in loops) == [(False, True), (True, False)]
+    assert not [inst for inst, op in found if op == "scatter"]
+    chunk = table_lib.APPLY_CHUNK
+    gathers = [line for line in hlo.splitlines() if " gather(" in line]
+    assert gathers and all(
+        f"f32[{chunk},{dim}]" in g or f"s32[{chunk}," in g
+        for g in gathers), gathers
+    # neither the key array nor a table array is copied, sliced or made
+    wide = [line.strip()[:120] for line in hlo.splitlines()
+            if re.search(r" (copy|copy-start|slice|dynamic-slice|fusion)\(",
+                         line)
+            and int((re.search(r"= \(?\w+\[(\d+)", line) or [0, 0])[1])
+            > SNAPSHOT_ROWS]
+    assert not wide, wide
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 4 << 30
+    # the staged rows, and as much again at most: the found slots, the
+    # padded keys, the loops' carries
+    assert memory.temp_size_in_bytes <= 2 * memory.output_size_in_bytes
+
+
 def test_step_lowers_the_same_with_dirty_tracking_armed(v5e):
     """The marks stay on the host: arming the tracking changes nothing of
     the step's program (the array cell's step is the autosave cell's)."""
