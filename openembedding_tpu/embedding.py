@@ -31,7 +31,7 @@ configuration (hashable, safe to close over in jit).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +47,29 @@ from .parallel import sharded
 from .parallel import sharded_table as st
 from .parallel import sharded_hash as sh
 from .parallel.mesh import MODEL_AXIS
+from .utils import observability
 from . import ragged
+
+
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class SameColumns:
+    """Which input columns of one step hold the same ids as another:
+    ``(twin, base)`` names, as :meth:`EmbeddingCollection.same_columns`
+    observed them in the host batch. A pytree node without leaves: a
+    jitted step that takes it with its batch is cached a value, so that
+    the program can read both names from ONE traced column (two parameters
+    of equal value are two columns to it) and :meth:`EmbeddingCollection.
+    plan` can build the pair one ``dedup.Plan``."""
+
+    twins: Tuple[Tuple[str, str], ...] = ()
+
+    def bind(self, inputs: Dict[str, Any]) -> Dict[str, Any]:
+        """``inputs`` with every twin reading its base's column."""
+        out = dict(inputs)
+        for twin, base in self.twins:
+            out[twin] = out[base]
+        return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -457,6 +479,50 @@ class EmbeddingCollection:
         return out
 
     # --- data plane --------------------------------------------------------
+    def _plans(self, name: str, batch_sharded: bool = True) -> bool:
+        """:meth:`plan` covers ``name``'s column."""
+        sspec = self._shardings[name]
+        return not sspec.is_grouped and sharded.shares_plan(
+            sspec, self.mesh, batch_sharded)
+
+    def same_columns(self, inputs: Dict[str, Any]) -> SameColumns:
+        """Observe, on the host, which columns of a step's ``inputs`` are
+        the same ids, among those :meth:`plan` covers (none on the routed
+        planes: their step is the program it is without): the same object
+        (what ``FusedMapper.fuse`` hands a table and its ``:linear`` twin),
+        or host arrays equal in shape, dtype and value. The first of a
+        group is the base of the others. Counters
+        ``plan_columns_same_object`` / ``plan_columns_compared_equal`` /
+        ``plan_columns_differ`` say what each later column was found to
+        be."""
+        by_object, by_look, twins = {}, {}, []
+        for name, col in inputs.items():
+            if not self._plans(name):
+                continue
+            seen = "plan_columns_differ" if by_object else None
+            base = by_object.get(id(col))
+            if base is not None:
+                seen = "plan_columns_same_object"
+            elif isinstance(col, np.ndarray):
+                # value against value only where the first ids agree: a
+                # model of many features pays a look a column, not a pass
+                # over every pair
+                alike = by_look.setdefault(
+                    (col.shape, col.dtype.str, col.flat[:4].tobytes()), [])
+                base = next((b for b in alike
+                             if np.array_equal(col, inputs[b])), None)
+                if base is not None:
+                    seen = "plan_columns_compared_equal"
+                else:
+                    alike.append(name)
+            if base is None:
+                by_object[id(col)] = name
+            else:
+                twins.append((name, base))
+            if seen:
+                observability.GLOBAL.add(seen, 1)
+        return SameColumns(tuple(twins))
+
     def plan(self, inputs: Dict[str, jnp.ndarray], *,
              batch_sharded: bool = True) -> Dict[str, Any]:
         """One step's dedup of every input column whose table's pull and
@@ -467,16 +533,32 @@ class EmbeddingCollection:
         The pull then resolves each distinct key once and the push
         deduplicates nothing again; rows and updates are what they are
         without. A column left out (the routed planes, which dedup their
-        own sender slice; the grouped ones) runs as it does without."""
-        plans = {}
+        own sender slice; the grouped ones) runs as it does without.
+
+        One plan a distinct column: tables handed the SAME array (inside a
+        jit, the same traced value: :meth:`SameColumns.bind`) in the same
+        key form (``sharded.plan_form``: array ids, int32 hash keys, wide
+        pairs) get the same ``Plan`` object, built once; each store still
+        lays its own ownership mask over it and finds its own slots. Under
+        ``record_stats`` a step counts ``dedup_plans_built`` and
+        ``dedup_plan_tables``."""
+        plans, built = {}, {}
         for name, idx in inputs.items():
-            sspec = self._shardings[name]
-            if sspec.is_grouped or not sharded.shares_plan(
-                    sspec, self.mesh, batch_sharded):
+            if not self._plans(name, batch_sharded):
                 continue
-            plans[name] = sharded.plan_sharded(
-                self._widen(self.specs[name], idx), mesh=self.mesh,
-                store=self._stores[name], batch_sharded=batch_sharded)
+            store = self._stores[name]
+            keys = self._widen(self.specs[name], idx)
+            column = id(idx), sharded.plan_form(store, keys)
+            if column not in built:
+                built[column] = sharded.plan_sharded(
+                    keys, mesh=self.mesh, store=store,
+                    batch_sharded=batch_sharded)
+            plans[name] = built[column]
+        if plans and observability.evaluate_performance():
+            table_lib.record_stat("dedup_plans_built",
+                                  jnp.int32(len(built)), True)
+            table_lib.record_stat("dedup_plan_tables",
+                                  jnp.int32(len(plans)), True)
         return plans
 
     def pull(self, states: Dict[str, Any], inputs: Dict[str, jnp.ndarray],

@@ -82,12 +82,18 @@ class FusedMapper:
 
     @host_fn
     def fuse(self, sparse: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
-        """Per-feature columns -> {name: [B, F] fused ids} (+ :linear copy).
+        """Per-feature columns -> {name: [B, F] fused ids} (+ :linear twin).
 
         Host-side (numpy) BY CONTRACT (``@host_fn``): runs in the input
         pipeline like the reference's dataset-map hashing
         (criteo_deepctr.py:202-240); calling it on tracers inside a
         jitted step is exactly what graftlint rule JG002 flags.
+
+        The ``:linear`` entry is the SAME array object as the table's, not
+        a copy: ``Trainer.train_step`` sees that on the host
+        (``EmbeddingCollection.same_columns``) and the one-chip step then
+        deduplicates the column once for both tables (one ``dedup.Plan``).
+        A pipeline that copies it still shares, after one comparison.
         """
         cols = [np.asarray(sparse[f]) for f in self.feature_names]
         ids = np.stack(cols, axis=1)  # [B, F]

@@ -13,7 +13,9 @@ the EmbeddingCollection outside the module and passed in as a dict
 DeepCTR's embedding_dim-k / linear split. That keeps the flax params purely
 dense (replicated, optax-updated) while the sparse variables stay on the
 sharded PS-equivalent path — the same split the reference draws between
-tf.Variables and PS variables.
+tf.Variables and PS variables. A feature and its ``:linear`` twin read the
+same id column: fed the same array (or equal ones), they share the one-chip
+step's dedup, one ``dedup.Plan`` for both (``EmbeddingCollection.plan``).
 
 ``LINEAR_SUFFIX`` features are created by ``linear_spec_names`` /
 ``make_feature_specs`` in this module so models and spec builders agree.

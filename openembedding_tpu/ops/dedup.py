@@ -27,13 +27,16 @@ FILL = jnp.iinfo(jnp.int32).min
 
 @struct.dataclass
 class Plan:
-    """One table's keys of one train step, deduplicated once, in front of
-    the pull: what :func:`unique_indices` / :func:`unique_pairs` return,
+    """One id column's keys of one train step, deduplicated once, in front
+    of the pull: what :func:`unique_indices` / :func:`unique_pairs` return,
     kept so that the pull resolves each distinct key once and expands by
     ``inverse``, and the push that follows combines its gradients by the
     same ``inverse`` into the same slots (``table.merge_gradients``,
     ``hash_table.merge_gradients``). The capacity is the number of keys,
-    so no key overflows."""
+    so no key overflows. It holds the keys as they come and nothing of a
+    table: twin tables fed one column (a fused table and its ``:linear``
+    column) pull and push through the SAME plan, each laying its own
+    ownership mask over it (``EmbeddingCollection.plan``)."""
 
     uniq: jnp.ndarray       # [n] keys, [n, 2] wide ones; fill past the last
     inverse: jnp.ndarray    # [n]: uniq[inverse[i]] is key i

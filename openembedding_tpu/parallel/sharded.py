@@ -86,6 +86,9 @@ keys over their occupied prefix and expands by ``inverse``, and to the
 push, whose unique buffer it is. It serves both where both see the same
 keys (:func:`shares_plan`). The routed body dedups its sender slice itself
 (``alltoall.exchange_pull``), and any call without a plan runs as it did.
+Tables fed one column of ids in one key form (:func:`plan_form`) share that
+plan: it holds nothing of a store but the form, each store lays its own
+ownership mask over it (``EmbeddingCollection.plan``).
 """
 
 from __future__ import annotations
@@ -255,13 +258,24 @@ def _plan_program(mesh: Mesh, store, batch_sharded: bool):
                              check_vma=False))
 
 
+def plan_form(store, indices: jnp.ndarray) -> tuple:
+    """All that :func:`plan_sharded` of ``indices`` takes from ``store``:
+    the program's name, the axis the batch lies on, the shape of the key
+    stream and the fill. Two stores of one form give one column the same
+    plan, bit for bit, so their tables can share it."""
+    return (_program_name(store, "plan"), store.spec.data_axis,
+            indices.shape, jnp.dtype(indices.dtype),
+            store.batch_shape(indices.shape), store.sentinel(indices.dtype))
+
+
 def plan_sharded(indices: jnp.ndarray, *, mesh: Mesh, store,
                  batch_sharded: bool = True) -> dedup.Plan:
     """The :class:`dedup.Plan` of ``indices`` for one step's
     :func:`pull_sharded` and :func:`apply_gradients_sharded` through
     ``store``'s table, where :func:`shares_plan` holds: the keys as they
     come (no ownership mask: each store lays its own over the distinct
-    keys), deduplicated at full capacity."""
+    keys), deduplicated at full capacity. It serves every table whose
+    store has this one's :func:`plan_form`."""
     _require_shared(store.spec, mesh, batch_sharded)
     return _plan_program(mesh, store, batch_sharded)(indices)
 

@@ -35,9 +35,14 @@ from .analysis import scope
 from .analysis.concurrency import sync_point
 from .analysis.retrace import RetraceGuard
 from .utils import observability
-from .embedding import EmbeddingCollection
+from .embedding import EmbeddingCollection, SameColumns
 from .parallel import pipelined as pipeline_lib
 from .parallel.mesh import DATA_AXIS
+
+
+# the key under which a placed batch carries its ``SameColumns`` to the
+# serial step program (static: no array)
+SAME_COLUMNS = "same_columns"
 
 
 @struct.dataclass
@@ -161,6 +166,9 @@ class Trainer:
         self._replicated = NamedSharding(self.mesh, P())
         self._batch_sharding = NamedSharding(self.mesh, P(DATA_AXIS))
         self._train_step = None
+        # what the last serial step's batch was observed to be
+        # (``_place_step_batch``): the program ``lower_train_step`` lowers
+        self._same_columns = SameColumns()
         self._eval_step = None
         # hot-row replica admission drivers, one per "a2a+cache" variable:
         # the frequency sketch observes every stepped batch and the replica
@@ -288,9 +296,15 @@ class Trainer:
         ``compile().memory_analysis()`` and compiled-HLO audits. ``batch``
         is placed as :meth:`shard_batch` places it; ShapeDtypeStructs that
         carry the shardings lower it from shapes alone (AOT for a described
-        topology, ``tests/test_tpu_lowering.py``)."""
+        topology, ``tests/test_tpu_lowering.py``). Which of its columns
+        are the same ids shapes cannot say: the program is the one the
+        last :meth:`train_step` ran (the two-plan one if none has), or the
+        one of the ``SameColumns`` that ``batch`` brings under
+        ``SAME_COLUMNS``."""
         if self._train_step is None:
             self._train_step = self._build_train_step()
+        if SAME_COLUMNS not in batch:
+            batch = {**batch, SAME_COLUMNS: self._same_columns}
         return self._train_step.lower(state, batch)
 
     def _build_train_step(self):
@@ -298,8 +312,14 @@ class Trainer:
 
         def step_fn(state: TrainState, batch) -> tuple:
             pull_inputs, dense_ids = self._split_sparse(batch["sparse"])
-            # a table's ids are deduplicated once a step, in front of the
-            # pull: pull and push both work on the distinct keys
+            # columns the host saw to be the same ids are ONE traced
+            # column (two parameters of equal value are two to the
+            # compiler; a twin's own is unused, and pruned)
+            pull_inputs = batch.get(SAME_COLUMNS, SameColumns()).bind(
+                pull_inputs)
+            # a column's ids are deduplicated once a step, in front of
+            # the pull: pull and push of every table that reads it work
+            # on the distinct keys
             plan = collection.plan(pull_inputs)
             rows = collection.pull(state.emb, pull_inputs, plan=plan)
             # The push's find and insert need nothing of the dense pass:
@@ -482,7 +502,7 @@ class Trainer:
                         state, batch, next_batch, at)
                 else:
                     with scope.span("trainer.place_batch", detail=at):
-                        placed = self.shard_batch(batch)
+                        placed = self._place_step_batch(batch)
                     with scope.span("trainer.dispatch", detail=at):
                         state, metrics = self._train_step(state, placed)
                 with scope.span("trainer.bookkeeping", detail=at):
@@ -683,6 +703,17 @@ class Trainer:
         device takes its slice straight from host memory (a jnp.asarray
         first would commit the whole batch to device 0 and reshard)."""
         return jax.device_put(batch, self._batch_sharding)
+
+    def _place_step_batch(self, batch):
+        """:meth:`shard_batch` for the serial step program, with what the
+        host sees of its sparse columns and the program cannot: which of
+        them are the same ids (``EmbeddingCollection.same_columns``). The
+        note is static, so the step is compiled once a value: tables fed
+        one column run ONE ``dedup.Plan``, a batch whose columns differ
+        the program with a plan each."""
+        cols, _ = self._split_sparse(batch["sparse"])
+        self._same_columns = self.collection.same_columns(cols)
+        return {**self.shard_batch(batch), SAME_COLUMNS: self._same_columns}
 
     def model_sign(self, state: TrainState) -> str:
         """Version-stamped serving signature for this state."""

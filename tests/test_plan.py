@@ -7,7 +7,15 @@ array, int32-key and wide-key hash tables, whatever the ids (padding, all
 one key, all distinct, the distinct keys ending on a chunk boundary), and
 a hash key never pushed reads its init row. ``Trainer.train_step`` builds
 the plan; three steps of it leave the state three steps without leave.
+
+One plan a distinct id column: tables fed the same ids (a fused table and its
+``:linear`` twin: the same host array, or an equal copy) run ONE plan a step,
+which leaves what a plan each leaves, bit for bit; columns that differ keep a
+plan each; the routed 2x2 step is the program it is without; and
+``lower_train_step`` lowers the program the steps ran.
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -15,11 +23,17 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from openembedding_tpu import EmbeddingCollection, EmbeddingSpec
+import optax
+
+from openembedding_tpu import EmbeddingCollection, EmbeddingSpec, Trainer
 from openembedding_tpu import hash_table as hash_lib
 from openembedding_tpu import table as table_lib
+from openembedding_tpu.embedding import SameColumns
+from openembedding_tpu.fused import LINEAR_SUFFIX, make_fused_specs
+from openembedding_tpu.models import deepctr
 from openembedding_tpu.parallel import sharded
 from openembedding_tpu.parallel.mesh import create_mesh
+from openembedding_tpu.training import SAME_COLUMNS
 from openembedding_tpu.utils import observability
 
 CHUNK = 8           # table.APPLY_CHUNK and FIND_CHUNK, set small for these
@@ -143,7 +157,8 @@ def _three_steps(name, seed, planned, monkeypatch):
     """The state three ``Trainer.train_step``s leave on the rehearsal
     configuration ``name``; without ``planned`` the step's plan is empty,
     which makes it ``collection.pull`` and ``apply_gradients`` as they run
-    without one."""
+    without one; ``planned="each"`` hides from the step that its two
+    tables are fed one array, which makes it a plan a table."""
     from benchmark import offload_system, run as bench_run, system
     from benchmark.traffic_gen import zipf_train
 
@@ -153,6 +168,9 @@ def _three_steps(name, seed, planned, monkeypatch):
     built = lib.build(config)
     if not planned:
         monkeypatch.setattr(built.coll, "plan", lambda *a, **kw: {})
+    elif planned == "each":
+        monkeypatch.setattr(built.coll, "same_columns",
+                            lambda inputs: SameColumns())
     state = lib.initial_state(built, seed, on_device=False)
     pool = [system.program_batch(built, raw)
             for raw in zipf_train.make(traffic, config, seed)]
@@ -182,6 +200,7 @@ def test_three_train_steps_leave_the_state_they_left(name, monkeypatch):
     try:
         want = _three_steps(name, 3300000007, False, monkeypatch)
         assert "pull_positions" not in observability.GLOBAL.snapshot()
+        observability.GLOBAL.reset()
         got = _three_steps(name, 3300000007, True, monkeypatch)
         counted = observability.GLOBAL.snapshot()
     finally:
@@ -196,3 +215,309 @@ def test_three_train_steps_leave_the_state_they_left(name, monkeypatch):
     assert positions == 3 * 2 * 64 * 26          # steps, tables, batch, ids
     assert 0 < live <= walked <= positions
     assert walked % STEP_CHUNK == 0 and walked < positions
+    # the mapper hands both tables one array: one plan a step for the two
+    assert _counts(counted) == {"plan_columns_same_object": 3,
+                                "dedup_plans_built": 3,
+                                "dedup_plan_tables": 6}
+
+
+# --- one plan a distinct id column ------------------------------------------
+
+PLAN_COUNTERS = ("plan_columns_same_object", "plan_columns_compared_equal",
+                 "plan_columns_differ", "dedup_plans_built",
+                 "dedup_plan_tables")
+FEATURES = ("c0", "c1", "c2", "c3")
+STEPS, BATCH = 3, 32
+
+
+def _counts(snapshot):
+    return {k: int(snapshot[k]["count"]) for k in PLAN_COUNTERS
+            if k in snapshot}
+
+
+def _fused_trainer(kind):
+    """A DeepFM over one fused table and its ``:linear`` twin on one
+    device, as ``examples/criteo_deepctr.py`` builds it."""
+    mesh = create_mesh(1, 1, jax.devices()[:1])
+    specs, mapper = make_fused_specs(
+        FEATURES, VOCAB if kind == "array" else -1, DIM,
+        optimizer={"category": "adagrad", "learning_rate": 0.1},
+        hash_capacity=1024,
+        key_dtype="int32" if kind == "hash32" else "wide")
+    coll = EmbeddingCollection(specs, mesh)
+    return coll, Trainer(deepctr.build_model("deepfm", FEATURES), coll,
+                         optax.adam(1e-2)), mapper
+
+
+def _fused_batches(mapper, twin):
+    """``STEPS`` batches of the mapper's own; the ``:linear`` column is the
+    table's array (``"same"``: what ``fuse`` returns), an equal
+    ``"copy"``, or the ids of other examples (``"differ"``)."""
+    rng = np.random.RandomState(21)
+    for _ in range(STEPS):
+        sparse = mapper.fuse({
+            f: (np.minimum(rng.zipf(1.3, size=BATCH), VOCAB) - 1)
+            .astype(np.int32) for f in FEATURES})
+        assert sparse[mapper.name + LINEAR_SUFFIX] is sparse[mapper.name]
+        if twin == "copy":
+            sparse[mapper.name + LINEAR_SUFFIX] = sparse[mapper.name].copy()
+        elif twin == "differ":
+            sparse[mapper.name + LINEAR_SUFFIX] = np.roll(
+                sparse[mapper.name], 1, axis=0)
+        yield {"label": (rng.rand(BATCH) < 0.3).astype(np.float32),
+               "dense": rng.randn(BATCH, 4).astype(np.float32),
+               "sparse": sparse}
+
+
+def _fused_steps(kind, twin, patch=None):
+    """(losses, state and scores, plan counters) of ``STEPS`` steps under
+    ``record_stats``; ``patch(collection)`` first."""
+    for program in PROGRAMS:
+        program.cache_clear()
+    coll, trainer, mapper = _fused_trainer(kind)
+    if patch:
+        patch(coll)
+    batches = list(_fused_batches(mapper, twin))
+    observability.GLOBAL.reset()
+    observability.set_evaluate_performance(True)
+    try:
+        state = trainer.init(jax.random.PRNGKey(3), batches[0])
+        losses = []
+        for b in batches:
+            state, metrics = trainer.train_step(state, b)
+            losses.append(float(metrics["loss"]))
+        jax.effects_barrier()
+        counted = _counts(observability.GLOBAL.snapshot())
+    finally:
+        observability.set_evaluate_performance(False)
+        observability.GLOBAL.reset()
+        for program in PROGRAMS:
+            program.cache_clear()
+    scores = trainer.eval_step(state, batches[0])
+    return losses, jax.device_get(
+        (state.params, state.opt_state, state.emb, scores)), counted
+
+
+def _a_plan_each(coll):
+    """What the step ran before tables shared a plan: nothing is seen to
+    be the same."""
+    coll.same_columns = lambda inputs: SameColumns()
+
+
+def _no_plan(coll):
+    coll.plan = lambda *a, **kw: {}
+
+
+@pytest.mark.parametrize("twin,seen", [
+    ("same", "plan_columns_same_object"),
+    ("copy", "plan_columns_compared_equal")])
+@pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
+def test_tables_fed_one_column_share_one_plan(kind, twin, seen):
+    """The same array or an equal copy: one plan a step for the two tables,
+    and losses, dense state, tables and scores are what a plan each
+    leaves, bit for bit."""
+    want = _fused_steps(kind, twin, _a_plan_each)
+    assert want[2] == {"dedup_plans_built": 2 * STEPS,
+                       "dedup_plan_tables": 2 * STEPS}
+    got = _fused_steps(kind, twin)
+    assert got[2] == {seen: STEPS, "dedup_plans_built": STEPS,
+                      "dedup_plan_tables": 2 * STEPS}
+    assert got[0] == want[0]
+    _same(got[1], want[1])
+
+
+@pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
+def test_columns_that_differ_keep_a_plan_each(kind):
+    """Nothing is bound: two plans a step, and the state the step leaves
+    without any plan (``collection.pull`` and ``apply_gradients`` as they
+    run alone)."""
+    got = _fused_steps(kind, "differ")
+    assert got[2] == {"plan_columns_differ": STEPS,
+                      "dedup_plans_built": 2 * STEPS,
+                      "dedup_plan_tables": 2 * STEPS}
+    want = _fused_steps(kind, "differ", _no_plan)
+    assert got[0] == want[0]
+    _same(got[1], want[1])
+    shared = _fused_steps(kind, "same")
+    assert shared[0] != got[0]          # other ids do train another model
+
+
+def test_a_plan_is_shared_in_one_key_form_alone(devices8):
+    """One array handed to an array table, an int32-key and a wide-key
+    hash table and a second array table: the two array tables share a
+    plan, each hash table has its own (``sharded.plan_form``), and every
+    table pulls what it pulls alone."""
+    mesh = create_mesh(1, 1, devices8[:1])
+    kinds = {"a": "array", "h": "hash32", "w": "hashwide", "b": "array"}
+    specs = [EmbeddingSpec(
+        name=name, input_dim=VOCAB if kind == "array" else -1,
+        output_dim=DIM if name != "b" else 1, hash_capacity=1024,
+        key_dtype={"array": None, "hash32": "int32",
+                   "hashwide": "wide"}[kind]) for name, kind in kinds.items()]
+    coll = EmbeddingCollection(specs, mesh)
+    states = coll.init(jax.random.PRNGKey(5))
+    ids = jnp.asarray(_ids("zipf", 0))
+    inputs = {name: ids for name in kinds}
+    assert coll.same_columns(inputs).twins == (
+        ("h", "a"), ("w", "a"), ("b", "a"))
+    plan = coll.plan(inputs)
+    assert plan["a"] is plan["b"]
+    assert len({id(p) for p in plan.values()}) == 3
+    assert plan["w"].uniq.shape == (N, 2) and plan["h"].uniq.shape == (N,)
+    _same(coll.pull(states, inputs, plan=plan), coll.pull(states, inputs))
+    # a column of its own, equal or not, is a plan of its own
+    apart = coll.plan(dict(inputs, b=jnp.asarray(_ids("zipf", 0))))
+    assert apart["a"] is not apart["b"]
+    _same(apart["a"], apart["b"])
+
+
+def test_same_columns_are_seen_on_the_host_alone(devices8):
+    """Device arrays are compared by identity, never read back; a routed
+    plane is left as it is; the counters say what each column was."""
+    one = create_mesh(1, 1, devices8[:1])
+    coll, _ = _collection("array", one, "a2a")
+    assert coll.same_columns({"t": np.zeros(4, np.int32)}).twins == ()
+    specs = [EmbeddingSpec(name=n, input_dim=VOCAB, output_dim=DIM)
+             for n in ("t", "u", "v")]
+    ids = _ids("zipf", -1)
+    cases = [
+        ({"t": ids, "u": ids, "v": ids.copy()}, (("u", "t"), ("v", "t")),
+         {"plan_columns_same_object": 1, "plan_columns_compared_equal": 1}),
+        ({"t": ids, "u": ids + 1, "v": ids + 1}, (("v", "u"),),
+         {"plan_columns_differ": 1, "plan_columns_compared_equal": 1}),
+        ({"t": ids, "u": ids.astype(np.int64), "v": ids.reshape(-1)}, (),
+         {"plan_columns_differ": 2}),
+        ({"t": jnp.asarray(ids), "u": jnp.asarray(ids), "v": ids}, (),
+         {"plan_columns_differ": 2}),
+    ]
+    for inputs, twins, counted in cases:
+        observability.GLOBAL.reset()
+        try:
+            got = EmbeddingCollection(specs, one).same_columns(inputs)
+            assert got.twins == twins
+            assert _counts(observability.GLOBAL.snapshot()) == counted
+        finally:
+            observability.GLOBAL.reset()
+        assert EmbeddingCollection(
+            specs, create_mesh(2, 2, devices8[:4])).same_columns(
+                inputs).twins == ()
+    bound = SameColumns((("u", "t"),)).bind({"t": 1, "u": 2, "v": 3})
+    assert bound == {"t": 1, "u": 1, "v": 3}
+
+
+@pytest.mark.parametrize("name", ["tiny_array", "tiny_hash", "tiny_offload"])
+def test_one_plan_leaves_what_a_plan_each_leaves(name, monkeypatch):
+    """At the rehearsal shapes of the array, hash and offload cells: three
+    steps through one plan a step leave the losses, dense state, tables
+    (and host store) that a plan a table leaves."""
+    monkeypatch.setattr(table_lib, "APPLY_CHUNK", STEP_CHUNK)
+    monkeypatch.setattr(table_lib, "FIND_CHUNK", STEP_CHUNK)
+    for program in PROGRAMS:
+        program.cache_clear()
+    observability.GLOBAL.reset()    # of what other tests' steps counted
+    observability.set_evaluate_performance(True)
+    try:
+        want = _three_steps(name, 3500000011, "each", monkeypatch)
+        each = _counts(observability.GLOBAL.snapshot())
+        observability.GLOBAL.reset()
+        got = _three_steps(name, 3500000011, True, monkeypatch)
+        shared = _counts(observability.GLOBAL.snapshot())
+    finally:
+        observability.set_evaluate_performance(False)
+        observability.GLOBAL.reset()
+        for program in PROGRAMS:
+            program.cache_clear()
+    assert each == {"dedup_plans_built": 6, "dedup_plan_tables": 6}
+    assert shared == {"plan_columns_same_object": 3, "dedup_plans_built": 3,
+                      "dedup_plan_tables": 6}
+    assert got[0] == want[0]
+    _same(got[1], want[1])
+
+
+# --- the program the steps ran ----------------------------------------------
+
+def _tiny(name, seed=3500000021):
+    from benchmark import run as bench_run, system
+    from benchmark.traffic_gen import zipf_train
+
+    config = bench_run.load("configs", name)
+    built = system.build(config)
+    traffic = dict(bench_run.load("traffic", "train_zipf"), pool_batches=2)
+    pool = [system.program_batch(built, raw)
+            for raw in zipf_train.make(traffic, config, seed)]
+    return system, built, pool
+
+
+def _abstract_step(built, batch):
+    """(state, batch) as ``benchmark.system.step_hlo`` hands them to
+    ``lower_train_step``: fresh ShapeDtypeStructs, one a name."""
+    state = jax.eval_shape(built.trainer.init, jax.random.PRNGKey(0), batch)
+    replicated = jax.sharding.NamedSharding(
+        built.mesh, jax.sharding.PartitionSpec())
+
+    def placed(tree, shardings):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, shardings)
+
+    state = state.replace(
+        emb=placed(state.emb, built.coll.state_shardings()),
+        **{k: placed(getattr(state, k), jax.tree.map(
+            lambda _: replicated, getattr(state, k)))
+           for k in ("step", "params", "opt_state")})
+    batch = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, jax.dtypes.canonicalize_dtype(x.dtype),
+            sharding=built.by_batch), batch)
+    return state, batch
+
+
+def _plan_calls(lowered_text):
+    return re.findall(r"call @(\w*plan_a2a)\w*\(", lowered_text)
+
+
+@pytest.mark.parametrize("name,program", [("tiny_array", "plan_a2a"),
+                                          ("tiny_hash", "hash_plan_a2a")])
+def test_lower_train_step_lowers_the_program_the_steps_ran(name, program):
+    """``step_hlo``'s path: shapes under both names cannot say that the
+    two columns are one. Before any step the text is the two-plan
+    program's; after a step of the mapper's batch it calls the plan's
+    program once, and so it does for a batch that brings its own note;
+    after a step whose columns differ it is the two-plan text again."""
+    system, built, pool = _tiny(name)
+    trainer = built.trainer
+    abstract = _abstract_step(built, pool[0])
+    before = trainer.lower_train_step(*abstract).as_text()
+    assert _plan_calls(before) == [program] * 2
+    noted = dict(abstract[1], **{SAME_COLUMNS: built.coll.same_columns(
+        pool[0]["sparse"])})
+    one = trainer.lower_train_step(abstract[0], noted).as_text()
+    assert _plan_calls(one) == [program]
+
+    state = system.initial_state(built, 3500000021, on_device=False)
+    state, _ = trainer.train_step(state, pool[0])
+    assert trainer.lower_train_step(*abstract).as_text() == one
+    apart = dict(pool[1], sparse={
+        k: np.roll(v, int(k.endswith(LINEAR_SUFFIX)), axis=0)
+        for k, v in pool[1]["sparse"].items()})
+    state, _ = trainer.train_step(state, apart)
+    assert trainer.lower_train_step(*abstract).as_text() == before
+    jax.block_until_ready(state)
+
+
+def test_the_routed_step_is_the_program_it_is_without():
+    """2x2: no plan, nothing bound. The step of the mapper's batch (one
+    array under both names) lowers to the text of a batch whose columns
+    are apart, with both columns its parameters and no plan program."""
+    system, built, pool = _tiny("tiny_array_x4")
+    assert built.coll.same_columns(pool[0]["sparse"]) == SameColumns()
+    assert built.coll.plan(pool[0]["sparse"]) == {}
+    abstract = _abstract_step(built, pool[0])
+    text = built.trainer.lower_train_step(*abstract).as_text()
+    assert not _plan_calls(text) and "plan_a2a" not in text
+    state = system.initial_state(built, 3500000021, on_device=False)
+    state, _ = built.trainer.train_step(state, pool[0])
+    jax.block_until_ready(state)
+    assert built.trainer.lower_train_step(*abstract).as_text() == text
+    main = re.search(r"func\.func public @main\((.*?)\) ->", text, re.S)[1]
+    assert main.count("tensor<64x26xi32>") == 2, main[:400]

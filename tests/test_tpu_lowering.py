@@ -28,6 +28,7 @@ from openembedding_tpu.analysis import contracts
 from openembedding_tpu.data import criteo
 from openembedding_tpu.ops import pallas_gather as pg, pallas_hash as ph
 from openembedding_tpu.parallel.mesh import DATA_AXIS, create_mesh
+from openembedding_tpu.training import SAME_COLUMNS
 
 pytestmark = pytest.mark.slow
 
@@ -59,9 +60,15 @@ HASH_CAPACITY = 1 << 26
 
 
 def _abstract_step(mesh, coll, trainer, mapper, rows):
-    """(state, batch) of the step as shapes and shardings alone."""
+    """(state, batch) of the step as shapes and shardings alone, and with
+    them what a step sees in the mapper's host batch and shapes do not
+    say: the table and its ``:linear`` twin are fed one array, so on one
+    chip the program is the one-plan step (on 2x2 no plan is built, and
+    nothing is the same to it)."""
     batch = mapper.fuse_batch(next(iter(criteo.synthetic_criteo(
         chip_smoke.BATCH, num_buckets=rows, num_batches=1))))
+    same = coll.same_columns(batch["sparse"])
+    assert len(same.twins) == (mesh.size == 1)
     state = jax.eval_shape(trainer.init, jax.random.PRNGKey(0), batch)
     repl = NamedSharding(mesh, P())
     state = state.replace(
@@ -74,7 +81,7 @@ def _abstract_step(mesh, coll, trainer, mapper, rows):
         lambda x: jax.ShapeDtypeStruct(
             x.shape, jax.dtypes.canonicalize_dtype(x.dtype),
             sharding=by_batch), batch)
-    return state, batch
+    return state, {**batch, SAME_COLUMNS: same}
 
 
 @functools.lru_cache(maxsize=None)      # several tests read each program
@@ -242,14 +249,14 @@ def test_v5e_apply_walks_its_buffer_in_place(v5e, shape, use_hash):
 
 @pytest.mark.parametrize("use_hash", [False, True], ids=["array", "hash"])
 def test_v5e_one_chip_step_dedups_once_and_pulls_distinct_keys(v5e, use_hash):
-    """The step's plan (``dedup.Plan``) is built once a table, for pull and
-    push both: the one-chip step sorts as often as it did when the push
-    alone deduplicated (a ``lexsort`` a table for ids, an ``argsort`` a
-    key word a table for wide keys), all under the plan's program. The
-    pull reads the distinct keys' rows in a loop of chunk-sized gathers
-    under ``resolve`` and hands every position its row in one ``expand``
-    gather a table; no gather of the pull is as long as the batch's
-    positions but that one, and none of it sits under a conditional."""
+    """The step's plan (``dedup.Plan``) is built once a distinct id column,
+    for the pull and push of both tables that read it: the one-chip step
+    sorts once (a ``lexsort`` for ids, an ``argsort`` a key word for wide
+    keys), under the plan's program. Each table's pull reads the distinct
+    keys' rows in a loop of chunk-sized gathers under ``resolve`` and
+    hands every position its row in one ``expand`` gather; no gather of
+    the pull is as long as the batch's positions but that one, and none of
+    it sits under a conditional."""
     mesh = create_mesh(1, 1, v5e[:1])
     hlo = _compile_deepfm_step(mesh, use_hash=use_hash).as_text()
     paths = trace_reduce.scope_names(hlo)
@@ -258,7 +265,7 @@ def test_v5e_one_chip_step_dedups_once_and_pulls_distinct_keys(v5e, use_hash):
     sorts = [paths[inst] for inst, op in found
              if op == "sort" and stages.get(inst) == "dedup"
              and ("argsort" if use_hash else "lexsort") in paths[inst]]
-    assert len(sorts) == (4 if use_hash else 2), sorts
+    assert len(sorts) == (2 if use_hash else 1), sorts
     assert all("plan_a2a" in path for path in sorts), sorts
 
     pull = "hash_pull_a2a" if use_hash else "pull_a2a"
@@ -295,6 +302,36 @@ def test_v5e_one_chip_push_follows_the_pull_and_copies_no_key_array(v5e):
     copies = [line.strip()[:120] for line in hlo.splitlines()
               if f"= s32[{HASH_CAPACITY}]" in line and " copy" in line]
     assert not copies, copies
+
+
+@pytest.mark.parametrize("use_hash,key_dtype,sorts", [
+    (False, "wide", 1), (True, "wide", 2), (True, "int32", 1)],
+    ids=["array", "hash-wide", "hash-int32"])
+def test_v5e_one_chip_step_builds_one_plan_for_both_tables(v5e, use_hash,
+                                                           key_dtype, sorts):
+    """The table and its ``:linear`` twin read one column, and the v5e step
+    holds the plan's program once: the sorts of ONE dedup (a ``jnp.unique``
+    of ids or int32 keys sorts once, ``unique_rows`` once a key word) lie
+    under ``plan_a2a`` / ``hash_plan_a2a``, the program sorts nowhere else
+    under ``dedup`` (the compiler may sort for a combine's scatter-add),
+    and the pulls and pushes of both tables are there."""
+    mesh = create_mesh(1, 1, v5e[:1])
+    hlo = _compile_deepfm_step(mesh, use_hash=use_hash,
+                               key_dtype=key_dtype).as_text()
+    prefix = "hash_" if use_hash else ""
+    paths = trace_reduce.scope_names(hlo)
+    stages = stage_reduce.instruction_stages(hlo, paths)
+    found = [m.groups() for m in map(_OPCODE.match, hlo.splitlines()) if m]
+    sorting = [paths[inst].split("/") for inst, op in found
+               if op == "sort" and stages.get(inst) == "dedup"
+               and paths[inst].endswith("/sort")]   # the program's own
+    assert len(sorting) == sorts, sorting
+    assert all(f"{prefix}plan_a2a" in path for path in sorting), sorting
+    for verb, stage in (("pull", "resolve"), ("push", "apply_")):
+        loops = [inst for inst, op in found if op == "while"
+                 and f"{prefix}{verb}_a2a" in paths.get(inst, "").split("/")
+                 and stages.get(inst, "").startswith(stage)]
+        assert len(loops) == 2, (verb, loops)           # two tables
 
 
 def _on(dev, shape, dtype):
