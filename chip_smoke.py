@@ -27,8 +27,8 @@ distinct chips with equal bytes, that ``bytes_in_use`` is even, and that
 the compiled step exchanges rows by all-to-all.
 
 Each phase prints one JSON line (wall, compile seconds and persistent-
-cache hits from jax.monitoring, and what it measured). The last line of
-stdout is ``{"ok": true, "device": {...}}``.
+cache hits from the program's load ledger, and what it measured). The last
+line of stdout is ``{"ok": true, "device": {...}}``.
 """
 
 import contextlib
@@ -53,39 +53,25 @@ WARM_STEPS = 2                   # Trainer.fit's own warmup before steady
 TIMED_STEPS = 30
 
 
-class Phases:
-    """One JSON line per phase: wall seconds, XLA compile seconds and
-    persistent-cache hits (jax.monitoring), plus what the phase returns."""
-
-    _COMPILE = "/jax/core/compile/backend_compile_duration"
-    _HIT = "/jax/compilation_cache/cache_hits"
-
-    def __init__(self):
-        self._cur = None
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_kw):
-        if self._cur is not None and event == self._COMPILE:
-            self._cur["compile_s"] += secs
-            self._cur["programs"] += 1
-
-    def _event(self, event, **_kw):
-        if self._cur is not None and event == self._HIT:
-            self._cur["cache_hits"] += 1
-
-    @contextlib.contextmanager
-    def phase(self, name):
-        self._cur = rec = {"phase": name, "compile_s": 0.0, "programs": 0,
-                           "cache_hits": 0}
-        t0 = time.perf_counter()
-        try:
-            yield rec
-        finally:
-            self._cur = None
-        rec["wall_s"] = round(time.perf_counter() - t0, 2)
-        rec["compile_s"] = round(rec["compile_s"], 2)
-        print(json.dumps(rec), flush=True)
+@contextlib.contextmanager
+def phase(name):
+    """One JSON line per phase: wall seconds, the seconds XLA spent
+    compiling or fetching programs, how many, and how many of them the
+    persistent cache held (the load ledger's totals at the phase's end
+    less those at its start), plus what the phase puts in ``rec``."""
+    from openembedding_tpu.analysis.retrace import LEDGER
+    rec = {"phase": name}
+    before, t0 = LEDGER.totals(), time.perf_counter()
+    try:
+        yield rec
+    finally:
+        after = LEDGER.totals()
+    rec["compile_s"] = round(after["compile_s"] + after["fetch_s"]
+                             - before["compile_s"] - before["fetch_s"], 2)
+    rec["programs"] = after["programs"] - before["programs"]
+    rec["cache_hits"] = after["hits"] - before["hits"]
+    rec["wall_s"] = round(time.perf_counter() - t0, 2)
+    print(json.dumps(rec), flush=True)
 
 
 def device_memory():
@@ -382,27 +368,26 @@ def main():
     data = 2 if n > 1 and n % 2 == 0 else 1
     mesh = create_mesh(data, n // data, devices)
     print(json.dumps({"mesh": dict(mesh.shape)}), flush=True)
-    phases = Phases()
 
-    with phases.phase("exchange_array") as rec:
+    with phase("exchange_array") as rec:
         rec.update(check_exchange(mesh, use_hash=False))
-    with phases.phase("exchange_hash") as rec:
+    with phase("exchange_hash") as rec:
         rec.update(check_exchange(mesh, use_hash=True))
-    with phases.phase("pallas") as rec:
+    with phase("pallas") as rec:
         rec.update(check_pallas())
 
     for kind, use_hash in (("array", False), ("hash", True)):
         coll = trainer = state = batches = None    # the last tables leave HBM
-        with phases.phase(f"train_{kind}") as rec:
+        with phase(f"train_{kind}") as rec:
             report, coll, trainer, state, batches = train(mesh,
                                                           use_hash=use_hash)
             rec.update(report)
             check_even_memory(mesh)
         if mesh.size > 1:
-            with phases.phase(f"pull_contract_{kind}"):
+            with phase(f"pull_contract_{kind}"):
                 check_pull_contract(mesh, coll, state, batches)
     # the wide-key hash model, trained last, is the one served
-    with phases.phase("serving") as rec:
+    with phase("serving") as rec:
         rec.update(serve(mesh, coll, trainer, state, batches))
 
     print(json.dumps({"ok": True, "device": found}), flush=True)

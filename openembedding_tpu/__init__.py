@@ -34,3 +34,8 @@ from .offload import HostOffloadedTable, ShardedOffloadedTable
 from .dirty import DirtyTracker
 from . import distributed
 from .training import Trainer, TrainState, binary_logloss
+from .analysis import retrace as _retrace
+
+# the load ledger listens from here on: every program any entry point
+# traces, lowers, fetches or compiles is on it (analysis/retrace.py)
+_retrace.install()

@@ -15,8 +15,10 @@ Three enforcement layers, all mechanical (ISSUE 3):
   wrappers with lock-order-cycle (potential-deadlock) detection and
   contention counters, and the deterministic interleaving harness
   (``sync_point``/``SerialSchedule``/``PointGate``).
-* :mod:`.retrace` — a runtime guard that counts XLA compilations around
-  a training loop and fails past a declared budget.
+* :mod:`.retrace` — the load ledger (every program JAX traces, lowers,
+  fetches from the compile cache or compiles, from the process's one
+  ``jax.monitoring`` listener) and the runtime guard that counts its
+  programs around a training loop and fails past a declared budget.
 * :mod:`.scope` — graftscope (ISSUE 6): span tracing into per-thread
   ring buffers (Chrome-trace/Perfetto export), the log-bucket histogram
   registry behind the ``/metrics`` ``_bucket``/``_sum``/``_count``

@@ -33,7 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .analysis import scope
 from .analysis.concurrency import sync_point
-from .analysis.retrace import RetraceGuard
+from .analysis.retrace import LEDGER, RetraceGuard
 from .utils import observability
 from .embedding import EmbeddingCollection, SameColumns
 from .parallel import pipelined as pipeline_lib
@@ -731,7 +731,9 @@ class Trainer:
         flight ahead of the device (see :meth:`train_step` and
         ``pipeline_depth`` in the constructor).
 
-        ``retrace_budget``: XLA compilations allowed after a TWO-step
+        ``retrace_budget``: programs XLA may compile, or fetch from the
+        persistent compile cache (a fetched program counts: the loop
+        stopped to load it all the same), after a TWO-step
         warmup (step 1 compiles the step program; step 2 may legally
         recompile once — its input is step 1's output, whose shardings/
         layouts can differ from the init-time state). A steady-state
@@ -740,6 +742,11 @@ class Trainer:
         :class:`analysis.retrace.RetraceBudgetExceeded` at the end of
         the loop — the mechanical version of watching jax_log_compiles
         (analysis/retrace.py).
+
+        Every call is noted on the load ledger (``analysis.retrace.LEDGER
+        .fit_calls``): entry and return on ``time.perf_counter()``, the
+        steps dispatched and the ledger's totals at entry — what the
+        calls before a long run cost, and where to cut the ledger at one.
 
         Offload overflow-detection lag: without ``persist_dir`` the loop
         reaches no natural join point, so an HBM-cache insert overflow
@@ -825,6 +832,17 @@ class Trainer:
             raise ValueError(
                 "fit(resume_from=) does not restore offloaded tables; "
                 "restore them via their own persist lane first")
+        call = LEDGER.fit_began()
+        try:
+            return self._fit(state, batches, call, log_every, log_fn,
+                             persist_dir, retrace_budget, autosave_every,
+                             autosave_dir, resume_from)
+        finally:
+            LEDGER.fit_returned(call)
+
+    def _fit(self, state, batches, call, log_every, log_fn, persist_dir,
+             retrace_budget, autosave_every, autosave_dir, resume_from):
+        """The loop of :meth:`fit`; ``call`` is its line of the ledger."""
         last = None
         it = iter(batches)
         base_cursor = 0
@@ -930,6 +948,8 @@ class Trainer:
                 guard.__exit__(type(e), e, None)
             self._drain_suppressed()
             raise
+        finally:
+            call.steps = i      # dispatched, whether the loop ended or broke
         # the guard covers the LOOP only: the drain below may legitimately
         # compile (a remainder-sized final flush chunk) and must not count
         # against the steady-state budget. A budget trip raises — but the

@@ -99,9 +99,23 @@ _STALE_BENCHMARK_TESTS = (
 )
 
 
+# Likewise three cases of an accepted test hold every metric of a traced
+# rehearsal but one to ``null``; since PR 36 a rehearsal reports two counts
+# of set-up (``setup_programs_loaded``, ``setup_cache_misses``) as numbers.
+# ``tests/benchmark/test_bench_setup.py`` asserts what the cases stood for.
+_STALE_REHEARSAL_CASES = tuple(
+    "test_bench_run.py::test_rehearsal_runs_end_to_end_with_null_timings"
+    f"[{config}-1]" for config in ("tiny_array", "tiny_hash",
+                                   "tiny_array_x4"))
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         if item.nodeid.endswith(_STALE_BENCHMARK_TESTS):
             item.add_marker(pytest.mark.xfail(
                 reason="describes the benchmark's five cells; "
                        "BENCHMARK.json has six since PR 34", strict=True))
+        elif item.nodeid.endswith(_STALE_REHEARSAL_CASES):
+            item.add_marker(pytest.mark.xfail(
+                reason="holds every metric but one to null; a rehearsal "
+                       "counts set-up's programs since PR 36", strict=True))
