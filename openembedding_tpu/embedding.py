@@ -583,7 +583,28 @@ class EmbeddingCollection:
         spec's training-side heuristic would misread it). ``plan`` is
         :meth:`plan`'s of these ``inputs``.
         """
+        return self.pull_resolved(
+            states, inputs, batch_sharded=batch_sharded, read_only=read_only,
+            serving_rows=serving_rows, plan=plan)[0]
+
+    def pull_resolved(self, states: Dict[str, Any],
+                      inputs: Dict[str, jnp.ndarray],
+                      *, batch_sharded: bool = True,
+                      read_only: bool = False,
+                      serving_rows: bool = False,
+                      plan: Optional[Dict[str, Any]] = None) -> tuple:
+        """:meth:`pull`, and what it resolved: ``(rows, resolved)``.
+
+        ``resolved``: name -> ``dedup.Resolution`` for every column the
+        ``plan`` covers, what its table's pull found for the plan's distinct
+        keys (each shard's rows as read, a hash table's slots). It is for
+        :meth:`apply_gradients` of the SAME inputs, plan and states, with
+        no write to the tables in between: the push then resolves nothing a
+        second time. Under ``read_only`` a missing hash key reads zeros, not
+        the row its insert writes, and nothing is handed on.
+        """
         plan = plan or {}
+        resolved = {}
         widened = {
             name: self._widen(self.specs[name], idx,
                               pair_ndim=2 if serving_rows else None)
@@ -609,6 +630,10 @@ class EmbeddingCollection:
                 r = sharded.pull_sharded(
                     states[name], idx, mesh=self.mesh, store=stores[name],
                     batch_sharded=batch_sharded, plan=plan.get(name))
+                if name in plan:
+                    r, found = r
+                    if not read_only:
+                        resolved[name] = found
             if spec.pooling and not serving_rows:
                 # wide sequence features carry [B, L, 2] pair ids; the
                 # combiner counts validity on the hi word (ragged.py)
@@ -617,7 +642,7 @@ class EmbeddingCollection:
                                      self._pool_vocab(spec),
                                      wide=spec.key_dtype == "wide")
             rows[name] = r
-        return rows
+        return rows, resolved
 
     def _pool_vocab(self, spec: EmbeddingSpec) -> Optional[int]:
         return None if spec.use_hash else spec.input_dim
@@ -673,15 +698,19 @@ class EmbeddingCollection:
                         inputs: Dict[str, jnp.ndarray],
                         row_grads: Dict[str, jnp.ndarray],
                         *, batch_sharded: bool = True,
-                        plan: Optional[Dict[str, Any]] = None
+                        plan: Optional[Dict[str, Any]] = None,
+                        resolved: Optional[Dict[str, Any]] = None
                         ) -> Dict[str, Any]:
         """Push+update for every column present in ``row_grads``.
 
         ``row_grads[name]`` has the shape of the pulled rows. Untouched
         variables keep their state object unchanged. ``plan`` is
-        :meth:`plan`'s of these ``inputs``, the one their pull ran on.
+        :meth:`plan`'s of these ``inputs``, the one their pull ran on;
+        ``resolved`` is what that pull handed on (:meth:`pull_resolved`),
+        ``states`` being the tables it read.
         """
         plan = plan or {}
+        resolved = resolved or {}
         # delta-checkpoint dirty marks for EAGER pushes (tracer inputs —
         # the jitted Trainer step — skip; the Trainer marks host-side)
         self.mark_dirty({n: inputs.get(n) for n in row_grads})
@@ -708,7 +737,8 @@ class EmbeddingCollection:
             new_states[name] = sharded.apply_gradients_sharded(
                 states[name], self._optimizers[name], idx_in, g,
                 mesh=self.mesh, store=self._stores[name],
-                batch_sharded=batch_sharded, plan=plan.get(name))
+                batch_sharded=batch_sharded, plan=plan.get(name),
+                resolved=resolved.get(name))
         if grouped_idx:
             from .parallel import grouped
             new_states.update(grouped.apply_gradients_grouped(

@@ -391,7 +391,8 @@ def insert_width(n: int) -> int:
 def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
                    valid: jnp.ndarray,
                    max_probes: int = DEFAULT_MAX_PROBES,
-                   record_stats: bool = False
+                   record_stats: bool = False,
+                   found: Optional[jnp.ndarray] = None
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Find each (unique) key's slot, inserting missing keys.
 
@@ -400,7 +401,12 @@ def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
     :func:`find_rows` does; a key found there is done. A call wider than
     ``table.FIND_CHUNK`` walks its keys a chunk a trip and stops after the
     last valid one, so the find costs what the keys of a step cost, not
-    what the padded unique buffer would. *Insert*: the keys
+    what the padded unique buffer would. A caller that holds the find's
+    answer hands it over as ``found`` ([n] int32, -1 for a key that is not
+    in the table or not ``valid``) and the phase is skipped: the push of a
+    train step, whose pull found these keys under this mask
+    (:func:`pull_distinct`) with no write to the table between the two.
+    *Insert*: the keys
     that missed, in their original order, are compacted into a buffer of
     :func:`insert_width` keys and only that buffer runs the insert loop
     below; its slots are written back to the keys' positions. When more
@@ -409,7 +415,8 @@ def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
     the program, the observed count leaves one of them without a key to
     place, and a loop stops at the first level that finds none. Where the
     buffer would be no narrower than the call (small calls) the loop is
-    all there is. A key already in the table can never take a slot (its
+    all there is, over every key, or over the misses where ``found`` says
+    which they are. A key already in the table can never take a slot (its
     earlier chain buckets are full), and compaction keeps every other
     contender's rank within its bucket, so slots, ``inserted``, ``failed``
     and the key array are the same whichever loop placed the keys.
@@ -435,29 +442,37 @@ def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
     ran the loop over the buffer / over every key,
     ``hash_insert_missed``, the keys that were not in the table, and
     ``hash_find_slots_live`` / ``hash_find_slots_walked``, the valid keys
-    of a call and the keys its find walked.
+    of a call and the keys its find walked (none where ``found`` came with
+    the call).
 
     Returns ``(table_keys, slot [n] (-1 = failed), inserted [n],
     failed [n])``.
     """
     return scope.stage("probe")(
-        lambda table_keys, new_keys, valid: _find_or_insert(
-            table_keys, new_keys, valid, max_probes, record_stats))(
-                table_keys, new_keys, valid)
+        lambda table_keys, new_keys, valid, found: _find_or_insert(
+            table_keys, new_keys, valid, max_probes, record_stats, found))(
+                table_keys, new_keys, valid, found)
 
 
-def _find_or_insert(table_keys, new_keys, valid, max_probes, record_stats):
+def _find_or_insert(table_keys, new_keys, valid, max_probes, record_stats,
+                    found=None):
     n = new_keys.shape[0]
     m = insert_width(n)
     if m >= n:
-        out = _insert_levels(table_keys, new_keys, valid, max_probes)
+        out = _insert_levels(
+            table_keys, new_keys,
+            valid if found is None else valid & (found < 0), max_probes,
+            slot0=found)
         _, _, inserted, failed = out
         record_stat("hash_insert_full", jnp.int32(1), record_stats)
         record_stat("hash_insert_missed",
                     jnp.sum(inserted | failed, dtype=jnp.int32), record_stats)
         return out
 
-    found, walked = _find_levels(table_keys, new_keys, valid, max_probes)
+    if found is None:
+        found, walked = _find_levels(table_keys, new_keys, valid, max_probes)
+    else:
+        walked = jnp.int32(0)
     miss = valid & (found < 0)
     missed = jnp.sum(miss, dtype=jnp.int32)
     fits = missed <= m
@@ -693,14 +708,18 @@ def _or_fresh(initializer, init_rng, keys, rows, hit, invalid):
 def pull_distinct(state: HashTableState, keys: jnp.ndarray,
                   valid: jnp.ndarray, initializer: Any,
                   max_probes: int = DEFAULT_MAX_PROBES, *,
-                  positions: int, record_stats: bool = False) -> jnp.ndarray:
+                  positions: int, record_stats: bool = False
+                  ) -> dedup.Resolution:
     """:func:`pull` for the distinct keys of a step's plan
     (``dedup.Plan.uniq`` with its ``valid``, a prefix of the buffer): one
     row a slot, each key resolved once. The find and the row read walk
-    the valid prefix in chunks (the push's find, :func:`_find_levels`, and
+    the valid prefix in chunks (:func:`_find_levels` and
     ``table.read_distinct``), so a pull costs what the distinct keys of
     the batch cost; the caller expands by the plan's ``inverse``, over
-    ``positions`` keys (``table.record_pull``'s count)."""
+    ``positions`` keys (``table.record_pull``'s count). Returned with the
+    rows are the slots the find found, -1 for a key the table does not
+    hold: what the step's push would find again, and takes from here
+    (:func:`merge_gradients`)."""
     if initializer is not None:
         initializer = make_initializer(initializer)
     keys = check_key_dtype(state.keys, keys)
@@ -714,7 +733,9 @@ def pull_distinct(state: HashTableState, keys: jnp.ndarray,
         hit = slot >= 0
         rows, _ = table_lib.read_distinct(weights, slot, hit)
         table_lib.record_pull(valid, walked, positions, record_stats)
-        return _or_fresh(initializer, init_rng, keys, rows, hit, ~valid)
+        return dedup.Resolution(
+            rows=_or_fresh(initializer, init_rng, keys, rows, hit, ~valid),
+            slot=slot)
 
     return read(state.keys, state.weights, state.init_rng, keys, valid)
 
@@ -754,7 +775,8 @@ def merge_gradients(state: HashTableState,
                     max_probes: int = DEFAULT_MAX_PROBES,
                     in_counts: Optional[jnp.ndarray] = None,
                     record_stats: bool = False,
-                    plan: Optional[dedup.Plan] = None):
+                    plan: Optional[dedup.Plan] = None,
+                    resolved: Optional[dedup.Resolution] = None):
     """The first half of :func:`apply_gradients`, which touches the key
     array alone: deduplicate the keys and combine their gradients into a
     buffer of ``dedup_capacity`` (default ``n``) slots, find or insert each
@@ -762,7 +784,12 @@ def merge_gradients(state: HashTableState,
     of keys no window held, and ``table.apply_rows``'s ``(rows, live,
     summed, counts, fresh, inserted)``. ``plan`` is the dedup of
     ``indices`` where the step has made it already, in front of its pull:
-    its slots are the buffer and nothing is deduplicated again."""
+    its slots are the buffer and nothing is deduplicated again.
+    ``resolved`` is what that pull found for the plan's slots
+    (:func:`pull_distinct` of the same keys under the same mask, the
+    table unwritten since): its ``slot`` is :func:`find_or_insert`'s
+    ``found``, and no key is looked for again. ``record_stats`` then counts
+    ``push_slots_carried``, the valid keys whose find the push took."""
     initializer = make_initializer(initializer)
     dim = state.dim
     empty = empty_key(state.keys.dtype)
@@ -784,7 +811,11 @@ def merge_gradients(state: HashTableState,
     summed, counts = dedup.combine_gradients(grads.reshape(-1, dim), inverse,
                                              capacity, in_counts)
     keys_arr, slot, inserted, failed = find_or_insert(
-        state.keys, uniq, valid, max_probes, record_stats)
+        state.keys, uniq, valid, max_probes, record_stats,
+        found=None if resolved is None else resolved.slot)
+    if resolved is not None:
+        record_stat("push_slots_carried", jnp.sum(valid, dtype=jnp.int32),
+                    record_stats)
     fresh = init_rows(initializer, state.init_rng, uniq, dim,
                       state.weights.dtype)
     return (keys_arr, jnp.sum(failed).astype(jnp.int32),
@@ -801,7 +832,9 @@ def apply_gradients(state: HashTableState,
                     max_probes: int = DEFAULT_MAX_PROBES,
                     in_counts: Optional[jnp.ndarray] = None,
                     record_stats: bool = False,
-                    plan: Optional[dedup.Plan] = None) -> HashTableState:
+                    plan: Optional[dedup.Plan] = None,
+                    resolved: Optional[dedup.Resolution] = None
+                    ) -> HashTableState:
     """Combine duplicate grads, insert missing keys, update touched rows.
 
     The hash-table analogue of ``table.apply_gradients``: dedup -> claim/probe
@@ -813,13 +846,13 @@ def apply_gradients(state: HashTableState,
     The dedup, the combine and the find (:func:`merge_gradients`) run over
     ``dedup_capacity`` (default ``n``) slots; the gather, the optimizer and
     the scatter are ``table.apply_rows``, whose cost follows the distinct
-    keys of the batch and not ``dedup_capacity``. ``plan`` is
-    :func:`merge_gradients`'s.
+    keys of the batch and not ``dedup_capacity``. ``plan`` and
+    ``resolved`` are :func:`merge_gradients`'s.
     """
     keys_arr, failed, merged = merge_gradients(
         state, initializer, indices, grads, dedup_capacity=dedup_capacity,
         max_probes=max_probes, in_counts=in_counts,
-        record_stats=record_stats, plan=plan)
+        record_stats=record_stats, plan=plan, resolved=resolved)
     weights, slots = table_lib.apply_rows(
         state.weights, state.slots, make_optimizer(optimizer), *merged,
         record_stats=record_stats)

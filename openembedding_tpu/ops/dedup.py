@@ -13,7 +13,7 @@ uniqueness (the reference measures this too: laboratory/benchmark/analyze.py).
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 from flax import struct
@@ -36,11 +36,34 @@ class Plan:
     so no key overflows. It holds the keys as they come and nothing of a
     table: twin tables fed one column (a fused table and its ``:linear``
     column) pull and push through the SAME plan, each laying its own
-    ownership mask over it (``EmbeddingCollection.plan``)."""
+    ownership mask over it (``EmbeddingCollection.plan``). What one
+    table's pull found for the plan's slots travels beside it, a
+    :class:`Resolution` a table."""
 
     uniq: jnp.ndarray       # [n] keys, [n, 2] wide ones; fill past the last
     inverse: jnp.ndarray    # [n]: uniq[inverse[i]] is key i
     valid: jnp.ndarray      # [n]: the slot holds a key, and not the fill
+
+
+@struct.dataclass
+class Resolution:
+    """What ONE table's pull resolved for the slots of its step's
+    :class:`Plan`, kept for the push of the same step, between which
+    nothing writes the table: the push neither finds a key nor reads a
+    weight row a second time. A shard's own, as it read them under its
+    ownership mask (before the sum over the model axis, before the
+    expansion by ``inverse``): on a mesh of several model shards every
+    shard carries its part."""
+
+    # [n, dim]: the stored row of a key the table holds, the init row of a
+    # hash key it does not (the row its insert writes), zeros for a slot
+    # the shard does not own or that holds no key
+    rows: jnp.ndarray
+    # [n] int32, hash tables: the key's slot in the shard's key array,
+    # -1 where it is not in the table, not owned or not valid (what
+    # ``hash_table.find_or_insert``'s find phase would find again);
+    # None for an array table, whose slot is the key
+    slot: Optional[jnp.ndarray] = None
 
 
 def plan_keys(keys: jnp.ndarray, fill_value: int = FILL) -> Plan:

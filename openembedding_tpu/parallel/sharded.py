@@ -67,12 +67,16 @@ answers:
 * ``resolve(local, keys, me)`` / ``read_local(local, flat)`` — the pull's
   owner side behind the exchange / on the masked-local body, where
   ``read_plan(local, plan, record_stats)`` reads one row a distinct key
-  of the step's plan (below);
+  of the step's plan (below) and returns what it resolved, a
+  ``dedup.Resolution`` of the plan's slots under this shard's ownership
+  mask: the rows, and for a kind that probes the slots it found;
 * ``carry(local)``, ``merge(local, carry, keys, grads, counts, me, ...)``
-  / ``apply_local(local, optimizer, flat, grads, ..., plan=)`` — the
-  push's owner side likewise, ``outputs(carry, weights, slots, axes)`` — what leaves
-  the program, ``slot_of(carry, keys, me)`` — a key's slot in this shard
-  (-1: not here), where the owner writes a cached key's row back;
+  / ``apply_local(local, optimizer, flat, grads, ..., plan=, resolved=)``
+  — the push's owner side likewise (``resolved``: ``read_plan``'s of the
+  same plan and the same table contents, which the push then takes
+  instead of resolving again), ``outputs(carry, weights, slots, axes)`` —
+  what leaves the program, ``slot_of(carry, keys, me)`` — a key's slot in
+  this shard (-1: not here), where the owner writes a cached key's row back;
 * ``ef_space(table)`` — the int8-EF residual's key space.
 
 No code in this file asks which kind it holds: a step that needs to is a
@@ -86,6 +90,10 @@ keys over their occupied prefix and expands by ``inverse``, and to the
 push, whose unique buffer it is. It serves both where both see the same
 keys (:func:`shares_plan`). The routed body dedups its sender slice itself
 (``alltoall.exchange_pull``), and any call without a plan runs as it did.
+One resolve a key a step, too: the planned pull returns, beside the rows,
+what each shard resolved for the plan's slots (``dedup.Resolution``, one a
+table), and the push that is handed it finds no key and reads no weight
+row again; a push that is not runs as it did.
 Tables fed one column of ids in one key form (:func:`plan_form`) share that
 plan: it holds nothing of a store but the form, each store lays its own
 ownership mask over it (``EmbeddingCollection.plan``).
@@ -244,6 +252,12 @@ def _plan_specs(batch_spec: P) -> dedup.Plan:
     return dedup.Plan(uniq=P(), inverse=batch_spec, valid=P())
 
 
+def _resolved_spec(spec: PlaneSpec) -> P:
+    """Of every leaf of a ``dedup.Resolution``: a shard's own, out of the
+    pull and into the push."""
+    return P(spec.model_axis)
+
+
 @functools.lru_cache(maxsize=None)
 def _plan_program(mesh: Mesh, store, batch_sharded: bool):
     batch_spec = P(store.spec.data_axis) if batch_sharded else P()
@@ -289,6 +303,7 @@ def _pull_program(mesh: Mesh, store, dim: int, batch_sharded: bool,
     batch_spec = P(spec.data_axis) if batch_sharded else P()
     cache_specs = ()
     plan_specs = (_plan_specs(batch_spec),) if planned else ()
+    out_specs = (batch_spec, _resolved_spec(spec)) if planned else batch_spec
 
     if spec.routes:
         grid = _exchange_args(mesh, spec, batch_sharded, record_stats)
@@ -331,34 +346,43 @@ def _pull_program(mesh: Mesh, store, dim: int, batch_sharded: bool,
             # with the step's plan a distinct key is read once: the psum
             # carries the distinct rows, and expand hands every position
             # its row, as exchange_pull's does
+            resolved = store.read_plan(local, plan[0], record_stats) \
+                if plan else None
             rows = scope.stage("exchange")(
                 lambda rows: lax.psum(rows, spec.model_axis))(
-                    store.read_plan(local, plan[0], record_stats) if plan
-                    else store.read_local(local, flat))
-            if plan:
-                # the plan's capacity is its positions: no inverse is out
-                # of range, and clip spares the fill mode's select
-                rows = scope.stage("expand")(
-                    lambda rows, inverse: jnp.take(rows, inverse, axis=0,
-                                                   mode="clip"))(
-                        rows, plan[0].inverse)
-            return rows.reshape(out_shape)
+                    resolved.rows if plan else store.read_local(local, flat))
+            if not plan:
+                return rows.reshape(out_shape)
+            # the plan's capacity is its positions: no inverse is out
+            # of range, and clip spares the fill mode's select
+            rows = scope.stage("expand")(
+                lambda rows, inverse: jnp.take(rows, inverse, axis=0,
+                                               mode="clip"))(
+                    rows, plan[0].inverse)
+            # what this shard read, as it read it (a -0.0 weight has not
+            # been through the sum), goes out for the step's push
+            return rows.reshape(out_shape), resolved
 
     _pull.__name__ = _program_name(store, "pull")
     fn = shard_map(_pull, mesh=mesh,
                    in_specs=(store.specs(()),) + cache_specs + (batch_spec,)
                    + plan_specs,
-                   out_specs=batch_spec,
+                   out_specs=out_specs,
                    check_vma=False)
     return jax.jit(fn)
 
 
-def _planned(plan, spec: PlaneSpec, mesh: Mesh, batch_sharded: bool) -> tuple:
-    """``plan`` as a program's trailing operands: none without one."""
+def _planned(plan, spec: PlaneSpec, mesh: Mesh, batch_sharded: bool,
+             resolved=None) -> tuple:
+    """``plan``, and the ``resolved`` that goes with it, as a program's
+    trailing operands: none without a plan."""
     if plan is None:
+        if resolved is not None:
+            raise ValueError("a dedup.Resolution is of a plan's slots: "
+                             "hand the push the plan its pull ran on")
         return ()
     _require_shared(spec, mesh, batch_sharded)
-    return (plan,)
+    return (plan,) if resolved is None else (plan, resolved)
 
 
 def pull_sharded(state, indices: jnp.ndarray, *, mesh: Mesh, store,
@@ -373,7 +397,10 @@ def pull_sharded(state, indices: jnp.ndarray, *, mesh: Mesh, store,
     path) else replicated. Returns rows with the same batch sharding. On the
     ``"a2a+cache"`` plane ``state`` is a :class:`hot_cache.CachedState`.
     ``plan`` is :func:`plan_sharded`'s of the same ``indices``: the same
-    rows, each distinct key resolved once.
+    rows, each distinct key resolved once, and with them what was
+    resolved, ``(rows, dedup.Resolution)``: the second for
+    :func:`apply_gradients_sharded` of the same step, while nothing has
+    written the table.
     """
     spec = store.spec
     record = observability.evaluate_performance()
@@ -395,12 +422,12 @@ def pull_sharded(state, indices: jnp.ndarray, *, mesh: Mesh, store,
 def _apply_program(mesh: Mesh, store, optimizer: SparseOptimizer, dim: int,
                    batch_sharded: bool, dedup_capacity: Optional[int],
                    slot_names: tuple, record_stats: bool = False,
-                   planned: bool = False):
+                   planned: int = 0):
     spec = store.spec
     batch_spec = P(spec.data_axis) if batch_sharded else P()
     table_specs = store.specs(slot_names)
     extra_in = extra_out = ()
-    plan_specs = (_plan_specs(batch_spec),) if planned else ()
+    plan_specs = (_plan_specs(batch_spec), _resolved_spec(spec))[:planned]
 
     if spec.routes:
         grid = _exchange_args(mesh, spec, batch_sharded, record_stats)
@@ -479,7 +506,8 @@ def _apply_program(mesh: Mesh, store, optimizer: SparseOptimizer, dim: int,
                 return (store.outputs(carry, weights, slots,
                                       spec.shard_axes), new_ef)
     else:
-        def _apply(arrays, idx, g, *plan):
+        def _apply(arrays, idx, g, *planned):
+            plan, resolved = (planned + (None, None))[:2]
             flat, _ = _flat_keys(store, idx, dim)
             g2 = g.reshape(-1, dim)
             if batch_sharded:
@@ -490,7 +518,7 @@ def _apply_program(mesh: Mesh, store, optimizer: SparseOptimizer, dim: int,
             carry, weights, slots = store.apply_local(
                 store.local(*arrays), optimizer, flat, g2,
                 dedup_capacity=dedup_capacity, record_stats=record_stats,
-                plan=plan[0] if plan else None)
+                plan=plan, resolved=resolved)
             return store.outputs(carry, weights, slots, spec.model_axis), ()
 
     _apply.__name__ = _program_name(store, "push")
@@ -506,7 +534,8 @@ def apply_gradients_sharded(state, optimizer: SparseOptimizer,
                             indices: jnp.ndarray, grads: jnp.ndarray, *,
                             mesh: Mesh, store, batch_sharded: bool = True,
                             dedup_capacity: Optional[int] = None,
-                            plan: Optional[dedup.Plan] = None):
+                            plan: Optional[dedup.Plan] = None,
+                            resolved: Optional[dedup.Resolution] = None):
     """Distributed push+update: every shard applies its owned rows.
 
     On the routed planes each key's pre-reduced grads reach its single
@@ -518,11 +547,14 @@ def apply_gradients_sharded(state, optimizer: SparseOptimizer,
     :class:`hot_cache.CachedState`. ``plan`` is :func:`plan_sharded`'s of
     the same ``indices``, the one the step's pull ran on: its slots are the
     push's unique buffer, and the same rows get the same update.
+    ``resolved`` is what that pull returned beside its rows, the table
+    unwritten since: the push takes each key's slot from it and looks for
+    none again.
     """
     spec = store.spec
     optimizer = make_optimizer(optimizer)
     record = observability.evaluate_performance()
-    plan = _planned(plan, spec, mesh, batch_sharded)
+    plan = _planned(plan, spec, mesh, batch_sharded, resolved)
     extra = ()
     if spec.is_cached:
         table = state.table
@@ -541,7 +573,7 @@ def apply_gradients_sharded(state, optimizer: SparseOptimizer,
         table = precision.unwrap(state)
     fn = _apply_program(mesh, store, optimizer, table.weights.shape[-1],
                         batch_sharded, dedup_capacity, tuple(table.slots),
-                        record, bool(plan))
+                        record, len(plan))
     outs, new_extra = observability.plane_timed(
         "push", spec.plane_label, record, fn,
         store.operands(table), *extra, indices, grads, *plan)

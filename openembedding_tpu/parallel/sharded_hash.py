@@ -464,7 +464,7 @@ class HashStore:
         return (tkeys, fails + failed), merged
 
     def apply_local(self, local, optimizer, flat, grads, *, dedup_capacity,
-                    record_stats, plan=None):
+                    record_stats, plan=None, resolved=None):
         me = lax.axis_index(self.spec.model_axis)
         # with the step's plan the mask falls on its distinct keys, and
         # the plan's slots are the unique buffer
@@ -473,7 +473,8 @@ class HashStore:
             _mask_non_owned(self.spec, flat, me) if plan is None else None,
             grads, dedup_capacity=dedup_capacity,
             max_probes=self.spec.max_probes, record_stats=record_stats,
-            plan=None if plan is None else self.own(plan, me))
+            plan=None if plan is None else self.own(plan, me),
+            resolved=resolved)
         return (new.keys, new.insert_failures), new.weights, new.slots
 
     def outputs(self, carry, weights, slots, axes):

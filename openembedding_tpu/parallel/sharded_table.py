@@ -411,7 +411,7 @@ class ArrayStore:
             rows, walked = table_lib.read_distinct(weights, row, live)
             table_lib.record_pull(live, walked, plan.inverse.shape[0],
                                   record_stats)
-            return rows
+            return dedup.Resolution(rows=rows)
 
         return read(local.weights, mine.uniq, mine.valid)
 
@@ -426,7 +426,7 @@ class ArrayStore:
             rows, grads, dedup_capacity=dedup_capacity, in_counts=counts)
 
     def apply_local(self, local, optimizer, flat, grads, *, dedup_capacity,
-                    record_stats, plan=None):
+                    record_stats, plan=None, resolved=None):
         @scope.stage("route")
         def mask(flat):
             owned, row = _masked_local(self.spec, flat)

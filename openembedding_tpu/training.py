@@ -259,13 +259,16 @@ class Trainer:
 
     # --- steps ---------------------------------------------------------------
     def _dense_update_and_push(self, state: TrainState, batch, rows,
-                               pull_inputs, dense_ids, plan=None):
+                               pull_inputs, dense_ids, plan=None,
+                               resolved=None):
         """Shared core of the serial AND pipelined step programs: loss
         + grads on ``rows``, dense optimizer update, sparse push. ONE
         definition traced by both schedules — the pipelined plane's
         exact-equivalence guarantee rests on them never diverging.
         ``plan`` is the serial step's ``collection.plan`` of
-        ``pull_inputs``, the one ``rows`` were pulled with."""
+        ``pull_inputs``, the one ``rows`` were pulled with, and
+        ``resolved`` what that pull resolved
+        (``collection.pull_resolved``): the push takes it."""
         def lfn(params, rows):
             logits = self._apply(params, batch.get("dense"), rows,
                                  dense_ids)
@@ -286,8 +289,8 @@ class Trainer:
             return optax.apply_updates(params, updates), opt_state
 
         params, opt_state = update(dense_g, state.opt_state, state.params)
-        emb = self.collection.apply_gradients(state.emb, pull_inputs,
-                                              row_g, plan=plan)
+        emb = self.collection.apply_gradients(
+            state.emb, pull_inputs, row_g, plan=plan, resolved=resolved)
         return params, opt_state, emb, loss
 
     def lower_train_step(self, state: TrainState, batch):
@@ -321,16 +324,22 @@ class Trainer:
             # the pull: pull and push of every table that reads it work
             # on the distinct keys
             plan = collection.plan(pull_inputs)
-            rows = collection.pull(state.emb, pull_inputs, plan=plan)
-            # The push's find and insert need nothing of the dense pass:
-            # only the plan and the key array the pull's find reads. This
-            # edge says the push follows the pull; left to order the two
-            # itself the v5e compiler copies an int32 key array (256 MiB
-            # a table) into the insert loop (tests/test_tpu_lowering.py).
+            # what the pull resolved for the distinct keys (a slot, a
+            # weight row) goes to the push with the plan: nothing writes
+            # the tables between the two
+            rows, resolved = collection.pull_resolved(
+                state.emb, pull_inputs, plan=plan)
+            # The push's insert needs nothing of the dense pass: only the
+            # plan, the slots the pull found and the key array the pull's
+            # find reads. This edge says the push follows the pull; left
+            # to order the two itself the v5e compiler copies an int32 key
+            # array (256 MiB a table) into the insert loop
+            # (tests/test_tpu_lowering.py).
             if plan:
-                rows, plan = jax.lax.optimization_barrier((rows, plan))
+                rows, plan, resolved = jax.lax.optimization_barrier(
+                    (rows, plan, resolved))
             params, opt_state, emb, loss = self._dense_update_and_push(
-                state, batch, rows, pull_inputs, dense_ids, plan)
+                state, batch, rows, pull_inputs, dense_ids, plan, resolved)
             new_state = TrainState(step=state.step + 1, params=params,
                                    opt_state=opt_state, emb=emb)
             return new_state, {"loss": loss}

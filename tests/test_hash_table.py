@@ -592,6 +592,44 @@ def test_chunked_find_counts_the_keys_it_walks(monkeypatch, mask):
             "hash_find_slots_walked": -(-bound // FIND_CHUNK) * FIND_CHUNK}
 
 
+@pytest.mark.parametrize("wide", [False, True], ids=["narrow", "wide"])
+@pytest.mark.parametrize("width", ["small_call", "chunked_call"])
+@pytest.mark.parametrize("mask", ["one_valid", "all_valid",
+                                  "holes_and_valid_last_slot"])
+def test_a_find_handed_over_is_not_made_again(monkeypatch, mask, width,
+                                              wide):
+    """``found`` is the find phase's answer (what a step's pull found for
+    the keys its push brings): with it the key array, slots, inserted and
+    failed are the call's without, bit for bit; a call wider than a chunk
+    holds no loop for the find and counts no key walked, and a small call,
+    all insert loop, takes it as the keys the loop may leave alone."""
+    from test_table import recorded
+    monkeypatch.setattr(ht.table_lib, "FIND_CHUNK", FIND_CHUNK)
+    table, keys, valid = _find_case(mask, wide)
+    if width == "small_call":
+        keys, valid = keys[:1024], valid[:1024]
+        assert ht.insert_width(1024) == 1024
+    found, _ = ht._find_levels(table, keys, valid, ht.DEFAULT_MAX_PROBES)
+    assert (np.asarray(found)[~np.asarray(valid)] == -1).all()
+    want = jax.jit(ht.find_or_insert)(table, keys, valid)
+    handed = jax.jit(lambda t, k, v, f: ht.find_or_insert(
+        t, k, v, record_stats=True, found=f))
+    got, stats = recorded(lambda: handed(table, keys, valid, found))
+    for name, g, w in zip(("keys", "slot", "inserted", "failed"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    assert stats["hash_insert_missed"] == int(np.asarray(want[2]).sum())
+    loops = lambda fn, *args: fn.lower(table, keys, valid, *args
+                                       ).compile().as_text().count(" while(")
+    if width == "chunked_call":
+        assert stats["hash_find_slots_walked"] == 0
+        assert stats["hash_find_slots_live"] == int(np.asarray(valid).sum())
+        assert loops(handed, found) == 2 and \
+            loops(jax.jit(ht.find_or_insert)) == 3
+    else:
+        assert loops(handed, found) == 1
+
+
 def _one_pass_find_levels(table_keys, query, valid, max_probes):
     """The find as it was before it walked chunks, over every key of the
     call in one pass a level: the reference of the test below."""

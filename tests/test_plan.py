@@ -13,6 +13,12 @@ One plan a distinct id column: tables fed the same ids (a fused table and its
 which leaves what a plan each leaves, bit for bit; columns that differ keep a
 plan each; the routed 2x2 step is the program it is without; and
 ``lower_train_step`` lowers the program the steps ran.
+
+What the pull resolved goes to the push (``dedup.Resolution``): the slot and
+the weight row of every distinct key. Steps whose push takes them leave the
+tables, key arrays, accumulators and failure counts of steps whose push
+resolves again, bit for bit, and the planned push's program holds no find
+and half the apply's gathers.
 """
 
 import re
@@ -146,6 +152,134 @@ def test_plan_is_for_the_masked_local_body_alone(devices8):
     with pytest.raises(ValueError, match="masked-local"):
         sharded.pull_sharded(states["t"], inputs["t"], mesh=mesh,
                              store=coll._stores["t"], plan=plan)
+
+
+# --- the push takes what the pull resolved ----------------------------------
+
+def _same_bits(got, want):
+    """Bit for bit: ``-0.0`` is not ``0.0``."""
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()
+
+
+def _fresh_batches(kind, steps, per_step=N):
+    """``steps`` batches whose keys are half the batch before's, half never
+    seen: every push finds keys and inserts keys."""
+    rng = np.random.RandomState(31)
+    space = VOCAB if kind == "array" else 1 << 20
+    ids = rng.randint(0, space, size=per_step)
+    for _ in range(steps):
+        ids = np.where(rng.rand(per_step) < 0.5, ids,
+                       rng.randint(0, space, size=per_step))
+        ids[:3] = -1 if kind == "array" else EMPTY      # and some padding
+        grads = rng.randn(per_step // 4, 4, DIM).astype(np.float32)
+        yield ({"t": jnp.asarray(ids.astype(np.int32).reshape(-1, 4))},
+               {"t": jnp.asarray(grads)})
+
+
+def _pushed(coll, states, batches, how):
+    """The states after each batch's pull and push: ``"carried"`` hands the
+    push what the pull resolved, ``"planned"`` the plan alone, ``"alone"``
+    nothing."""
+    out = []
+    for inputs, grads in batches:
+        plan = coll.plan(inputs) if how != "alone" else None
+        rows, resolved = coll.pull_resolved(states, inputs, plan=plan)
+        assert set(resolved) == (set() if plan is None else {"t"})
+        states = coll.apply_gradients(
+            states, inputs, grads, plan=plan,
+            resolved=resolved if how == "carried" else None)
+        out.append((rows, states))
+    return out
+
+
+@pytest.mark.parametrize("chunks", ["one_chunk", "several_chunks"])
+@pytest.mark.parametrize("model,plane", [(1, "a2a"), (2, "psum")],
+                         ids=["1x1", "psum2"])
+@pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
+def test_the_push_takes_what_the_pull_resolved(devices8, monkeypatch, kind,
+                                               model, plane, chunks):
+    """Fresh keys every step, a buffer of one chunk or of several: four
+    steps whose push takes the pull's slots and rows leave, step by step,
+    the state of steps whose push has the plan alone and of steps without a
+    plan. The resolution is each shard's own: rows of the plan's length a
+    shard, a hash table's slots beside them."""
+    if chunks == "several_chunks":
+        monkeypatch.setattr(table_lib, "APPLY_CHUNK", CHUNK)
+        monkeypatch.setattr(table_lib, "FIND_CHUNK", CHUNK)
+    for program in PROGRAMS:
+        program.cache_clear()
+    mesh = create_mesh(1, model, devices8[:model])
+    coll, states = _collection(kind, mesh, plane)
+    batches = list(_fresh_batches(kind, 4))
+    try:
+        want = _pushed(coll, states, batches, "alone")
+        for how in ("planned", "carried"):
+            _same_bits(_pushed(coll, states, batches, how), want)
+        inputs, _ = batches[0]
+        plan = coll.plan(inputs)
+        resolved = coll.pull_resolved(states, inputs, plan=plan)[1]["t"]
+    finally:
+        for program in PROGRAMS:
+            program.cache_clear()
+    assert resolved.rows.shape == (model * N, DIM)
+    if kind == "array":
+        assert resolved.slot is None
+    else:       # nothing is in the table before the first push
+        assert resolved.slot.shape == (model * N,)
+        assert (np.asarray(resolved.slot) == -1).all()
+    if kind != "array":
+        final = want[-1][1]["t"]
+        assert int(final.insert_failures) == 0
+        assert int(final.num_used()) > N    # keys were inserted, step by step
+
+
+@pytest.mark.parametrize("model,plane", [(1, "a2a"), (2, "psum")],
+                         ids=["1x1", "psum2"])
+@pytest.mark.parametrize("kind", ["hash32", "hashwide"])
+def test_a_key_no_window_holds_fails_the_same(devices8, small_chunks, kind,
+                                              model, plane):
+    """A table too full for its keys: a carried slot of -1 runs the insert
+    that fails as it fails without, and the failures are counted the
+    same."""
+    mesh = create_mesh(1, model, devices8[:model])
+    spec = EmbeddingSpec(
+        name="t", input_dim=-1, output_dim=DIM, hash_capacity=64 * model,
+        plane=plane, key_dtype="int32" if kind == "hash32" else "wide",
+        optimizer={"category": "adagrad", "learning_rate": 0.1})
+    coll = EmbeddingCollection([spec], mesh)
+    states = coll.init(jax.random.PRNGKey(5))
+    batches = list(_fresh_batches(kind, 6))
+    want = _pushed(coll, states, batches, "alone")
+    assert int(want[-1][1]["t"].insert_failures) > 0
+    for how in ("planned", "carried"):
+        _same_bits(_pushed(coll, states, batches, how), want)
+
+
+@pytest.mark.parametrize("model,plane", [(1, "a2a"), (2, "psum")],
+                         ids=["1x1", "psum2"])
+@pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
+def test_a_negative_zero_weight_keeps_its_sign(devices8, small_chunks, kind,
+                                               model, plane):
+    """The carried row is the shard's own read, before the sum over the
+    model axis: ``-0.0 + 0.0`` is ``0.0``, and a weight that a zero
+    gradient leaves alone would lose its sign on the way to the push."""
+    mesh = create_mesh(1, model, devices8[:model])
+    coll, states = _collection(kind, mesh, plane)
+    (inputs, grads), = _fresh_batches(kind, 1)
+    grads = {"t": grads["t"].at[..., 0].set(0.0)}
+    states = coll.apply_gradients(states, inputs, grads)    # the keys are in
+    table = states["t"]
+    states = {"t": table.replace(
+        weights=table.weights.at[:, 0].set(-0.0))}
+    batches = [(inputs, grads)] * 2
+    want = _pushed(coll, states, batches, "alone")
+    column = np.asarray(want[-1][1]["t"].weights)[:, 0]
+    assert np.signbit(column).all() and not column.any()
+    for how in ("planned", "carried"):
+        _same_bits(_pushed(coll, states, batches, how), want)
 
 
 # --- the train step ----------------------------------------------------------

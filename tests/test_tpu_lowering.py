@@ -140,13 +140,15 @@ def test_v5e_step_keeps_its_stage_names(v5e, use_hash):
 
 @pytest.mark.parametrize("shape", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
 def test_v5e_hash_push_finds_then_inserts_in_place(v5e, shape):
-    """Each table's push holds three loops under ``probe``: the find, the
-    insert loop over the buffer of misses and the one over the whole call.
-    Which of the two places keys is decided by what each is given, under
-    no conditional of the probe's own: through one the chip's compiler
-    copies the key array, and on one chip no copy of it is left. On one
-    chip the pull's find, over the distinct keys of the step's plan, is a
-    fourth loop a table; behind the exchange it is one pass."""
+    """Behind the exchange each table's push holds three loops under
+    ``probe``: the find, the insert loop over the buffer of misses and the
+    one over the whole call. Which of the two places keys is decided by
+    what each is given, under no conditional of the probe's own: through
+    one the chip's compiler copies the key array, and on one chip no copy
+    of it is left. On one chip the find is the pull's, over the distinct
+    keys of the step's plan, one loop a table, and the push, which takes
+    the slots it found (``dedup.Resolution``), holds the two insert loops
+    alone: two loops a table fewer than a push that finds again."""
     data, model = shape
     mesh = create_mesh(data, model, v5e[:data * model])
     hlo = _compile_deepfm_step(mesh, use_hash=True).as_text()
@@ -156,11 +158,12 @@ def test_v5e_hash_push_finds_then_inserts_in_place(v5e, shape):
     loops = [inst for inst, op in found
              if op == "while" and stages.get(inst) == "probe"]
     pushing = [inst for inst in loops if "hash_push_a2a" in paths[inst]]
-    assert pushing and len(pushing) % 6 == 0, loops     # two tables
     assert not re.search(r'conditional\(.*op_name="[^"]*jit\(probe\)/cond',
                          hlo)
-    if mesh.size == 1:
-        assert len(pushing) == 6 and len(loops) == 8, loops
+    if mesh.size > 1:
+        assert pushing and len(pushing) % 6 == 0, loops     # two tables
+    else:
+        assert len(pushing) == 4 and len(loops) == 6, loops
         keys = f"s32[{HASH_CAPACITY},2]"
         copies = [line.strip()[:120] for line in hlo.splitlines()
                   if f"= {keys}" in line and " copy(" in line]
@@ -168,12 +171,13 @@ def test_v5e_hash_push_finds_then_inserts_in_place(v5e, shape):
 
 
 def test_v5e_hash_finds_walk_chunks_in_place(v5e):
-    """The finds of each table, its pull's and its push's, are the loops
-    under ``probe`` that neither sort nor scatter: their trips gather a
-    chunk of bucket rows, and nothing as large as the unique buffer's
-    worth of them (26 x 4096 keys x 128 slots) is left in the program
-    outside the insert loops, whose full-width one keeps it. The key array
-    the finds read is copied nowhere, into a loop or out of it."""
+    """The find of each table, its pull's (the push takes what that found),
+    is the loop under ``probe`` that neither sorts nor scatters: its trips
+    gather a chunk of bucket rows, and nothing as large as the unique
+    buffer's worth of them (26 x 4096 keys x 128 slots) is left in the
+    program outside the insert loops, whose full-width one keeps it. The
+    key array the finds read is copied nowhere, into a loop or out of
+    it."""
     mesh = create_mesh(1, 1, v5e[:1])
     hlo = _compile_deepfm_step(mesh, use_hash=True).as_text()
     lines = hlo.splitlines()
@@ -206,7 +210,7 @@ def test_v5e_hash_finds_walk_chunks_in_place(v5e):
                 inserting |= inside
             else:
                 finds.append([i for c in inside for i in comps[c]])
-    assert len(finds) == 4, len(finds)          # two tables, pull and push
+    assert len(finds) == 2, len(finds)          # two tables, the pull's
     for body in finds:
         assert any(makes(i, chunk) for i in body)
         assert not [i.name for i in body if makes(i, unique)]
