@@ -788,8 +788,11 @@ def merge_gradients(state: HashTableState,
     ``resolved`` is what that pull found for the plan's slots
     (:func:`pull_distinct` of the same keys under the same mask, the
     table unwritten since): its ``slot`` is :func:`find_or_insert`'s
-    ``found``, and no key is looked for again. ``record_stats`` then counts
-    ``push_slots_carried``, the valid keys whose find the push took."""
+    ``found``, and no key is looked for again; its ``rows`` hold a missing
+    key's init row where the apply wants it (``table.apply_rows``'s
+    ``pulled``), so ``fresh`` and ``inserted`` are None and no init row is
+    made here. ``record_stats`` then counts ``push_slots_carried``, the
+    valid keys whose find the push took."""
     initializer = make_initializer(initializer)
     dim = state.dim
     empty = empty_key(state.keys.dtype)
@@ -813,11 +816,13 @@ def merge_gradients(state: HashTableState,
     keys_arr, slot, inserted, failed = find_or_insert(
         state.keys, uniq, valid, max_probes, record_stats,
         found=None if resolved is None else resolved.slot)
-    if resolved is not None:
+    if resolved is None:
+        fresh = init_rows(initializer, state.init_rng, uniq, dim,
+                          state.weights.dtype)
+    else:
+        fresh = inserted = None
         record_stat("push_slots_carried", jnp.sum(valid, dtype=jnp.int32),
                     record_stats)
-    fresh = init_rows(initializer, state.init_rng, uniq, dim,
-                      state.weights.dtype)
     return (keys_arr, jnp.sum(failed).astype(jnp.int32),
             (slot, valid & (slot >= 0), summed, counts, fresh, inserted))
 
@@ -847,7 +852,8 @@ def apply_gradients(state: HashTableState,
     ``dedup_capacity`` (default ``n``) slots; the gather, the optimizer and
     the scatter are ``table.apply_rows``, whose cost follows the distinct
     keys of the batch and not ``dedup_capacity``. ``plan`` and
-    ``resolved`` are :func:`merge_gradients`'s.
+    ``resolved`` are :func:`merge_gradients`'s; with ``resolved`` the apply
+    takes the weight rows from it and gathers none.
     """
     keys_arr, failed, merged = merge_gradients(
         state, initializer, indices, grads, dedup_capacity=dedup_capacity,
@@ -855,6 +861,7 @@ def apply_gradients(state: HashTableState,
         record_stats=record_stats, plan=plan, resolved=resolved)
     weights, slots = table_lib.apply_rows(
         state.weights, state.slots, make_optimizer(optimizer), *merged,
+        pulled=None if resolved is None else resolved.rows,
         record_stats=record_stats)
     return HashTableState(
         keys=keys_arr, weights=weights, slots=slots,

@@ -439,7 +439,8 @@ class ArrayStore:
         new = table_lib.apply_gradients(
             local, optimizer, mask(flat) if plan is None else None, grads,
             dedup_capacity=dedup_capacity, record_stats=record_stats,
-            plan=None if plan is None else self.own(plan))
+            plan=None if plan is None else self.own(plan),
+            resolved=resolved)
         return (), new.weights, new.slots
 
     def outputs(self, carry, weights, slots, axes):

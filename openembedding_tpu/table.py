@@ -189,7 +189,9 @@ def apply_gradients(state: TableState,
                     dedup_capacity: Optional[int] = None,
                     in_counts: Optional[jnp.ndarray] = None,
                     record_stats: bool = False,
-                    plan: Optional[dedup.Plan] = None) -> TableState:
+                    plan: Optional[dedup.Plan] = None,
+                    resolved: Optional[dedup.Resolution] = None
+                    ) -> TableState:
     """Push + update in one step: combine duplicate grads, update touched rows.
 
     ``indices`` is [n] (or any shape), ``grads`` matches with a trailing
@@ -202,12 +204,17 @@ def apply_gradients(state: TableState,
     ``dedup_capacity`` slots; the gather, the optimizer and the scatter run
     over the distinct rows of the batch (:func:`apply_rows`), so their cost
     follows those and not ``dedup_capacity``. ``record_stats`` is
-    :func:`apply_rows`'s, ``plan`` :func:`merge_gradients`'s.
+    :func:`apply_rows`'s, ``plan`` :func:`merge_gradients`'s. ``resolved``
+    is what the step's pull read for the plan's slots
+    (:func:`read_distinct` of the same rows, the table unwritten since):
+    its rows are :func:`apply_rows`'s ``pulled``.
     """
     merged = merge_gradients(indices, grads, dedup_capacity=dedup_capacity,
                              in_counts=in_counts, plan=plan)
-    weights, slots = apply_rows(state.weights, state.slots, optimizer,
-                                *merged, record_stats=record_stats)
+    weights, slots = apply_rows(
+        state.weights, state.slots, optimizer, *merged,
+        pulled=None if resolved is None else resolved.rows,
+        record_stats=record_stats)
     return TableState(weights=weights, slots=slots)
 
 
@@ -216,7 +223,8 @@ def apply_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
                live: jnp.ndarray, summed: jnp.ndarray, counts: jnp.ndarray,
                fresh: Optional[jnp.ndarray] = None,
                inserted: Optional[jnp.ndarray] = None,
-               *, record_stats: bool = False):
+               *, pulled: Optional[jnp.ndarray] = None,
+               record_stats: bool = False):
     """The sparse apply over a deduplicated buffer: gather the rows, run the
     optimizer, scatter them back. Shared by the array and the hash
     ``apply_gradients``.
@@ -226,6 +234,11 @@ def apply_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
     and its write is dropped), ``summed`` [capacity, dim] and ``counts``
     the combined gradients. ``fresh`` [capacity, dim] replaces the gathered
     weights where ``inserted`` is set (the hash path's new keys).
+    ``pulled`` [capacity, dim] is every live slot's weight row where the
+    caller holds it already (a train step's pull read it for the same
+    buffer, ``dedup.Resolution.rows``: the stored row, or the init row of
+    a key its push inserts, so no ``fresh`` goes with it): a trip slices it
+    as it slices ``summed`` and gathers the optimizer's slot arrays alone.
 
     Both dedups leave the live slots in a prefix of the buffer, and a
     batch's distinct rows fill a fraction of it (a third, for Criteo-shaped
@@ -240,20 +253,25 @@ def apply_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
 
     ``record_stats`` (the trace-time gate of ``alltoall.record_stat``)
     counts ``apply_slots_live`` and ``apply_slots_walked`` a call: the
-    second over the buffer's capacity is the share of it the loop walked.
+    second over the buffer's capacity is the share of it the loop walked;
+    and ``push_rows_carried``, the live slots whose weight row came as
+    ``pulled``.
     """
     capacity = rows.shape[0]
     chunk = APPLY_CHUNK
     oob = jnp.asarray(weights.shape[0], rows.dtype)
     arrays, tree = jax.tree.flatten((weights, slots))
 
-    def updated(arrays, rows, live, summed, counts, fresh, inserted):
+    def updated(arrays, rows, live, summed, counts, fresh, inserted, pulled):
         """(where a run of slots is written, its new rows array by array)."""
         # a dead slot gathers row 0 and is dropped on the scatter, so its
         # (garbage) update never lands
         at = jnp.where(live, rows, 0)
-        w, s = gather_rows(*jax.tree.unflatten(tree, arrays), at)
-        if fresh is not None:
+        w, s = jax.tree.unflatten(tree, arrays)
+        w, s = gather_rows(None if pulled is not None else w, s, at)
+        if pulled is not None:
+            w = pulled
+        elif fresh is not None:
             w = jnp.where(inserted[:, None], fresh, w)
         new = optimizer_block_update(optimizer, w, s, summed, counts)
         return jnp.where(live, at, oob), jax.tree.leaves(new)
@@ -304,7 +322,7 @@ def apply_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
                 pick(arrays, True), jnp.where(live, rows, oob), staged))
         return arrays, trips * chunk
 
-    per_slot = (rows, live, summed, counts, fresh, inserted)
+    per_slot = (rows, live, summed, counts, fresh, inserted, pulled)
     if capacity <= chunk:
         arrays = scatter_rows(arrays, *updated(arrays, *per_slot))
         walked = jnp.int32(capacity)
@@ -313,16 +331,20 @@ def apply_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
     record_stat("apply_slots_live", jnp.sum(live, dtype=jnp.int32),
                 record_stats)
     record_stat("apply_slots_walked", walked, record_stats)
+    if pulled is not None:
+        record_stat("push_rows_carried", jnp.sum(live, dtype=jnp.int32),
+                    record_stats)
     return jax.tree.unflatten(tree, arrays)
 
 
-def gather_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
-                at: jnp.ndarray):
+def gather_rows(weights: Optional[jnp.ndarray],
+                slots: Dict[str, jnp.ndarray], at: jnp.ndarray):
     """Rows of the weights and of every slot array at ``at``: the read
-    half of a sparse update (array and hash apply paths)."""
+    half of a sparse update (array and hash apply paths). ``weights`` None:
+    the caller holds those rows, and None is what it gets for them."""
     @scope.stage("apply_gather")
     def gather(weights, slots, at):
-        return (jnp.take(weights, at, axis=0),
+        return (None if weights is None else jnp.take(weights, at, axis=0),
                 {k: jnp.take(v, at, axis=0) for k, v in slots.items()})
 
     return gather(weights, slots, at)

@@ -292,7 +292,9 @@ def _three_steps(name, seed, planned, monkeypatch):
     configuration ``name``; without ``planned`` the step's plan is empty,
     which makes it ``collection.pull`` and ``apply_gradients`` as they run
     without one; ``planned="each"`` hides from the step that its two
-    tables are fed one array, which makes it a plan a table."""
+    tables are fed one array, which makes it a plan a table;
+    ``planned="resolve_again"`` keeps from the push what the pull resolved,
+    which makes it the push that has the plan alone."""
     from benchmark import offload_system, run as bench_run, system
     from benchmark.traffic_gen import zipf_train
 
@@ -305,6 +307,8 @@ def _three_steps(name, seed, planned, monkeypatch):
     elif planned == "each":
         monkeypatch.setattr(built.coll, "same_columns",
                             lambda inputs: SameColumns())
+    elif planned == "resolve_again":
+        _resolve_again(built.coll)
     state = lib.initial_state(built, seed, on_device=False)
     pool = [system.program_batch(built, raw)
             for raw in zipf_train.make(traffic, config, seed)]
@@ -319,6 +323,12 @@ def _three_steps(name, seed, planned, monkeypatch):
         lib.flush(built, state)
         out = out, lib.store_rows(built, pool[:3])
     return losses, out
+
+
+def _resolve_again(coll):
+    """What the step ran before the push took the pull's resolution."""
+    pull = coll.pull_resolved
+    coll.pull_resolved = lambda *a, **kw: (pull(*a, **kw)[0], {})
 
 
 @pytest.mark.parametrize("name", ["tiny_array", "tiny_hash", "tiny_offload"])
@@ -353,6 +363,55 @@ def test_three_train_steps_leave_the_state_they_left(name, monkeypatch):
     assert _counts(counted) == {"plan_columns_same_object": 3,
                                 "dedup_plans_built": 3,
                                 "dedup_plan_tables": 6}
+
+
+CARRY_COUNTERS = ("pull_keys_live", "push_slots_carried", "push_rows_carried",
+                  "hash_find_slots_live", "hash_find_slots_walked",
+                  "apply_slots_live")
+
+
+@pytest.mark.parametrize("name", ["tiny_array", "tiny_hash", "tiny_offload"])
+def test_a_step_resolves_a_key_once_and_counts_it(name, monkeypatch):
+    """At the rehearsal shapes, under ``record_stats``: three steps whose
+    push takes the pull's resolution leave the losses, dense state, tables
+    (and host store) of three whose push resolves again, bit for bit. The
+    push took a slot and a row for every live distinct key the pull
+    resolved; the push that resolves again counts nothing carried, and
+    its finds walk the pushes' keys on top of what the table fills (and
+    the offload tier's inserts) walk in both."""
+    monkeypatch.setattr(table_lib, "APPLY_CHUNK", STEP_CHUNK)
+    monkeypatch.setattr(table_lib, "FIND_CHUNK", STEP_CHUNK)
+    counted = {}
+    observability.set_evaluate_performance(True)
+    try:
+        for how in ("resolve_again", True):
+            for program in PROGRAMS:
+                program.cache_clear()
+            observability.GLOBAL.reset()
+            counted[how] = _three_steps(name, 3700000003, how, monkeypatch), {
+                k: int(v["count"])
+                for k, v in observability.GLOBAL.snapshot().items()
+                if k in CARRY_COUNTERS}
+    finally:
+        observability.set_evaluate_performance(False)
+        observability.GLOBAL.reset()
+        for program in PROGRAMS:
+            program.cache_clear()
+    (want, again), (got, took) = counted["resolve_again"], counted[True]
+    assert got[0] == want[0]
+    _same_bits(got[1], want[1])
+    live = took["pull_keys_live"]
+    assert live > 0 and again["pull_keys_live"] == live
+    assert took["push_rows_carried"] == took["apply_slots_live"] == live
+    assert not {"push_rows_carried", "push_slots_carried"} & set(again)
+    if name == "tiny_array":
+        assert "push_slots_carried" not in took
+    else:
+        assert took["push_slots_carried"] == live
+        assert took["hash_find_slots_live"] == again["hash_find_slots_live"]
+        pushes = again["hash_find_slots_walked"] - \
+            took["hash_find_slots_walked"]
+        assert pushes >= live and pushes % STEP_CHUNK == 0
 
 
 # --- one plan a distinct id column ------------------------------------------
@@ -637,6 +696,58 @@ def test_lower_train_step_lowers_the_program_the_steps_ran(name, program):
     state, _ = trainer.train_step(state, apart)
     assert trainer.lower_train_step(*abstract).as_text() == before
     jax.block_until_ready(state)
+
+
+@pytest.mark.parametrize("name", ["tiny_array", "tiny_hash"])
+def test_the_planned_push_neither_finds_nor_gathers_weights(name,
+                                                            monkeypatch):
+    """The compiled one-plan step (CPU), chunks small enough for loops:
+    beside the two insert loops a table, the push that resolves again holds
+    a find loop under ``probe``, the push that takes the pull's resolution
+    none; and its apply gathers the accumulator alone, half the gathers
+    under ``apply_gather``. The pull's find and read are what they were."""
+    from benchmark import stage_reduce, trace_reduce
+    monkeypatch.setattr(table_lib, "APPLY_CHUNK", STEP_CHUNK)
+    monkeypatch.setattr(table_lib, "FIND_CHUNK", STEP_CHUNK)
+    prefix = "hash_" if name == "tiny_hash" else ""
+
+    def counts(patch):
+        for program in PROGRAMS:
+            program.cache_clear()
+        _, built, pool = _tiny(name)
+        if patch:
+            patch(built.coll)
+        state, batch = _abstract_step(built, pool[0])
+        batch = dict(batch, **{SAME_COLUMNS: built.coll.same_columns(
+            pool[0]["sparse"])})
+        hlo = built.trainer.lower_train_step(state, batch).compile().as_text()
+        paths = trace_reduce.scope_names(hlo)
+        stages = stage_reduce.instruction_stages(hlo, paths)
+        found = re.findall(
+            r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (?:\(.*?\)|\S+) (gather|while)\(",
+            hlo, re.M)
+
+        def count(op, stage, verb):
+            return sum(o == op and stages.get(inst) == stage
+                       and f"{prefix}{verb}_a2a" in paths.get(inst, "").split("/")
+                       for inst, o in found)
+
+        return {"push finds and inserts": count("while", "probe", "push"),
+                "pull finds": count("while", "probe", "pull"),
+                "pull reads": count("while", "resolve", "pull"),
+                "apply gathers": count("gather", "apply_gather", "push")}
+
+    try:
+        again, took = counts(_resolve_again), counts(None)
+    finally:
+        for program in PROGRAMS:
+            program.cache_clear()
+    hashed = name == "tiny_hash"
+    assert again == {"push finds and inserts": 6 * hashed,
+                     "pull finds": 2 * hashed, "pull reads": 2,
+                     "apply gathers": 4}
+    assert took == dict(again, **{"push finds and inserts": 4 * hashed,
+                                  "apply gathers": 2})
 
 
 def test_the_routed_step_is_the_program_it_is_without():

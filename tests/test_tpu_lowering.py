@@ -231,7 +231,10 @@ def test_v5e_apply_walks_its_buffer_in_place(v5e, shape, use_hash):
     of the unique buffer, under an apply stage and under no conditional
     (on four chips the push's branches only merge; the apply follows
     them): a loop in a branch gets its table copied in. No copy of an
-    array as long as a device's share of a table is left."""
+    array as long as a device's share of a table is left. A trip gathers
+    the weights and the accumulator; on one chip the weight rows come with
+    the step's plan from its pull (``dedup.Resolution``) and a trip gathers
+    the accumulator alone."""
     data, model = shape
     mesh = create_mesh(data, model, v5e[:data * model])
     hlo = _compile_deepfm_step(mesh, use_hash=use_hash).as_text()
@@ -242,6 +245,9 @@ def test_v5e_apply_walks_its_buffer_in_place(v5e, shape, use_hash):
              and stages.get(inst, "").startswith("apply_")]
     assert len(loops) == 2, loops
     assert not [inst for inst in loops if "/cond/" in paths.get(inst, "")]
+    gathers = [inst for inst, op in found
+               if op == "gather" and stages.get(inst) == "apply_gather"]
+    assert len(gathers) == (2 if mesh.size == 1 else 4), gathers
     rows = (HASH_CAPACITY // mesh.size if use_hash
             else chip_smoke.FEATURES * ROWS_PER_FEATURE)
     copied = [line.strip()[:120] for line in hlo.splitlines()
