@@ -268,11 +268,12 @@ def apply_rows(weights: jnp.ndarray, slots: Dict[str, jnp.ndarray],
         # (garbage) update never lands
         at = jnp.where(live, rows, 0)
         w, s = jax.tree.unflatten(tree, arrays)
-        w, s = gather_rows(None if pulled is not None else w, s, at)
-        if pulled is not None:
-            w = pulled
-        elif fresh is not None:
-            w = jnp.where(inserted[:, None], fresh, w)
+        if pulled is None:
+            w, s = gather_rows(w, s, at)
+            if fresh is not None:
+                w = jnp.where(inserted[:, None], fresh, w)
+        else:
+            w, (_, s) = pulled, gather_rows(None, s, at)
         new = optimizer_block_update(optimizer, w, s, summed, counts)
         return jnp.where(live, at, oob), jax.tree.leaves(new)
 

@@ -199,7 +199,7 @@ def _pushed(coll, states, batches, how):
 @pytest.mark.parametrize("model,plane", [(1, "a2a"), (2, "psum")],
                          ids=["1x1", "psum2"])
 @pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
-def test_the_push_takes_what_the_pull_resolved(devices8, monkeypatch, kind,
+def test_the_push_takes_what_the_pull_resolved(devices8, request, kind,
                                                model, plane, chunks):
     """Fresh keys every step, a buffer of one chunk or of several: four
     steps whose push takes the pull's slots and rows leave, step by step,
@@ -207,23 +207,16 @@ def test_the_push_takes_what_the_pull_resolved(devices8, monkeypatch, kind,
     plan. The resolution is each shard's own: rows of the plan's length a
     shard, a hash table's slots beside them."""
     if chunks == "several_chunks":
-        monkeypatch.setattr(table_lib, "APPLY_CHUNK", CHUNK)
-        monkeypatch.setattr(table_lib, "FIND_CHUNK", CHUNK)
-    for program in PROGRAMS:
-        program.cache_clear()
+        request.getfixturevalue("small_chunks")
     mesh = create_mesh(1, model, devices8[:model])
     coll, states = _collection(kind, mesh, plane)
     batches = list(_fresh_batches(kind, 4))
-    try:
-        want = _pushed(coll, states, batches, "alone")
-        for how in ("planned", "carried"):
-            _same_bits(_pushed(coll, states, batches, how), want)
-        inputs, _ = batches[0]
-        plan = coll.plan(inputs)
-        resolved = coll.pull_resolved(states, inputs, plan=plan)[1]["t"]
-    finally:
-        for program in PROGRAMS:
-            program.cache_clear()
+    want = _pushed(coll, states, batches, "alone")
+    for how in ("planned", "carried"):
+        _same_bits(_pushed(coll, states, batches, how), want)
+    inputs, _ = batches[0]
+    plan = coll.plan(inputs)
+    resolved = coll.pull_resolved(states, inputs, plan=plan)[1]["t"]
     assert resolved.rows.shape == (model * N, DIM)
     if kind == "array":
         assert resolved.slot is None
