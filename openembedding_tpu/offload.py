@@ -37,6 +37,20 @@ by id range or id array (``restore`` goes through it), and ``warm(cache,
 ids)`` makes a set of ids cache-resident in bulk chunks, the books updated
 as a step's ``apply_prepared`` updates them. Inserts update the cache in
 place (the table operands are donated): use the state a call returns.
+
+**A keyed tier.** :class:`ShardedOffloadedTable` built WITHOUT a ``vocab``
+holds unbounded 64-bit keys (the reference's own key path,
+``to_hash_bucket_fast(col, 2**62)``, which is what its PMem server stores):
+the host store is addressed by key through an index on the host
+(``offload_keys.py``), store rows are handed out in order of first sight
+and the store grows a block at a time, the cache is a wide-key hash table,
+and a key no store has seen is born in the step as an all-in-HBM hash
+table makes it, its trained row reaching the store at the next write-back.
+Prepare, eviction, flush, persist and restore are the bounded tier's, by
+store row; only the device boundary (insert, write-back read) and the
+files speak in keys. A table fed the same column (a ``:linear`` twin) is
+made with ``companion()`` and shares the index: one walk a column a step.
+The class's docstring has the protocol.
 """
 
 from __future__ import annotations
@@ -44,6 +58,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import sys
 import threading
 from typing import Any, Dict, Optional
 
@@ -60,6 +75,7 @@ from .optim.initializers import make_initializer
 from .optim.optimizers import make_optimizer
 from .utils import fs
 from . import hash_table as hash_lib
+from . import offload_keys as keys_lib
 from . import table as table_lib
 
 OFFLOAD_META_FILE = "offload_meta"
@@ -79,7 +95,9 @@ def _persist_store(path: str, *, vocab: int, meta: EmbeddingVariableMeta,
                    host_weights: np.ndarray,
                    host_slots: Dict[str, np.ndarray],
                    host_work_id: np.ndarray,
-                   compress: str = "") -> Dict[str, Any]:
+                   compress: str = "",
+                   keys: Optional[Any] = None,
+                   stored: Optional[np.ndarray] = None) -> Dict[str, Any]:
     """Shared base/delta checkpoint writer (both offload tiers).
 
     First call writes a base file with every row; later calls write only
@@ -89,6 +107,11 @@ def _persist_store(path: str, *, vocab: int, meta: EmbeddingVariableMeta,
     ``COMPACT_CHAIN_LEN`` entries: a fresh base replaces the whole chain and
     superseded files are deleted, bounding file count, meta size, and
     restore replay time over arbitrarily long runs.
+
+    A keyed store (``keys``: the key of each store row; ``stored``: the
+    rows that hold something) writes its rows under their KEYS in ``ids``,
+    the base every stored row; a bounded store's base says ``row_range``
+    and lists no id.
 
     The commit is CRASH-CONSISTENT (the transactional property of the
     reference's checkpoint list in the pool root,
@@ -121,21 +144,27 @@ def _persist_store(path: str, *, vocab: int, meta: EmbeddingVariableMeta,
     from .utils import compress as compress_lib
     savez = np.savez_compressed \
         if compress_lib.check_persist_codec(compress) else np.savez
-    if not chain:
-        fname = f"base_{work_id}.npz"
-        with fs.open_atomic(fs.join(path, fname)) as f:
-            savez(f, ids=np.arange(vocab, dtype=np.int64),
-                  weights=host_weights, work_id=host_work_id,
-                  **{f"slot_{k}": v for k, v in host_slots.items()})
-        changed = vocab
+    base = not chain
+    fname = f"base_{work_id}.npz" if base else f"inc_{work_id}.npz"
+    if base and keys is None:
+        # every row, said by its range: the ids would be vocab x 8 bytes
+        # of 0..vocab-1 (1.3 GB at 163.6M rows)
+        named = {"row_range": np.asarray([0, vocab], np.int64)}
+        rows, changed = slice(None), vocab
     else:
-        ids = np.nonzero(host_work_id > persisted_work)[0].astype(np.int64)
-        fname = f"inc_{work_id}.npz"
-        with fs.open_atomic(fs.join(path, fname)) as f:
-            savez(f, ids=ids, weights=host_weights[ids],
-                  work_id=host_work_id[ids],
-                  **{f"slot_{k}": v[ids] for k, v in host_slots.items()})
-        changed = int(ids.size)
+        if keys is None:
+            take = host_work_id > persisted_work
+        else:
+            take = stored if base else stored & (
+                np.asarray(host_work_id)[:stored.size] > persisted_work)
+        rows = np.nonzero(take)[0]
+        named = {"ids": rows.astype(np.int64) if keys is None
+                 else keys[rows]}
+        changed = int(rows.size)
+    with fs.open_atomic(fs.join(path, fname)) as f:
+        savez(f, weights=host_weights[rows], work_id=host_work_id[rows],
+              **named,
+              **{f"slot_{k}": v[rows] for k, v in host_slots.items()})
     chain.append({"file": fname, "work_id": work_id})
     # the commit point: before this rename readers see the old chain
     fs.write_json_atomic(meta_path, {"checkpoints": chain, "vocab": vocab,
@@ -199,7 +228,9 @@ def _replay_store(path: str, *, vocab: int, load) -> int:
     max_work = 0
     for entry in meta["checkpoints"]:
         data = np.load(fs.open_file(fs.join(path, entry["file"]), "rb"))
-        load(data["ids"], data["weights"],
+        ids = data["ids"] if "ids" in data.files \
+            else slice(*(int(r) for r in data["row_range"]))
+        load(ids, data["weights"],
              {k[len("slot_"):]: data[k] for k in data.files
               if k.startswith("slot_")}, data["work_id"])
         max_work = max(max_work, int(entry["work_id"]))
@@ -360,6 +391,9 @@ class HostOffloadedTable:
         self.clear_cache()  # stale pre-restore rows must not write back
 
 
+_NO_ROWS = np.zeros(0, np.int64)
+
+
 @dataclasses.dataclass
 class PreparedBatch:
     """Host-side half of a prepare, produced ahead of time.
@@ -376,10 +410,15 @@ class PreparedBatch:
     inserting rows the rebuild just dropped.
     """
 
-    uniq: np.ndarray                      # unique valid batch ids
-    missing: np.ndarray                   # the non-resident subset
+    uniq: np.ndarray                      # store rows of the batch's
+                                          # unique valid ids (a bounded
+                                          # tier: the ids themselves)
+    missing: np.ndarray                   # those stored and not cached
     rows: Optional[np.ndarray]            # host_weights[missing]
     slot_rows: Dict[str, np.ndarray]      # host_slots[*][missing]
+    # keyed tier: rows of keys the store has nothing for yet; the step
+    # itself makes their rows
+    fresh: np.ndarray = dataclasses.field(default_factory=lambda: _NO_ROWS)
     needs_evict: bool = False
     gen: int = 0                          # residency generation stamp
     lookups: int = 0                      # ids the batch held, duplicates
@@ -419,18 +458,39 @@ class ShardedOffloadedTable:
     The work_id watermark + incremental base/delta persistence protocol is
     unchanged from :class:`HostOffloadedTable` (the ICDE'23 checkpoint
     design, PmemEmbeddingTable.h:285-328).
+
+    **The key space decides the store.** With a ``vocab`` the ids are
+    bounded, an id is its own store row and the cache is keyed by int32
+    row ids. WITHOUT one (``vocab=None``, as ``input_dim == -1`` is a
+    hash variable) the tier holds 64-bit keys, the reference's
+    ``to_hash_bucket_fast(col, 2**62)`` ids: a host index maps a key to
+    its store row (``offload_keys.KeyIndex``; rows are handed out in
+    order of first sight), the store and every book are by store row and
+    grow a block at a time (``offload_keys.BlockArray``), the cache is a
+    wide-key table, and a batch column is int64 keys or ``[..., 2]``
+    int32 pairs (``FusedMapper``'s wide form). A key is then one of
+    three things to a prepare: cached or planned (nothing to do), stored
+    and not cached (its row is copied in, as in a bounded tier), or
+    never seen: it is handed a store row and booked, nothing is copied,
+    and the STEP makes its row as an all-in-HBM hash table does
+    (``hash_table.find_or_insert`` + the initializer's per-key row), so
+    the tier's initializer is what a fresh key reads. Its trained row
+    reaches the store at the next write-back. Everything by store row
+    below (``uniq``, ``missing``, ``dirty_ids``) is an id in a bounded
+    tier and an index row in a keyed one.
     """
 
     def __init__(self, name: str, meta: EmbeddingVariableMeta,
                  optimizer: Any, initializer: Any = None, *,
-                 vocab: int, cache_capacity: int, mesh,
+                 cache_capacity: int, mesh, vocab: Optional[int] = None,
                  persist_pending_window: int = 64,
                  occupancy_threshold: float = 0.7,
                  keep_fraction: float = 0.5,
                  backing_dir: Optional[str] = None,
                  persist_compress: str = "",
                  seed: int = 0,
-                 overflow_check_every_n_batches: int = 0):
+                 overflow_check_every_n_batches: int = 0,
+                 _space: Optional[keys_lib.KeySpace] = None):
         from .parallel import sharded_hash as sh
         self.name = name
         self.meta = meta
@@ -440,7 +500,9 @@ class ShardedOffloadedTable:
             initializer or table_lib.DEFAULT_INITIALIZER)
         self._optimizer_config = optimizer
         self._initializer_config = initializer
-        self.vocab = int(vocab)
+        # no vocab: an unbounded key space, a store addressed by key
+        self.keyed = vocab is None or int(vocab) < 0
+        self.vocab = -1 if self.keyed else int(vocab)
         self.cache_capacity = int(cache_capacity)
         self.persist_pending_window = persist_pending_window
         # bounded-lag overflow detection for loops that never reach a
@@ -459,7 +521,8 @@ class ShardedOffloadedTable:
         # npz members — np.load reads raw and compressed chains alike)
         self.persist_compress = compress_lib.check_persist_codec(
             persist_compress)
-        self.spec = sh.make_hash_sharding_spec(mesh, cache_capacity)
+        self.spec = sh.make_hash_sharding_spec(
+            mesh, cache_capacity, key_width=64 if self.keyed else 32)
         dim = meta.embedding_dim
         dtype = np.dtype(table_lib.resolve_dtype(meta))
 
@@ -469,16 +532,44 @@ class ShardedOffloadedTable:
                 arr = np.lib.format.open_memmap(
                     os.path.join(backing_dir, f"{name}_{fname}.npy"),
                     mode="w+", dtype=adtype, shape=shape)
+            elif fill is not None and not fill:
+                return np.zeros(shape, adtype)  # pages come when written
             else:
                 arr = np.empty(shape, adtype)
             if fill is not None:
                 arr[:] = fill
             return arr
 
+        # a keyed tier's key space: its own, or the one it was made a
+        # companion over (``companion``)
+        self._owns_space = self.keyed and _space is None
+        self._space = (_space or keys_lib.KeySpace()) if self.keyed else None
+        self._made_with = dict(
+            cache_capacity=cache_capacity, mesh=mesh,
+            persist_pending_window=persist_pending_window,
+            occupancy_threshold=occupancy_threshold,
+            keep_fraction=keep_fraction, backing_dir=backing_dir,
+            persist_compress=persist_compress, seed=seed,
+            overflow_check_every_n_batches=overflow_check_every_n_batches)
+
+        def _rows(fname, tail, adtype, fill=0):
+            """One per-row array of the store or its books: ``vocab``
+            rows, or blocks that grow with a keyed store."""
+            if not self.keyed:
+                return _alloc(fname, (self.vocab,) + tuple(tail), adtype,
+                              fill)
+            return keys_lib.BlockArray(
+                tail, adtype, self._space.layout,
+                lambda i, shape: _alloc(f"{fname}.{i}", shape, adtype, fill))
+
         # host store, eagerly initialized in bounded chunks (a table bigger
         # than HBM must not be materialized on device either)
         from .optim import initializers as init_lib
-        if isinstance(self.initializer, init_lib.Constant):
+        if self.keyed:
+            # a store row is written (load_rows, a write-back) before
+            # anything reads it: a key's first row is the cache's to make
+            self.host_weights = _rows("weights", (dim,), dtype)
+        elif isinstance(self.initializer, init_lib.Constant):
             # constant init fills host-side: the chunked device path would
             # push the whole store through device transfers to compute a
             # constant. Nothing is put on the device here, not even a
@@ -496,19 +587,21 @@ class ShardedOffloadedTable:
         self.host_slots: Dict[str, np.ndarray] = {}
         for sname, sshape in self.optimizer.slot_shapes(dim).items():
             sdtype = np.dtype(self.optimizer.slot_dtype(sname, dtype))
-            self.host_slots[sname] = _alloc(
-                f"slot_{sname}", (self.vocab,) + tuple(sshape), sdtype,
+            self.host_slots[sname] = _rows(
+                f"slot_{sname}", tuple(sshape), sdtype,
                 self.optimizer.slot_init(sname))
-        self.host_work_id = _alloc("work_id", (self.vocab,), np.int64, 0)
+        self.host_work_id = _rows("work_id", (), np.int64)
 
-        self._resident = np.zeros(self.vocab, bool)
+        self._resident = _rows("resident", (), bool) if self.keyed \
+            else np.zeros(self.vocab, bool)
         self._resident_count = 0  # kept exact; vocab-sized sums are O(GB)
         # PLANNED residency: rows an in-flight PreparedBatch will insert at
         # its apply. Lets a K-deep prepare chain compute batch N+k's misses
         # against residency-as-of-batch-N+k-1 without waiting for the
         # device applies; apply/cancel move or clear the marks, eviction
         # invalidates them wholesale via the generation bump
-        self._planned = np.zeros(self.vocab, bool)
+        self._planned = _rows("planned", (), bool) if self.keyed \
+            else np.zeros(self.vocab, bool)
         self._planned_count = 0
         self._gen = 0
         # guards the residency books (_resident/_planned/counts/_gen):
@@ -526,7 +619,8 @@ class ShardedOffloadedTable:
         # prepares/applies redone because an eviction rebuilt residency
         # under them (the generation protocol's retry paths)
         self.gen_retries = 0
-        self._last_touch = np.zeros(self.vocab, np.int64)
+        self._last_touch = _rows("last_touch", (), np.int64) if self.keyed \
+            else np.zeros(self.vocab, np.int64)
         self.work_id = 1
         self.persisted_work = 0
         self._batches_since_persist = 0
@@ -544,8 +638,27 @@ class ShardedOffloadedTable:
         # chunk granularity, drives the whole-model delta checkpoints
         # (checkpoint.save_checkpoint mode="delta") — this tier is where
         # the machinery was generalized FROM (dirty.py).
-        self._dirty = DirtyTracker(self.vocab, rows_per_chunk=1,
-                                   name=f"offload.{name}", lock=self._book)
+        if self.keyed:
+            # the key space has key -> store row and the key of each row;
+            # the table's own: the rows handed out whose row its store
+            # does not hold yet (UNBORN: a fresh key's row lives in the
+            # cache until a write-back brings it)
+            self._unborn = _rows("unborn", (), bool, fill=True)
+            dirty_bits = _rows("dirty", (), bool)
+            self._dirty = keys_lib.BlockDirty(
+                dirty_bits, name=f"offload.{name}", lock=self._book)
+            mine = [self.host_weights, self.host_work_id,
+                    *self.host_slots.values(), self._resident,
+                    self._planned, self._last_touch, self._unborn,
+                    dirty_bits]
+            with self._space._grow_lock:
+                for arr in mine:    # rows a companion's keys already took
+                    arr.grow(len(self._space.keys))
+                self._space.arrays.extend(mine)
+        else:
+            self._dirty = DirtyTracker(self.vocab, rows_per_chunk=1,
+                                       name=f"offload.{name}",
+                                       lock=self._book)
         self._persister: Optional[threading.Thread] = None
         self._persister_err: Optional[BaseException] = None
         # latest cumulative insert_failures copy; read ONLY at join
@@ -561,7 +674,9 @@ class ShardedOffloadedTable:
         store is flagged, its pages are OS-evictable rather than
         resident), residency-book bytes, and the live row counters. Row
         counters read under ``_book``; the vocab-sized dirty scan is
-        deliberately NOT performed (O(GB) at north-star vocab)."""
+        deliberately NOT performed (O(GB) at north-star vocab). A keyed
+        tier adds its index and keys, and ``store_rows`` /
+        ``index_load``."""
         store = self.host_weights.nbytes + self.host_work_id.nbytes \
             + sum(a.nbytes for a in self.host_slots.values())
         book = self._resident.nbytes + self._planned.nbytes \
@@ -570,7 +685,18 @@ class ShardedOffloadedTable:
             resident = self._resident_count
             planned = self._planned_count
             evictions = self.evictions
+            keyed = {} if not self.keyed else {
+                # a keyed store's own: the index, the key of every row
+                # and the unborn flags; rows handed out; the index's load
+                # (counted by the table the key space was made with)
+                "index_bytes": float(self._index.nbytes
+                                     * self._owns_space),
+                "key_bytes": float(self._keys.nbytes * self._owns_space
+                                   + self._unborn.nbytes),
+                "store_rows": float(self._index.rows),
+                "index_load": float(self._index.load)}
         return {
+            **keyed,
             "store_bytes": float(store),
             "store_memmap": float(isinstance(self.host_weights, np.memmap)),
             "book_bytes": float(book),
@@ -579,6 +705,32 @@ class ShardedOffloadedTable:
             "cache_capacity_rows": float(self.cache_capacity),
             "evictions": float(evictions),
         }
+
+    @property
+    def _index(self) -> keys_lib.KeyIndex:
+        return self._space.index
+
+    @property
+    def _keys(self) -> keys_lib.BlockArray:
+        return self._space.keys
+
+    def companion(self, name: str, meta: EmbeddingVariableMeta,
+                  optimizer: Any = None, initializer: Any = None
+                  ) -> "ShardedOffloadedTable":
+        """A second keyed table over THIS table's keys: the ``:linear``
+        twin of a fused table, fed the same column. It has its own rows,
+        books and cache (its ``embedding_spec()`` goes beside this one's);
+        the index, the key of every store row and where a row lives are
+        shared, so a step's keys are found once for both
+        (``offload_keys.KeySpace``) and a key's store row is the same in
+        both. Built like this table unless told otherwise."""
+        if not self.keyed:
+            raise ValueError("a bounded tier's ids are their own rows: "
+                             "there is no index to share")
+        return ShardedOffloadedTable(
+            name, meta, optimizer or self._optimizer_config,
+            initializer or self._initializer_config, _space=self._space,
+            **self._made_with)
 
     # --- spec / state creation ---------------------------------------------
     def embedding_spec(self, **kw) -> EmbeddingSpec:
@@ -591,10 +743,11 @@ class ShardedOffloadedTable:
             dtype=self.meta.datatype, optimizer=self._optimizer_config,
             initializer=self._initializer_config,
             hash_capacity=self.cache_capacity,
-            # the cache is keyed by BOUNDED host-store row ids ([0, vocab));
-            # int32 keys are the right optimization here, not the wide
-            # default (which would mismatch this table's own insert plane)
-            key_dtype="int32")
+            # the cache's keys are the tier's ids, and must be the form
+            # its own insert plane (``self.spec``) writes: int32 where
+            # they are bounded store rows ([0, vocab)), wide pairs where
+            # the key space is not
+            key_dtype="wide" if self.keyed else "int32")
         return EmbeddingSpec(**{**base, **kw})
 
     def create_cache(self, rng: Optional[jax.Array] = None):
@@ -621,7 +774,8 @@ class ShardedOffloadedTable:
             raise RuntimeError("async writeback failed") from err
 
     def _start_writeback(self, cache, dirty_ids: np.ndarray) -> None:
-        """Copy the ``dirty_ids`` rows of the cache into the host store:
+        """Copy the ``dirty_ids`` rows (store rows, read from the cache
+        under their keys) of the cache into the host store:
         the device gathers just those rows (``read_rows_sharded``, in
         calls of ``WRITEBACK_CHUNK`` keys), so what crosses to the host is
         what is dirty and not the table. The reads are dispatched here,
@@ -643,8 +797,7 @@ class ShardedOffloadedTable:
 
         def read(lo):
             sub = dirty_ids[lo:lo + size]
-            keys = np.full((size,), hash_lib.empty_key(key_dtype), key_dtype)
-            keys[:sub.size] = sub
+            keys = self._device_keys(sub, size, key_dtype)
             out = sh.read_rows_sharded(cache, jnp.asarray(keys),
                                        mesh=self.mesh, spec=self.spec)
             for leaf in jax.tree.leaves(out):
@@ -664,6 +817,8 @@ class ShardedOffloadedTable:
                     self.host_slots[sname][ids] = \
                         srows[sname][:sub.size][found]
                 self.host_work_id[ids] = work
+                if self.keyed:      # the store holds the key's row now
+                    self._unborn[ids] = False
 
         starts = range(0, dirty_ids.size, size)
         if dirty_ids.size > WRITEBACK_ASYNC_ROWS:
@@ -710,17 +865,33 @@ class ShardedOffloadedTable:
         srows = {k: v[ids] for k, v in self.host_slots.items()}
         return rows, srows
 
+    def _device_keys(self, rows: np.ndarray, size: int,
+                     key_dtype: np.dtype) -> np.ndarray:
+        """The cache's keys of store ``rows``, padded with EMPTY to
+        ``size``: the rows themselves, or a keyed tier's ``[size, 2]``
+        (lo, hi) pairs."""
+        empty = hash_lib.empty_key(key_dtype)
+        if self.keyed:
+            keys = np.full((size, 2), empty, key_dtype)
+            keys[:rows.size] = hash_lib.split64(self._keys[rows])
+        else:
+            keys = np.full((size,), empty, key_dtype)
+            keys[:rows.size] = rows
+        return keys
+
     def _packed_layout(self, key_dtype: np.dtype):
         """Static column layout for the one-transfer insert, or None when
-        the table's dtypes rule it out (keys must be int32 so they bitcast
-        into an f32 column; weights and every slot must be f32)."""
+        the table's dtypes rule it out (key words must be int32 and
+        weights and every slot f32, so that one 32-bit buffer carries
+        them all): the key's column (a keyed tier: its two), the weight
+        row, then each slot's."""
         if key_dtype != np.int32 \
                 or self.host_weights.dtype != np.float32 \
                 or any(a.dtype != np.float32
                        for a in self.host_slots.values()):
             return None
         dim = int(np.prod(self.host_weights.shape[1:], dtype=np.int64))
-        col = 1 + dim
+        col = (2 if self.keyed else 1) + dim
         layout = []
         for sname in sorted(self.host_slots):
             shape = tuple(self.host_slots[sname].shape[1:])
@@ -743,13 +914,16 @@ class ShardedOffloadedTable:
 
     def _insert_rows(self, cache, ids: np.ndarray, rows: np.ndarray,
                      slot_rows: Dict[str, np.ndarray], size: int):
-        """Device half of an insert: pre-gathered host rows -> HBM cache,
+        """Device half of an insert: pre-gathered host rows (of store
+        rows ``ids``) -> HBM cache,
         in calls of ``size`` keys each (the last one padded with EMPTY
         keys, which the insert skips). The cache is updated in place: the
         program donates keys, weights and slots.
 
-        The payload ships as ONE packed f32 buffer per chunk (keys bitcast
-        into column 0) when dtypes allow — the per-step transfer count is
+        The payload ships as ONE packed 32-bit buffer per chunk (f32 with
+        the int32 key bitcast into column 0; a keyed tier's int32, both
+        key words in columns 0-1 and the rows by their bits) when dtypes
+        allow — the per-step transfer count is
         a measured cost on high-latency links (`python -m tools.offload_diag puts`) —
         with the generic per-array path as the fallback."""
         from .parallel import sharded_hash as sh
@@ -765,23 +939,24 @@ class ShardedOffloadedTable:
             with scope.span("offload.insert_pack", table=self.name):
                 if packed_fmt is not None:
                     dim, total_cols, layout = packed_fmt
+                    first = 2 if self.keyed else 1      # key columns
                     buf = np.zeros((size, total_cols), np.float32)
-                    kcol = np.full((size,), hash_lib.empty_key(np.int32),
-                                   np.int32)
-                    kcol[:sub.size] = sub
-                    buf[:, 0] = kcol.view(np.float32)
-                    buf[:sub.size, 1:1 + dim] = \
+                    words = buf.view(np.int32)  # the same 32 bits a cell
+                    words[:, :first] = self._device_keys(
+                        sub, size, np.int32).reshape(size, first)
+                    buf[:sub.size, first:first + dim] = \
                         rows[lo:lo + size].reshape(sub.size, dim)
                     for sname, start, cols, _shape in layout:
                         buf[:sub.size, start:start + cols] = \
                             slot_rows[sname][lo:lo + size].reshape(
                                 sub.size, cols)
                     h2d_bytes += buf.nbytes
-                    args = (jnp.asarray(buf), layout)
+                    # a wide table's program takes the buffer as int32:
+                    # a key word is any 32 bits, a NaN's among them
+                    args = (jnp.asarray(words if self.keyed else buf),
+                            layout)
                 else:
-                    ck = np.full((size,), hash_lib.empty_key(key_dtype),
-                                 key_dtype)
-                    ck[:sub.size] = sub
+                    ck = self._device_keys(sub, size, key_dtype)
                     cw = np.zeros((size,) + self.host_weights.shape[1:],
                                   self.host_weights.dtype)
                     cw[:sub.size] = rows[lo:lo + size]
@@ -863,11 +1038,61 @@ class ShardedOffloadedTable:
             cache = self._insert_rows(cache, sub, rows, srows, size)
         return cache
 
-    def host_prepare(self, ids, *, lookups: Optional[int] = None
-                     ) -> PreparedBatch:
+    def distinct(self, ids) -> np.ndarray:
+        """The distinct valid ids of a batch column, sorted: what
+        :meth:`host_prepare` makes of it first, so that tables fed one
+        column can share the pass (``Trainer`` does). A keyed tier
+        answers int64 keys, from int64 ids or ``[..., 2]`` (lo, hi)
+        pairs (a trailing axis of 2 is a pair axis, the collection's own
+        rule; a narrow dtype's minimum is its padding)."""
+        ids = np.asarray(ids)
+        if not self.keyed:
+            ids = np.unique(ids.ravel())
+            return ids[(ids >= 0) & (ids < self.vocab)]
+        if ids.ndim >= 2 and ids.shape[-1] == 2:
+            if ids.dtype == np.int32 and ids.flags.c_contiguous \
+                    and sys.byteorder == "little":
+                keys = np.unique(ids.view(np.int64))    # (lo, hi) as is
+            else:
+                keys = np.unique(hash_lib.join64(ids.astype(np.int32)))
+        else:
+            keys = np.unique(ids.ravel())
+            if keys.dtype.itemsize < 8:
+                keys = keys[keys != np.iinfo(keys.dtype).min]
+            keys = keys.astype(np.int64)
+        return keys[keys_lib.valid_keys(keys)]
+
+    def lookups_of(self, ids) -> int:
+        """Lookups a batch column holds, duplicates included."""
+        ids = np.asarray(ids)
+        pairs = self.keyed and ids.ndim >= 2 and ids.shape[-1] == 2
+        return int(ids.size // 2 if pairs else ids.size)
+
+    def rows_of(self, ids, *, insert: bool = False) -> np.ndarray:
+        """Store row of each of ``ids`` (DISTINCT; a keyed tier's int64
+        keys), -1 where the store has none; with ``insert`` a keyed
+        tier hands such a key its row (unborn: nothing stored yet)."""
+        ids = np.asarray(ids, np.int64)
+        if not self.keyed:
+            return np.where((ids >= 0) & (ids < self.vocab), ids, -1)
+        # a companion asked about the same key array is answered from
+        # the space's last answers: one walk a column a step
+        with scope.span("offload.key_index", table=self.name):
+            rows, _handed, probes = self._space.rows_of(
+                ids, insert, lambda: scope.span("offload.store_grow",
+                                                table=self.name))
+        # rows handed out and the index's load are gauges of the memory
+        # ledger (``memory_stats``: ``store_rows``, ``index_load``)
+        if probes:
+            scope.HISTOGRAMS.inc("offload_index_probes", probes,
+                                 table=self.name)
+        return rows
+
+    def host_prepare(self, ids, *, lookups: Optional[int] = None,
+                     distinct: bool = False) -> PreparedBatch:
         """Host-only half of :meth:`prepare`: residency math + host gather.
-        (``lookups``: the size of the batch these ids came from, where
-        ``ids`` is already its unique set — a recomputed prepare.)
+        (``distinct``: ``ids`` is already :meth:`distinct` of a batch
+        column of ``lookups`` lookups.)
 
         Misses are computed against ``resident OR planned``, and the
         result's own misses are marked PLANNED before returning — so a
@@ -884,24 +1109,34 @@ class ShardedOffloadedTable:
         ``flush``/``persist``/``restore``/``finish`` (see
         :meth:`check_overflow`; per-step reads would serialize the
         pipeline on a device round trip per table).
+
+        A keyed tier first finds each distinct key's store row in its
+        index (one walk, span ``offload.key_index``); a key the index
+        has not seen is handed the next row there and comes out
+        ``fresh``: planned like a miss, copied from nowhere.
         """
-        ids = np.asarray(ids).ravel()
         if lookups is None:
-            lookups = int(ids.size)
-        ids = np.unique(ids)
-        ids = ids[(ids >= 0) & (ids < self.vocab)]
+            lookups = self.lookups_of(ids)
+        if not distinct:
+            ids = self.distinct(ids)
+        rows = self.rows_of(ids, insert=True) if self.keyed else ids
+        return self._prepare_rows(rows, lookups)
+
+    def _prepare_rows(self, ids: np.ndarray, lookups: int) -> PreparedBatch:
+        """:meth:`host_prepare` of a batch's distinct store rows."""
         budget = int(self.occupancy_threshold * self.cache_capacity)
         while True:
             with self._book:
                 gen = self._gen
-                missing = ids[~(self._resident[ids] | self._planned[ids])]
+                absent = ids[~(self._resident[ids] | self._planned[ids])]
+                missing, fresh = self._born_unborn(absent)
                 if self._resident_count + self._planned_count \
-                        + missing.size > budget:
+                        + absent.size > budget:
                     # eviction rebuilds the cache (sync path); no gather
                     return PreparedBatch(uniq=ids, missing=missing,
                                          rows=None, slot_rows={},
-                                         needs_evict=True, gen=gen,
-                                         lookups=lookups)
+                                         fresh=fresh, needs_evict=True,
+                                         gen=gen, lookups=lookups)
             # gather OUTSIDE the lock (large memmap reads; safe — missing
             # rows are neither resident nor planned, so neither writeback
             # nor eviction touches them)
@@ -912,8 +1147,8 @@ class ShardedOffloadedTable:
                     continue  # evicted under the gather; recompute
                 # mark AFTER the gather succeeded — a failed prepare
                 # leaks nothing
-                self._planned[missing] = True
-                self._planned_count += int(missing.size)
+                self._planned[absent] = True
+                self._planned_count += int(absent.size)
             # counted here, on whichever thread prepares: the step's
             # critical path carries no counter of the tier but the bytes
             # it copies
@@ -921,8 +1156,20 @@ class ShardedOffloadedTable:
                                  table=self.name)
             scope.HISTOGRAMS.inc("offload_miss_rows", missing.size,
                                  table=self.name)
+            if fresh.size:
+                scope.HISTOGRAMS.inc("offload_fresh_keys", fresh.size,
+                                     table=self.name)
             return PreparedBatch(uniq=ids, missing=missing, rows=rows,
-                                 slot_rows=srows, gen=gen, lookups=lookups)
+                                 slot_rows=srows, fresh=fresh, gen=gen,
+                                 lookups=lookups)
+
+    def _born_unborn(self, rows: np.ndarray):
+        """``rows`` split into those the store holds a row for and those
+        it does not yet (a keyed tier's fresh keys; none when bounded)."""
+        if not self.keyed:
+            return rows, _NO_ROWS
+        unborn = self._unborn[rows]
+        return rows[~unborn], rows[unborn]
 
     def _count_gen_retry(self) -> None:
         self.gen_retries += 1
@@ -935,8 +1182,9 @@ class ShardedOffloadedTable:
         earlier ones' planned rows."""
         with self._book:
             if prep.gen == self._gen and not prep.needs_evict:
-                self._planned[prep.missing] = False
-                self._planned_count -= int(prep.missing.size)
+                for rows in (prep.missing, prep.fresh):
+                    self._planned[rows] = False
+                    self._planned_count -= int(rows.size)
 
     def apply_prepared(self, cache, prep: PreparedBatch):
         """Device half: turn a :class:`PreparedBatch` into cache inserts.
@@ -969,7 +1217,7 @@ class ShardedOffloadedTable:
                 self._planned[:] = False
                 self._planned_count = 0
                 self._count_gen_retry()
-                inner = self.host_prepare(prep.uniq, lookups=prep.lookups)
+                inner = self._prepare_rows(prep.uniq, prep.lookups)
                 try:
                     return self._apply_prepared(cache, inner)
                 except BaseException:
@@ -996,23 +1244,32 @@ class ShardedOffloadedTable:
             with self._book:
                 cache = self._evict(cache, protect=prep.uniq,
                                     budget=budget,
-                                    incoming=prep.missing.size)
+                                    incoming=prep.missing.size
+                                    + prep.fresh.size)
                 # re-gather AFTER eviction made host rows current
-                missing = prep.uniq[~self._resident[prep.uniq]]
+                absent = prep.uniq[~self._resident[prep.uniq]]
+                missing, fresh = self._born_unborn(absent)
                 rows, slot_rows = self._gather_host(missing)
-                self._resident[missing] = True
-                self._resident_count += int(missing.size)
+                self._resident[absent] = True
+                self._resident_count += int(absent.size)
         else:
-            missing, rows, slot_rows = prep.missing, prep.rows, \
-                prep.slot_rows
+            missing, fresh, rows, slot_rows = prep.missing, prep.fresh, \
+                prep.rows, prep.slot_rows
+            absent = np.concatenate([missing, fresh]) if fresh.size \
+                else missing
             with self._book:
                 # transfer planned -> resident atomically: a concurrent
                 # host_prepare must never observe these keys as absent
                 # from both books
-                self._resident[missing] = True
-                self._resident_count += int(missing.size)
-                self._planned[missing] = False
-                self._planned_count -= int(missing.size)
+                self._resident[absent] = True
+                self._resident_count += int(absent.size)
+                self._planned[absent] = False
+                self._planned_count -= int(absent.size)
+        if fresh.size:
+            # the step makes a fresh key's row in the cache: the store
+            # has none, so the row is owed a write-back from now on
+            with self._book:
+                self._dirty.mark_rows(fresh)
         if missing.size == 0:
             return cache
         try:
@@ -1026,11 +1283,11 @@ class ShardedOffloadedTable:
             # same prep must be able to re-run the planned->resident
             # transfer it came in with
             with self._book:
-                self._resident[missing] = False
-                self._resident_count -= int(missing.size)
+                self._resident[absent] = False
+                self._resident_count -= int(absent.size)
                 if not prep.needs_evict:
-                    self._planned[missing] = True
-                    self._planned_count += int(missing.size)
+                    self._planned[absent] = True
+                    self._planned_count += int(absent.size)
             raise
 
     def prepare(self, cache, ids):
@@ -1065,7 +1322,9 @@ class ShardedOffloadedTable:
     def load_rows(self, ids, weights, slot_rows=None) -> None:
         """Write known rows into the host store: ``ids`` is a range
         ``slice(lo, hi)`` (one contiguous copy a call: the way to fill a
-        store of 10^8 rows chunk by chunk) or an array of row ids;
+        store of 10^8 rows chunk by chunk) or an array of row ids, a
+        keyed tier's an array of DISTINCT int64 keys (a key the store has
+        not seen is handed its row);
         ``weights`` ``[n, dim]`` and ``slot_rows`` ``{slot: [n, ...]}``
         their rows (a slot left out keeps what it holds). The rows are
         stamped with the current ``work_id``, so the next ``persist``
@@ -1074,12 +1333,32 @@ class ShardedOffloadedTable:
         its files through the same writer."""
         self._join_writeback()
         self._join_persist()
+        self._write_rows(ids, weights, slot_rows or {}, self.work_id,
+                         held_refused=True)
+
+    def _write_rows(self, ids, weights, slot_rows, work_id,
+                    held_refused: bool = False) -> None:
+        if self.keyed:
+            keys = np.asarray(ids, np.int64).ravel()
+            if keys.size > 1 and not (keys[1:] > keys[:-1]).all() \
+                    and np.unique(keys).size != keys.size:
+                raise ValueError(
+                    f"offloaded table {self.name!r}: load_rows takes each "
+                    "key once")
+            if not keys_lib.valid_keys(keys).all():
+                raise ValueError(
+                    f"offloaded table {self.name!r}: a key whose high "
+                    "word is the EMPTY marker cannot be stored")
+            ids = self.rows_of(keys, insert=True)
         with self._book:
-            if self._resident[ids].any() or self._planned[ids].any():
+            if held_refused and (self._resident[ids].any()
+                                 or self._planned[ids].any()):
                 raise ValueError(
                     f"offloaded table {self.name!r}: load_rows over rows "
                     "the cache holds; load before warming, or restore")
-        _store_rows(self, ids, weights, slot_rows or {}, self.work_id)
+        _store_rows(self, ids, weights, slot_rows, work_id)
+        if self.keyed:
+            self._unborn[ids] = False
 
     def warm(self, cache, ids):
         """Make ``ids`` cache-resident in bulk: the state a cache is in
@@ -1088,8 +1367,11 @@ class ShardedOffloadedTable:
         :meth:`apply_prepared` moves them (resident marks and count, last
         touch); nothing is evicted: a set that does not fit the budget is
         refused. Returns the updated cache state."""
-        ids = np.unique(np.asarray(ids).ravel())
-        ids = ids[(ids >= 0) & (ids < self.vocab)]
+        ids = self.distinct(ids)
+        if self.keyed:      # the keys the store holds a row for
+            ids = self.rows_of(ids)
+            ids = ids[ids >= 0]
+            ids = ids[~self._unborn[ids]]
         self._join_writeback()
         with self._book:
             missing = ids[~(self._resident[ids] | self._planned[ids])]
@@ -1136,7 +1418,7 @@ class ShardedOffloadedTable:
             resident_ids = np.nonzero(self._resident)[0]
             keep_target = max(0, min(int(self.keep_fraction * budget),
                                      budget - incoming))
-            prot = np.zeros(self.vocab, bool)
+            prot = np.zeros(len(self._resident), bool)
             prot[protect] = True
             candidates = resident_ids[~prot[resident_ids]]
             order = np.argsort(self._last_touch[candidates], kind="stable")
@@ -1150,6 +1432,11 @@ class ShardedOffloadedTable:
             dirty_ids = resident_ids[self._dirty.mask_rows(resident_ids)]
             self._start_writeback(cache, dirty_ids)
             self._join_writeback()
+            if self.keyed:
+                # a row still unborn was never in the cache (a fresh key
+                # prepared for a pull alone): nothing to rebuild it from,
+                # and its next prepare finds it fresh again
+                keep = keep[~self._unborn[keep]]
             cache = self._cleared(cache)
             self._resident[:] = False
             self._resident_count = 0
@@ -1170,8 +1457,9 @@ class ShardedOffloadedTable:
     def note_update(self, ids, *, uniq: Optional[np.ndarray] = None) -> None:
         """Record that the jitted step applied gradients for ``ids``
         (host-side dirty marks + work watermark advance). ``uniq`` skips
-        the np.unique when the caller already holds this batch's unique
-        valid ids (a PreparedBatch carries them).
+        the np.unique (and a keyed tier's index walk) when the caller
+        already holds the store rows of this batch's unique valid ids (a
+        PreparedBatch carries them).
 
         With ``overflow_check_every_n_batches`` set, every N-th call also
         reads the deferred overflow counter (one device round trip,
@@ -1180,8 +1468,8 @@ class ShardedOffloadedTable:
         within N steps instead of only at ``finish()``."""
         with scope.span("offload.note_update", table=self.name):
             if uniq is None:
-                uniq = np.unique(np.asarray(ids).ravel())
-                uniq = uniq[(uniq >= 0) & (uniq < self.vocab)]
+                uniq = self.rows_of(self.distinct(ids))
+                uniq = uniq[uniq >= 0]
             with self._book:
                 self._dirty.mark_rows(uniq)
             self.work_id += 1
@@ -1259,26 +1547,24 @@ class ShardedOffloadedTable:
         # rows (their host_work_id stamps are > the last COMMITTED meta)
         self.persisted_work = self.work_id
         self._batches_since_persist = 0
+        store = dict(vocab=self.vocab, meta=self.meta, work_id=work,
+                     persisted_work=persisted,
+                     host_weights=self.host_weights,
+                     host_slots=self.host_slots,
+                     host_work_id=self.host_work_id,
+                     compress=self.persist_compress)
+        if self.keyed:      # rows under their keys; no unborn row
+            with self._book:
+                store.update(keys=self._keys, stored=~np.asarray(
+                    self._unborn)[:self._index.rows])
         if blocking:
             with scope.span("offload.persist", table=self.name):
-                return _persist_store(
-                    path, vocab=self.vocab, meta=self.meta, work_id=work,
-                    persisted_work=persisted,
-                    host_weights=self.host_weights,
-                    host_slots=self.host_slots,
-                    host_work_id=self.host_work_id,
-                    compress=self.persist_compress)
+                return _persist_store(path, **store)
 
         def _run():
             try:
                 with scope.span("offload.persist", table=self.name):
-                    _persist_store(
-                        path, vocab=self.vocab, meta=self.meta,
-                        work_id=work, persisted_work=persisted,
-                        host_weights=self.host_weights,
-                        host_slots=self.host_slots,
-                        host_work_id=self.host_work_id,
-                        compress=self.persist_compress)
+                    _persist_store(path, **store)
             except BaseException as e:  # noqa: BLE001 — re-raised at join
                 self._persister_err = e
                 self.persisted_work = persisted
@@ -1297,15 +1583,26 @@ class ShardedOffloadedTable:
         restore may have run on initializer rows for the failed keys, and
         the same ``cache_capacity`` would overflow again after it — wrap
         restore in the same RuntimeError handling as ``flush``/
-        ``finish`` if you use it as a recovery path."""
+        ``finish`` if you use it as a recovery path.
+
+        A keyed tier's cache makes a fresh key's row from its
+        ``init_rng``: ``replace(init_rng=...)`` on the returned state
+        keeps the key the discarded cache drew under."""
         self._join_writeback()
         self._join_persist()
         # surface any overflow the discarded cache accumulated — training
         # before this restore may have run against initializer rows, and
         # the same cache_capacity would overflow again after it
         self.check_overflow()
+        if self.keyed:
+            # the store is what the files hold and nothing else: a key
+            # born after them has no row again (its place in the index
+            # stays: a companion may hold it). A new tier's index is
+            # built as the files' keys arrive
+            with self._book:
+                self._unborn[:] = True
         max_work = _replay_store(path, vocab=self.vocab,
-                                 load=functools.partial(_store_rows, self))
+                                 load=self._write_rows)
         self.work_id = max(self.work_id, max_work + 1)
         self.persisted_work = max_work
         self._batches_since_persist = 0

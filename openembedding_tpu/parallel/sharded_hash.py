@@ -228,6 +228,10 @@ def _insert_packed_program(mesh: Mesh, spec: HashShardingSpec,
     keys/weights/slots pytree: column 0 carries int32 keys bitcast to
     f32, columns [1, 1+dim) the weight row, the rest each slot's row
     (``layout`` = ((name, start_col, n_cols, row_shape), ...), static).
+    A wide table's buffer is int32: columns 0 and 1 are a key's (lo, hi)
+    words as they are, the f32 rows ride as their bits from column 2 on
+    (a key word is any 32 bits, a NaN's among them: no float leaves the
+    unpack but by a bitcast).
 
     Rationale: the offload tier ships an insert payload to the device
     EVERY step; one coalesced transfer replaces 2+len(slots) separate
@@ -242,8 +246,12 @@ def _insert_packed_program(mesh: Mesh, spec: HashShardingSpec,
     def _insert(tkeys, tweights, tslots, failures, init_rng, packed):
         local = HashStore(spec).local(tkeys, tweights, tslots, init_rng)
         n = packed.shape[0]
-        k = lax.bitcast_convert_type(packed[:, 0], jnp.int32)
-        w = packed[:, 1:1 + dim]
+        if spec.wide:
+            k, first = packed[:, :2], 2
+            packed = lax.bitcast_convert_type(packed, jnp.float32)
+        else:
+            k, first = lax.bitcast_convert_type(packed[:, 0], jnp.int32), 1
+        w = packed[:, first:first + dim]
         srows = {name: packed[:, s:s + c].reshape((n,) + shape)
                  for name, s, c, shape in layout}
         masked = _mask_non_owned(spec, k, _my_shard(mesh, spec))
@@ -273,12 +281,16 @@ def insert_rows_sharded_packed(state: hash_lib.HashTableState,
                                mesh: Mesh,
                                spec: HashShardingSpec
                                ) -> hash_lib.HashTableState:
-    """:func:`insert_rows_sharded` behavior from ONE packed f32 buffer
-    (int32 keys only — the offload cache's key plane; wide tables use
-    the unpacked path), in place like it. See
+    """:func:`insert_rows_sharded` behavior from ONE packed buffer, in
+    place like it: f32 with the int32 key in column 0 (a bounded offload
+    cache's key plane), or for a wide table int32 with the key's two
+    words in columns 0-1 and the f32 rows as their bits. See
     :func:`_insert_packed_program`."""
-    if spec.wide:
-        raise ValueError("packed insert supports int32-key tables only")
+    want = jnp.dtype(jnp.int32 if spec.wide else jnp.float32)
+    if packed.dtype != want:
+        raise ValueError(f"packed insert of a {spec.key_width}-bit-key "
+                         f"table takes a {want.name} buffer, got "
+                         f"{packed.dtype}")
     dim = state.weights.shape[-1]
     fn = _insert_packed_program(mesh, spec, dim, layout,
                                 observability.evaluate_performance())

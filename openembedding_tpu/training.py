@@ -124,7 +124,12 @@ class Trainer:
         state lives in ``TrainState.emb`` like any hash variable; the
         Trainer auto-prepares each batch's rows before the jitted step and
         records dirty marks after it (PmemEmbeddingOptimizerVariable.h's
-        pre-touch + work advance).
+        pre-touch + work advance). A tier built with a ``vocab`` takes
+        bounded int ids; one built without holds 64-bit keys (int64 host
+        columns or ``[..., 2]`` int32 pairs) and a key no step has seen
+        is born in the step, as in an all-in-HBM hash table. Tables fed
+        one column (``fields`` and ``fields:linear``) share the pass
+        that makes a batch's ids distinct.
 
         ``pipeline_depth``: how many batches of offload host-prepare may
         run ahead of the device (the reference's prefetch ``steps``
@@ -599,17 +604,40 @@ class Trainer:
                 prev.join()
             try:
                 sync_point("trainer.prep.run")
-                for name, table in self.offload.items():
-                    with scope.span("offload.host_prepare", detail=at,
-                                    table=name):
-                        results[name] = table.host_prepare(
-                            batch["sparse"][name])
+                seen: Dict[Any, Any] = {}
+                for name in self.offload:
+                    results[name] = self._host_prepare(name, batch, at,
+                                                       seen)
             except BaseException as e:  # noqa: BLE001 — re-raised at join
                 err.append(e)
 
         t = threading.Thread(target=_run, daemon=True, name="oe-prep")
         t.start()
         self._preps.append((t, batch, results, err))
+
+    def _distinct(self, name: str, col, seen: Dict[Any, Any]):
+        """``col``'s distinct ids in ``name``'s id space, made once for
+        all the tables that share both (``seen``; the column is held while
+        ``seen`` lives, so its id is its own)."""
+        table = self.offload[name]
+        space = (id(col), table.vocab)
+        if space not in seen:
+            seen[space] = table.distinct(col)
+        return seen[space]
+
+    def _host_prepare(self, name: str, batch, at, seen: Dict[Any, Any]):
+        """``host_prepare`` of one offloaded table for ``batch``. Tables
+        fed the SAME column (the same array object, which is what
+        ``FusedMapper.fuse`` hands ``fields`` and ``fields:linear``) over
+        the same id space share the pass that makes its ids distinct
+        (``seen``: one ``np.unique`` a column a batch, and for a keyed
+        tier one joining of its pairs into int64 keys)."""
+        table = self.offload[name]
+        col = batch["sparse"][name]
+        with scope.span("offload.host_prepare", detail=at, table=name):
+            return table.host_prepare(self._distinct(name, col, seen),
+                                      distinct=True,
+                                      lookups=table.lookups_of(col))
 
     def _cancel_preps(self) -> None:
         """Abandon every in-flight prepare (the caller is about to step a
@@ -652,13 +680,12 @@ class Trainer:
         emb = dict(state.emb)
         uniqs: Dict[str, Any] = {}
         names = list(self.offload)
+        seen: Dict[Any, Any] = {}
         for i, name in enumerate(names):
             table = self.offload[name]
             prep = prepped.get(name) if prepped is not None else None
             if prep is None:    # nothing looked ahead: prepared in line
-                with scope.span("offload.host_prepare", detail=at,
-                                table=name):
-                    prep = table.host_prepare(batch["sparse"][name])
+                prep = self._host_prepare(name, batch, at, seen)
             try:
                 emb[name] = table.apply_prepared(emb[name], prep)
             except BaseException:

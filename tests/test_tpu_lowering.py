@@ -541,3 +541,138 @@ def test_step_lowers_the_same_with_dirty_tracking_armed(v5e):
             mesh, coll, trainer, mapper, 1 << 12)).as_text()
 
     assert lowered(True) == lowered(False)
+
+
+def _keyed_tiers(mesh, capacity=HASH_CAPACITY):
+    """The keyed offload cell's two tiers (no ``vocab``: 64-bit keys)."""
+    from openembedding_tpu import offload
+    from openembedding_tpu.meta import EmbeddingVariableMeta
+    return {name: offload.ShardedOffloadedTable(
+        name, EmbeddingVariableMeta(embedding_dim=dim, vocabulary_size=-1),
+        {"category": "adagrad"}, initializer, cache_capacity=capacity,
+        mesh=mesh)
+        for name, dim, initializer in (
+            ("fields", 9, {"category": "normal", "stddev": 1e-4}),
+            ("fields:linear", 1, {"category": "constant", "value": 0.0}))}
+
+
+@pytest.mark.parametrize("keys", [1 << 13, 1 << 21], ids=["step", "bulk"])
+def test_v5e_wide_key_offload_insert_updates_the_cache_in_place(v5e, keys):
+    """The keyed tier's insert between two steps (8,192 keys) and its bulk
+    insert (2**21) at the keyed offload cell's size: a wide-key cache of
+    2**26 slots (512 MiB of keys), dim 9 with its accumulator. One int32
+    buffer brings both key words and the rows; the table operands are
+    donated and every output aliases its operand; the compiler copies no
+    ``s32[2**26,2]`` key array and no table-sized row array (it copied the
+    int32 key array twice before: PERF.md, PR 27 and PR 33); the module
+    keeps the name the benchmark's reader finds it by."""
+    from openembedding_tpu.parallel import sharded_hash as sh
+    capacity, dim = HASH_CAPACITY, 9
+    mesh = create_mesh(1, 1, v5e[:1])
+    tier = _keyed_tiers(mesh)["fields"]
+    assert tier.keyed and tier.spec.wide
+    cache = jax.eval_shape(tier.create_cache)
+    assert cache.keys.shape == (capacity, 2)
+    row = NamedSharding(mesh, tier.spec.row_spec())
+    whole = NamedSharding(mesh, P())
+    table = _abstract((cache.keys, cache.weights, cache.slots),
+                      (row, row, {k: row for k in cache.slots}))
+    _, columns, layout = tier._packed_layout(cache.keys.dtype)
+    assert columns == 2 + dim + dim
+    program = sh._insert_packed_program(mesh, tier.spec, dim, layout)
+    compiled = program.lower(
+        *table, _abstract(cache.insert_failures, whole),
+        _abstract(cache.init_rng, whole),
+        jax.ShapeDtypeStruct((keys, columns), jnp.int32,
+                             sharding=whole)).compile()
+    hlo = compiled.as_text()
+    header = next(line for line in hlo.splitlines()
+                  if line.startswith("HloModule"))
+    assert sh.OFFLOAD_INSERT_STAGE in header
+    aliased = re.findall(r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)",
+                         header)
+    assert sorted(aliased) == [("0", "0"), ("1", "1"), ("2", "2")], header
+    sized = (f"s32[{capacity},2]", f"f32[{capacity},{dim}]")
+    copies = [line.strip()[:120] for line in hlo.splitlines()
+              if " copy(" in line and any(f"= {s}" in line for s in sized)]
+    assert not copies, copies
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= capacity * (8 + 2 * dim * 4)
+    assert memory.temp_size_in_bytes < (5 << 30 if keys > 1 << 13
+                                        else 1 << 30), memory
+
+
+def test_v5e_keyed_offload_step_copies_no_key_array(v5e):
+    """The keyed offload cell's step, built as the cell builds it (the
+    tiers' own ``embedding_spec()`` s, ``Trainer(offload=)``): the hash
+    cell's wide-key program, whose push follows the pull and inserts the
+    keys no store has seen in place. No copy of a ``s32[2**26,2]`` key
+    array, every table operand aliased, and the one-plan step."""
+    import optax
+    from openembedding_tpu import EmbeddingCollection, Trainer
+    from openembedding_tpu.fused import FusedMapper
+    from openembedding_tpu.models import deepctr
+    mesh = create_mesh(1, 1, v5e[:1])
+    tiers = _keyed_tiers(mesh)
+    features = tuple(criteo.SPARSE_NAMES)
+    coll = EmbeddingCollection(
+        [t.embedding_spec() for t in tiers.values()], mesh)
+    assert all(s.key_dtype == "wide" for s in coll.specs.values())
+    trainer = Trainer(deepctr.build_model("deepfm", features), coll,
+                      optax.adam(1e-3), offload=tiers)
+    mapper = FusedMapper(features, (-1,) * len(features))
+    compiled = trainer.lower_train_step(
+        *_abstract_step(mesh, coll, trainer, mapper, 1 << 20)).compile()
+    hlo = compiled.as_text()
+    copies = [line.strip()[:120] for line in hlo.splitlines()
+              if f"= s32[{HASH_CAPACITY},2]" in line and " copy" in line]
+    assert not copies, copies
+    header = next(line for line in hlo.splitlines()
+                  if line.startswith("HloModule"))
+    aliased = re.findall(r"\{[\d, ]*\}: \((\d+), \{\}, (?:may|must)-alias\)",
+                         header)
+    # both tables' keys, weights and accumulators among the aliased
+    assert len(aliased) >= 6, header
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * HASH_CAPACITY * 8
+    assert memory.temp_size_in_bytes < 2 << 30, memory
+
+
+# sha256 of programs lowered on the CPU backend at the parent of PR 38
+# (a84d95b): a bounded tier's programs are the parent's, text for text
+# (the tiny_* step programs were compared tree against tree: CHANGES.md)
+_BOUNDED_TEXTS = {
+    "offload_insert":
+    "b3135e0a68c1ceb495b40894b1e789d7188830cdd120f5399c5209e4bddfef8c",
+    "offload_read":
+    "87359f73cc880fcb6f584f481cf9af2eeb9a59f1c0230383dc08b1af32c3bcb8"}
+
+
+def test_bounded_tier_programs_lower_to_the_parents_text():
+    """A tier built WITH a ``vocab`` keeps its arithmetic: its packed
+    insert and its write-back read lower, on the CPU backend, to the text
+    they had before the keyed tier came (PR 37's tree)."""
+    import hashlib
+    import numpy as np
+    from openembedding_tpu import offload
+    from openembedding_tpu.meta import EmbeddingVariableMeta
+    from openembedding_tpu.parallel import sharded_hash as sh
+    mesh = create_mesh(1, 1, jax.devices("cpu")[:1])
+    tier = offload.ShardedOffloadedTable(
+        "fields", EmbeddingVariableMeta(embedding_dim=9,
+                                        vocabulary_size=1024),
+        {"category": "adagrad"}, {"category": "constant", "value": 0.0},
+        vocab=1024, cache_capacity=1 << 14, mesh=mesh)
+    cache = tier.create_cache()
+    _, columns, layout = tier._packed_layout(np.dtype(cache.keys.dtype))
+    table = (cache.keys, cache.weights, cache.slots)
+    texts = {
+        "offload_insert": sh._insert_packed_program(
+            mesh, tier.spec, 9, layout).lower(
+                *table, cache.insert_failures, cache.init_rng,
+                jnp.zeros((512, columns), jnp.float32)).as_text(),
+        "offload_read": sh._read_rows_program(
+            mesh, tier.spec, tuple(cache.slots)).lower(
+                *table, jnp.zeros((512,), jnp.int32)).as_text()}
+    assert {k: hashlib.sha256(v.encode()).hexdigest()
+            for k, v in texts.items()} == _BOUNDED_TEXTS
