@@ -487,8 +487,9 @@ class EmbeddingCollection:
 
     def same_columns(self, inputs: Dict[str, Any]) -> SameColumns:
         """Observe, on the host, which columns of a step's ``inputs`` are
-        the same ids, among those :meth:`plan` covers (none on the routed
-        planes: their step is the program it is without): the same object
+        the same ids, among those :meth:`plan` covers (none on the cached
+        and grouped planes or under an ``int8_ef`` push: their step is the
+        program it is without): the same object
         (what ``FusedMapper.fuse`` hands a table and its ``:linear`` twin),
         or host arrays equal in shape, dtype and value. The first of a
         group is the base of the others. Counters
@@ -528,17 +529,21 @@ class EmbeddingCollection:
         """One step's dedup of every input column whose table's pull and
         push can share it (``sharded.shares_plan``: the masked-local body,
         which is every plane on one chip but the cached one, on a mesh
-        with no data axis to gather over): name -> ``dedup.Plan``, for
-        :meth:`pull` and :meth:`apply_gradients` of the SAME ``inputs``.
-        The pull then resolves each distinct key once and the push
-        deduplicates nothing again; rows and updates are what they are
-        without. A column left out (the routed planes, which dedup their
-        own sender slice; the grouped ones) runs as it does without.
+        with no data axis to gather over; the plain ``a2a`` exchange over
+        several shards): name -> ``dedup.Plan``, or the routed body's
+        ``alltoall.RoutedPlan`` (the sender's dedup and buckets, the keys
+        sent, the owner's dedup of what it received), for :meth:`pull` and
+        :meth:`apply_gradients` of the SAME ``inputs``. The pull then
+        resolves each distinct key once and the push deduplicates nothing
+        again; rows and updates are what they are without. A column left
+        out (the cached plane, an ``int8_ef`` push, the grouped planes)
+        runs as it does without.
 
         One plan a distinct column: tables handed the SAME array (inside a
         jit, the same traced value: :meth:`SameColumns.bind`) in the same
         key form (``sharded.plan_form``: array ids, int32 hash keys, wide
-        pairs) get the same ``Plan`` object, built once; each store still
+        pairs; on the routed body the owners' layout and the buckets'
+        size too) get the same plan object, built once; each store still
         lays its own ownership mask over it and finds its own slots. Under
         ``record_stats`` a step counts ``dedup_plans_built`` and
         ``dedup_plan_tables``."""

@@ -811,8 +811,9 @@ def merge_gradients(state: HashTableState,
         uniq, inverse, valid = dedup.unique_indices(
             flat_idx, capacity, fill_value=empty)
     valid = valid & ((uniq[:, 1] if state.wide else uniq) != empty)
-    summed, counts = dedup.combine_gradients(grads.reshape(-1, dim), inverse,
-                                             capacity, in_counts)
+    summed, counts = dedup.combine_gradients(
+        grads.reshape(-1, dim), inverse, capacity, in_counts,
+        counts=None if plan is None else plan.counts)
     keys_arr, slot, inserted, failed = find_or_insert(
         state.keys, uniq, valid, max_probes, record_stats,
         found=None if resolved is None else resolved.slot)

@@ -38,11 +38,18 @@ class Plan:
     column) pull and push through the SAME plan, each laying its own
     ownership mask over it (``EmbeddingCollection.plan``). What one
     table's pull found for the plan's slots travels beside it, a
-    :class:`Resolution` a table."""
+    :class:`Resolution` a table. The routed exchange holds two of them a
+    column and device (``alltoall.RoutedPlan``): of the slice it sends,
+    and of the keys it receives as their owner."""
 
     uniq: jnp.ndarray       # [n] keys, [n, 2] wide ones; fill past the last
     inverse: jnp.ndarray    # [n]: uniq[inverse[i]] is key i
     valid: jnp.ndarray      # [n]: the slot holds a key, and not the fill
+    # [n] int32, where the plan's maker has counted them: the positions
+    # each slot stands for (the routed owner's: summed over the senders).
+    # They are the column's, whatever the table: a push that finds them
+    # here sums its gradients alone (:func:`combine_gradients`)
+    counts: Optional[jnp.ndarray] = None
 
 
 @struct.dataclass
@@ -53,7 +60,9 @@ class Resolution:
     weight row a second time. A shard's own, as it read them under its
     ownership mask (before the sum over the model axis, before the
     expansion by ``inverse``): on a mesh of several model shards every
-    shard carries its part."""
+    shard carries its part. Behind the routed exchange it is the owner's,
+    of the plan of the keys it received (``alltoall.RoutedPlan.owner``):
+    the rows as the owner read them, before they cross the wire."""
 
     # [n, dim]: the stored row of a key the table holds, the init row of a
     # hash key it does not (the row its insert writes), zeros for a slot
@@ -105,8 +114,23 @@ def unique_indices(indices: jnp.ndarray, capacity: int | None = None,
     return unique(indices)
 
 
+def count_keys(inverse: jnp.ndarray, capacity: int,
+               in_counts: jnp.ndarray | None = None) -> jnp.ndarray:
+    """:func:`combine_gradients`' counts alone ([capacity] int32): what a
+    plan's maker keeps as ``Plan.counts``."""
+    @scope.stage("dedup")
+    def count(inverse, in_counts):
+        add = jnp.int32(1) if in_counts is None \
+            else in_counts.astype(jnp.int32)
+        return jnp.zeros((capacity,), dtype=jnp.int32).at[inverse].add(
+            add, mode="drop")
+
+    return count(inverse, in_counts)
+
+
 def combine_gradients(grads: jnp.ndarray, inverse: jnp.ndarray, capacity: int,
-                      in_counts: jnp.ndarray | None = None
+                      in_counts: jnp.ndarray | None = None,
+                      counts: jnp.ndarray | None = None
                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sum duplicate-key gradients into the unique buffer with counts.
 
@@ -119,8 +143,15 @@ def combine_gradients(grads: jnp.ndarray, inverse: jnp.ndarray, capacity: int,
     *already pre-reduced* (the owner side of the all-to-all exchange receives
     (sum, count) pairs from every peer and must SUM the counts) — the
     reference's server-side MpscGradientReducer merging client pre-reduces.
+    ``counts`` are the combined counts where the caller holds them already
+    (``Plan.counts``): the gradients alone are summed, and they come back.
     """
     n, dim = grads.shape
+    if counts is not None:
+        return scope.stage("dedup")(
+            lambda grads, inverse: jnp.zeros(
+                (capacity, dim), dtype=grads.dtype).at[inverse].add(
+                    grads, mode="drop"))(grads, inverse), counts
 
     @scope.stage("dedup")
     def combine(grads, inverse, in_counts):

@@ -44,6 +44,15 @@ gated ``a2a_extra_entries_*`` accumulators count residue-routed entries
 (the reference ships the same measurement methodology,
 laboratory/benchmark/analyze.py). Raise ``a2a_capacity``/``a2a_slack`` if
 your key distribution routinely needs more than one round.
+
+One plan a distinct id column a step. Pull and push of one train step route
+the same keys the same way, and so do tables fed one column; what that
+takes — the slice, its dedup and counts, the owners, round 1's buckets,
+the key all-to-all, the owner's dedup of what it received — needs no table
+and no gradient. :func:`plan_exchange` makes it once (a
+:class:`RoutedPlan`), and :func:`exchange_pull` / :func:`exchange_push`
+that are handed it do the rest: rows back, gradients out. Handed none
+they run as they always did.
 """
 
 from __future__ import annotations
@@ -53,6 +62,7 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from flax import struct
 from jax import lax
 
 from ..analysis import scope
@@ -285,6 +295,111 @@ def carve_segments(rows: jnp.ndarray, sizes: Sequence[int]) -> list:
     return [rows[offs[i]:offs[i + 1]] for i in range(len(sizes))]
 
 
+@struct.dataclass
+class RoutedPlan:
+    """One id column of one train step on the routed plane: everything the
+    exchange does with its keys that needs no table and no gradient, made
+    once (:func:`plan_exchange`) for every :func:`exchange_pull` and
+    :func:`exchange_push` of every table that reads the column. Each
+    device's own, laid over the exchange grid.
+
+    The sender's half: its slice of the batch deduplicated and counted,
+    each distinct key's owner, and round 1's buckets. The owner's half:
+    the keys round 1 brought it with their counts (they cross the wire
+    here, once a step), deduplicated over the ``[num_shards * capacity]``
+    bucket slots, sentinel padding no key, the counts of a key's senders
+    summed: the owner reads one row and merges once a distinct key, as the
+    masked-local body does with its step's ``dedup.Plan``, and lays rows
+    back into bucket order by ``owner.inverse``. Both plans bring their
+    ``counts``, which are the column's: a table's push sums and sends its
+    gradients alone. Round 1 is the plan's; what it did not hold
+    (``spilled`` > 0) goes the way it goes without a plan: the pull's
+    residue rounds, the push's gathered branch."""
+
+    sender: dedup.Plan      # of the slice: [m] uniq, inverse, valid, counts
+    owners: jnp.ndarray     # [m] int32: a distinct key's owner shard
+    dest: jnp.ndarray       # [m] int32: its bucket slot in round 1
+    ok: jnp.ndarray         # [m] bool: round 1 holds it
+    owner: dedup.Plan       # of the [num_shards * capacity] keys received
+    spilled: jnp.ndarray    # [] int32: keys round 1 left, over all devices
+
+
+def plan_exchange(flat_idx: jnp.ndarray,
+                  owner_fn: Callable[[jnp.ndarray], jnp.ndarray],
+                  *,
+                  sentinel,
+                  num_shards: int,
+                  grid_axes: Sequence[str],
+                  grid_sizes: Sequence[int],
+                  split_axes: Sequence[str],
+                  split_sizes: Sequence[int],
+                  capacity: int = 0,
+                  slack: float = 2.0,
+                  record_stats: bool = False) -> RoutedPlan:
+    """The :class:`RoutedPlan` of ``flat_idx`` ([n], or [n, 2] wide pairs;
+    identical on all ``split_axes`` peers): what :func:`exchange_pull` and
+    :func:`exchange_push` each compute in front of round 1 when they are
+    handed none, the key all-to-all itself (the counts ride with the keys,
+    as they do in a push that is handed no plan), and the owner's dedup of
+    the keys it received, with their counts summed.
+
+    ``record_stats`` counts ``routed_plan_keys_sent`` (the distinct keys
+    this device put into round 1's buckets), ``routed_plan_owner_keys_live``
+    and ``routed_plan_owner_slots`` (the distinct keys among the bucket
+    slots it received: the rest is padding and duplicates no owner reads
+    any more)."""
+    wide = flat_idx.ndim == 2
+    parts = math.prod(split_sizes)
+    m = -(-flat_idx.shape[0] // parts)
+    cap = bucket_capacity(m, num_shards, capacity, slack)
+
+    @scope.stage("route")
+    def my_slice(flat_idx):
+        my_part = linear_shard_id(split_axes, split_sizes)
+        return split_slice(flat_idx, parts, my_part, sentinel)[0]
+
+    sender = dedup.plan_keys(my_slice(flat_idx), sentinel)
+    sender = sender.replace(counts=dedup.count_keys(sender.inverse, m))
+    kw = flat_idx.shape[1] if wide else 1  # key words per entry
+
+    @scope.stage("route")
+    def to_buckets(uniq, counts):
+        owners = owner_fn(uniq)
+        dest, ok = bucketize(owners, num_shards, cap)
+        # keys and counts share one integer buffer, as exchange_push's do
+        ku = uniq if wide else uniq[:, None]
+        kc = jnp.concatenate([ku, counts.astype(ku.dtype)[:, None]], axis=1)
+        return owners, dest, ok, fill_buckets(kc, dest, num_shards, cap,
+                                              sentinel)
+
+    @scope.stage("route")
+    def from_buckets(rkc):
+        flat_kc = rkc.reshape((-1, kw + 1))
+        return (flat_kc[:, :kw] if wide else flat_kc[:, 0],
+                flat_kc[:, kw].astype(jnp.int32))
+
+    owners, dest, ok, send = to_buckets(sender.uniq, sender.counts)
+    keys, counts = from_buckets(grid_all_to_all(send, grid_axes, grid_sizes))
+    owner = dedup.plan_keys(keys, sentinel)
+    # a padding slot's count is the fill: it lands in the slot of the
+    # fill's own key, which holds no key
+    owner = owner.replace(counts=dedup.count_keys(
+        owner.inverse, num_shards * cap, counts))
+    spilled = jnp.zeros((), jnp.int32)
+    if cap < m:     # else the buckets hold the whole slice
+        spilled = scope.stage("exchange")(
+            lambda x: lax.psum(x, tuple(grid_axes)))(
+                jnp.sum((owners < num_shards) & ~ok).astype(jnp.int32))
+    record_stat("routed_plan_keys_sent", jnp.sum(ok, dtype=jnp.int32),
+                record_stats)
+    record_stat("routed_plan_owner_keys_live",
+                jnp.sum(owner.valid, dtype=jnp.int32), record_stats)
+    record_stat("routed_plan_owner_slots", jnp.int32(num_shards * cap),
+                record_stats)
+    return RoutedPlan(sender=sender, owners=owners, dest=dest, ok=ok,
+                      owner=owner, spilled=spilled)
+
+
 def exchange_pull(flat_idx: jnp.ndarray,
                   resolve_fn: Callable[[jnp.ndarray], jnp.ndarray],
                   owner_fn: Callable[[jnp.ndarray], jnp.ndarray],
@@ -299,7 +414,9 @@ def exchange_pull(flat_idx: jnp.ndarray,
                   capacity: int = 0,
                   slack: float = 2.0,
                   record_stats: bool = False,
-                  wire_dtype=None) -> jnp.ndarray:
+                  wire_dtype=None,
+                  plan: Optional[RoutedPlan] = None,
+                  read_plan: Optional[Callable] = None):
     """Owner-routed lookup of ``flat_idx`` [n] -> rows [n, dim]. EXACT.
 
     ``flat_idx`` must be identical on all ``split_axes`` peers (they divide
@@ -326,6 +443,16 @@ def exchange_pull(flat_idx: jnp.ndarray,
     carries ONE round-to-nearest cast (the residue accumulator fills
     every entry exactly once, so rounds never compound the error).
     ``None`` leaves the program byte-identical to the uncompressed one.
+
+    ``plan`` is :func:`plan_exchange`'s of the same ``flat_idx``: the
+    slice, its dedup, the owners, round 1's buckets and the key all-to-all
+    are taken from it, and the owner answers round 1 through
+    ``read_plan(plan.owner) -> dedup.Resolution``: one row a distinct key
+    it received, laid back into bucket order by ``plan.owner.inverse``.
+    The same rows come back, and with them what the owner resolved:
+    ``(rows, resolution)``, for :func:`exchange_push` of the same plan
+    while nothing has written the table. Keys round 1 did not hold go
+    through the residue rounds as they do without a plan (``resolve_fn``).
     """
     n = flat_idx.shape[0]
     wide = flat_idx.ndim == 2
@@ -333,20 +460,24 @@ def exchange_pull(flat_idx: jnp.ndarray,
     parts = math.prod(split_sizes)
     m = -(-n // parts)
 
-    @scope.stage("route")
-    def my_slice(flat_idx):
-        my_part = linear_shard_id(split_axes, split_sizes)
-        return split_slice(flat_idx, parts, my_part, sentinel)[0]
-
-    sl = my_slice(flat_idx)
-    if wide:
-        uniq, inverse, _valid = dedup.unique_rows(sl, m,
-                                                  fill_value=sentinel)
-    else:
-        uniq, inverse, _valid = dedup.unique_indices(sl, m,
-                                                     fill_value=sentinel)
     cap = bucket_capacity(m, num_shards, capacity, slack)
-    owners = scope.stage("route")(owner_fn)(uniq)
+    if plan is not None:
+        uniq, inverse, owners = (plan.sender.uniq, plan.sender.inverse,
+                                 plan.owners)
+    else:
+        @scope.stage("route")
+        def my_slice(flat_idx):
+            my_part = linear_shard_id(split_axes, split_sizes)
+            return split_slice(flat_idx, parts, my_part, sentinel)[0]
+
+        sl = my_slice(flat_idx)
+        if wide:
+            uniq, inverse, _valid = dedup.unique_rows(sl, m,
+                                                      fill_value=sentinel)
+        else:
+            uniq, inverse, _valid = dedup.unique_indices(
+                sl, m, fill_value=sentinel)
+        owners = scope.stage("route")(owner_fn)(uniq)
     out_dtype = jax.eval_shape(resolve_fn, uniq).dtype
     acc_dtype = out_dtype if wire_dtype is None else jnp.dtype(wire_dtype)
 
@@ -367,11 +498,7 @@ def exchange_pull(flat_idx: jnp.ndarray,
         return lax.psum(jnp.sum(pending < num_shards).astype(jnp.int32),
                         tuple(grid_axes))
 
-    def one_round(pending, acc):
-        send, dest, ok = to_buckets(pending, uniq)
-        req = grid_all_to_all(send, grid_axes, grid_sizes)
-        rows = scope.stage("resolve")(resolve_fn)(
-            req.reshape((-1, kw)) if wide else req.ravel())
+    def respond(rows, dest, ok, pending, acc):
         if wire_dtype is not None:
             # the ONE lossy point of a compressed pull: owner-resolved
             # rows narrow to the wire dtype before the response leg,
@@ -382,12 +509,31 @@ def exchange_pull(flat_idx: jnp.ndarray,
                                grid_axes, grid_sizes)
         if wire_dtype is not None:
             resp = unpin_wire(resp, acc_dtype)
-        pending, acc = from_buckets(resp, dest, ok, pending, acc)
+        return from_buckets(resp, dest, ok, pending, acc)
+
+    def one_round(pending, acc):
+        send, dest, ok = to_buckets(pending, uniq)
+        req = grid_all_to_all(send, grid_axes, grid_sizes)
+        rows = scope.stage("resolve")(resolve_fn)(
+            req.reshape((-1, kw)) if wide else req.ravel())
+        pending, acc = respond(rows, dest, ok, pending, acc)
         return pending, acc, count_left(pending)
 
     pending0 = owners.astype(jnp.int32)
     acc0 = jnp.zeros((m, dim), dtype=acc_dtype)
-    pending, uniq_rows, left = one_round(pending0, acc0)
+    if plan is None:
+        pending, uniq_rows, left = one_round(pending0, acc0)
+    else:
+        # round 1 is the plan's: the keys are at their owner, which reads
+        # a row a distinct key and hands every bucket slot its key's
+        resolved = read_plan(plan.owner)
+        rows = scope.stage("expand")(
+            lambda rows, inverse: jnp.take(rows, inverse, axis=0,
+                                           mode="clip"))(
+                resolved.rows, plan.owner.inverse)
+        pending, uniq_rows = respond(rows, plan.dest, plan.ok, pending0,
+                                     acc0)
+        left = plan.spilled
     # record the per-device residue: the callback fires on every device
     # shard, so the host accumulator sums locals into the global total
     record_stat("a2a_extra_entries_pull",
@@ -408,8 +554,10 @@ def exchange_pull(flat_idx: jnp.ndarray,
         # the row-assembly gather ships the pinned 16-bit wire form too;
         # the upcast after it is exact (bf16 -> f32 loses nothing)
         out = assemble(pin_wire(slice_rows))
-        return unpin_wire(out[:n], acc_dtype).astype(out_dtype)
-    return assemble(slice_rows)[:n]
+        out = unpin_wire(out[:n], acc_dtype).astype(out_dtype)
+    else:
+        out = assemble(slice_rows)[:n]
+    return out if plan is None else (out, resolved)
 
 
 def exchange_push(flat_idx: jnp.ndarray,
@@ -428,7 +576,9 @@ def exchange_push(flat_idx: jnp.ndarray,
                   slack: float = 2.0,
                   record_stats: bool = False,
                   wire_dtype=None,
-                  ef_state=None):
+                  ef_state=None,
+                  plan: Optional[RoutedPlan] = None,
+                  merge_plan: Optional[Callable] = None):
     """Owner-routed push: pre-reduce, route (key, grad sum, count) to owners.
     EXACT for any key distribution.
 
@@ -489,34 +639,56 @@ def exchange_push(flat_idx: jnp.ndarray,
       overflow branch, so feedback is branch-independent. Padding rows'
       scales are garbage on the routed wire (single-fill buffer);
       owners zero them by key validity so no NaN can reach a merger.
+
+    ``plan`` is :func:`plan_exchange`'s of the same ``flat_idx``, the one
+    the step's :func:`exchange_pull` ran on (no ``ef_state`` goes with
+    it): the slice's gradients are combined by its ``inverse`` into its
+    distinct keys' slots and ride round 1's buckets by its ``dest``. The
+    keys and their counts are at their owner already, so the gradients
+    cross alone, and the owner merges through ``merge_plan(state, grads
+    [K, dim]) -> (state, merged)``, which sums them by
+    ``plan.owner.inverse`` and deduplicates and counts nothing. A step
+    round 1 did not hold (``plan.spilled`` > 0) takes the gathered branch
+    as it is without a plan; ``merge_fn`` and ``merge_plan`` return one
+    structure.
     """
     dim = grads.shape[-1]
     parts = math.prod(split_sizes)
     wide = flat_idx.ndim == 2
     m = -(-flat_idx.shape[0] // parts)
-
-    @scope.stage("route")
-    def my_slice(flat_idx, grads):
-        my_part = linear_shard_id(split_axes, split_sizes)
-        return (split_slice(flat_idx, parts, my_part, sentinel)[0],
-                split_slice_rows(grads.reshape((-1, dim)), parts, my_part))
-
-    sl, g2 = my_slice(flat_idx, grads)
-    if wide:
-        uniq, inverse, _valid = dedup.unique_rows(sl, m,
-                                                  fill_value=sentinel)
-    else:
-        uniq, inverse, _valid = dedup.unique_indices(sl, m,
-                                                     fill_value=sentinel)
-    summed, counts = dedup.combine_gradients(g2, inverse, m)
     cap = bucket_capacity(m, num_shards, capacity, slack)
 
-    @scope.stage("route")
-    def to_owners(uniq):
-        owners = owner_fn(uniq)
-        return (owners,) + bucketize(owners, num_shards, cap)
+    if plan is not None:
+        g2 = scope.stage("route")(
+            lambda grads: split_slice_rows(
+                grads.reshape((-1, dim)), parts,
+                linear_shard_id(split_axes, split_sizes)))(grads)
+        uniq, inverse = plan.sender.uniq, plan.sender.inverse
+        summed, counts = dedup.combine_gradients(
+            g2, inverse, m, counts=plan.sender.counts)
+        owners, dest, ok = plan.owners, plan.dest, plan.ok
+    else:
+        @scope.stage("route")
+        def my_slice(flat_idx, grads):
+            my_part = linear_shard_id(split_axes, split_sizes)
+            return (split_slice(flat_idx, parts, my_part, sentinel)[0],
+                    split_slice_rows(grads.reshape((-1, dim)), parts,
+                                     my_part))
 
-    owners, dest, ok = to_owners(uniq)
+        @scope.stage("route")
+        def to_owners(uniq):
+            owners = owner_fn(uniq)
+            return (owners,) + bucketize(owners, num_shards, cap)
+
+        sl, g2 = my_slice(flat_idx, grads)
+        if wide:
+            uniq, inverse, _valid = dedup.unique_rows(sl, m,
+                                                      fill_value=sentinel)
+        else:
+            uniq, inverse, _valid = dedup.unique_indices(
+                sl, m, fill_value=sentinel)
+        summed, counts = dedup.combine_gradients(g2, inverse, m)
+        owners, dest, ok = to_owners(uniq)
     kw = flat_idx.shape[1] if wide else 1  # key words per exchange entry
 
     quant = ef_state is not None
@@ -559,11 +731,24 @@ def exchange_push(flat_idx: jnp.ndarray,
             g = unpin_wire(g, wire_dtype).astype(summed.dtype)
         return k, g, rc
 
+    @scope.stage("route")
+    def rows_from_buckets(rg):
+        g = rg.reshape((num_shards * cap, dim))
+        if wire_dtype is not None:
+            g = unpin_wire(g, wire_dtype).astype(summed.dtype)
+        return g
+
     @scope.stage("push_routed")
     def routed(st):
         payload = q8 if quant else (
             summed if wire_dtype is None
             else pin_wire(summed.astype(wire_dtype)))
+        if plan is not None:
+            send_g = scope.stage("route")(
+                lambda payload, dest: fill_buckets(
+                    payload, dest, num_shards, cap, 0))(payload, dest)
+            return merge_plan(st, rows_from_buckets(
+                grid_all_to_all(send_g, grid_axes, grid_sizes)))
         send_kc, send_g = to_buckets(uniq, counts, payload, scale, dest)
         rkc = grid_all_to_all(send_kc, grid_axes, grid_sizes)
         rg = grid_all_to_all(send_g, grid_axes, grid_sizes)
@@ -592,7 +777,7 @@ def exchange_push(flat_idx: jnp.ndarray,
         out = routed(state)
         return (out, new_ef) if quant else out
     local_spill = jnp.sum((owners < num_shards) & ~ok).astype(jnp.int32)
-    spilled = scope.stage("exchange")(
+    spilled = plan.spilled if plan is not None else scope.stage("exchange")(
         lambda x: lax.psum(x, tuple(grid_axes)))(local_spill)
     # per-device residue: the callback fires on every device shard, so the
     # host accumulator sums locals into the global total

@@ -66,37 +66,55 @@ answers:
   ``owner(keys)`` — the key space;
 * ``resolve(local, keys, me)`` / ``read_local(local, flat)`` — the pull's
   owner side behind the exchange / on the masked-local body, where
-  ``read_plan(local, plan, record_stats)`` reads one row a distinct key
-  of the step's plan (below) and returns what it resolved, a
-  ``dedup.Resolution`` of the plan's slots under this shard's ownership
-  mask: the rows, and for a kind that probes the slots it found;
-* ``carry(local)``, ``merge(local, carry, keys, grads, counts, me, ...)``
-  / ``apply_local(local, optimizer, flat, grads, ..., plan=, resolved=)``
-  — the push's owner side likewise (``resolved``: ``read_plan``'s of the
-  same plan and the same table contents, which the push then takes
-  instead of resolving again), ``outputs(carry, weights, slots, axes)`` —
-  what leaves the program, ``slot_of(carry, keys, me)`` — a key's slot in
-  this shard (-1: not here), where the owner writes a cached key's row back;
+  ``read_plan(local, plan, record_stats, me)`` reads one row a distinct
+  key of the step's plan (below) and returns what it resolved, a
+  ``dedup.Resolution`` of the plan's slots under shard ``me``'s ownership
+  mask (None: this model-axis shard): the rows, and for a kind that
+  probes the slots it found;
+* ``carry(local)``, ``merge(local, carry, keys, grads, counts, me, ...,
+  plan=, resolved=)`` / ``apply_local(local, optimizer, flat, grads, ...,
+  plan=, resolved=)`` — the push's owner side likewise (``resolved``:
+  ``read_plan``'s of the same plan and the same table contents, which the
+  push then takes instead of resolving again), ``outputs(carry, weights,
+  slots, axes)`` — what leaves the program, ``slot_of(carry, keys, me)``
+  — a key's slot in this shard (-1: not here), where the owner writes a
+  cached key's row back;
+* ``routing()`` — what ``owner`` reads of the spec (hashable): stores that
+  agree on it route one column alike, and share its routed plan;
 * ``ef_space(table)`` — the int8-EF residual's key space.
 
 No code in this file asks which kind it holds: a step that needs to is a
 method the store lacks.
 
-One dedup a table a step. The masked-local body looks every position of a
-batch up, and the push behind it dedups the same keys; the train step
-(``Trainer``) therefore builds a table's :class:`dedup.Plan` once
-(:func:`plan_sharded`) and hands it to the pull, which resolves the distinct
-keys over their occupied prefix and expands by ``inverse``, and to the
-push, whose unique buffer it is. It serves both where both see the same
-keys (:func:`shares_plan`). The routed body dedups its sender slice itself
-(``alltoall.exchange_pull``), and any call without a plan runs as it did.
+One dedup a distinct id column a step. The masked-local body looks every
+position of a batch up, and the push behind it dedups the same keys; the
+train step (``Trainer``) therefore builds a column's :class:`dedup.Plan`
+once (:func:`plan_sharded`) and hands it to the pull, which resolves the
+distinct keys over their occupied prefix and expands by ``inverse``, and to
+the push, whose unique buffer it is. It serves both where both see the
+same keys (:func:`shares_plan`). The routed body has the step's plan too,
+an ``alltoall.RoutedPlan``, on the plain ``a2a`` plane: everything about a
+column that needs no table and no gradient, in the plan's program. At the
+sender the device's slice of the batch, its dedup, the owners and round
+1's buckets (one unique and one bucketing a column, where pull and push of
+every table made their own); then the key all-to-all; at the owner one
+dedup of the bucket slots it received. The owner's pull and push are the
+masked-local body's over that plan (``store.read_plan``,
+``store.merge(plan=, resolved=)``): a row read a distinct key, the rows
+laid back into bucket order by ``inverse``, one combine a table. What
+round 1 did not hold runs as it does without a plan (the pull's residue
+rounds, the push's gathered branch), and so does any call without one:
+the cached and grouped planes, an ``int8_ef`` push, the pipelined
+schedule, serving, ``eval_step``.
 One resolve a key a step, too: the planned pull returns, beside the rows,
 what each shard resolved for the plan's slots (``dedup.Resolution``, one a
-table), and the push that is handed it finds no key and reads no weight
-row again; a push that is not runs as it did.
+table; on the routed body the owner's, of the keys it received), and the
+push that is handed it finds no key and reads no weight row again; a push
+that is not runs as it did.
 Tables fed one column of ids in one key form (:func:`plan_form`) share that
-plan: it holds nothing of a store but the form, each store lays its own
-ownership mask over it (``EmbeddingCollection.plan``).
+plan: it holds nothing of a store but the form (on the routed body, also
+where a key goes), each store lays its own ownership mask over it
+(``EmbeddingCollection.plan``).
 """
 
 from __future__ import annotations
@@ -233,65 +251,101 @@ def _my_shard(grid: dict) -> jnp.ndarray:
 
 
 def shares_plan(spec: PlaneSpec, mesh: Mesh, batch_sharded: bool) -> bool:
-    """A table's pull and push of one step can share one :class:`dedup.Plan`:
-    both run the masked-local body, and a device pushes the keys it pulled
-    (its push gathers no other device's slice of the batch)."""
-    return not spec.routes and (
-        not batch_sharded or mesh.shape[spec.data_axis] == 1)
+    """A table's pull and push of one step can share one plan of its id
+    column. On the masked-local body a :class:`dedup.Plan`, where a device
+    pushes the keys it pulled (its push gathers no other device's slice of
+    the batch). On the routed body an ``alltoall.RoutedPlan``, where pull
+    and push route the same keys the same way: the plain exchange, not
+    the cached plane (hits are masked out of the keys between the two),
+    the grouped one (the collection's own exchange) or an ``int8_ef`` push
+    (its residual is positional in the sender buffer it makes itself)."""
+    if spec.routes:
+        return not (spec.is_cached or spec.is_grouped or spec.is_int8_ef)
+    return not batch_sharded or mesh.shape[spec.data_axis] == 1
 
 
 def _require_shared(spec: PlaneSpec, mesh: Mesh, batch_sharded: bool):
     if not shares_plan(spec, mesh, batch_sharded):
         raise ValueError(
-            f"plane {spec.plane_label!r} over {spec.num_shards} shard(s): "
-            "only the masked-local body, on a mesh with no data axis to "
-            "gather the batch over, has a plan (sharded.shares_plan)")
+            f"plane {spec.plane_label!r} over {spec.num_shards} shard(s) "
+            "has no plan: the masked-local body has one on a mesh with no "
+            "data axis to gather the batch over, the routed body on the "
+            "plain exchange (sharded.shares_plan)")
 
 
-def _plan_specs(batch_spec: P) -> dedup.Plan:
-    return dedup.Plan(uniq=P(), inverse=batch_spec, valid=P())
+def _plan_specs(spec: PlaneSpec, batch_spec: P):
+    """Of a step's plan, out of its program and into pull and push: the
+    masked-local body's is every device's, the routed body's each
+    device's own."""
+    if not spec.routes:
+        return dedup.Plan(uniq=P(), inverse=batch_spec, valid=P())
+    own = P(spec.shard_axes)
+    mine = dedup.Plan(uniq=own, inverse=own, valid=own, counts=own)
+    return a2a.RoutedPlan(sender=mine, owners=own, dest=own, ok=own,
+                          owner=mine, spilled=P())
 
 
 def _resolved_spec(spec: PlaneSpec) -> P:
     """Of every leaf of a ``dedup.Resolution``: a shard's own, out of the
     pull and into the push."""
-    return P(spec.model_axis)
+    return P(spec.shard_axes) if spec.routes else P(spec.model_axis)
 
 
 @functools.lru_cache(maxsize=None)
-def _plan_program(mesh: Mesh, store, batch_sharded: bool):
-    batch_spec = P(store.spec.data_axis) if batch_sharded else P()
+def _plan_program(mesh: Mesh, store, batch_sharded: bool,
+                  record_stats: bool = False):
+    spec = store.spec
+    batch_spec = P(spec.data_axis) if batch_sharded else P()
 
-    def _plan(idx):
-        flat, _ = _flat_keys(store, idx, 0)
-        return dedup.plan_keys(flat, store.sentinel(flat.dtype))
+    if spec.routes:
+        grid = _exchange_args(mesh, spec, batch_sharded, record_stats)
+
+        def _plan(idx):
+            flat, _ = _flat_keys(store, idx, 0)
+            return a2a.plan_exchange(
+                flat, store.owner, sentinel=store.sentinel(flat.dtype),
+                **grid)
+    else:
+        def _plan(idx):
+            flat, _ = _flat_keys(store, idx, 0)
+            return dedup.plan_keys(flat, store.sentinel(flat.dtype))
 
     _plan.__name__ = _program_name(store, "plan")
     return jax.jit(shard_map(_plan, mesh=mesh, in_specs=(batch_spec,),
-                             out_specs=_plan_specs(batch_spec),
+                             out_specs=_plan_specs(spec, batch_spec),
                              check_vma=False))
 
 
 def plan_form(store, indices: jnp.ndarray) -> tuple:
     """All that :func:`plan_sharded` of ``indices`` takes from ``store``:
     the program's name, the axis the batch lies on, the shape of the key
-    stream and the fill. Two stores of one form give one column the same
-    plan, bit for bit, so their tables can share it."""
-    return (_program_name(store, "plan"), store.spec.data_axis,
+    stream and the fill; on the routed body also where a key goes
+    (``store.routing()``, the axes the table lies on) and the buckets'
+    size. Two stores of one form give one column the same plan, bit for
+    bit, so their tables can share it."""
+    spec = store.spec
+    routed = (store.routing(), spec.shard_axes, spec.a2a_capacity,
+              spec.a2a_slack) if spec.routes else ()
+    return (_program_name(store, "plan"), spec.data_axis,
             indices.shape, jnp.dtype(indices.dtype),
-            store.batch_shape(indices.shape), store.sentinel(indices.dtype))
+            store.batch_shape(indices.shape),
+            store.sentinel(indices.dtype)) + routed
 
 
 def plan_sharded(indices: jnp.ndarray, *, mesh: Mesh, store,
-                 batch_sharded: bool = True) -> dedup.Plan:
-    """The :class:`dedup.Plan` of ``indices`` for one step's
-    :func:`pull_sharded` and :func:`apply_gradients_sharded` through
-    ``store``'s table, where :func:`shares_plan` holds: the keys as they
-    come (no ownership mask: each store lays its own over the distinct
-    keys), deduplicated at full capacity. It serves every table whose
-    store has this one's :func:`plan_form`."""
+                 batch_sharded: bool = True):
+    """The plan of ``indices`` for one step's :func:`pull_sharded` and
+    :func:`apply_gradients_sharded` through ``store``'s table, where
+    :func:`shares_plan` holds. On the masked-local body a
+    :class:`dedup.Plan`: the keys as they come (no ownership mask: each
+    store lays its own over the distinct keys), deduplicated at full
+    capacity. On the routed body an ``alltoall.RoutedPlan``: each device's
+    slice deduplicated, counted and bucketed by owner, the keys sent, and
+    what each owner received deduplicated again, its counts summed. It
+    serves every table whose store has this one's :func:`plan_form`."""
     _require_shared(store.spec, mesh, batch_sharded)
-    return _plan_program(mesh, store, batch_sharded)(indices)
+    record = store.spec.routes and observability.evaluate_performance()
+    return _plan_program(mesh, store, batch_sharded, record)(indices)
 
 
 @functools.lru_cache(maxsize=None)
@@ -302,18 +356,23 @@ def _pull_program(mesh: Mesh, store, dim: int, batch_sharded: bool,
     spec = store.spec
     batch_spec = P(spec.data_axis) if batch_sharded else P()
     cache_specs = ()
-    plan_specs = (_plan_specs(batch_spec),) if planned else ()
+    plan_specs = (_plan_specs(spec, batch_spec),) if planned else ()
     out_specs = (batch_spec, _resolved_spec(spec)) if planned else batch_spec
 
     if spec.routes:
         grid = _exchange_args(mesh, spec, batch_sharded, record_stats)
 
-        def _pull_core(arrays, flat, me):
+        def _pull_core(arrays, flat, me, plan=None):
             local = store.local(*arrays)
+            # with the step's plan the owner reads a row a distinct key
+            # it received (the masked-local body's read, over its own
+            # plan) and returns what it resolved beside the rows
             return a2a.exchange_pull(
                 flat, lambda keys: store.resolve(local, keys, me),
                 store.owner, sentinel=store.sentinel(flat.dtype), dim=dim,
-                wire_dtype=spec.pull_wire_dtype, **grid)
+                wire_dtype=spec.pull_wire_dtype, plan=plan,
+                read_plan=lambda mine: store.read_plan(
+                    local, mine, record_stats, me), **grid)
 
         if spec.is_cached:
             cache_specs = (P(), P())        # replicated on every device
@@ -335,10 +394,14 @@ def _pull_program(mesh: Mesh, store, dim: int, batch_sharded: bool,
                 rows = _pull_core(arrays, resid, _my_shard(grid))
                 return (rows + served).reshape(out_shape)
         else:
-            def _pull(arrays, idx):
+            def _pull(arrays, idx, *plan):
                 me = _my_shard(grid)
                 flat, out_shape = _flat_keys(store, idx, dim)
-                return _pull_core(arrays, flat, me).reshape(out_shape)
+                out = _pull_core(arrays, flat, me, *plan)
+                if not plan:
+                    return out.reshape(out_shape)
+                rows, resolved = out
+                return rows.reshape(out_shape), resolved
     else:
         def _pull(arrays, idx, *plan):
             flat, out_shape = _flat_keys(store, idx, dim)
@@ -397,7 +460,8 @@ def pull_sharded(state, indices: jnp.ndarray, *, mesh: Mesh, store,
     path) else replicated. Returns rows with the same batch sharding. On the
     ``"a2a+cache"`` plane ``state`` is a :class:`hot_cache.CachedState`.
     ``plan`` is :func:`plan_sharded`'s of the same ``indices``: the same
-    rows, each distinct key resolved once, and with them what was
+    rows, each distinct key resolved once (on the routed body: by its
+    owner, of the keys round 1 brought it), and with them what was
     resolved, ``(rows, dedup.Resolution)``: the second for
     :func:`apply_gradients_sharded` of the same step, while nothing has
     written the table.
@@ -427,24 +491,49 @@ def _apply_program(mesh: Mesh, store, optimizer: SparseOptimizer, dim: int,
     batch_spec = P(spec.data_axis) if batch_sharded else P()
     table_specs = store.specs(slot_names)
     extra_in = extra_out = ()
-    plan_specs = (_plan_specs(batch_spec), _resolved_spec(spec))[:planned]
+    plan_specs = (_plan_specs(spec, batch_spec),
+                  _resolved_spec(spec))[:planned]
 
     if spec.routes:
         grid = _exchange_args(mesh, spec, batch_sharded, record_stats)
 
-        def _push_core(arrays, flat, g2, ef=None):
+        def _push_core(arrays, flat, g2, ef=None, plan=None, resolved=None):
             local = store.local(*arrays)
-            merge_fn = functools.partial(
+            merge = functools.partial(
                 store.merge, local, me=_my_shard(grid),
                 dedup_capacity=dedup_capacity, record_stats=record_stats)
+
+            # With what the step's pull resolved the apply takes the weight
+            # rows that pull read. A step round 1 did not hold merges as it
+            # does without a plan, and reads its rows there: one structure
+            # out of both branches, one apply behind them.
+            def merge_fn(st, keys, grads, counts):
+                st, merged = merge(st, keys, grads, counts)
+                if resolved is not None:
+                    merged = merged[:4] + (table_lib.pulled_rows(
+                        local.weights, *merged[:2], *merged[4:]),)
+                return st, merged
+
+            # the owner merges by its plan of the keys round 1 brought it
+            def merge_plan(st, grads):
+                st, merged = merge(st, None, grads, None, plan=plan.owner,
+                                   resolved=resolved)
+                if resolved is not None:
+                    merged = merged[:4] + (resolved.rows,)
+                return st, merged
+
             out = a2a.exchange_push(
                 flat, g2, store.carry(local), merge_fn, store.owner,
                 sentinel=store.sentinel(flat.dtype),
-                wire_dtype=spec.push_wire_dtype, ef_state=ef, **grid)
+                wire_dtype=spec.push_wire_dtype, ef_state=ef, plan=plan,
+                merge_plan=merge_plan, **grid)
             (carry, merged), new_ef = out if ef is not None else (out, ())
+            pulled = None
+            if resolved is not None:
+                *merged, pulled = merged
             weights, slots = table_lib.apply_rows(
                 local.weights, local.slots, optimizer, *merged,
-                record_stats=record_stats)
+                pulled=pulled, record_stats=record_stats)
             return carry, weights, slots, new_ef
 
         if spec.is_cached:
@@ -499,10 +588,14 @@ def _apply_program(mesh: Mesh, store, optimizer: SparseOptimizer, dim: int,
                 extra_in = extra_out = (P(spec.shard_axes),) * 2
 
             def _apply(arrays, *rest):
-                *ef, idx, g = rest
+                # an int8_ef push's residual comes in front of the batch,
+                # a step's plan (never both) behind it
+                at = len(rest) - planned
+                *ef, idx, g = rest[:at]
                 flat, _ = _flat_keys(store, idx, dim)
                 carry, weights, slots, new_ef = _push_core(
-                    arrays, flat, g.reshape(-1, dim), ef=tuple(ef) or None)
+                    arrays, flat, g.reshape(-1, dim), tuple(ef) or None,
+                    *rest[at:])
                 return (store.outputs(carry, weights, slots,
                                       spec.shard_axes), new_ef)
     else:

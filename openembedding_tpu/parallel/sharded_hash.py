@@ -455,8 +455,14 @@ class HashStore:
         keys = _mask_non_owned(self.spec, plan.uniq, me)
         return plan.replace(uniq=keys, valid=plan.valid & self.valid(keys))
 
-    def read_plan(self, local, plan, record_stats):
-        mine = self.own(plan, lax.axis_index(self.spec.model_axis))
+    def routing(self) -> tuple:
+        """What :meth:`owner` reads of the spec: two stores that agree on
+        it send one column's keys to the same owners."""
+        return self.spec.num_shards, self.spec.key_width
+
+    def read_plan(self, local, plan, record_stats, me=None):
+        mine = self.own(plan, lax.axis_index(self.spec.model_axis)
+                        if me is None else me)
         return hash_lib.pull_distinct(
             local, mine.uniq, mine.valid, self.initializer,
             self.spec.max_probes, positions=plan.inverse.shape[0],
@@ -466,13 +472,18 @@ class HashStore:
         return local.keys, jnp.zeros((), jnp.int32)
 
     def merge(self, local, carry, keys, grads, counts, me, *,
-              dedup_capacity, record_stats):
+              dedup_capacity, record_stats, plan=None, resolved=None):
         tkeys, fails = carry
+        # with the owner's plan of the keys it received nothing is
+        # deduplicated, and with what its pull resolved nothing is found
         tkeys, failed, merged = hash_lib.merge_gradients(
             local.replace(keys=tkeys), self.initializer,
-            _mask_non_owned(self.spec, keys, me), grads,
-            dedup_capacity=dedup_capacity, max_probes=self.spec.max_probes,
-            in_counts=counts, record_stats=record_stats)
+            _mask_non_owned(self.spec, keys, me) if plan is None else None,
+            grads, dedup_capacity=dedup_capacity,
+            max_probes=self.spec.max_probes, in_counts=counts,
+            record_stats=record_stats,
+            plan=None if plan is None else self.own(plan, me),
+            resolved=resolved)
         return (tkeys, fails + failed), merged
 
     def apply_local(self, local, optimizer, flat, grads, *, dedup_capacity,

@@ -323,12 +323,13 @@ def snapshot_rows_sharded(arrays, phys: jnp.ndarray, count, *, mesh: Mesh,
         list(arrays), phys, jnp.asarray(count, jnp.int32))
 
 
-def _masked_local(spec: ShardingSpec, flat: jnp.ndarray):
-    """``(owned [n], local row [n])`` of ``flat`` on this model-axis shard
-    (the masked-local body of the psum plane). Invalid indices (negative
-    or beyond the padded vocab) are owned by nobody: the pull's psum
-    returns zero rows for them, like ``table_lib.pull``."""
-    s = lax.axis_index(spec.model_axis)
+def _masked_local(spec: ShardingSpec, flat: jnp.ndarray, me=None):
+    """``(owned [n], local row [n])`` of ``flat`` on shard ``me``; None:
+    this model-axis shard (the masked-local body of the psum plane).
+    Invalid indices (negative or beyond the padded vocab) are owned by
+    nobody: the pull's psum returns zero rows for them, like
+    ``table_lib.pull``."""
+    s = lax.axis_index(spec.model_axis) if me is None else me
     shard, local = spec.shard_and_local(flat)
     owned = (shard == s) & (flat >= 0) & (flat < spec.padded_vocab)
     return owned, local
@@ -392,19 +393,26 @@ class ArrayStore:
             lambda flat: _masked_local(self.spec, flat))(flat)
         return scope.stage("resolve")(_take_owned)(local.weights, owned, row)
 
-    def own(self, plan: dedup.Plan) -> dedup.Plan:
-        """``plan`` as this model-axis shard sees it: a key it owns is its
-        local row, any other -1 and not valid."""
+    def routing(self) -> tuple:
+        """What :meth:`owner` reads of the spec: two stores that agree on
+        it send one column's keys to the same owners."""
+        return (self.spec.layout, self.spec.num_shards,
+                self.spec.rows_per_shard)
+
+    def own(self, plan: dedup.Plan, me=None) -> dedup.Plan:
+        """``plan`` as shard ``me`` sees it (None: this model-axis shard):
+        a key it owns is its local row, any other -1 and not valid."""
         @scope.stage("route")
-        def mask(uniq, valid):
-            owned, row = _masked_local(self.spec, uniq)
+        def mask(uniq, valid, *me):
+            owned, row = _masked_local(self.spec, uniq, *me)
             return jnp.where(owned, row, -1), valid & owned
 
-        uniq, valid = mask(plan.uniq, plan.valid)
+        uniq, valid = mask(plan.uniq, plan.valid,
+                           *(() if me is None else (me,)))
         return plan.replace(uniq=uniq, valid=valid)
 
-    def read_plan(self, local, plan, record_stats):
-        mine = self.own(plan)
+    def read_plan(self, local, plan, record_stats, me=None):
+        mine = self.own(plan, me)
 
         @scope.stage("resolve")
         def read(weights, row, live):
@@ -419,7 +427,12 @@ class ArrayStore:
         return ()
 
     def merge(self, local, carry, keys, grads, counts, me, *,
-              dedup_capacity, record_stats):
+              dedup_capacity, record_stats, plan=None, resolved=None):
+        if plan is not None:
+            # the owner's plan of the keys it received: its slots are the
+            # buffer, and ``resolved`` (their rows) is the apply's to take
+            return carry, table_lib.merge_gradients(
+                None, grads, plan=self.own(plan, me))
         rows = scope.stage("route")(
             lambda keys, me: self.slot_of(carry, keys, me))(keys, me)
         return carry, table_lib.merge_gradients(

@@ -164,7 +164,8 @@ def merge_gradients(indices: jnp.ndarray, grads: jnp.ndarray, *,
     ``dedup_capacity`` (default ``n``) slots. Returns :func:`apply_rows`'s
     ``(rows, live, summed, counts)``. ``plan`` is the dedup of ``indices``
     where the step has made it already, in front of its pull: its slots
-    are the buffer and nothing is deduplicated again."""
+    are the buffer and nothing is deduplicated again (nor counted, where
+    the plan brings its ``counts``)."""
     flat_grads = grads.reshape(-1, grads.shape[-1])
     if plan is None:
         flat_idx = indices.ravel()
@@ -176,8 +177,9 @@ def merge_gradients(indices: jnp.ndarray, grads: jnp.ndarray, *,
     # negative indices are invalid keys: pull clamps them to row 0, the
     # update must NOT let them wrap around onto a real row.
     valid = valid & (uniq >= 0)
-    summed, counts = dedup.combine_gradients(flat_grads, inverse, capacity,
-                                             in_counts)
+    summed, counts = dedup.combine_gradients(
+        flat_grads, inverse, capacity, in_counts,
+        counts=None if plan is None else plan.counts)
     return uniq, valid, summed, counts
 
 
@@ -349,6 +351,17 @@ def gather_rows(weights: Optional[jnp.ndarray],
                 {k: jnp.take(v, at, axis=0) for k, v in slots.items()})
 
     return gather(weights, slots, at)
+
+
+def pulled_rows(weights: jnp.ndarray, rows: jnp.ndarray, live: jnp.ndarray,
+                fresh: Optional[jnp.ndarray] = None,
+                inserted: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """The weight rows :func:`apply_rows` reads for a buffer it is handed
+    no ``pulled`` for, in one pass: its ``pulled``, for a caller that has
+    to hand over one structure whether a pull resolved the buffer or not
+    (the routed push's two branches, ``parallel/sharded.py``)."""
+    w, _ = gather_rows(weights, {}, jnp.where(live, rows, 0))
+    return w if fresh is None else jnp.where(inserted[:, None], fresh, w)
 
 
 def read_rows(arrays, at: jnp.ndarray, count: jnp.ndarray):

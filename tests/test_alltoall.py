@@ -88,6 +88,90 @@ def test_routing_overflow_counts(devices8):
     assert a2a.routing_overflow(idx, 8, 1, lambda u: u % 8) == 0
 
 
+@pytest.mark.parametrize("capacity,spilled", [(0, 0), (1, 1)],
+                         ids=["fits", "spills"])
+def test_plan_exchange_is_what_a_hand_count_says(devices8, capacity, spilled):
+    """Eight ids over data 2 x model 2, two a device, owner ``key % 4``:
+    the routed plan holds each device's distinct keys and how often it
+    asked for each, their owners and bucket slots, the keys each owner
+    received deduplicated with their counts summed over the senders, and
+    how many keys round 1 left; the counters say the same."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    from openembedding_tpu.ops import dedup
+    from openembedding_tpu.utils import observability as obs
+    mesh = create_mesh(2, 2, devices8[:4])
+    axes = ("data", "model")
+    fill = int(dedup.FILL)
+    # device 0: {0}; 1: {4, 8}, both owner 0's; 2: {4} and a key no shard
+    # owns; 3: {9}
+    ids = jnp.asarray([0, 0, 8, 4, 4, -1, 9, 9], jnp.int32)
+
+    def owner_fn(keys):
+        return jnp.where(keys >= 0, keys % 4, 4).astype(jnp.int32)
+
+    def plan(idx):
+        return a2a.plan_exchange(
+            idx, owner_fn, sentinel=fill, num_shards=4, grid_axes=axes,
+            grid_sizes=(2, 2), split_axes=("model",), split_sizes=(2,),
+            capacity=capacity, record_stats=True)
+
+    own = P(axes)
+    mine = dedup.Plan(uniq=own, inverse=own, valid=own, counts=own)
+    obs.GLOBAL.reset()
+    obs.set_evaluate_performance(True)
+    try:
+        got = jax.jit(shard_map(
+            plan, mesh=mesh, in_specs=(P("data"),),
+            out_specs=a2a.RoutedPlan(sender=mine, owners=own, dest=own,
+                                     ok=own, owner=mine, spilled=P()),
+            check_vma=False))(ids)
+        jax.effects_barrier()
+        counted = {k: int(v["count"])
+                   for k, v in obs.GLOBAL.snapshot().items()}
+    finally:
+        obs.set_evaluate_performance(False)
+        obs.GLOBAL.reset()
+    cap = capacity or 2                 # a bucket holds a slice of two
+    assert np.asarray(got.sender.uniq).reshape(4, 2).tolist() == [
+        [0, fill], [4, 8], [-1, 4], [9, fill]]
+    assert np.asarray(got.sender.inverse).reshape(4, 2).tolist() == [
+        [0, 0], [1, 0], [1, 0], [0, 0]]
+    assert np.asarray(got.sender.counts).reshape(4, 2).tolist() == [
+        [2, 0], [1, 1], [1, 1], [2, 0]]
+    assert np.asarray(got.owners).reshape(4, 2).tolist() == [
+        [0, 4], [0, 0], [4, 0], [1, 4]]
+    ok = np.asarray(got.ok).reshape(4, 2)
+    assert ok.tolist() == [[True, False], [True, not spilled], [False, True],
+                           [True, False]]
+    # bucket ``owner`` of a device's send buffer, filled from its start
+    dest = np.asarray(got.dest).reshape(4, 2)
+    assert dest[ok].tolist() == [0, 0] + [1] * (not spilled) + [0, cap]
+    assert (dest[~ok] == 4 * cap).all()
+    assert int(got.spilled) == spilled
+    # owner 0 holds 0 and 4 (from two senders) and 8 where it fitted,
+    # owner 1 holds 9, the others nothing; padding is no key
+    held = [[0, 4] + [8] * (not spilled), [9], [], []]
+    uniq = np.asarray(got.owner.uniq).reshape(4, 4 * cap)
+    valid = np.asarray(got.owner.valid).reshape(4, 4 * cap)
+    assert [uniq[i][valid[i]].tolist() for i in range(4)] == held
+    # a key's positions over all its senders: 4 was asked for by two
+    counts = np.asarray(got.owner.counts).reshape(4, 4 * cap)
+    assert [counts[i][valid[i]].tolist() for i in range(4)] == [
+        [2, 2] + [1] * (not spilled), [2], [], []]
+    received = [[0, 4] + [8] * (not spilled) + [4], [9], [], []]
+    back = np.take_along_axis(
+        uniq, np.asarray(got.owner.inverse).reshape(4, 4 * cap), axis=1)
+    assert [sorted(k for k in row if k != fill) for row in back.tolist()] \
+        == [sorted(r) for r in received]
+    assert counted == {"routed_plan_keys_sent": int(ok.sum()),
+                       "routed_plan_owner_keys_live": int(valid.sum()),
+                       "routed_plan_owner_slots": 4 * 4 * cap}
+    assert (counted["routed_plan_keys_sent"],
+            counted["routed_plan_owner_keys_live"]) == (5 - spilled,
+                                                        4 - spilled)
+
+
 # --- array-table parity ------------------------------------------------------
 
 @pytest.mark.parametrize("data,model", [(1, 8), (2, 4), (8, 1)])

@@ -1,18 +1,23 @@
 """One dedup a table a step (``dedup.Plan``): the masked-local pull resolves
-each distinct key once and expands, the push takes the same plan.
+each distinct key once and expands, the push takes the same plan. The
+routed body has the step's plan too (``alltoall.RoutedPlan``): one dedup
+and one bucketing at the sender, the keys sent once, one dedup and one row
+read a key at the owner.
 
 With a plan or without, a pull returns the same rows and a push leaves the
-same table, bit for bit: on one shard and on a two-shard ``psum`` mesh, for
-array, int32-key and wide-key hash tables, whatever the ids (padding, all
-one key, all distinct, the distinct keys ending on a chunk boundary), and
-a hash key never pushed reads its init row. ``Trainer.train_step`` builds
+same table, bit for bit: on one shard, on a two-shard ``psum`` mesh and
+routed over a 2x2 mesh, for array, int32-key and wide-key hash tables,
+whatever the ids (padding, all one key, all distinct, the distinct keys
+ending on a chunk boundary), buckets that hold a step's keys or not, and a
+hash key never pushed reads its init row. ``Trainer.train_step`` builds
 the plan; three steps of it leave the state three steps without leave.
 
 One plan a distinct id column: tables fed the same ids (a fused table and its
 ``:linear`` twin: the same host array, or an equal copy) run ONE plan a step,
-which leaves what a plan each leaves, bit for bit; columns that differ keep a
-plan each; the routed 2x2 step is the program it is without; and
-``lower_train_step`` lowers the program the steps ran.
+which leaves what a plan each leaves, bit for bit, routed too; columns that
+differ keep a plan each; the cached, grouped and pipelined planes and an
+``int8_ef`` push have none and lower to the text they had before the routed
+plan came; and ``lower_train_step`` lowers the program the steps ran.
 
 What the pull resolved goes to the push (``dedup.Resolution``): the slot and
 the weight row of every distinct key. Steps whose push takes them leave the
@@ -37,6 +42,7 @@ from openembedding_tpu import table as table_lib
 from openembedding_tpu.embedding import SameColumns
 from openembedding_tpu.fused import LINEAR_SUFFIX, make_fused_specs
 from openembedding_tpu.models import deepctr
+from openembedding_tpu.parallel import alltoall as a2a
 from openembedding_tpu.parallel import sharded
 from openembedding_tpu.parallel.mesh import create_mesh
 from openembedding_tpu.training import SAME_COLUMNS
@@ -81,10 +87,22 @@ def _ids(case, pad):
     return ids.astype(np.int32).reshape(N // 4, 4)
 
 
-def _collection(kind, mesh, plane):
+# (data, model, plane): one shard, the masked-local body over two model
+# shards, the routed body over a 2x2 mesh
+MESHES = pytest.mark.parametrize(
+    "data,model,plane", [(1, 1, "a2a"), (1, 2, "psum"), (2, 2, "a2a")],
+    ids=["1x1", "psum2", "a2a2x2"])
+
+
+def _mesh(devices, data, model):
+    return create_mesh(data, model, devices[:data * model])
+
+
+def _collection(kind, mesh, plane, a2a_capacity=0):
     spec = EmbeddingSpec(
         name="t", input_dim=VOCAB if kind == "array" else -1,
         output_dim=DIM, hash_capacity=1024, plane=plane,
+        a2a_capacity=a2a_capacity,
         key_dtype={"array": None, "hash32": "int32",
                    "hashwide": "wide"}[kind],
         optimizer={"category": "adagrad", "learning_rate": 0.1},
@@ -100,14 +118,14 @@ def _same(got, want):
 
 @pytest.mark.parametrize("case", ["padding", "all_duplicate", "all_distinct",
                                   "chunk_boundary", "zipf"])
-@pytest.mark.parametrize("model,plane", [(1, "a2a"), (2, "psum")],
-                         ids=["1x1", "psum2"])
+@MESHES
 @pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
 def test_plan_changes_no_row_and_no_update(devices8, small_chunks, kind,
-                                           model, plane, case):
-    mesh = create_mesh(1, model, devices8[:model])
+                                           data, model, plane, case):
+    mesh = _mesh(devices8, data, model)
     coll, states = _collection(kind, mesh, plane)
-    assert not coll.sharding_spec("t").routes
+    routed = coll.sharding_spec("t").routes
+    assert routed == (data * model == 4)
     inputs = {"t": jnp.asarray(_ids(case, -1 if kind == "array" else EMPTY))}
     asked = np.asarray(inputs["t"]) != (-1 if kind == "array" else EMPTY)
     grads = {"t": jnp.asarray(
@@ -115,10 +133,22 @@ def test_plan_changes_no_row_and_no_update(devices8, small_chunks, kind,
 
     plan = coll.plan(inputs)
     assert set(plan) == {"t"}
-    # the plan is of the keys as they come: a hash table's padding is the
-    # fill, an array's -1 a key like another until its store masks it
-    planned = np.asarray(inputs["t"])[asked | (kind == "array")]
-    assert int(np.asarray(plan["t"].valid).sum()) == len(set(planned.tolist()))
+    if routed:
+        # every key a table can hold reaches one owner, which holds it
+        # once however many senders asked for it; nothing spills at
+        # this size (a bucket holds a slice)
+        assert isinstance(plan["t"], a2a.RoutedPlan)
+        distinct = len(set(np.asarray(inputs["t"])[asked].tolist()))
+        assert int(np.asarray(plan["t"].owner.valid).sum()) == distinct
+        assert int(np.asarray(plan["t"].ok).sum()) >= distinct
+        assert int(plan["t"].spilled) == 0
+    else:
+        # the plan is of the keys as they come: a hash table's padding is
+        # the fill, an array's -1 a key like another until its store masks
+        # it
+        planned = np.asarray(inputs["t"])[asked | (kind == "array")]
+        assert int(np.asarray(plan["t"].valid).sum()) == \
+            len(set(planned.tolist()))
 
     rows = coll.pull(states, inputs)
     _same(coll.pull(states, inputs, plan=plan), rows)
@@ -140,18 +170,42 @@ def test_plan_changes_no_row_and_no_update(devices8, small_chunks, kind,
     assert np.abs(moved[asked]).max() > 0 and not moved[~asked].any()
 
 
-def test_plan_is_for_the_masked_local_body_alone(devices8):
-    """A routed table dedups its own sender slice: the collection plans
-    nothing for it, and the builder refuses a plan it is handed."""
+@pytest.mark.parametrize("plane,planned", [
+    ("a2a", True), ("a2a+bf16", True), ("a2a+pipelined", True),
+    ("a2a+cache", False), ("a2a+grouped", False), ("a2a+int8", False),
+    ("psum", False)])
+def test_which_planes_have_a_plan_and_which_refuse_one(devices8, plane,
+                                                       planned):
+    """Over a 2x2 mesh. The plain exchange has a routed plan, whatever its
+    wire; so have the per-table programs of the pipelined plane (its
+    schedule builds none: ``Trainer``). The cached plane (hits leave the
+    keys between pull and push), the grouped one (its own exchange), an
+    ``int8_ef`` push (its residual is positional in its own sender buffer)
+    and ``psum`` over a data axis (the push gathers other devices' slices)
+    have none: the collection plans nothing for them, and the builder
+    refuses a plan it is handed."""
     mesh = create_mesh(2, 2, devices8[:4])
-    coll, states = _collection("array", mesh, "a2a")
+    coll, states = _collection("array", mesh, plane)
     inputs = {"t": jnp.asarray(_ids("zipf", -1))}
-    assert coll.plan(inputs) == {}
-    one = create_mesh(1, 1, devices8[:1])
-    plan = _collection("array", one, "a2a")[0].plan(inputs)["t"]
-    with pytest.raises(ValueError, match="masked-local"):
+    spec, store = coll.sharding_spec("t"), coll._stores["t"]
+    assert sharded.shares_plan(spec, mesh, True) == planned
+    plan = coll.plan(inputs)
+    if planned:
+        assert isinstance(plan["t"], a2a.RoutedPlan)
+        rows, resolved = sharded.pull_sharded(
+            states["t"], inputs["t"], mesh=mesh, store=store, plan=plan["t"])
+        np.testing.assert_array_equal(
+            rows, sharded.pull_sharded(states["t"], inputs["t"], mesh=mesh,
+                                       store=store))
+        assert resolved.rows.shape[0] == plan["t"].owner.uniq.shape[0]
+        return
+    assert plan == {}
+    with pytest.raises(ValueError, match="has no plan"):
+        sharded.plan_sharded(inputs["t"], mesh=mesh, store=store)
+    other = _collection("array", mesh, "a2a")[0].plan(inputs)["t"]
+    with pytest.raises(ValueError, match="has no plan"):
         sharded.pull_sharded(states["t"], inputs["t"], mesh=mesh,
-                             store=coll._stores["t"], plan=plan)
+                             store=store, plan=other)
 
 
 # --- the push takes what the pull resolved ----------------------------------
@@ -196,19 +250,19 @@ def _pushed(coll, states, batches, how):
 
 
 @pytest.mark.parametrize("chunks", ["one_chunk", "several_chunks"])
-@pytest.mark.parametrize("model,plane", [(1, "a2a"), (2, "psum")],
-                         ids=["1x1", "psum2"])
+@MESHES
 @pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
 def test_the_push_takes_what_the_pull_resolved(devices8, request, kind,
-                                               model, plane, chunks):
+                                               data, model, plane, chunks):
     """Fresh keys every step, a buffer of one chunk or of several: four
     steps whose push takes the pull's slots and rows leave, step by step,
     the state of steps whose push has the plan alone and of steps without a
     plan. The resolution is each shard's own: rows of the plan's length a
-    shard, a hash table's slots beside them."""
+    shard (routed: of the bucket slots an owner received), a hash table's
+    slots beside them."""
     if chunks == "several_chunks":
         request.getfixturevalue("small_chunks")
-    mesh = create_mesh(1, model, devices8[:model])
+    mesh = _mesh(devices8, data, model)
     coll, states = _collection(kind, mesh, plane)
     batches = list(_fresh_batches(kind, 4))
     want = _pushed(coll, states, batches, "alone")
@@ -217,11 +271,13 @@ def test_the_push_takes_what_the_pull_resolved(devices8, request, kind,
     inputs, _ = batches[0]
     plan = coll.plan(inputs)
     resolved = coll.pull_resolved(states, inputs, plan=plan)[1]["t"]
-    assert resolved.rows.shape == (model * N, DIM)
+    # a slice of N / 4 keys fits its bucket: 4 buckets of it an owner
+    slots = 4 * N if data * model == 4 else model * N
+    assert resolved.rows.shape == (slots, DIM)
     if kind == "array":
         assert resolved.slot is None
     else:       # nothing is in the table before the first push
-        assert resolved.slot.shape == (model * N,)
+        assert resolved.slot.shape == (slots,)
         assert (np.asarray(resolved.slot) == -1).all()
     if kind != "array":
         final = want[-1][1]["t"]
@@ -229,37 +285,93 @@ def test_the_push_takes_what_the_pull_resolved(devices8, request, kind,
         assert int(final.num_used()) > N    # keys were inserted, step by step
 
 
-@pytest.mark.parametrize("model,plane", [(1, "a2a"), (2, "psum")],
-                         ids=["1x1", "psum2"])
+def _spilling_batches(kind):
+    """Six batches over a 2x2 mesh whose buckets hold two keys: fresh keys
+    (a slice of ten has more than two for some owner), one key alone (no
+    bucket is passed), fresh keys again."""
+    fresh = list(_fresh_batches(kind, 5))
+    inputs, grads = fresh[2]
+    fresh.insert(2, ({"t": jnp.full_like(inputs["t"], 17)}, grads))
+    return fresh
+
+
+@pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
+def test_a_step_the_buckets_do_not_hold_runs_as_it_does_without(
+        devices8, small_chunks, kind):
+    """``a2a_capacity`` 2 over a 2x2 mesh: round 1 is the plan's, the keys
+    it leaves go through the pull's residue rounds and the push takes its
+    gathered branch, both under the plan and both as without one: rows,
+    tables, key arrays, accumulators and failure counts bit for bit, step
+    by step, whether the push has what the pull resolved, the plan alone
+    or nothing; a step that fits (one key) takes the plan's way in between.
+    ``a2a_extra_entries_*`` count the same keys with a plan as without."""
+    mesh = create_mesh(2, 2, devices8[:4])
+    coll, states = _collection(kind, mesh, "a2a", a2a_capacity=2)
+    batches = _spilling_batches(kind)
+    spilled = [int(coll.plan(inputs)["t"].spilled) for inputs, _ in batches]
+    assert spilled[2] == 0 and all(n > 0 for n in spilled[:2] + spilled[3:])
+    counted = {}
+    observability.set_evaluate_performance(True)
+    try:
+        for how in ("alone", "planned", "carried"):
+            for program in PROGRAMS:
+                program.cache_clear()
+            observability.GLOBAL.reset()
+            counted[how] = _pushed(coll, states, batches, how), {
+                k: int(v["count"])
+                for k, v in observability.GLOBAL.snapshot().items()
+                if k.startswith(("a2a_extra_entries", "push_rows_carried"))}
+            jax.effects_barrier()
+    finally:
+        observability.set_evaluate_performance(False)
+        observability.GLOBAL.reset()
+        for program in PROGRAMS:
+            program.cache_clear()
+    want, extra = counted["alone"]
+    assert extra == {"a2a_extra_entries_pull": sum(spilled),
+                     "a2a_extra_entries_push": sum(spilled)}
+    for how in ("planned", "carried"):
+        _same_bits(counted[how][0], want)
+        assert {k: v for k, v in counted[how][1].items()
+                if k.startswith("a2a")} == extra
+    # the apply took its rows from the pull in every step: the owner's
+    # read where round 1 held the step, the gathered branch's own elsewhere
+    assert counted["carried"][1]["push_rows_carried"] > 0
+    assert "push_rows_carried" not in counted["planned"][1]
+
+
+@MESHES
 @pytest.mark.parametrize("kind", ["hash32", "hashwide"])
 def test_a_key_no_window_holds_fails_the_same(devices8, small_chunks, kind,
-                                              model, plane):
+                                              data, model, plane):
     """A table too full for its keys: a carried slot of -1 runs the insert
     that fails as it fails without, and the failures are counted the
     same."""
-    mesh = create_mesh(1, model, devices8[:model])
+    mesh = _mesh(devices8, data, model)
     spec = EmbeddingSpec(
-        name="t", input_dim=-1, output_dim=DIM, hash_capacity=64 * model,
+        name="t", input_dim=-1, output_dim=DIM,
+        hash_capacity=64 * model,
         plane=plane, key_dtype="int32" if kind == "hash32" else "wide",
         optimizer={"category": "adagrad", "learning_rate": 0.1})
     coll = EmbeddingCollection([spec], mesh)
     states = coll.init(jax.random.PRNGKey(5))
-    batches = list(_fresh_batches(kind, 6))
+    batches = list(_fresh_batches(kind, 6 * data))
     want = _pushed(coll, states, batches, "alone")
     assert int(want[-1][1]["t"].insert_failures) > 0
     for how in ("planned", "carried"):
         _same_bits(_pushed(coll, states, batches, how), want)
 
 
-@pytest.mark.parametrize("model,plane", [(1, "a2a"), (2, "psum")],
-                         ids=["1x1", "psum2"])
+@MESHES
 @pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
 def test_a_negative_zero_weight_keeps_its_sign(devices8, small_chunks, kind,
-                                               model, plane):
+                                               data, model, plane):
     """The carried row is the shard's own read, before the sum over the
-    model axis: ``-0.0 + 0.0`` is ``0.0``, and a weight that a zero
-    gradient leaves alone would lose its sign on the way to the push."""
-    mesh = create_mesh(1, model, devices8[:model])
+    model axis (routed: the owner's, before the response crosses the wire
+    and is added into the sender's buffer): ``-0.0 + 0.0`` is ``0.0``, and
+    a weight that a zero gradient leaves alone would lose its sign on the
+    way to the push."""
+    mesh = _mesh(devices8, data, model)
     coll, states = _collection(kind, mesh, plane)
     (inputs, grads), = _fresh_batches(kind, 1)
     grads = {"t": grads["t"].at[..., 0].set(0.0)}
@@ -421,10 +533,11 @@ def _counts(snapshot):
             if k in snapshot}
 
 
-def _fused_trainer(kind):
+def _fused_trainer(kind, shape=(1, 1)):
     """A DeepFM over one fused table and its ``:linear`` twin on one
-    device, as ``examples/criteo_deepctr.py`` builds it."""
-    mesh = create_mesh(1, 1, jax.devices()[:1])
+    device (or routed over a 2x2 mesh), as ``examples/criteo_deepctr.py``
+    builds it."""
+    mesh = _mesh(jax.devices(), *shape)
     specs, mapper = make_fused_specs(
         FEATURES, VOCAB if kind == "array" else -1, DIM,
         optimizer={"category": "adagrad", "learning_rate": 0.1},
@@ -455,12 +568,12 @@ def _fused_batches(mapper, twin):
                "sparse": sparse}
 
 
-def _fused_steps(kind, twin, patch=None):
+def _fused_steps(kind, twin, patch=None, shape=(1, 1)):
     """(losses, state and scores, plan counters) of ``STEPS`` steps under
     ``record_stats``; ``patch(collection)`` first."""
     for program in PROGRAMS:
         program.cache_clear()
-    coll, trainer, mapper = _fused_trainer(kind)
+    coll, trainer, mapper = _fused_trainer(kind, shape)
     if patch:
         patch(coll)
     batches = list(_fused_batches(mapper, twin))
@@ -497,19 +610,26 @@ def _no_plan(coll):
 @pytest.mark.parametrize("twin,seen", [
     ("same", "plan_columns_same_object"),
     ("copy", "plan_columns_compared_equal")])
+@pytest.mark.parametrize("shape", [(1, 1), (2, 2)], ids=["1x1", "a2a2x2"])
 @pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
-def test_tables_fed_one_column_share_one_plan(kind, twin, seen):
+def test_tables_fed_one_column_share_one_plan(kind, twin, seen, shape):
     """The same array or an equal copy: one plan a step for the two tables,
     and losses, dense state, tables and scores are what a plan each
-    leaves, bit for bit."""
-    want = _fused_steps(kind, twin, _a_plan_each)
+    leaves, bit for bit; routed over a 2x2 mesh they are also what the
+    step without any plan leaves (held for the mapper's own batch)."""
+    want = _fused_steps(kind, twin, _a_plan_each, shape)
     assert want[2] == {"dedup_plans_built": 2 * STEPS,
                        "dedup_plan_tables": 2 * STEPS}
-    got = _fused_steps(kind, twin)
+    got = _fused_steps(kind, twin, None, shape)
     assert got[2] == {seen: STEPS, "dedup_plans_built": STEPS,
                       "dedup_plan_tables": 2 * STEPS}
     assert got[0] == want[0]
     _same(got[1], want[1])
+    if shape != (1, 1) and twin == "same":
+        alone = _fused_steps(kind, twin, _no_plan, shape)
+        assert alone[2] == {seen: STEPS}
+        assert got[0] == alone[0]
+        _same(got[1], alone[1])
 
 
 @pytest.mark.parametrize("kind", ["array", "hash32", "hashwide"])
@@ -558,8 +678,9 @@ def test_a_plan_is_shared_in_one_key_form_alone(devices8):
 
 
 def test_same_columns_are_seen_on_the_host_alone(devices8):
-    """Device arrays are compared by identity, never read back; a routed
-    plane is left as it is; the counters say what each column was."""
+    """Device arrays are compared by identity, never read back; a plane
+    that has no plan (the cached one) is left as it is, the routed one is
+    observed as one chip is; the counters say what each column was."""
     one = create_mesh(1, 1, devices8[:1])
     coll, _ = _collection("array", one, "a2a")
     assert coll.same_columns({"t": np.zeros(4, np.int32)}).twins == ()
@@ -584,9 +705,13 @@ def test_same_columns_are_seen_on_the_host_alone(devices8):
             assert _counts(observability.GLOBAL.snapshot()) == counted
         finally:
             observability.GLOBAL.reset()
+        four = create_mesh(2, 2, devices8[:4])
+        assert EmbeddingCollection(specs, four).same_columns(
+            inputs).twins == twins
         assert EmbeddingCollection(
-            specs, create_mesh(2, 2, devices8[:4])).same_columns(
-                inputs).twins == ()
+            [EmbeddingSpec(name=n, input_dim=VOCAB, output_dim=DIM,
+                           plane="a2a+cache") for n in ("t", "u", "v")],
+            four).same_columns(inputs).twins == ()
     bound = SameColumns((("u", "t"),)).bind({"t": 1, "u": 2, "v": 3})
     assert bound == {"t": 1, "u": 1, "v": 3}
 
@@ -743,19 +868,82 @@ def test_the_planned_push_neither_finds_nor_gathers_weights(name,
                                   "apply gathers": 2})
 
 
-def test_the_routed_step_is_the_program_it_is_without():
-    """2x2: no plan, nothing bound. The step of the mapper's batch (one
-    array under both names) lowers to the text of a batch whose columns
-    are apart, with both columns its parameters and no plan program."""
+def test_the_routed_step_binds_the_twin_column_and_plans_once():
+    """2x2: the step of the mapper's batch (one array under both names)
+    sees the twin, reads both tables' ids from ONE parameter and calls the
+    plan's program once; a batch whose columns are apart keeps both
+    parameters and a plan each; ``lower_train_step`` lowers the program
+    the last step ran."""
     system, built, pool = _tiny("tiny_array_x4")
-    assert built.coll.same_columns(pool[0]["sparse"]) == SameColumns()
-    assert built.coll.plan(pool[0]["sparse"]) == {}
+    same = built.coll.same_columns(pool[0]["sparse"])
+    assert same == SameColumns((("fields" + LINEAR_SUFFIX, "fields"),))
+    plan = built.coll.plan(same.bind(pool[0]["sparse"]))
+    assert plan["fields"] is plan["fields" + LINEAR_SUFFIX]
+    assert isinstance(plan["fields"], a2a.RoutedPlan)
     abstract = _abstract_step(built, pool[0])
-    text = built.trainer.lower_train_step(*abstract).as_text()
-    assert not _plan_calls(text) and "plan_a2a" not in text
+
+    def columns(text):
+        main = re.search(r"func\.func public @main\((.*?)\) ->", text, re.S)
+        return main[1].count("tensor<64x26xi32>")
+
+    apart = built.trainer.lower_train_step(*abstract).as_text()
+    assert _plan_calls(apart) == ["plan_a2a"] * 2 and columns(apart) == 2
     state = system.initial_state(built, 3500000021, on_device=False)
     state, _ = built.trainer.train_step(state, pool[0])
     jax.block_until_ready(state)
-    assert built.trainer.lower_train_step(*abstract).as_text() == text
-    main = re.search(r"func\.func public @main\((.*?)\) ->", text, re.S)[1]
-    assert main.count("tensor<64x26xi32>") == 2, main[:400]
+    one = built.trainer.lower_train_step(*abstract).as_text()
+    assert _plan_calls(one) == ["plan_a2a"] and columns(one) == 1
+    noted = dict(abstract[1], **{SAME_COLUMNS: same})
+    assert built.trainer.lower_train_step(abstract[0], noted).as_text() == one
+
+
+# sha256 of the step programs of the planes that take no plan, lowered on
+# the CPU backend over a 2x2 mesh at the parent of PR 39 (85d4baa): the
+# routed plan changes nothing of a program that is handed none
+_UNPLANNED_TEXTS = {
+    "a2a+cache":
+    "d6676542729d6b1628f303d5924a305e9e2913f105a4e201b1f0efc8b71429cc",
+    "a2a+grouped":
+    "b2e717cebafc60e50069e862b3cfce015dd9ff726c2192f54c23e58c928adec8",
+    "a2a+pipelined":
+    "e0379a84535e585b165b41e08d1b7c33708e6bb00b287d9bb7bb1ceb03c684bd",
+    "a2a+int8":
+    "69653fedc2a0a74ad398a1dde71e1a3a1b4b1fa67bf53a7ac05a0b02f4dc92d6"}
+
+
+@pytest.mark.parametrize("plane", sorted(_UNPLANNED_TEXTS))
+def test_a_plane_without_a_plan_lowers_to_the_text_it_had(devices8, plane):
+    """The cached, grouped and pipelined steps and the step of an
+    ``int8_ef`` push over a 2x2 mesh: a DeepFM over two features and their
+    ``:linear`` twins (``analysis.programs``' harness), fed one array a
+    pair. No plan program is called, and the text is the parent's."""
+    import hashlib
+    mesh = create_mesh(2, 2, devices8[:4])
+    features, vocab, batch = ("c0", "c1"), 4096, 64
+    coll = EmbeddingCollection(
+        deepctr.make_feature_specs(features, vocab, 8, plane=plane), mesh,
+        default_optimizer={"category": "adagrad", "learning_rate": 0.1})
+    trainer = Trainer(deepctr.build_model("deepfm", features), coll,
+                      optax.adam(1e-2))
+    rng = np.random.RandomState(0)
+    data = {"label": rng.randint(0, 2, size=batch).astype(np.float32),
+            "dense": rng.randn(batch, 4).astype(np.float32),
+            "sparse": {f: rng.randint(0, vocab, size=batch).astype(np.int32)
+                       for f in features}}
+    for f in features:
+        data["sparse"][f + deepctr.LINEAR_SUFFIX] = data["sparse"][f]
+    # (the pipelined plane's per-table programs have a plan; it is the
+    # schedule that builds none)
+    assert coll.same_columns(data["sparse"]) == SameColumns() \
+        or "pipelined" in plane
+    placed = trainer.shard_batch(data)
+    state = trainer.init(jax.random.PRNGKey(0), placed)
+    if "pipelined" in plane:
+        state = trainer._prime_pipeline(state, data)
+        pull_inputs, _ = trainer._split_sparse(data["sparse"])
+        text = trainer._build_pipelined_train_step().lower(
+            state, placed, trainer.shard_batch(pull_inputs)).as_text()
+    else:
+        text = trainer.lower_train_step(state, placed).as_text()
+    assert "plan_a2a" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == _UNPLANNED_TEXTS[plane]

@@ -233,3 +233,71 @@ def test_both_kinds_through_the_one_builder(devices8, kind, data, model):
                                         store=store)), np.asarray(got[0]))
     assert sharded._pull_program.cache_info().misses == 1
     assert sharded._apply_program.cache_info().misses == 1
+
+
+@pytest.mark.parametrize("capacity,spilled", [(0, 0), (1, 1)],
+                         ids=["fits", "spills"])
+def test_a_routed_step_counts_what_a_hand_count_says(devices8, capacity,
+                                                     spilled):
+    """Eight ids over data 2 x model 2, two a device (the ``mod`` layout:
+    owner ``id % 4``): device 0 asks for {0}, 1 for {4, 8}, 2 for {4} and
+    an id no shard owns, 3 for {9}. Plan, pull and push through the
+    builder, under ``record_stats``: five distinct keys are sent (four
+    where a bucket holds one key and 8 is left for the residue round and
+    the gathered branch, which ``a2a_extra_entries_*`` count as they do
+    without a plan); the owners hold four distinct keys of the 32 bucket
+    slots they received (16 at capacity 1), read a row each and hand the
+    push a row each; rows and table are what they are without a plan."""
+    from openembedding_tpu.parallel import sharded
+    from openembedding_tpu.utils import observability as obs
+
+    mesh = create_mesh(2, 2, devices8[:4])
+    meta = EmbeddingVariableMeta(embedding_dim=DIM, vocabulary_size=VOCAB)
+    opt = make_optimizer({"category": "adagrad", "learning_rate": 0.1})
+    spec = st.make_sharding_spec(meta, mesh, a2a_capacity=capacity)
+    store = st.ArrayStore(spec)
+    state = st.create_sharded_table(meta, opt, mesh=mesh, spec=spec,
+                                    rng=jax.random.PRNGKey(3))
+    ids = jnp.asarray([0, 0, 8, 4, 4, -1, 9, 9], jnp.int32)
+    grads = jnp.asarray(np.random.RandomState(8).randn(8, DIM), jnp.float32)
+    want_rows = sharded.pull_sharded(state, ids, mesh=mesh, store=store)
+    want = sharded.apply_gradients_sharded(state, opt, ids, grads, mesh=mesh,
+                                           store=store)
+    programs = (sharded._plan_program, sharded._pull_program,
+                sharded._apply_program)
+    obs.GLOBAL.reset()
+    obs.set_evaluate_performance(True)
+    try:
+        plan = sharded.plan_sharded(ids, mesh=mesh, store=store)
+        rows, resolved = sharded.pull_sharded(state, ids, mesh=mesh,
+                                              store=store, plan=plan)
+        got = sharded.apply_gradients_sharded(
+            state, opt, ids, grads, mesh=mesh, store=store, plan=plan,
+            resolved=resolved)
+        jax.block_until_ready(got)
+        jax.effects_barrier()
+        counted = {k: int(v["count"])       # (plane timings hold no count)
+                   for k, v in obs.GLOBAL.snapshot().items() if "count" in v}
+    finally:
+        obs.set_evaluate_performance(False)
+        obs.GLOBAL.reset()
+        for program in programs:        # the recording programs
+            program.cache_clear()
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(want_rows))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    cap = capacity or 2
+    assert int(plan.spilled) == spilled
+    assert {k: counted[k] for k in counted if k.startswith("routed_plan")} \
+        == {"routed_plan_keys_sent": 5 - spilled,
+            "routed_plan_owner_keys_live": 4 - spilled,
+            "routed_plan_owner_slots": 4 * 4 * cap}
+    assert counted["a2a_extra_entries_pull"] == spilled
+    assert counted.get("a2a_extra_entries_push", 0) == spilled
+    # the owners' read of round 1: a row a distinct key, for 4 * cap
+    # positions a device
+    assert counted["pull_keys_live"] == 4 - spilled
+    assert counted["pull_positions"] == 4 * 4 * cap
+    # the apply took every live row from the pull: the owner's read, or
+    # the gathered branch's own where the step spilled (4 distinct keys)
+    assert counted["push_rows_carried"] == counted["apply_slots_live"] == 4

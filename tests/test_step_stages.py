@@ -25,6 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from benchmark import stage_reduce, system as system_lib
 from openembedding_tpu.analysis import programs
 from openembedding_tpu.parallel.mesh import create_mesh
+from openembedding_tpu.training import SAME_COLUMNS
 
 CONFIGS = os.path.join(os.path.dirname(system_lib.__file__), "configs")
 EVERYWHERE = {"dedup", "route", "resolve", "apply_gather", "apply_update",
@@ -32,7 +33,9 @@ EVERYWHERE = {"dedup", "route", "resolve", "apply_gather", "apply_update",
 # one chip takes the masked-local body: nothing is bucketed and no push
 # branches; its pull reads the distinct keys of the step's plan and expands
 # them (``dedup.Plan``); its psum over one device is lowered and then
-# compiled away, so ``exchange`` is a symbol only
+# compiled away, so ``exchange`` is a symbol only. Four devices route, with
+# the step's plan too (``alltoall.RoutedPlan``): the same stage names, which
+# is how the benchmark's readers find their stages
 STAGES = {
     "tiny_array": (EVERYWHERE | {"exchange", "expand"},
                    EVERYWHERE | {"expand"}),
@@ -64,6 +67,7 @@ def _lower_step(name):
         "ids": np.zeros((rows, config["sparse_features"]), np.int64),
         "label": np.zeros((rows,), np.float32),
         "dense": np.zeros((rows, config["dense_features"]), np.float32)})
+    same = system.coll.same_columns(batch["sparse"])
     state = jax.eval_shape(system.trainer.init, jax.random.PRNGKey(0), batch)
     replicated = NamedSharding(system.mesh, P())
 
@@ -81,7 +85,11 @@ def _lower_step(name):
         lambda x: jax.ShapeDtypeStruct(
             x.shape, jax.dtypes.canonicalize_dtype(x.dtype),
             sharding=system.by_batch), batch)
-    return system.trainer.lower_train_step(state, batch)
+    # the program the cell's steps run: the mapper hands the table and its
+    # ``:linear`` twin one array, which shapes alone do not say
+    assert len(same.twins) == 1
+    return system.trainer.lower_train_step(state,
+                                           {**batch, SAME_COLUMNS: same})
 
 
 @pytest.mark.parametrize("name", sorted(STAGES))

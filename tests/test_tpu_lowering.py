@@ -62,13 +62,12 @@ HASH_CAPACITY = 1 << 26
 def _abstract_step(mesh, coll, trainer, mapper, rows):
     """(state, batch) of the step as shapes and shardings alone, and with
     them what a step sees in the mapper's host batch and shapes do not
-    say: the table and its ``:linear`` twin are fed one array, so on one
-    chip the program is the one-plan step (on 2x2 no plan is built, and
-    nothing is the same to it)."""
+    say: the table and its ``:linear`` twin are fed one array, so the
+    program is the one-plan step, on one chip and routed over 2x2."""
     batch = mapper.fuse_batch(next(iter(criteo.synthetic_criteo(
         chip_smoke.BATCH, num_buckets=rows, num_batches=1))))
     same = coll.same_columns(batch["sparse"])
-    assert len(same.twins) == (mesh.size == 1)
+    assert len(same.twins) == 1
     state = jax.eval_shape(trainer.init, jax.random.PRNGKey(0), batch)
     repl = NamedSharding(mesh, P())
     state = state.replace(
@@ -140,15 +139,17 @@ def test_v5e_step_keeps_its_stage_names(v5e, use_hash):
 
 @pytest.mark.parametrize("shape", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
 def test_v5e_hash_push_finds_then_inserts_in_place(v5e, shape):
-    """Behind the exchange each table's push holds three loops under
-    ``probe``: the find, the insert loop over the buffer of misses and the
-    one over the whole call. Which of the two places keys is decided by
-    what each is given, under no conditional of the probe's own: through
-    one the chip's compiler copies the key array, and on one chip no copy
-    of it is left. On one chip the find is the pull's, over the distinct
-    keys of the step's plan, one loop a table, and the push, which takes
-    the slots it found (``dedup.Resolution``), holds the two insert loops
-    alone: two loops a table fewer than a push that finds again."""
+    """A push that finds for itself holds three loops under ``probe``: the
+    find, the insert loop over the buffer of misses and the one over the
+    whole call. Which of the two places keys is decided by what each is
+    given, under no conditional of the probe's own: through one the chip's
+    compiler copies the key array, and on one chip no copy of it is left.
+    The find is the pull's, over the distinct keys of the step's plan, one
+    loop a table, and the push, which takes the slots it found
+    (``dedup.Resolution``), holds the two insert loops alone: two loops a
+    table fewer than a push that finds again. Behind the exchange the
+    owner's push is that push in the routed branch (two loops a table) and
+    the push that finds for itself in the gathered one (three)."""
     data, model = shape
     mesh = create_mesh(data, model, v5e[:data * model])
     hlo = _compile_deepfm_step(mesh, use_hash=True).as_text()
@@ -161,7 +162,7 @@ def test_v5e_hash_push_finds_then_inserts_in_place(v5e, shape):
     assert not re.search(r'conditional\(.*op_name="[^"]*jit\(probe\)/cond',
                          hlo)
     if mesh.size > 1:
-        assert pushing and len(pushing) % 6 == 0, loops     # two tables
+        assert len(pushing) == 10 and len(loops) == 12, loops   # two tables
     else:
         assert len(pushing) == 4 and len(loops) == 6, loops
         keys = f"s32[{HASH_CAPACITY},2]"
@@ -231,10 +232,11 @@ def test_v5e_apply_walks_its_buffer_in_place(v5e, shape, use_hash):
     of the unique buffer, under an apply stage and under no conditional
     (on four chips the push's branches only merge; the apply follows
     them): a loop in a branch gets its table copied in. No copy of an
-    array as long as a device's share of a table is left. A trip gathers
-    the weights and the accumulator; on one chip the weight rows come with
-    the step's plan from its pull (``dedup.Resolution``) and a trip gathers
-    the accumulator alone."""
+    array as long as a device's share of a table is left. The weight rows
+    come with the step's plan from its pull (``dedup.Resolution``) and a
+    trip gathers the accumulator alone; on four chips the push's gathered
+    branch, which no pull resolved for, reads its weight rows itself, in
+    one pass a table."""
     data, model = shape
     mesh = create_mesh(data, model, v5e[:data * model])
     hlo = _compile_deepfm_step(mesh, use_hash=use_hash).as_text()
@@ -247,7 +249,10 @@ def test_v5e_apply_walks_its_buffer_in_place(v5e, shape, use_hash):
     assert not [inst for inst in loops if "/cond/" in paths.get(inst, "")]
     gathers = [inst for inst, op in found
                if op == "gather" and stages.get(inst) == "apply_gather"]
-    assert len(gathers) == (2 if mesh.size == 1 else 4), gathers
+    branch = [inst for inst in gathers if "/cond/" in paths[inst]]
+    assert len(gathers) - len(branch) == 2, gathers
+    assert len(branch) == (0 if mesh.size == 1 else 2), branch
+    assert all("push_spilled" in paths[inst] for inst in branch), branch
     rows = (HASH_CAPACITY // mesh.size if use_hash
             else chip_smoke.FEATURES * ROWS_PER_FEATURE)
     copied = [line.strip()[:120] for line in hlo.splitlines()
@@ -342,6 +347,69 @@ def test_v5e_one_chip_step_builds_one_plan_for_both_tables(v5e, use_hash,
                  and f"{prefix}{verb}_a2a" in paths.get(inst, "").split("/")
                  and stages.get(inst, "").startswith(stage)]
         assert len(loops) == 2, (verb, loops)           # two tables
+
+
+def test_v5e_routed_step_takes_the_steps_plan(v5e):
+    """The planned 2x2 step at the x4 cell's size. The plan's program
+    sorts once a distinct id column at the sender (its slice's dedup) and
+    once at the owner (the bucket slots it received), beside the one
+    bucketing; pull and push of both tables dedup nothing outside the
+    push's gathered branch (the compiler may sort for a combine's
+    scatter-add) and bucket nothing outside it and the pull's residue
+    loop. Each table's owner reads the distinct keys' rows in a loop of
+    chunk-sized gathers under ``resolve``; the push gathers no weight row
+    outside the gathered branch (its apply gathers the accumulator alone),
+    and no array as long as a device's share of a table is copied, inside
+    the push's conditional or out of it."""
+    mesh = create_mesh(2, 2, v5e[:4])
+    hlo = _compile_deepfm_step(mesh, use_hash=False).as_text()
+    paths = trace_reduce.scope_names(hlo)
+    stages = stage_reduce.instruction_stages(hlo, paths)
+    found = [m.groups() for m in map(_OPCODE.match, hlo.splitlines()) if m]
+    lines = {m.group(1): line for line in hlo.splitlines()
+             if (m := _OPCODE.match(line))}
+
+    def length(inst):
+        return int(re.search(r"= \(?\w+\[(\d+)", lines[inst])[1])
+
+    def under(inst, name):
+        return name in paths.get(inst, "").split("/")
+
+    slice_keys = chip_smoke.FEATURES * chip_smoke.BATCH // mesh.size
+    planning = sorted((stages.get(inst), length(inst)) for inst, op in found
+                      if op == "sort" and under(inst, "plan_a2a")
+                      and paths[inst].endswith("/sort"))  # the program's own
+    received = planning[1][1]       # four buckets of twice the mean
+    assert received == 2 * slice_keys
+    assert planning == [("dedup", slice_keys), ("dedup", received),
+                        ("route", slice_keys)], planning
+    # what is left of sorts under pull and push: the residue loop's
+    # bucketing, the gathered branch, a combine's scatter-add
+    for inst, op in found:
+        if op != "sort" or stages.get(inst) not in ("dedup", "route"):
+            continue
+        if under(inst, "pull_a2a"):
+            assert "/while/" in paths[inst], paths[inst]
+        elif under(inst, "push_a2a") and "push_spilled" not in paths[inst]:
+            assert not paths[inst].endswith("/sort"), paths[inst]
+    reads = [inst for inst, op in found if op == "while"
+             and under(inst, "pull_a2a") and stages.get(inst) == "resolve"]
+    assert len(reads) == 2, reads                       # two tables
+    chunk = hl.table_lib.APPLY_CHUNK
+    assert chunk in {length(inst) for inst, op in found if op == "gather"
+                     and stages.get(inst) == "resolve"
+                     and under(inst, "pull_a2a")}
+    weights = [inst for inst, op in found if op == "gather"
+               and under(inst, "push_a2a")
+               and stages.get(inst) == "apply_gather"
+               and "push_spilled" not in paths[inst]]
+    assert len(weights) == 2, weights       # the accumulators alone
+    rows = chip_smoke.FEATURES * ROWS_PER_FEATURE
+    copied = [line.strip()[:120] for line in hlo.splitlines()
+              if (" copy(" in line or " copy-start(" in line)
+              and int((re.search(r"= \(?\w+\[(\d+)", line) or [0, 0])[1])
+              >= rows]
+    assert not copied, copied
 
 
 def _on(dev, shape, dtype):
