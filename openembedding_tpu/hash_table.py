@@ -392,7 +392,8 @@ def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
                    valid: jnp.ndarray,
                    max_probes: int = DEFAULT_MAX_PROBES,
                    record_stats: bool = False,
-                   found: Optional[jnp.ndarray] = None
+                   found: Optional[jnp.ndarray] = None,
+                   known: Optional[jnp.ndarray] = None
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Find each (unique) key's slot, inserting missing keys.
 
@@ -406,6 +407,10 @@ def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
     in the table or not ``valid``) and the phase is skipped: the push of a
     train step, whose pull found these keys under this mask
     (:func:`pull_distinct`) with no write to the table between the two.
+    ``known`` ([n] bool) says for which keys ``found`` is that answer: the
+    find then walks the others alone (the routed push behind a step its
+    buckets did not hold, whose owner met keys no pull resolved), and
+    none where every valid key is known.
     *Insert*: the keys
     that missed, in their original order, are compacted into a buffer of
     :func:`insert_width` keys and only that buffer runs the insert loop
@@ -449,15 +454,19 @@ def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
     failed [n])``.
     """
     return scope.stage("probe")(
-        lambda table_keys, new_keys, valid, found: _find_or_insert(
-            table_keys, new_keys, valid, max_probes, record_stats, found))(
-                table_keys, new_keys, valid, found)
+        lambda table_keys, new_keys, valid, found, known: _find_or_insert(
+            table_keys, new_keys, valid, max_probes, record_stats, found,
+            known))(table_keys, new_keys, valid, found, known)
 
 
 def _find_or_insert(table_keys, new_keys, valid, max_probes, record_stats,
-                    found=None):
+                    found=None, known=None):
     n = new_keys.shape[0]
     m = insert_width(n)
+    if known is not None:
+        # a key nothing is known of is found by the loop that places it
+        # (small calls) or by a find of its own
+        found = jnp.where(known, found, -1)
     if m >= n:
         out = _insert_levels(
             table_keys, new_keys,
@@ -471,6 +480,10 @@ def _find_or_insert(table_keys, new_keys, valid, max_probes, record_stats,
 
     if found is None:
         found, walked = _find_levels(table_keys, new_keys, valid, max_probes)
+    elif known is not None:
+        sought, walked = _find_levels(table_keys, new_keys, valid & ~known,
+                                      max_probes)
+        found = jnp.where(known, found, sought)
     else:
         walked = jnp.int32(0)
     miss = valid & (found < 0)
@@ -766,34 +779,20 @@ def snapshot_keys(table_keys: jnp.ndarray, arrays, query: jnp.ndarray,
         arrays, jnp.where(found, slot, oob), count)
 
 
-def merge_gradients(state: HashTableState,
-                    initializer: Any,
-                    indices: jnp.ndarray,
-                    grads: jnp.ndarray,
-                    *,
-                    dedup_capacity: Optional[int] = None,
-                    max_probes: int = DEFAULT_MAX_PROBES,
-                    in_counts: Optional[jnp.ndarray] = None,
-                    record_stats: bool = False,
-                    plan: Optional[dedup.Plan] = None,
-                    resolved: Optional[dedup.Resolution] = None):
-    """The first half of :func:`apply_gradients`, which touches the key
-    array alone: deduplicate the keys and combine their gradients into a
-    buffer of ``dedup_capacity`` (default ``n``) slots, find or insert each
-    key. Returns ``(keys, failed, merged)``: the new key array, the number
-    of keys no window held, and ``table.apply_rows``'s ``(rows, live,
-    summed, counts, fresh, inserted)``. ``plan`` is the dedup of
-    ``indices`` where the step has made it already, in front of its pull:
-    its slots are the buffer and nothing is deduplicated again.
-    ``resolved`` is what that pull found for the plan's slots
-    (:func:`pull_distinct` of the same keys under the same mask, the
-    table unwritten since): its ``slot`` is :func:`find_or_insert`'s
-    ``found``, and no key is looked for again; its ``rows`` hold a missing
-    key's init row where the apply wants it (``table.apply_rows``'s
-    ``pulled``), so ``fresh`` and ``inserted`` are None and no init row is
-    made here. ``record_stats`` then counts ``push_slots_carried``, the
-    valid keys whose find the push took."""
-    initializer = make_initializer(initializer)
+def combine_keys(state: HashTableState,
+                 indices: jnp.ndarray,
+                 grads: jnp.ndarray,
+                 *,
+                 dedup_capacity: Optional[int] = None,
+                 in_counts: Optional[jnp.ndarray] = None,
+                 plan: Optional[dedup.Plan] = None):
+    """The half of :func:`merge_gradients` that reads nothing of the
+    table (``state`` says the key form and the row width): deduplicate the
+    keys and combine their gradients into a buffer of ``dedup_capacity``
+    (default ``n``) slots. ``(uniq, valid, summed, counts)``. ``plan`` is
+    the dedup of ``indices`` where the step has made it already, in front
+    of its pull: its slots are the buffer and nothing is deduplicated
+    again."""
     dim = state.dim
     empty = empty_key(state.keys.dtype)
     if plan is not None:
@@ -814,18 +813,70 @@ def merge_gradients(state: HashTableState,
     summed, counts = dedup.combine_gradients(
         grads.reshape(-1, dim), inverse, capacity, in_counts,
         counts=None if plan is None else plan.counts)
+    return uniq, valid, summed, counts
+
+
+def place_keys(state: HashTableState, initializer: Any, uniq: jnp.ndarray,
+               valid: jnp.ndarray, *,
+               max_probes: int = DEFAULT_MAX_PROBES,
+               record_stats: bool = False,
+               resolved: Optional[dedup.Resolution] = None,
+               known: Optional[jnp.ndarray] = None):
+    """The half of :func:`merge_gradients` that touches the key array, and
+    it alone: find or insert each of :func:`combine_keys`' distinct keys.
+    ``(keys, failed, slot, inserted, fresh)``: the new key array, the
+    number of keys no window held, each key's slot (-1: it failed), the
+    keys the call inserted, and their init rows, None where ``resolved``
+    came with the call (its ``rows`` hold them). ``resolved`` is what a
+    pull found for these keys, the table unwritten since: its ``slot`` is
+    :func:`find_or_insert`'s ``found``, for the keys ``known`` says (every
+    key, without it). ``record_stats`` then counts ``push_slots_carried``,
+    the valid keys whose find the call took."""
     keys_arr, slot, inserted, failed = find_or_insert(
         state.keys, uniq, valid, max_probes, record_stats,
-        found=None if resolved is None else resolved.slot)
+        found=None if resolved is None else resolved.slot, known=known)
     if resolved is None:
-        fresh = init_rows(initializer, state.init_rng, uniq, dim,
-                          state.weights.dtype)
+        fresh = init_rows(make_initializer(initializer), state.init_rng,
+                          uniq, state.dim, state.weights.dtype)
     else:
-        fresh = inserted = None
-        record_stat("push_slots_carried", jnp.sum(valid, dtype=jnp.int32),
-                    record_stats)
-    return (keys_arr, jnp.sum(failed).astype(jnp.int32),
-            (slot, valid & (slot >= 0), summed, counts, fresh, inserted))
+        fresh = None
+        record_stat("push_slots_carried", jnp.sum(
+            valid if known is None else valid & known, dtype=jnp.int32),
+            record_stats)
+    return keys_arr, jnp.sum(failed).astype(jnp.int32), slot, inserted, fresh
+
+
+def merge_gradients(state: HashTableState,
+                    initializer: Any,
+                    indices: jnp.ndarray,
+                    grads: jnp.ndarray,
+                    *,
+                    dedup_capacity: Optional[int] = None,
+                    max_probes: int = DEFAULT_MAX_PROBES,
+                    in_counts: Optional[jnp.ndarray] = None,
+                    record_stats: bool = False,
+                    plan: Optional[dedup.Plan] = None,
+                    resolved: Optional[dedup.Resolution] = None):
+    """The first half of :func:`apply_gradients`, which touches the key
+    array alone: :func:`combine_keys`, then :func:`place_keys`. Returns
+    ``(keys, failed, merged)``: the new key array, the number of keys no
+    window held, and ``table.apply_rows``'s ``(rows, live, summed, counts,
+    fresh, inserted)``. ``plan`` is :func:`combine_keys`'s. ``resolved`` is
+    what the step's pull found for the plan's slots
+    (:func:`pull_distinct` of the same keys under the same mask, the
+    table unwritten since): no key is looked for again, and its ``rows``
+    hold a missing key's init row where the apply wants it
+    (``table.apply_rows``'s ``pulled``), so ``fresh`` and ``inserted`` are
+    None and no init row is made here."""
+    uniq, valid, summed, counts = combine_keys(
+        state, indices, grads, dedup_capacity=dedup_capacity,
+        in_counts=in_counts, plan=plan)
+    keys_arr, failed, slot, inserted, fresh = place_keys(
+        state, initializer, uniq, valid, max_probes=max_probes,
+        record_stats=record_stats, resolved=resolved)
+    return (keys_arr, failed,
+            (slot, valid & (slot >= 0), summed, counts, fresh,
+             None if resolved is not None else inserted))
 
 
 def apply_gradients(state: HashTableState,

@@ -562,7 +562,6 @@ def exchange_pull(flat_idx: jnp.ndarray,
 
 def exchange_push(flat_idx: jnp.ndarray,
                   grads: jnp.ndarray,
-                  state,
                   merge_fn: Callable,
                   owner_fn: Callable[[jnp.ndarray], jnp.ndarray],
                   *,
@@ -582,22 +581,22 @@ def exchange_push(flat_idx: jnp.ndarray,
     """Owner-routed push: pre-reduce, route (key, grad sum, count) to owners.
     EXACT for any key distribution.
 
-    ``merge_fn(state, keys [K], grads [K, dim], counts [K]) -> (state,
-    merged)`` runs on the owner with the per-peer pre-reduces and merges
-    them: ``merged`` is a pytree of arrays whose leading axis is the slots
-    of the owner's deduplicated buffer (``table.merge_gradients``), and
-    ``state`` what merging itself changes (a hash table's key array; a
-    pytree with stable structure/shapes/dtypes — it is threaded through
-    ``lax.cond``). Entries with a sentinel key are padding and must be
-    ignored by ``merge_fn`` (both built-in mergers drop them via the
-    invalid-key contract; their count values are garbage by design).
-    Returns ``(state, merged)``, for the caller to apply
-    (``table.apply_rows``) after the exchange: the apply's loop carries the
-    table, and the v5e compiler copies a table that a loop inside a branch
-    of a conditional carries. Each branch merges at its own size, and
-    ``merged`` is padded at the tail with zeros (dead slots) to the longer
-    of the two; the apply walks the occupied prefix, so the padding costs
-    nothing.
+    ``merge_fn(keys [K], grads [K, dim], counts [K]) -> merged`` runs on
+    the owner with the per-peer pre-reduces and merges them: ``merged`` is
+    a pytree of arrays whose leading axis is the slots of the owner's
+    deduplicated buffer (``table.merge_gradients``,
+    ``hash_table.combine_keys``). Entries with a sentinel key are padding
+    and must be ignored by ``merge_fn`` (both built-in mergers drop them
+    via the invalid-key contract; their count values are garbage by
+    design). Returns ``merged``, for the caller to write to its table
+    after the exchange (a hash table's find-or-insert, then
+    ``table.apply_rows``): a merger writes nothing, because those loops
+    carry the table, and the v5e compiler copies a table that a loop
+    inside a branch of a conditional carries (each key array of 2^26 wide
+    slots is 512 MiB). Each branch merges at its own size, and ``merged``
+    is padded at the tail with zeros (dead slots) to the longer of the
+    two; the find and the apply walk the occupied prefix, so the padding
+    costs nothing.
 
     Unlike the pull (idempotent reads, residue rounds compose), a push must
     apply each key's optimizer update EXACTLY ONCE per step with all peer
@@ -645,9 +644,9 @@ def exchange_push(flat_idx: jnp.ndarray,
     it): the slice's gradients are combined by its ``inverse`` into its
     distinct keys' slots and ride round 1's buckets by its ``dest``. The
     keys and their counts are at their owner already, so the gradients
-    cross alone, and the owner merges through ``merge_plan(state, grads
-    [K, dim]) -> (state, merged)``, which sums them by
-    ``plan.owner.inverse`` and deduplicates and counts nothing. A step
+    cross alone, and the owner merges through ``merge_plan(grads [K,
+    dim]) -> merged``, which sums them by ``plan.owner.inverse`` and
+    deduplicates and counts nothing. A step
     round 1 did not hold (``plan.spilled`` > 0) takes the gathered branch
     as it is without a plan; ``merge_fn`` and ``merge_plan`` return one
     structure.
@@ -739,7 +738,7 @@ def exchange_push(flat_idx: jnp.ndarray,
         return g
 
     @scope.stage("push_routed")
-    def routed(st):
+    def routed():
         payload = q8 if quant else (
             summed if wire_dtype is None
             else pin_wire(summed.astype(wire_dtype)))
@@ -747,18 +746,18 @@ def exchange_push(flat_idx: jnp.ndarray,
             send_g = scope.stage("route")(
                 lambda payload, dest: fill_buckets(
                     payload, dest, num_shards, cap, 0))(payload, dest)
-            return merge_plan(st, rows_from_buckets(
+            return merge_plan(rows_from_buckets(
                 grid_all_to_all(send_g, grid_axes, grid_sizes)))
         send_kc, send_g = to_buckets(uniq, counts, payload, scale, dest)
         rkc = grid_all_to_all(send_kc, grid_axes, grid_sizes)
         rg = grid_all_to_all(send_g, grid_axes, grid_sizes)
-        return merge_fn(st, *from_buckets(rkc, rg))
+        return merge_fn(*from_buckets(rkc, rg))
 
     gather_all = scope.stage("exchange")(
         lambda x: lax.all_gather(x, tuple(grid_axes), tiled=True))
 
     @scope.stage("push_spilled")
-    def gathered(st):
+    def gathered():
         k = gather_all(uniq)            # [P*m] or [P*m, 2]
         c = gather_all(counts)
         if quant:
@@ -770,11 +769,11 @@ def exchange_push(flat_idx: jnp.ndarray,
                            wire_dtype).astype(summed.dtype)
         else:
             g = gather_all(summed)
-        return merge_fn(st, k, g, c)
+        return merge_fn(k, g, c)
 
     if cap >= m:
         # buckets can hold the whole slice: bucketize cannot overflow
-        out = routed(state)
+        out = routed()
         return (out, new_ef) if quant else out
     local_spill = jnp.sum((owners < num_shards) & ~ok).astype(jnp.int32)
     spilled = plan.spilled if plan is not None else scope.stage("exchange")(
@@ -785,18 +784,15 @@ def exchange_push(flat_idx: jnp.ndarray,
     # each merged leaf as long as the longer branch leaves it
     lengths = jax.tree.map(
         lambda a, b: max(a.shape[0], b.shape[0]),
-        *(jax.eval_shape(branch, state)[1] for branch in (routed, gathered)))
+        *(jax.eval_shape(branch) for branch in (routed, gathered)))
 
     def padded(branch):
-        def run(st):
-            st, merged = branch(st)
-            return st, jax.tree.map(
-                lambda x, n: jnp.pad(
-                    x, [(0, n - x.shape[0])] + [(0, 0)] * (x.ndim - 1)),
-                merged, lengths)
-        return run
+        return lambda: jax.tree.map(
+            lambda x, n: jnp.pad(
+                x, [(0, n - x.shape[0])] + [(0, 0)] * (x.ndim - 1)),
+            branch(), lengths)
 
-    out = lax.cond(spilled == 0, padded(routed), padded(gathered), state)
+    out = lax.cond(spilled == 0, padded(routed), padded(gathered))
     return (out, new_ef) if quant else out
 
 

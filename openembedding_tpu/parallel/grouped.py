@@ -380,7 +380,7 @@ def _array_push_program(mesh: Mesh, plan: GroupPlan, batch_sharded: bool,
                     ((0, 0), (0, plan.bucket_dim - m.dim)))
             for t, m in enumerate(members)])
 
-        def merge_fn(st, keys, g, counts):
+        def merge_fn(keys, g, counts):
             merged = []
             for t, (kw, _ow, gw, cw) in _sorted_member_windows(
                     keys, bounds, plan.bases[:-1], g, counts):
@@ -392,10 +392,10 @@ def _array_push_program(mesh: Mesh, plan: GroupPlan, batch_sharded: bool,
                 merged.append(table_lib.merge_gradients(
                     jnp.where(mine, local, -1), gw[:, :m.dim],
                     in_counts=cw))
-            return st, tuple(merged)
+            return tuple(merged)
 
-        _, merged = a2a.exchange_push(
-            flat_all, g_all, (), merge_fn, owner, sentinel=dedup.FILL,
+        merged = a2a.exchange_push(
+            flat_all, g_all, merge_fn, owner, sentinel=dedup.FILL,
             num_shards=first.num_shards, grid_axes=grid_axes,
             grid_sizes=grid_sizes, split_axes=split_axes,
             split_sizes=split_sizes, capacity=first.a2a_capacity,
@@ -567,13 +567,20 @@ def _hash_push_program(mesh: Mesh, plan: GroupPlan, batch_sharded: bool,
                     ((0, 0), (0, plan.bucket_dim - m.dim)))
             for t, m in enumerate(members)])
 
-        def merge_fn(st, q, g, counts):
+        tables = [hash_lib.HashTableState(
+            keys=tkeys[t], weights=tweights[t], slots=tslots[t],
+            init_rng=rngs[t], insert_failures=jnp.zeros((), jnp.int32))
+            for t in range(T)]
+
+        # the branches of the push combine and write nothing; each table's
+        # find-or-insert and apply follow the exchange (alltoall.
+        # exchange_push: a table inside the conditional is copied)
+        def merge_fn(q, g, counts):
             keyc_all = q[:, :kw] if plan.wide else q[:, 0]
-            new, merged = [], []
+            merged = []
             for t, (tag, _ow, keyc, gw, cw) in _sorted_member_windows(
                     q[:, kw], bounds, range(T), keyc_all, g, counts):
                 m = members[t]
-                k_t, fails = st[t]
                 mine = (tag == t) & (m.spec.owner_shard(keyc) == me)
                 if plan.wide:
                     masked = jnp.where(mine[:, None], keyc,
@@ -581,34 +588,30 @@ def _hash_push_program(mesh: Mesh, plan: GroupPlan, batch_sharded: bool,
                 else:
                     masked = jnp.where(mine, keyc,
                                        jnp.asarray(empty, keyc.dtype))
-                cur = hash_lib.HashTableState(
-                    keys=k_t, weights=tweights[t], slots=tslots[t],
-                    init_rng=rngs[t],
-                    insert_failures=jnp.zeros((), jnp.int32))
-                k_t, failed, rows = hash_lib.merge_gradients(
-                    cur, m.initializer, masked, gw[:, :m.dim],
-                    max_probes=m.spec.max_probes, in_counts=cw,
-                    record_stats=record_stats)
-                new.append((k_t, fails + failed))
-                merged.append(rows)
-            return tuple(new), tuple(merged)
+                merged.append(hash_lib.combine_keys(
+                    tables[t], masked, gw[:, :m.dim], in_counts=cw))
+            return tuple(merged)
 
-        res, merged = a2a.exchange_push(
-            flat_all, g_all,
-            tuple((tkeys[t], jnp.zeros((), jnp.int32)) for t in range(T)),
-            merge_fn, owner, sentinel=empty,
+        merged = a2a.exchange_push(
+            flat_all, g_all, merge_fn, owner, sentinel=empty,
             num_shards=first.num_shards, grid_axes=grid_axes,
             grid_sizes=grid_sizes, split_axes=split_axes,
             split_sizes=split_sizes, capacity=first.a2a_capacity,
             slack=first.a2a_slack, record_stats=record_stats,
             wire_dtype=first.push_wire_dtype)
-        # per-shard failure deltas -> replicated global totals
-        return tuple(
-            (k, *table_lib.apply_rows(tweights[t], tslots[t],
-                                      members[t].optimizer, *merged[t],
-                                      record_stats=record_stats),
-             lax.psum(f, first.shard_axes))
-            for t, (k, f) in enumerate(res))
+        out = []
+        for t, m in enumerate(members):
+            uniq, valid, summed, counts = merged[t]
+            k_t, failed, slot, inserted, fresh = hash_lib.place_keys(
+                tables[t], m.initializer, uniq, valid,
+                max_probes=m.spec.max_probes, record_stats=record_stats)
+            # per-shard failure deltas -> replicated global totals
+            out.append((k_t, *table_lib.apply_rows(
+                tweights[t], tslots[t], m.optimizer, slot,
+                valid & (slot >= 0), summed, counts, fresh, inserted,
+                record_stats=record_stats),
+                lax.psum(failed, first.shard_axes)))
+        return tuple(out)
 
     _apply.__name__ = "grouped_hash_push"
     row = first.row_spec()

@@ -71,11 +71,18 @@ answers:
   ``dedup.Resolution`` of the plan's slots under shard ``me``'s ownership
   mask (None: this model-axis shard): the rows, and for a kind that
   probes the slots it found;
-* ``carry(local)``, ``merge(local, carry, keys, grads, counts, me, ...,
-  plan=, resolved=)`` / ``apply_local(local, optimizer, flat, grads, ...,
-  plan=, resolved=)`` — the push's owner side likewise (``resolved``:
+* ``merge(local, keys, grads, counts, me, ..., plan=, resolved=,
+  carries=)`` then ``apply_merged(local, optimizer, merged, ...)`` /
+  ``apply_local(local, optimizer, flat, grads, ..., plan=, resolved=)`` —
+  the push's owner side likewise: ``merge`` runs inside a branch of the
+  exchange's conditional and writes nothing (distinct keys, summed
+  gradients, counts), ``apply_merged`` writes the table after it (a hash
+  table's one find-or-insert, the sparse apply) and returns ``(carry,
+  weights, slots)`` as ``apply_local`` does. ``resolved`` is
   ``read_plan``'s of the same plan and the same table contents, which the
-  push then takes instead of resolving again), ``outputs(carry, weights,
+  push then takes instead of resolving again; ``carries`` says a
+  resolution goes with the push, so that a merge that is handed none (the
+  gathered branch) returns the same structure. ``outputs(carry, weights,
   slots, axes)`` — what leaves the program, ``slot_of(carry, keys, me)``
   — a key's slot in this shard (-1: not here), where the owner writes a
   cached key's row back;
@@ -100,8 +107,10 @@ sender the device's slice of the batch, its dedup, the owners and round
 every table made their own); then the key all-to-all; at the owner one
 dedup of the bucket slots it received. The owner's pull and push are the
 masked-local body's over that plan (``store.read_plan``,
-``store.merge(plan=, resolved=)``): a row read a distinct key, the rows
-laid back into bucket order by ``inverse``, one combine a table. What
+``store.merge(plan=, resolved=)`` then ``store.apply_merged``): a row read
+a distinct key, the rows laid back into bucket order by ``inverse``, one
+combine a table, one find-or-insert a hash table behind the push's
+conditional. What
 round 1 did not hold runs as it does without a plan (the pull's residue
 rounds, the push's gathered branch), and so does any call without one:
 the cached and grouped planes, an ``int8_ef`` push, the pipelined
@@ -133,7 +142,6 @@ from ..analysis import scope
 from ..ops import dedup
 from ..optim.optimizers import SparseOptimizer, make_optimizer
 from ..utils import observability
-from .. import table as table_lib
 from . import alltoall as a2a
 from . import hot_cache
 from . import precision
@@ -499,42 +507,30 @@ def _apply_program(mesh: Mesh, store, optimizer: SparseOptimizer, dim: int,
 
         def _push_core(arrays, flat, g2, ef=None, plan=None, resolved=None):
             local = store.local(*arrays)
+            # Both branches of the push merge and write nothing: what they
+            # return, one structure out of either, is written to the table
+            # after them (``store.apply_merged``). With what the step's
+            # pull resolved it holds each slot's weight row and a hash
+            # key's slot; a step round 1 did not hold merges as it does
+            # without a plan, and carries of those what it can without
+            # the table.
             merge = functools.partial(
                 store.merge, local, me=_my_shard(grid),
-                dedup_capacity=dedup_capacity, record_stats=record_stats)
-
-            # With what the step's pull resolved the apply takes the weight
-            # rows that pull read. A step round 1 did not hold merges as it
-            # does without a plan, and reads its rows there: one structure
-            # out of both branches, one apply behind them.
-            def merge_fn(st, keys, grads, counts):
-                st, merged = merge(st, keys, grads, counts)
-                if resolved is not None:
-                    merged = merged[:4] + (table_lib.pulled_rows(
-                        local.weights, *merged[:2], *merged[4:]),)
-                return st, merged
-
-            # the owner merges by its plan of the keys round 1 brought it
-            def merge_plan(st, grads):
-                st, merged = merge(st, None, grads, None, plan=plan.owner,
-                                   resolved=resolved)
-                if resolved is not None:
-                    merged = merged[:4] + (resolved.rows,)
-                return st, merged
+                dedup_capacity=dedup_capacity,
+                carries=resolved is not None)
 
             out = a2a.exchange_push(
-                flat, g2, store.carry(local), merge_fn, store.owner,
+                flat, g2, merge, store.owner,
                 sentinel=store.sentinel(flat.dtype),
                 wire_dtype=spec.push_wire_dtype, ef_state=ef, plan=plan,
-                merge_plan=merge_plan, **grid)
-            (carry, merged), new_ef = out if ef is not None else (out, ())
-            pulled = None
-            if resolved is not None:
-                *merged, pulled = merged
-            weights, slots = table_lib.apply_rows(
-                local.weights, local.slots, optimizer, *merged,
-                pulled=pulled, record_stats=record_stats)
-            return carry, weights, slots, new_ef
+                # the owner merges by its plan of the keys round 1
+                # brought it
+                merge_plan=lambda grads: merge(
+                    None, grads, None, plan=plan.owner, resolved=resolved),
+                **grid)
+            merged, new_ef = out if ef is not None else (out, ())
+            return store.apply_merged(local, optimizer, merged,
+                                      record_stats=record_stats) + (new_ef,)
 
         if spec.is_cached:
             cache_slot_specs = {name: P() for name in slot_names}

@@ -25,10 +25,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..analysis import scope
 from ..meta import EmbeddingVariableMeta
+from ..ops import dedup
 from ..utils import observability
 from ..optim.initializers import make_initializer
 from ..optim.optimizers import SparseOptimizer, make_optimizer
 from .. import hash_table as hash_lib
+from .. import table as table_lib
 from . import alltoall as a2a
 from . import precision
 from . import sharded
@@ -387,8 +389,9 @@ def snapshot_keys_sharded(table_keys, arrays, keys: jnp.ndarray, count, *,
 class HashStore:
     """A hash table behind ``parallel/sharded.py``'s builder (which lists
     what a store answers): a key's slot is found by probing, fresh keys are
-    inserted while merging, so the carry is the key array and the count of
-    keys no probe window held. A missing-but-valid key pulls its
+    inserted behind the merge (``apply_merged``), so the carry out of a
+    push is the key array and the count of keys no probe window held. A
+    missing-but-valid key pulls its
     deterministic init row (computed only by the owner shard), an EMPTY one
     zeros; ``initializer=None`` is the read-only serving contract (missing
     keys -> zeros). Cached keys (``"a2a+cache"``) are always PRESENT in the
@@ -468,23 +471,68 @@ class HashStore:
             self.spec.max_probes, positions=plan.inverse.shape[0],
             record_stats=record_stats)
 
-    def carry(self, local):
-        return local.keys, jnp.zeros((), jnp.int32)
-
-    def merge(self, local, carry, keys, grads, counts, me, *,
-              dedup_capacity, record_stats, plan=None, resolved=None):
-        tkeys, fails = carry
+    def merge(self, local, keys, grads, counts, me, *, dedup_capacity,
+              plan=None, resolved=None, carries=False):
+        """``(uniq, valid, summed, counts)`` of the owner's distinct keys,
+        and where ``carries`` ``(rows, found, known)`` behind them: each
+        slot's weight row and its key's slot as the step's pull resolved
+        them, ``known`` throughout; without a resolution (the gathered
+        branch) a key's init row, no slot and ``known`` nowhere, for
+        ``apply_merged`` to find the keys and read the stored rows. The
+        table is read by neither: the branches of the push's conditional
+        hold no operand of a table's shape."""
         # with the owner's plan of the keys it received nothing is
-        # deduplicated, and with what its pull resolved nothing is found
-        tkeys, failed, merged = hash_lib.merge_gradients(
-            local.replace(keys=tkeys), self.initializer,
+        # deduplicated
+        merged = hash_lib.combine_keys(
+            local,
             _mask_non_owned(self.spec, keys, me) if plan is None else None,
-            grads, dedup_capacity=dedup_capacity,
-            max_probes=self.spec.max_probes, in_counts=counts,
-            record_stats=record_stats,
-            plan=None if plan is None else self.own(plan, me),
-            resolved=resolved)
-        return (tkeys, fails + failed), merged
+            grads, dedup_capacity=dedup_capacity, in_counts=counts,
+            plan=None if plan is None else self.own(plan, me))
+        if not carries:
+            return merged
+        uniq, valid = merged[:2]
+        if resolved is not None:
+            return merged + (resolved.rows, resolved.slot,
+                             jnp.ones(valid.shape, bool))
+        return merged + (
+            hash_lib.init_rows(self.initializer, local.init_rng, uniq,
+                               local.dim, local.weights.dtype),
+            jnp.full(valid.shape, -1, jnp.int32),
+            jnp.zeros(valid.shape, bool))
+
+    def apply_merged(self, local, optimizer, merged, *, record_stats):
+        """One find-or-insert and one sparse apply of what the push's
+        branches merged, behind their conditional: with what a pull
+        resolved (``known``) no key is looked for and no weight row read;
+        the others are found here, and a stored row read, in loops that
+        make no trip where every key is known."""
+        uniq, valid, summed, counts, *carried = merged
+        resolved = known = pulled = None
+        if carried:
+            rows, found, known = carried
+            resolved = dedup.Resolution(rows=rows, slot=found)
+        tkeys, failed, slot, inserted, fresh = hash_lib.place_keys(
+            local, self.initializer, uniq, valid,
+            max_probes=self.spec.max_probes, record_stats=record_stats,
+            resolved=resolved, known=known)
+        live = valid & (slot >= 0)
+        a2a.record_stat("routed_owner_fresh_keys",
+                        jnp.sum(inserted, dtype=jnp.int32), record_stats)
+        if carried:
+            @scope.stage("resolve")
+            def stored_rows(weights, rows, slot, stored):
+                read, _ = table_lib.read_distinct(weights, slot, stored)
+                return jnp.where(stored[:, None], read, rows)
+
+            # a key the table held and no pull resolved: its stored row
+            pulled = stored_rows(local.weights, rows, slot,
+                                 live & ~known & ~inserted)
+            inserted = None
+        weights, slots = table_lib.apply_rows(
+            local.weights, local.slots, optimizer, slot, live, summed,
+            counts, fresh, inserted, pulled=pulled,
+            record_stats=record_stats)
+        return (tkeys, failed), weights, slots
 
     def apply_local(self, local, optimizer, flat, grads, *, dedup_capacity,
                     record_stats, plan=None, resolved=None):

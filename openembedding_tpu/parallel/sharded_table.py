@@ -423,20 +423,30 @@ class ArrayStore:
 
         return read(local.weights, mine.uniq, mine.valid)
 
-    def carry(self, local):
-        return ()
-
-    def merge(self, local, carry, keys, grads, counts, me, *,
-              dedup_capacity, record_stats, plan=None, resolved=None):
+    def merge(self, local, keys, grads, counts, me, *, dedup_capacity,
+              plan=None, resolved=None, carries=False):
         if plan is not None:
             # the owner's plan of the keys it received: its slots are the
             # buffer, and ``resolved`` (their rows) is the apply's to take
-            return carry, table_lib.merge_gradients(
+            merged = table_lib.merge_gradients(
                 None, grads, plan=self.own(plan, me))
+            return merged if resolved is None else merged + (resolved.rows,)
         rows = scope.stage("route")(
-            lambda keys, me: self.slot_of(carry, keys, me))(keys, me)
-        return carry, table_lib.merge_gradients(
+            lambda keys, me: self.slot_of((), keys, me))(keys, me)
+        merged = table_lib.merge_gradients(
             rows, grads, dedup_capacity=dedup_capacity, in_counts=counts)
+        # a step round 1 did not hold reads its weight rows here, so that
+        # one apply follows both branches
+        return merged + (table_lib.pulled_rows(local.weights, *merged[:2]),) \
+            if carries else merged
+
+    def apply_merged(self, local, optimizer, merged, *, record_stats):
+        rows, live, summed, counts, *pulled = merged
+        weights, slots = table_lib.apply_rows(
+            local.weights, local.slots, optimizer, rows, live, summed,
+            counts, pulled=pulled[0] if pulled else None,
+            record_stats=record_stats)
+        return (), weights, slots
 
     def apply_local(self, local, optimizer, flat, grads, *, dedup_capacity,
                     record_stats, plan=None, resolved=None):

@@ -126,9 +126,33 @@ _STALE_SIX_CELLS = (
 )
 
 
+# And four describe the benchmark's seven cells as PR 38 left them: a count
+# of seven cells, of one cell on four chips, of 67 per-layer metrics with
+# the keyed offload cell's four last, and that cell's configuration as the
+# last entry. PR 40 adds an eighth cell on four chips
+# (``deepfm_dim9_hash_x4.train_zipf_keys``), appends it to 31 lists and
+# one metric after those four.
+# ``tests/benchmark/test_bench_hash_x4.py`` asserts what they stood for.
+_STALE_SEVEN_CELLS = (
+    "test_bench_offload_keys.py::"
+    "test_dry_resolves_seven_cells_each_to_its_own_runner",
+    "test_bench_offload_keys.py::"
+    "test_the_configuration_is_the_offload_cells_widths_over_the_hash_"
+    "cells_keys",
+    "test_bench_offload_keys.py::"
+    "test_the_new_cell_reports_what_the_offload_cell_reports_and_four_more",
+    "test_bench_autosave_keys.py::"
+    "test_the_array_autosave_cell_is_as_it_was_accepted",
+)
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
-        if item.nodeid.split("[")[0].endswith(_STALE_SIX_CELLS):
+        if item.nodeid.endswith(_STALE_SEVEN_CELLS):
+            item.add_marker(pytest.mark.xfail(
+                reason="describes the benchmark's seven cells; "
+                       "BENCHMARK.json has eight since PR 40", strict=True))
+        elif item.nodeid.split("[")[0].endswith(_STALE_SIX_CELLS):
             item.add_marker(pytest.mark.xfail(
                 reason="describes the benchmark's six cells; "
                        "BENCHMARK.json has seven since PR 38", strict=True))
