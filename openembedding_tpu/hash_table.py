@@ -381,10 +381,13 @@ def _find_rows(table_keys, query, max_probes):
 
 
 def insert_width(n: int) -> int:
-    """Keys the insert loop of a :func:`find_or_insert` call of ``n`` keys
-    runs over when at most that many miss: an eighth of the call, rounded
-    up to 1024. A training push misses ~5% of its keys; a bulk load misses
-    all of them and takes the full-width loop."""
+    """Keys the compact buffer of a :func:`find_or_insert` call of ``n``
+    keys holds, and the most that may miss for its loop to place them: an
+    eighth of the call, rounded up to 1024. It decides WHICH loop places
+    the misses, not what that costs: the loop over the buffer walks the
+    misses alone, ``table.INSERT_CHUNK`` a trip. A training push misses
+    ~5% of its keys; a bulk load misses all of them and takes the
+    full-width loop."""
     return -(-n // (8 * 1024)) * 1024
 
 
@@ -412,19 +415,22 @@ def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
     buckets did not hold, whose owner met keys no pull resolved), and
     none where every valid key is known.
     *Insert*: the keys
-    that missed, in their original order, are compacted into a buffer of
-    :func:`insert_width` keys and only that buffer runs the insert loop
-    below; its slots are written back to the keys' positions. When more
-    keys miss than the buffer holds (a bulk load, a cold table's first
-    steps) the loop runs over all ``n`` keys instead: both loops are in
-    the program, the observed count leaves one of them without a key to
-    place, and a loop stops at the first level that finds none. Where the
-    buffer would be no narrower than the call (small calls) the loop is
-    all there is, over every key, or over the misses where ``found`` says
+    that missed, in their original order, are compacted into the front of
+    a buffer of :func:`insert_width` keys and only they run the insert
+    loop below, ``table.INSERT_CHUNK`` keys a trip, every trip of a level
+    before the next level (:func:`_insert_trips`); their slots are written
+    back to the keys' positions. When more keys miss than the buffer holds
+    (a bulk load, a cold table's first steps) the loop runs over all ``n``
+    keys instead, a level a pass: both loops are in the program, the
+    observed count leaves one of them without a key to place, and a loop
+    stops at the first level that finds none. Where the buffer would be
+    no narrower than the call (small calls) the full-width loop is all
+    there is, over every key, or over the misses where ``found`` says
     which they are. A key already in the table can never take a slot (its
-    earlier chain buckets are full), and compaction keeps every other
-    contender's rank within its bucket, so slots, ``inserted``, ``failed``
-    and the key array are the same whichever loop placed the keys.
+    earlier chain buckets are full), and compaction and the trips keep
+    every other contender's rank within its bucket, so slots,
+    ``inserted``, ``failed`` and the key array are the same whichever
+    loop placed the keys.
 
     The insert loop makes one pass per chain level: every unplaced key
     probes its level-j bucket — a contiguous 128-slot row — matches
@@ -436,16 +442,24 @@ def find_or_insert(table_keys: jnp.ndarray, new_keys: jnp.ndarray,
     chain level — which is exactly the "only overflow when the bucket
     filled up" invariant lookup relies on.
 
-    Every level costs O(width * 128) gathers + O(width log width) sort
-    work — *independent of table capacity* (an earlier design materialized
-    a [capacity] claim buffer per probe round: O(max_probes * capacity) HBM
-    traffic per insert call, benign at 2^23 rows, fatal at the reference's
-    10^9-row scale, documents/en/pmem.md north star).
+    A level costs O(width * 128) gathers + O(width log width) sort work
+    and a scatter of ``width`` keys into the key array, where ``width`` is
+    the call's keys in the full-width loop and the misses, rounded up to
+    whole trips, in the compact one (a push that places 1,000 keys of
+    106,496 walks 1,024, not the buffer's 13,312; one that places none
+    runs no level) — *independent of table capacity* (an earlier design
+    materialized a [capacity] claim buffer per probe round:
+    O(max_probes * capacity) HBM traffic per insert call, benign at 2^23
+    rows, fatal at the reference's 10^9-row scale, documents/en/pmem.md
+    north star).
 
     ``record_stats`` (the trace-time gate of ``alltoall.record_stat``)
     counts ``hash_insert_compact`` / ``hash_insert_full``, the calls that
     ran the loop over the buffer / over every key,
-    ``hash_insert_missed``, the keys that were not in the table, and
+    ``hash_insert_missed``, the keys that were not in the table,
+    ``hash_insert_keys_walked``, the keys the compact loop's trips walked,
+    summed over the levels it ran (0 where the full-width loop ran: over
+    ``hash_insert_missed``, the padding a call still pays), and
     ``hash_find_slots_live`` / ``hash_find_slots_walked``, the valid keys
     of a call and the keys its find walked (none where ``found`` came with
     the call).
@@ -495,16 +509,18 @@ def _find_or_insert(table_keys, new_keys, valid, max_probes, record_stats,
     # One of the two loops has keys to place and the other runs no level.
     # Under a lax.cond the v5e compiler copies the key array on its way
     # into a branch's loop (512 MiB a table at 2^26 wide slots); through
-    # two whiles it stays in place, as through the one.
-    table_keys, slot_m, _, _ = _insert_levels(
+    # two whiles it stays in place, as through the one. The misses are the
+    # first ``missed`` keys of the buffer, and its loop walks those alone.
+    table_keys, slot_m, walked_m = _insert_trips(
         table_keys, jnp.take(new_keys, at, axis=0, mode="clip"),
-        (at < n) & fits, max_probes)
+        jnp.where(fits, missed, 0), max_probes)
     table_keys, slot, _, _ = _insert_levels(
         table_keys, new_keys, valid & ~fits, max_probes,
         slot0=found.at[at].set(slot_m, mode="drop"))
     record_stat("hash_insert_compact", fits.astype(jnp.int32), record_stats)
     record_stat("hash_insert_full", (~fits).astype(jnp.int32), record_stats)
     record_stat("hash_insert_missed", missed, record_stats)
+    record_stat("hash_insert_keys_walked", walked_m, record_stats)
     record_stat("hash_find_slots_live", jnp.sum(valid, dtype=jnp.int32),
                 record_stats)
     record_stat("hash_find_slots_walked", walked, record_stats)
@@ -570,21 +586,19 @@ def _find_levels(table_keys, query, valid, max_probes):
     return slot[:n], trips * chunk
 
 
-def _insert_levels(table_keys, new_keys, valid, max_probes, slot0=None):
-    """The insert loop of :func:`find_or_insert` over every key given, one
-    chain level at a time while a valid key is neither found nor placed.
-    ``slot0`` is what ``slot`` reads for a key the loop does neither to."""
-    capacity = table_keys.shape[0]
-    n = new_keys.shape[0]
-    bsz, nb, chain = table_layout(capacity, max_probes)
-    h = probe_starts(new_keys, capacity, max_probes)
-    b0 = h // bsz
+def _level_placer(capacity, n, max_probes):
+    """One chain level of the insert loop for ``n`` keys at a time, as a
+    function ``(key array, keys [n], their level's buckets [n], valid [n],
+    slot [n], done [n]) -> (key array, slot, done, placed [n])``: every
+    valid key not done probes its bucket (a contiguous 128-slot row); a
+    key found there is done, and the others are placed by rank among the
+    contenders for their bucket, in the keys' order. A key ranked past the
+    bucket's free slots stays not done and overflows to the next level."""
+    bsz, nb, _chain = table_layout(capacity, max_probes)
     oob = jnp.asarray(capacity, jnp.int32)
     ids = jnp.arange(n, dtype=jnp.int32)
 
-    def level(carry):
-        j, keys_arr, slot, done, inserted = carry
-        bj = b0 + j
+    def place_level(keys_arr, new_keys, bj, valid, slot, done):
         start = bj * bsz
         match, emptym = _bucket_masks(keys_arr, new_keys, bj, max_probes)
         active = valid & ~done
@@ -612,8 +626,27 @@ def _insert_levels(table_keys, new_keys, valid, max_probes, slot0=None):
         pslot = start + tgt
         keys_arr = keys_arr.at[jnp.where(place, pslot, oob)].set(
             new_keys, mode="drop")
-        slot = jnp.where(place, pslot, slot)
-        done = done | place
+        return keys_arr, jnp.where(place, pslot, slot), done | place, place
+
+    return place_level
+
+
+def _insert_levels(table_keys, new_keys, valid, max_probes, slot0=None):
+    """The insert loop of :func:`find_or_insert` over every key given, one
+    chain level at a time while a valid key is neither found nor placed: a
+    level costs the call's width, however few of its keys are left.
+    ``slot0`` is what ``slot`` reads for a key the loop does neither to."""
+    capacity = table_keys.shape[0]
+    n = new_keys.shape[0]
+    bsz, _nb, chain = table_layout(capacity, max_probes)
+    h = probe_starts(new_keys, capacity, max_probes)
+    b0 = h // bsz
+    place_level = _level_placer(capacity, n, max_probes)
+
+    def level(carry):
+        j, keys_arr, slot, done, inserted = carry
+        keys_arr, slot, done, place = place_level(
+            keys_arr, new_keys, b0 + j, valid, slot, done)
         inserted = inserted | place
         return j + 1, keys_arr, slot, done, inserted
 
@@ -629,6 +662,58 @@ def _insert_levels(table_keys, new_keys, valid, max_probes, slot0=None):
         unplaced, level, (jnp.int32(0), table_keys, slot0, done0, ins0))
     failed = valid & ~done
     return table_keys, slot, inserted, failed
+
+
+def _insert_trips(table_keys, new_keys, count, max_probes):
+    """The insert loop of :func:`find_or_insert` over the first ``count``
+    of the keys given (the compacted misses, none of them in the table),
+    ``table.INSERT_CHUNK`` keys a trip: a level costs the trips that hold a
+    key, not the buffer. Levels outside, trips inside: within a level the
+    trips run in the keys' order and each sees the key array as the trips
+    before it left it, whose keys took their buckets' first free slots, so
+    a key's rank among its bucket's contenders, its slot and the level at
+    which it overflows are :func:`_insert_levels`' over the whole buffer.
+    The key array is carried through both loops and written where it is.
+    ``(key array, slot [m] (-1: not placed), keys walked)``."""
+    capacity = table_keys.shape[0]
+    m = new_keys.shape[0]
+    chunk = min(table_lib.INSERT_CHUNK, m)
+    bsz, _nb, chain = table_layout(capacity, max_probes)
+    # whole chunks only, as the find pads
+    short = -m % chunk
+    new_keys = jnp.pad(new_keys,
+                       [(0, short)] + [(0, 0)] * (new_keys.ndim - 1))
+    b0 = probe_starts(new_keys, capacity, max_probes) // bsz
+    valid = jnp.arange(m + short, dtype=jnp.int32) < count
+    place_level = _level_placer(capacity, chunk, max_probes)
+    trips = (count + (chunk - 1)) // chunk
+
+    def level(carry):
+        j, keys_arr, slot, done = carry
+
+        def trip(i, walked):
+            keys_arr, slot, done = walked
+            keys_i, b0_i, valid_i, slot_i, done_i = (
+                lax.dynamic_slice_in_dim(x, i * chunk, chunk)
+                for x in (new_keys, b0, valid, slot, done))
+            keys_arr, slot_i, done_i, _ = place_level(
+                keys_arr, keys_i, b0_i + j, valid_i, slot_i, done_i)
+            return (keys_arr,) + tuple(
+                lax.dynamic_update_slice_in_dim(x, part, i * chunk, 0)
+                for x, part in ((slot, slot_i), (done, done_i)))
+
+        return (j + 1,) + lax.fori_loop(0, trips, trip,
+                                        (keys_arr, slot, done))
+
+    def unplaced(carry):
+        j, _keys_arr, _slot, done = carry
+        return (j < chain) & ~jnp.all(done)
+
+    levels, table_keys, slot, _ = lax.while_loop(
+        unplaced, level,
+        (jnp.int32(0), table_keys, jnp.full((m + short,), -1, jnp.int32),
+         ~valid))
+    return table_keys, slot[:m], levels * trips * chunk
 
 
 def insert_rows(state: HashTableState,

@@ -53,6 +53,10 @@ APPLY_CHUNK = 4096
 # (hash_table.find_or_insert); fixed the same way.
 FIND_CHUNK = 4096
 
+# Missed keys one trip of the hash push's compact insert loop places
+# (hash_table.find_or_insert); fixed the same way.
+INSERT_CHUNK = 1024
+
 
 def occupied_prefix(mask: jnp.ndarray) -> jnp.ndarray:
     """One past the last set position of ``mask`` [n], 0 where none is set:

@@ -334,6 +334,7 @@ def test_wide_keys_insert_rows_and_find():
 TP_N = 2048                     # keys a call; insert_width(TP_N) == 1024
 TP_CAPACITY = 1 << 14           # 128 buckets, a chain of two
 TP_BUCKET = 40                  # the start bucket the contended cases fill
+TP_TRIP = 128                   # table.INSERT_CHUNK, set small for the trips
 
 
 def _level_loop_oracle(table_keys, new_keys, valid, max_probes):
@@ -384,11 +385,20 @@ def _level_loop_oracle(table_keys, new_keys, valid, max_probes):
     return table_keys, slot, inserted, valid & ~done
 
 
+def _assert_same_call(got, want):
+    """Two ``find_or_insert`` results, equal bit for bit."""
+    for name, g, w in zip(("keys", "slot", "inserted", "failed"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+
+
 @functools.lru_cache(maxsize=None)
 def _tp_table(wide):
     """(table keys holding ``present``, present [TP_N], fresh [TP_N],
-    contending [400]): distinct keys; the last all start at bucket
-    TP_BUCKET, whose chain holds 256."""
+    contending [400], neighbours [40]): distinct keys; the contending all
+    start at bucket TP_BUCKET, whose chain holds 256, the neighbours (in
+    no table and no other list) at the bucket after it, where that chain
+    ends."""
     rng = np.random.RandomState(7)
     if wide:
         pool = np.unique(rng.randint(1, 2**62, size=80000, dtype=np.int64))
@@ -405,30 +415,50 @@ def _tp_table(wide):
     crowd = pool[start == TP_BUCKET][:400]
     assert len(crowd) == 400
     rest = pool[start != TP_BUCKET]
+    beside = rest[start[start != TP_BUCKET] == TP_BUCKET + 1]
+    beside = np.setdiff1d(beside, rest[:2 * TP_N])[:40]
+    assert len(beside) == 40
     present = as_keys(rest[:TP_N])
     shape = (TP_CAPACITY, 2) if wide else (TP_CAPACITY,)
     table = jnp.full(shape, ht.empty_key(jnp.int32), jnp.int32)
     table, slot, _, failed = _level_loop_oracle(
         table, present, jnp.ones((TP_N,), bool), ht.DEFAULT_MAX_PROBES)
     assert not bool(failed.any()) and bool((slot >= 0).all())
-    return table, present, as_keys(rest[TP_N:2 * TP_N]), as_keys(crowd)
+    return (table, present, as_keys(rest[TP_N:2 * TP_N]), as_keys(crowd),
+            as_keys(beside))
 
 
 def _tp_case(case, wide):
     """(table keys holding the present keys, the call's keys, valid,
     how many of them miss)."""
-    table, present, fresh, crowd = _tp_table(wide)
+    table, present, fresh, crowd, beside = _tp_table(wide)
     empty = ht.empty_key(jnp.int32)
     m = ht.insert_width(TP_N)
     assert m == 1024
     misses = {"none_missing": 0, "five_percent": 102, "exactly_m": m,
               "m_plus_1": m + 1, "all_missing": TP_N,
-              "bucket_overflow": 200, "window_full": 400}[case]
+              "bucket_overflow": 200, "window_full": 400,
+              "one_trip": TP_TRIP, "one_trip_and_one": TP_TRIP + 1,
+              "overflow_across_trips": 2 * TP_TRIP + 20,
+              "window_full_across_trips": 4 * TP_TRIP + 20}[case]
     new = fresh[:misses]
     if case == "bucket_overflow":   # 200 > the 128 slots of one bucket
         new = crowd[:200]
     if case == "window_full":       # 400 > the 256 slots of the chain
         new = crowd
+    # The misses keep their order in the compact buffer, so at TP_TRIP
+    # keys a trip: the first trip fills the bucket, the second holds the
+    # contenders that overflow, and a later one the keys that START where
+    # those overflow to. A level at a time the late keys take that
+    # bucket's first free slots and the overflowing ones the next; a trip
+    # at a time with every level inside it would hand them out the other
+    # way round.
+    if case == "overflow_across_trips":
+        new = jnp.concatenate([crowd[:200], fresh[:2 * TP_TRIP - 200],
+                               beside[:20]])
+    if case == "window_full_across_trips":
+        new = jnp.concatenate([crowd, fresh[:4 * TP_TRIP - 400],
+                               beside[:20]])
     # the misses spread among the hits, one invalid key in the call
     keys = np.asarray(present).copy()
     at = np.random.RandomState(3).permutation(TP_N)[:misses]
@@ -454,9 +484,7 @@ def test_two_phase_find_or_insert_equals_level_loop(case, wide):
     table, keys, valid, misses = _tp_case(case, wide)
     want = _level_loop_oracle(table, keys, valid, ht.DEFAULT_MAX_PROBES)
     got = jax.jit(ht.find_or_insert)(table, keys, valid)
-    for name, g, w in zip(("keys", "slot", "inserted", "failed"), got, want):
-        assert g.dtype == w.dtype and g.shape == w.shape, name
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    _assert_same_call(got, want)
     _, slot, inserted, failed = map(np.asarray, got)
     assert int((inserted | failed).sum()) == misses
     if case == "bucket_overflow":
@@ -475,10 +503,56 @@ def test_two_phase_find_or_insert_equals_level_loop(case, wide):
         assert not failed.any()
 
 
-@pytest.mark.parametrize("case,compact,full", [
-    ("five_percent", 1, 0), ("exactly_m", 1, 0), ("m_plus_1", 0, 1),
-    ("all_missing", 0, 1)])
-def test_find_or_insert_counts_its_branch(case, compact, full):
+# case -> (trips a level, levels) of the compact loop at TP_TRIP keys a trip
+TRIP_CASES = {"none_missing": (0, 0), "five_percent": (1, 1),
+              "one_trip": (1, 1), "one_trip_and_one": (2, 1),
+              "exactly_m": (8, 1), "m_plus_1": (0, 0),
+              "bucket_overflow": (2, 2), "window_full": (4, 2),
+              "overflow_across_trips": (3, 2),
+              "window_full_across_trips": (5, 2)}
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["narrow", "wide"])
+@pytest.mark.parametrize("case", TRIP_CASES)
+def test_compact_insert_walks_its_misses_in_trips(monkeypatch, case, wide):
+    """The compact loop places the misses a trip of ``table.INSERT_CHUNK``
+    keys at a time, level by level: no trip, one, one exactly full, several,
+    all the buffer holds, and contenders for one bucket on both sides of a
+    trip's end. Keys, slots, inserted and failed are the full-width loop's
+    bit for bit, and the loop walks the trips that hold a miss, once a
+    level."""
+    from test_table import recorded
+    monkeypatch.setattr(ht.table_lib, "INSERT_CHUNK", TP_TRIP)
+    table, keys, valid, misses = _tp_case(case, wide)
+    want = _level_loop_oracle(table, keys, valid, ht.DEFAULT_MAX_PROBES)
+    got, stats = recorded(lambda: jax.jit(
+        lambda t, k, v: ht.find_or_insert(t, k, v, record_stats=True))(
+            table, keys, valid))
+    _assert_same_call(got, want)
+    trips, levels = TRIP_CASES[case]
+    assert trips == (-(-misses // TP_TRIP) if misses <= 1024 else 0)
+    assert stats.get("hash_insert_keys_walked", 0) == levels * trips * TP_TRIP
+    assert stats["hash_insert_missed"] == misses
+    _, slot, inserted, failed = map(np.asarray, got)
+    assert int((inserted | failed).sum()) == misses
+    if "across_trips" in case:
+        # the keys in their compact order: the crowd, filler, the late keys
+        new = slot[np.flatnonzero(inserted | failed)]
+        crowd = new[:200 if case == "overflow_across_trips" else 400]
+        late = new[-20:]
+        beyond = crowd[crowd >= (TP_BUCKET + 1) * ht.BUCKET]
+        assert (crowd[:128] // ht.BUCKET == TP_BUCKET).all()
+        assert (late // ht.BUCKET == TP_BUCKET + 1).all()
+        assert len(beyond) >= 72 and late.max() < beyond.min()
+        assert (crowd[128:] < 0).sum() == failed.sum() == \
+            (0 if case == "overflow_across_trips" else 400 - 128 - len(beyond))
+
+
+@pytest.mark.parametrize("case,compact,full,walked", [
+    ("five_percent", 1, 0, 1024), ("exactly_m", 1, 0, 1024),
+    ("m_plus_1", 0, 1, 0), ("all_missing", 0, 1, 0),
+    ("bucket_overflow", 1, 0, 2048)])
+def test_find_or_insert_counts_its_branch(case, compact, full, walked):
     from openembedding_tpu.utils import observability
     table, keys, valid, misses = _tp_case(case, True)
     fn = jax.jit(lambda t, k, v: ht.find_or_insert(t, k, v,
@@ -492,12 +566,15 @@ def test_find_or_insert_counts_its_branch(case, compact, full):
         observability.set_evaluate_performance(False)
     got = observability.GLOBAL.snapshot()
     observability.GLOBAL.reset()
-    # the find of a call of one chunk or less walks the call
+    # the find of a call of one chunk or less walks the call, and a buffer
+    # of one trip or less is walked whole, once a level
     assert {k: int(got.get(k, {}).get("count", 0)) for k in (
         "hash_insert_compact", "hash_insert_full", "hash_insert_missed",
+        "hash_insert_keys_walked",
         "hash_find_slots_live", "hash_find_slots_walked")
     } == {"hash_insert_compact": compact, "hash_insert_full": full,
           "hash_insert_missed": misses,
+          "hash_insert_keys_walked": walked,
           "hash_find_slots_live": int(valid.sum()),
           "hash_find_slots_walked": TP_N}
 
@@ -510,22 +587,23 @@ def test_find_or_insert_default_program_has_no_host_callback(monkeypatch):
         return jax.jit(lambda t, k, v: ht.find_or_insert(t, k, v, **kw)
                        ).lower(table, keys, valid).compile().as_text()
 
-    # the two insert loops, chosen by what they are given to do and by no
+    # the two insert loops (the compact one holds its loop over the trips
+    # of a level), chosen by what they are given to do and by no
     # conditional (which would copy the key array); a find of one chunk
     # or less is one pass and no loop
     default = compiled(keys, valid)
     contracts.check_no_host_transfers(default)
-    assert default.count(" while(") == 2 and " conditional(" not in default
+    assert default.count(" while(") == 3 and " conditional(" not in default
     recording = compiled(keys, valid, record_stats=True)
-    assert contracts.host_transfer_ops(recording) == ["host-callback"] * 5
+    assert contracts.host_transfer_ops(recording) == ["host-callback"] * 6
     # a call no wider than the buffer is the loop alone
     small = compiled(keys[:1024], valid[:1024])
     assert small.count(" while(") == 1
-    # wider than a chunk, the find is a third loop, and records as little
+    # wider than a chunk, the find is one more loop, and records as little
     monkeypatch.setattr(ht.table_lib, "FIND_CHUNK", FIND_CHUNK)
     chunked = compiled(keys, valid)
     contracts.check_no_host_transfers(chunked)
-    assert chunked.count(" while(") == 3 and " conditional(" not in chunked
+    assert chunked.count(" while(") == 4 and " conditional(" not in chunked
 
 
 # --- the chunked find (find_or_insert's first phase) -------------------------
@@ -541,7 +619,7 @@ def _find_case(mask, wide):
     """(table keys, the call's keys, valid): a twentieth of the keys are
     not in the table, and the slots the mask leaves out hold real keys,
     absent ones among them, which a find must neither report nor place."""
-    table, present, fresh, _crowd = _tp_table(wide)
+    table, present, fresh, _crowd, _beside = _tp_table(wide)
     keys = np.asarray(present)[:FIND_N].copy()
     at = np.random.RandomState(5).permutation(FIND_N)[:FIND_N // 20]
     keys[at] = np.asarray(fresh)[:len(at)]
@@ -565,9 +643,7 @@ def test_chunked_find_equals_level_loop(monkeypatch, mask, wide):
     assert ht.insert_width(FIND_N) < FIND_N     # the call has a find phase
     want = _level_loop_oracle(table, keys, valid, ht.DEFAULT_MAX_PROBES)
     got = jax.jit(ht.find_or_insert)(table, keys, valid)
-    for name, g, w in zip(("keys", "slot", "inserted", "failed"), got, want):
-        assert g.dtype == w.dtype and g.shape == w.shape, name
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    _assert_same_call(got, want)
     _, slot, inserted, failed = map(np.asarray, got)
     valid = np.asarray(valid)
     assert not failed.any() and (slot[~valid] == -1).all()
@@ -615,17 +691,15 @@ def test_a_find_handed_over_is_not_made_again(monkeypatch, mask, width,
     handed = jax.jit(lambda t, k, v, f: ht.find_or_insert(
         t, k, v, record_stats=True, found=f))
     got, stats = recorded(lambda: handed(table, keys, valid, found))
-    for name, g, w in zip(("keys", "slot", "inserted", "failed"), got, want):
-        assert g.dtype == w.dtype and g.shape == w.shape, name
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+    _assert_same_call(got, want)
     assert stats["hash_insert_missed"] == int(np.asarray(want[2]).sum())
     loops = lambda fn, *args: fn.lower(table, keys, valid, *args
                                        ).compile().as_text().count(" while(")
     if width == "chunked_call":
         assert stats["hash_find_slots_walked"] == 0
         assert stats["hash_find_slots_live"] == int(np.asarray(valid).sum())
-        assert loops(handed, found) == 2 and \
-            loops(jax.jit(ht.find_or_insert)) == 3
+        assert loops(handed, found) == 3 and \
+            loops(jax.jit(ht.find_or_insert)) == 4
     else:
         assert loops(handed, found) == 1
 

@@ -430,23 +430,27 @@ def test_the_routed_hash_push_has_no_table_in_its_conditional(devices8,
 
 
 # sha256 of the one-plan step of the benchmark's rehearsal configurations,
-# lowered on the CPU backend at the parent of PR 40 (4d7b794): the six
-# one-chip cells' steps (the saving cells run the array and the hash
-# step) and the array step routed over 2x2 are the text they were; the
-# cached, grouped, pipelined and ``int8_ef`` steps are held by
-# ``tests/test_plan.py``'s own pins, which stand. The routed hash step is
-# the one that changed: 541,620 characters of text at the parent.
+# lowered on the CPU backend. The array step on one chip (the saving cells
+# run it too) and routed over 2x2 are the text they were at the parent of
+# PR 40 (4d7b794); the cached, grouped, pipelined and ``int8_ef`` steps
+# are held by ``tests/test_plan.py``'s own pins, which stand. The hash
+# steps are as PR 41 left them: their compact insert loop walks its
+# misses in trips (``hash_table._insert_trips``), and nothing else of
+# them changed (the one-chip hash step 254,067 -> 267,241 characters).
+# The routed hash step was 541,620 characters at the parent of PR 40.
 _PARENT_TEXTS = {
     "tiny_array":
     "4ca88b7d0911ec21e52b2c57ad5bbab94e1fa9a64e57a4698f62065e213053f7",
-    "tiny_hash":
-    "02c24b81487e4d308ba7b34f8b52648f10d418d711bd5951aa331f89fec651d9",
     "tiny_array_x4":
     "8b1a36f64fc673c9b5ee3c88379cc06419e810e46cc703bf188a058462504f89",
+    "tiny_hash":
+    "123b2a344528d98a0baeceaa87ed0dee27c78de974505048ad167e489a6dcb98",
     "tiny_offload":
-    "5e8e138440ed7bb20f76004d9234775a4ea73fe3c7aff94711e7c6bba9c65b0f",
+    "a51d0d990f5c725ade306314a6f60a782be34cafe6ad5e14e6aaa6f93eab05ff",
     "tiny_hash_offload":
-    "ebcaf2c431ec5f9314113d993c446f3691f808e037348939a722639ee710af05"}
+    "f71db69caf2cce5bd348946efb886b76effe8aef29b20e45f7cb3622c38a7ad6",
+    "tiny_hash_x4":
+    "bb8ad2d9bae0b7fd1bb2b6357c09d0a02448b38ea14d49bef22d85db2c9273a3"}
 _PARENT_HASH_X4 = \
     "cba1bc0249f5329d0a49a09487298525c572407eaa9530e95383fbfdee969d66"
 

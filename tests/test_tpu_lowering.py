@@ -139,17 +139,19 @@ def test_v5e_step_keeps_its_stage_names(v5e, use_hash):
 
 @pytest.mark.parametrize("shape", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
 def test_v5e_hash_push_finds_then_inserts_in_place(v5e, shape):
-    """A push that finds for itself holds three loops under ``probe``: the
-    find, the insert loop over the buffer of misses and the one over the
-    whole call. Which of the two places keys is decided by what each is
-    given, under no conditional of the probe's own: through one the chip's
-    compiler copies the key array, and on one chip no copy of it is left.
-    The find is the pull's, over the distinct keys of the step's plan, one
-    loop a table, and the push, which takes the slots it found
-    (``dedup.Resolution``), holds the two insert loops alone: two loops a
-    table fewer than a push that finds again. Behind the exchange the
-    owner's push is that push in the routed branch (two loops a table) and
-    the push that finds for itself in the gathered one (three)."""
+    """A push that finds for itself holds three outermost loops under
+    ``probe``: the find, the insert loop over the buffer of misses and the
+    one over the whole call. Which of the two places keys is decided by
+    what each is given, under no conditional of the probe's own: through
+    one the chip's compiler copies the key array, and no copy of it is
+    left, on one chip or on four. The loop over the buffer holds one loop
+    more, the trips of a level, ``table.INSERT_CHUNK`` misses each: the
+    key array goes through both where it is. On one chip the find is the
+    pull's, over the distinct keys of the step's plan, one loop a table,
+    and the push, which takes the slots it found (``dedup.Resolution``),
+    holds the two insert loops alone. Behind the exchange the owner's one
+    find-or-insert a table follows the push's conditional: its find walks
+    the keys no pull resolved (three outermost loops a table)."""
     data, model = shape
     mesh = create_mesh(data, model, v5e[:data * model])
     hlo = _compile_deepfm_step(mesh, use_hash=True).as_text()
@@ -158,17 +160,23 @@ def test_v5e_hash_push_finds_then_inserts_in_place(v5e, shape):
     found = [m.groups() for m in map(_OPCODE.match, hlo.splitlines()) if m]
     loops = [inst for inst, op in found
              if op == "while" and stages.get(inst) == "probe"]
+    trips = [inst for inst in loops
+             if paths[inst].endswith("/probe/while/body/while")]
+    assert len(trips) == 2 and all(
+        "hash_push_a2a" in paths[inst] for inst in trips), trips
+    loops = [inst for inst in loops if inst not in trips]
+    assert all(paths[inst].endswith("/probe/while") for inst in loops)
     pushing = [inst for inst in loops if "hash_push_a2a" in paths[inst]]
     assert not re.search(r'conditional\(.*op_name="[^"]*jit\(probe\)/cond',
                          hlo)
     if mesh.size > 1:
-        assert len(pushing) == 10 and len(loops) == 12, loops   # two tables
+        assert len(pushing) == 6 and len(loops) == 8, loops     # two tables
     else:
         assert len(pushing) == 4 and len(loops) == 6, loops
-        keys = f"s32[{HASH_CAPACITY},2]"
-        copies = [line.strip()[:120] for line in hlo.splitlines()
-                  if f"= {keys}" in line and " copy(" in line]
-        assert not copies, copies
+    keys = f"s32[{HASH_CAPACITY // mesh.size},2]"
+    copies = [line.strip()[:120] for line in hlo.splitlines()
+              if f"= {keys}" in line and " copy" in line]
+    assert not copies, copies
 
 
 def test_v5e_hash_finds_walk_chunks_in_place(v5e):
