@@ -12,7 +12,9 @@ import jax
 import numpy as np
 
 from . import autosave_system
-from .autosave_system import counts as _counts, delta, mark, save  # noqa: F401
+from .autosave_system import (  # noqa: F401
+    compaction_budget, counts as _counts, delta, join_compactor, mark, save,
+    save_last)
 
 KEYS_ABSENT = "ckpt_delta_keys_absent"
 SNAPSHOT_SPANS = ("ckpt.claim", "ckpt.stage")

@@ -88,6 +88,41 @@ def save(system, state, path, step):
         step=step)
 
 
+def save_last(system, state, path, step):
+    """The runner's own save, with the clock stopped, of the steps after
+    the last in-window save: the delta save's own call under a budget no
+    chain meets, so that it starts no fold. It is no save of the
+    deployment: it brings the chain level with the live table for the
+    comparison, and a fold of the cell's 6.54 GB base would hold the
+    machine 140 s with the chip idle and write 6.5 GB more to its disk in
+    every run (my chip run, PR 43)."""
+    import sys
+    from openembedding_tpu import checkpoint_delta
+    return checkpoint_delta.save_delta(
+        path, system.coll, state.emb, step=step,
+        dense_state=(state.params, state.opt_state),
+        compact_chain_len=sys.maxsize, compact_bytes_ratio=float("inf"))
+
+
+def join_compactor(path):
+    """Wait until no fold of ``path`` runs (the program folds a chain into
+    a new base on a background thread once a save meets its budget), and
+    raise what a fold failed with: after this, and until the next save,
+    nothing writes the directory."""
+    from openembedding_tpu import checkpoint_delta
+    checkpoint_delta.join_compactor(path)
+
+
+def compaction_budget():
+    """(entries, share of the base's bytes) at which a save of the program
+    starts a fold, as its saves' defaults stand."""
+    import inspect
+    from openembedding_tpu import checkpoint_delta
+    defaults = inspect.signature(checkpoint_delta.begin_delta).parameters
+    return (defaults["compact_chain_len"].default,
+            defaults["compact_bytes_ratio"].default)
+
+
 def mark(system, batch):
     """Mark a program batch's rows dirty, as a custom loop does."""
     system.coll.mark_dirty(batch["sparse"])

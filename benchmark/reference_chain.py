@@ -51,6 +51,14 @@ def fields(path, vid):
         os.path.join(path, variables(path)[vid])) if f.endswith(".npy"))
 
 
+def base_bytes(path):
+    """Bytes of the base's field files, every variable."""
+    return sum(os.path.getsize(os.path.join(path, directory, f))
+               for directory in variables(path).values()
+               for f in os.listdir(os.path.join(path, directory))
+               if f.endswith(".npy"))
+
+
 def _vid_of(record):
     return int(record["file"].rsplit("_", 1)[1].split(".")[0])
 
@@ -80,11 +88,11 @@ def replayed(path, vid, field, entries=None):
     return rows
 
 
-def entry_rows(path):
-    """[{variable id: rows}] of each committed entry, as its file holds
-    them (not as the manifest says)."""
+def entry_rows(path, first=0):
+    """[{variable id: rows}] of each committed entry from the chain's
+    ``first`` on, as its file holds them (not as the manifest says)."""
     out = []
-    for entry in manifest(path)["chain"]:
+    for entry in manifest(path)["chain"][first:]:
         counts = {}
         for record in entry["vars"].values():
             with np.load(os.path.join(path, record["file"])) as payload:

@@ -123,11 +123,11 @@ def replayed(path, vid, field, index, entries=None):
     return rows
 
 
-def entry_keys(path):
-    """[{variable id: keys}] of each committed entry, as its file holds
-    them (not as the manifest says)."""
+def entry_keys(path, first=0):
+    """[{variable id: keys}] of each committed entry from the chain's
+    ``first`` on, as its file holds them (not as the manifest says)."""
     out = []
-    for entry in manifest(path)["chain"]:
+    for entry in manifest(path)["chain"][first:]:
         counts = {}
         for record in entry["vars"].values():
             with np.load(os.path.join(path, record["file"])) as payload:

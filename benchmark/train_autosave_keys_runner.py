@@ -12,11 +12,15 @@ What differs from ``train_autosave_runner.py``, whose steps these are:
    keys, and marking ahead of the push inserts nothing): the save has to
    leave exactly those out (``counts_chain_keys.held_after`` says which,
    from the ranks of the raw batches) and count them.
-3. The window stays in the pool's first pass, so every in-window save
-   carries keys that training inserted since the save before.
-4. The chain is replayed BY KEY (``reference_chain_keys.py``) and held to
-   the live table, read back slot by slot: ``chain_mismatch_rows`` (keys
-   whose weights or any accumulator differ in any bit),
+3. The window's ``lead_in_steps`` + ``window_periods`` periods lie in the
+   pool's first pass but for their last steps (the traffic file says how
+   many), so every in-window save carries keys that training inserted
+   since the save before.
+4. In the array runner's order (the compactor joined, the window's
+   entries counted, then the runner's own save of the last steps, which
+   asks for no fold) the chain is replayed BY KEY (``reference_chain_keys.py``) and
+   held to the live table, read back slot by slot: ``chain_mismatch_rows``
+   (keys whose weights or any accumulator differ in any bit),
    ``chain_missing_keys`` (live keys the replay lacks),
    ``chain_extra_keys`` (replayed keys the table lacks) and
    ``chain_rows_off`` (entries whose key count is not the distinct keys
@@ -37,19 +41,10 @@ import jax
 from . import (autosave_keys_system as keys_system, correct,
                counts_chain_keys, reference, reference_chain_keys,
                system as system_lib)
-from .train_autosave_runner import PeriodFeed, _to_the_end, fed
+from .train_autosave_runner import (_to_the_end, entries_off, fed,
+                                    save_the_tail, settle, window_batches)
 from .train_runner import (CompileCounter, Feed, FOLLOWED_STEPS, OUT_DIR,
                            _followed)
-
-
-def keys_off(path, expected):
-    """Entries of the chain whose files do not hold, for every variable,
-    ``expected[entry]`` keys."""
-    held = reference_chain_keys.entry_keys(path)
-    if len(held) != len(expected):
-        return max(len(held), len(expected))
-    return sum(1 for keys, want in zip(held, expected)
-               if not keys or any(n != want for n in keys.values()))
 
 
 def chain_faults(path, system, emb, entries=None):
@@ -87,8 +82,9 @@ def run(cell, config, traffic, inputs, *, seed, seconds, trace, t_process,
         on_device, plant=None):
     """One run of a hash autosave cell; returns the result line as a dict
     (and a ``context`` for the per-layer readers under ``"_context"``), as
-    ``train_runner.run`` does. ``plant(system)`` plants a fault before
-    anything trains (``autosave_keys_controls``)."""
+    ``train_runner.run`` does. ``seconds`` is not read: the window's
+    length is the configuration's ``window_periods``. ``plant(system)``
+    plants a fault before anything trains (``autosave_keys_controls``)."""
     counter = CompileCounter()
 
     def mark(phase):
@@ -110,9 +106,9 @@ def run(cell, config, traffic, inputs, *, seed, seconds, trace, t_process,
     probe = jax.jit(lambda x: x + 1)
     lag = trainer.pipeline_depth + 1
 
-    def feed_of(batches, steps=None, cls=Feed, **kw):
-        return cls(batches, probe, lag=lag,
-                   in_flight=traffic["steps_in_flight"], steps=steps, **kw)
+    def feed_of(batches, steps=None, **kw):
+        return Feed(batches, probe, lag=lag,
+                    in_flight=traffic["steps_in_flight"], steps=steps, **kw)
 
     raw_first = raw_pool[:FOLLOWED_STEPS]
     state, prog = _followed(system, trainer, state, feed_of, raw_first,
@@ -124,19 +120,20 @@ def run(cell, config, traffic, inputs, *, seed, seconds, trace, t_process,
     mark("warm")
     ckpt_dir = keys_system.save_dir(config, OUT_DIR)
     try:
-        return _window(cell, config, traffic, seed, seconds, trace,
-                       t_process, on_device, counter, mark, system, state,
-                       raw_pool, pool, feed_of, prog, ckpt_dir)
+        return _window(cell, config, traffic, seed, trace, t_process,
+                       on_device, counter, mark, system, state, raw_pool,
+                       pool, feed_of, prog, ckpt_dir)
     finally:
         shutil.rmtree(ckpt_dir, ignore_errors=True)
 
 
-def _window(cell, config, traffic, seed, seconds, trace, t_process,
-            on_device, counter, mark, system, state, raw_pool, pool,
-            feed_of, prog, ckpt_dir):
+def _window(cell, config, traffic, seed, trace, t_process, on_device,
+            counter, mark, system, state, raw_pool, pool, feed_of, prog,
+            ckpt_dir):
     """The base and the warm save, the window, the chain's comparison."""
     every = config["checkpoint"]["autosave_every"]
     lead_in = traffic["lead_in_steps"]
+    to_hand, traced_from = window_batches(config, traffic)
     trainer = system.trainer
     trained = FOLLOWED_STEPS + traffic["warmup_steps"]
     base = keys_system.save(system, state, ckpt_dir, trained)
@@ -165,14 +162,14 @@ def _window(cell, config, traffic, seed, seconds, trace, t_process,
 
     from openembedding_tpu.utils import observability
     trace_dir = os.path.join(OUT_DIR, f"{cell}.{seed}.trace")
-    at_seconds = None
+    trace_at = None
     if trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 2
-        at_seconds = (
-            max(seconds - traffic["trace_seconds"], 0.0),
+        trace_at = (
+            traced_from,
             lambda: jax.profiler.start_trace(trace_dir,
                                              profiler_options=options))
 
@@ -186,9 +183,8 @@ def _window(cell, config, traffic, seed, seconds, trace, t_process,
         at_start["compiles"] = counter.count
         at_start["saves"] = keys_system.counts()
 
-    feed = feed_of(pool, cls=PeriodFeed, seconds=seconds, lead_in=lead_in,
-                   on_start=on_start, at_seconds=at_seconds)
-    feed.period = every
+    feed = feed_of(pool, to_hand, lead_in=lead_in, on_start=on_start,
+                   at_step=trace_at)
     with jax.profiler.TraceAnnotation("benchmark.fit"):
         state, last = trainer.fit(state, feed, autosave_every=every,
                                   autosave_dir=ckpt_dir)
@@ -222,39 +218,45 @@ def _window(cell, config, traffic, seed, seconds, trace, t_process,
 
     peaks = [d.memory_stats() for d in system.mesh.devices.flat]
     memory_peak = max((p or {}).get("peak_bytes_in_use", 0) for p in peaks)
-    # the chain as the window left it, held to the guarantees
+    # the chain as the window left it, held to the guarantees: no fold
+    # runs under a reader, and none ran under the window
     saved_at = list(range(every, feed.handed + 1, every))
     expected += [counts_chain_keys.distinct_keys(
         fed(raw_pool, at - every, at)) for at in saved_at]
-    if feed.handed > (saved_at[-1] if saved_at else 0):
-        keys_system.save(system, state, ckpt_dir, trained + feed.handed)
-        expected.append(counts_chain_keys.distinct_keys(
-            fed(raw_pool, saved_at[-1] if saved_at else 0, feed.handed)))
+    settle(keys_system, ckpt_dir, made=len(expected),
+           made_bytes=int(warm["bytes"] + saves["ckpt_delta_bytes"]))
+    off = entries_off(reference_chain_keys.entry_keys(ckpt_dir), expected)
+    extra = [("insert_failures",
+              system_lib.insert_failures(system, state.emb), 0)]
+    late = None
+    if config.get("rehearsal"):
+        # the first in-window entry (the chain's second) against the
+        # table at its own step, while the chain still lists it
+        late = at_step(config, seed, on_device, raw_pool, traffic,
+                       saved_at[0], ckpt_dir, entry=2)
+    expected.append(counts_chain_keys.distinct_keys(
+        fed(raw_pool, saved_at[-1], feed.handed)))
+    off += save_the_tail(keys_system, reference_chain_keys.entry_keys,
+                         system, state, ckpt_dir, trained + feed.handed,
+                         len(expected) - 1, expected[-1])
     mark("window")
     found = chain_faults(ckpt_dir, system, state.emb)
-    off = keys_off(ckpt_dir, expected)
     mark("chain_compared")
-    extra = [("insert_failures",
-              system_lib.insert_failures(system, state.emb), 0),
-             ("chain_mismatch_rows", found["mismatch_rows"], 0),
-             ("chain_missing_keys", found["missing_keys"], 0),
-             ("chain_extra_keys", found["extra_keys"], 0),
-             ("chain_rows_off", off, 0)]
+    extra += [("chain_mismatch_rows", found["mismatch_rows"], 0),
+              ("chain_missing_keys", found["missing_keys"], 0),
+              ("chain_extra_keys", found["extra_keys"], 0),
+              ("chain_rows_off", off, 0)]
+    if late is not None:
+        extra.append(("chain_late_rows", late, 0))
     step_hlo = snapshot_hlo = None
     if trace:       # kept beside the trace: they name its operations
         step_hlo = system_lib.step_hlo(system, state, pool[0])
         with open(os.path.join(OUT_DIR, f"{cell}.{seed}.step.hlo.txt"),
                   "w") as f:
             f.write(step_hlo)
-        snapshot_hlo = keys_system.snapshot_hlo(
-            system, state.emb, expected[1] if saved_at else expected[0])
+        snapshot_hlo = keys_system.snapshot_hlo(system, state.emb,
+                                                expected[1])
     del state, last, pool                      # the tables leave the device
-    if config.get("rehearsal") and saved_at:
-        # the first in-window entry (the chain's second) against the
-        # table at its own step
-        extra.append(("chain_late_rows", at_step(
-            config, seed, on_device, raw_pool, traffic, saved_at[0],
-            ckpt_dir, entry=2), 0))
     ref = reference.follow(seed, config, raw_pool[:FOLLOWED_STEPS])
     values, where = correct.numbers(prog, ref)
     ok, compared = correct.decide(values, config["limits"], extra=extra)

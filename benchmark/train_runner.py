@@ -40,11 +40,14 @@ class Feed:
     a one-element program it queued behind an earlier step. The wait's
     return stamps that step's completion on the host clock. Stops after
     ``steps`` batches, or when the steps already handed out will fill
-    ``seconds``.
+    ``seconds``. One call (the profiler's start) is made on the way: where
+    the steps handed out will reach ``at_seconds``, or, for a window whose
+    length is a count of steps, before batch ``at_step`` is handed out.
     """
 
     def __init__(self, pool, probe, *, lag, in_flight, steps=None,
-                 seconds=None, lead_in=0, on_start=None, at_seconds=None):
+                 seconds=None, lead_in=0, on_start=None, at_seconds=None,
+                 at_step=None):
         self.pool, self.probe = pool, probe
         self.lag = lag              # steps between a batch's hand-out and
         self.in_flight = in_flight  # its dispatch: fit's lookahead window
@@ -53,11 +56,12 @@ class Feed:
         # whatever the first steps of a call to fit cost stays outside
         self.lead_in, self.on_start = lead_in, on_start
         self.at_seconds = at_seconds    # (seconds, callable), called once
+        self.at_step = at_step          # (batches handed out, callable)
         self.done = []              # host-clock completion time per step
         self.handed = 0
         self.waited_s = 0.0
         self.started = None         # the window's start, on the host clock
-        self.called_at = None       # (start, end) of the at_seconds call
+        self.called_at = None       # (start, end) of that one call
 
     def _step_s(self):
         recent = self.done[-21:]
@@ -69,6 +73,12 @@ class Feed:
         self.started = now
         if self.on_start:
             self.on_start()
+
+    def _call(self, call, now):
+        call()
+        self.called_at = (now - self.started,
+                          time.perf_counter() - self.started)
+        self.waited_s += time.perf_counter() - now
 
     def __iter__(self):
         if not self.lead_in:
@@ -83,10 +93,10 @@ class Feed:
                     + (self.lag + self.in_flight) * self._step_s()
                 if self.at_seconds and ahead >= self.at_seconds[0]:
                     call, self.at_seconds = self.at_seconds[1], None
-                    call()
-                    self.called_at = (now - self.started,
-                                      time.perf_counter() - self.started)
-                    self.waited_s += time.perf_counter() - now
+                    self._call(call, now)
+                if self.at_step and self.handed >= self.at_step[0]:
+                    call, self.at_step = self.at_step[1], None
+                    self._call(call, now)
                 if self.seconds is not None and ahead >= self.seconds:
                     break
             token = self.probe(token)

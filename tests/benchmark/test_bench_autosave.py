@@ -2,8 +2,15 @@
 configuration is the array cell's plus the deployment, ``tiny_array_ckpt``
 rehearses the cell end to end, the three planted faults come out as not
 correct, the checkpoint's plain reference imports nothing of the program,
-and the new readers reduce what a run leaves them."""
+and the new readers reduce what a run leaves them. Since PR 43 also: the
+window is a stated number of save periods whatever ``--seconds`` says, a
+traced run starts the profiler by step count, and no comparison reads the
+directory under a fold (the program's compaction budgets are lowered here,
+in the test's own process, so that the runner's last save would meet them,
+which asks for no fold, or a save inside the window does, which ends the
+run in a line)."""
 
+import functools
 import json
 import os
 import subprocess
@@ -14,7 +21,7 @@ import numpy as np
 import pytest
 
 from benchmark import (autosave_controls, counts_chain, reference_chain, run,
-                       train_autosave_runner)
+                       train_autosave_runner, train_runner)
 from benchmark.metrics import _autosave
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -93,20 +100,54 @@ def test_the_checkpoints_reference_imports_nothing_of_the_program():
                        "import numpy as np"]
 
 
-def test_period_feed_closes_only_on_whole_periods():
-    class Probe:
-        def __call__(self, x):
-            return self
+class Probe:
+    def __call__(self, x):
+        return self
 
-        def block_until_ready(self):
-            return self
+    def block_until_ready(self):
+        return self
 
-    feed = train_autosave_runner.PeriodFeed(
-        list(range(7)), Probe(), lag=1, in_flight=2, seconds=0.0, lead_in=3)
-    feed.period = 5
+
+def test_the_window_is_the_configurations_periods_not_the_clocks():
+    assert not hasattr(train_autosave_runner, "PeriodFeed")
+    traffic = run.load("traffic", "train_zipf_autosave")
+    assert "trace_seconds" not in traffic and traffic["trace_periods"] == 1
+    # lead-in + periods x every handed out; the profiler before the last
+    assert train_autosave_runner.window_batches(
+        run.load("configs", "deepfm_dim9_array_ckpt"), traffic) \
+        == (24 + 1200, 24 + 1000)
+    assert train_autosave_runner.window_batches(
+        run.load("configs", "tiny_array_ckpt"), traffic) == (24 + 64, 24)
+    config = {"checkpoint": {"autosave_every": 5, "window_periods": 2}}
+    to_hand, traced_from = train_autosave_runner.window_batches(
+        config, {"lead_in_steps": 3, "trace_periods": 1})
+    assert (to_hand, traced_from) == (13, 8)
+    called = []
+    feed = train_runner.Feed(
+        list(range(7)), Probe(), lag=1, in_flight=2, steps=to_hand,
+        lead_in=3, at_step=(traced_from, lambda: called.append(
+            feed.handed)))
     handed = list(feed)
-    assert len(handed) == 3 + 5 and feed.handed - feed.lead_in == 5
+    assert len(handed) == 13 and feed.handed - feed.lead_in == 10
     assert handed[:8] == [0, 1, 2, 3, 4, 5, 6, 0]
+    assert called == [8] and feed.called_at is not None
+
+
+def test_the_step_counted_call_waits_for_the_window_to_open():
+    """A window of one period starts its trace at its first step: not
+    before the lead-in is done, which is when the clock starts."""
+    called = []
+    feed = train_runner.Feed(
+        list(range(7)), Probe(), lag=1, in_flight=2, steps=9, lead_in=3,
+        at_step=(3, lambda: called.append((feed.handed, feed.started))))
+    assert len(list(feed)) == 9
+    (at, started), = called
+    assert at >= 3 and started is not None and feed.called_at[0] >= 0
+    # the other cells' hook is as it was: by the clock, once
+    feed = train_runner.Feed(
+        list(range(7)), Probe(), lag=1, in_flight=2, seconds=0.0,
+        at_seconds=(0.0, lambda: called.append("by the clock")))
+    assert list(feed) == [] and called[-1] == "by the clock"
 
 
 def test_a_program_that_tracks_chunks_is_refused_before_the_tables():
@@ -132,16 +173,28 @@ def test_counts_of_a_save():
     assert counts_chain.gather_bytes(config, 1000) == 80_000
 
 
-@pytest.mark.parametrize("trace", ["0", "1"])
-def test_tiny_array_ckpt_runs_end_to_end_with_null_timings(trace):
+@functools.lru_cache(maxsize=None)
+def _tiny_run(seconds, trace):
+    """One run of the rehearsal, once a test process (the file's tests run
+    in one)."""
     env = {k: v for k, v in os.environ.items() if k != "BENCH_RUN"}
     env["JAX_PLATFORMS"] = "cpu"
-    # a window shorter than one period: it closes at the first one, so
-    # the run holds one save and the 24 lead-in steps' worth after it
-    out = subprocess.run(
+    return subprocess.run(
         [sys.executable, "-m", "benchmark.run", "--workload", TINY,
-         "--seed", str(SEED), "--seconds", "0.05", "--trace", trace],
+         "--seed", str(SEED), "--seconds", seconds, "--trace", trace],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+
+
+def _line_of(out, start):
+    return next(json.loads(text) for text in out.stdout.splitlines()
+                if text.startswith(start))
+
+
+@pytest.mark.parametrize("trace", ["0", "1"])
+def test_tiny_array_ckpt_runs_end_to_end_with_null_timings(trace):
+    # one period (``window_periods``): the run holds one save and the 24
+    # lead-in steps' worth after it
+    out = _tiny_run("0.05", trace)
     assert out.returncode == 0, out.stderr[-2000:]
     line = json.loads(out.stdout.splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0
@@ -171,6 +224,237 @@ def test_tiny_array_ckpt_runs_end_to_end_with_null_timings(trace):
                   if text.startswith('{"window_s"'))
     assert window["autosave"]["ckpt_delta_saves"] == 1
     assert window["autosave"]["trainer.autosave"]["calls"] == 1
+
+
+def test_the_window_holds_the_same_work_at_any_seconds():
+    """What ``--seconds`` decided before PR 43: 5 s were some thousand
+    steps of the rehearsal and a save every 64 of them."""
+    short, long = _tiny_run("0.05", "0"), _tiny_run("5", "0")
+    assert long.returncode == 0, long.stderr[-2000:]
+    lines = [json.loads(out.stdout.splitlines()[-1])
+             for out in (short, long)]
+    assert [line["attempted"] for line in lines] == [64, 64]
+    assert all(line["correct"] is True for line in lines)
+    windows = [_line_of(out, '{"window_s"') for out in (short, long)]
+    assert [w["steps"] for w in windows] == [64, 64]
+    assert [w["autosave"]["ckpt_delta_saves"] for w in windows] == [1, 1]
+    # the same entries: the warm save's, the window's, the last steps'
+    entries = [_line_of(out, '{"compared_at"')["entry_rows"]
+               for out in (short, long)]
+    assert entries[0] == entries[1] and len(entries[0]) == 3
+    tails = [_line_of(out, '{"tail_save"') for out in (short, long)]
+    assert tails[0] == tails[1] and tails[0]["chain_after"] == 3
+
+
+def test_the_traced_tail_holds_a_save_at_any_seconds():
+    """The profiler starts by step count: a traced run reports the
+    per-save metrics it reported when ``--seconds`` placed the trace."""
+    short, long = _tiny_run("0.05", "1"), _tiny_run("5", "1")
+    assert long.returncode == 0, long.stderr[-2000:]
+    metrics = [json.loads(out.stdout.splitlines()[-1])["metrics"]
+               for out in (short, long)]
+    assert set(metrics[0]) == set(metrics[1])
+    per_save = {"train_autosave_stall_ms_per_save",
+                "train_autosave_d2h_ms_per_save",
+                "train_autosave_write_ms_per_save",
+                "train_autosave_commit_lag_ms",
+                "train_autosave_rows_per_save",
+                "train_autosave_mb_per_save"}
+    assert per_save <= set(metrics[1])
+    for name in ("train_autosave_rows_per_save",
+                 "train_autosave_mb_per_save", "train_compiles_in_window"):
+        assert metrics[0][name] == metrics[1][name]
+    assert metrics[1]["train_autosave_rows_per_save"]["value"] > 0
+    # the profiler ran: it left its directory
+    assert os.path.isdir(os.path.join(
+        ROOT, "benchmark", "out", f"{TINY}.{SEED}.trace"))
+
+
+def _with_budget(monkeypatch, **budget):
+    """Lower the budgets the program's saves start a fold at, in this
+    process alone: its saves read them as ``begin_delta``'s defaults."""
+    from openembedding_tpu import checkpoint_delta
+    for name, value in budget.items():
+        monkeypatch.setitem(checkpoint_delta.begin_delta.__kwdefaults__,
+                            name, value)
+
+
+# the rehearsal's chain: 3.28 MB (warm), 3.28 MB (the window's), 1.47 MB
+# (the last steps') over a base of 34.1 MB
+@pytest.mark.parametrize("budget", [{"compact_chain_len": 3},
+                                    {"compact_bytes_ratio": 0.21}],
+                         ids=["entries", "bytes"])
+def test_the_runners_own_last_save_starts_no_fold(monkeypatch, capsys,
+                                                  budget):
+    """A budget that the last save's entry meets, and no save before it:
+    as the array cell's stands (its last save is the chain's eighth
+    entry). The runner's own save asks for no fold (a fold of the cell's
+    base holds the machine 140 s and writes 6.5 GB: PERF.md), so the
+    chain it leaves lists every entry and nothing writes the directory
+    under the comparison."""
+    _with_budget(monkeypatch, **budget)
+    assert autosave_controls.main(["tiny_array_ckpt", "none", str(SEED),
+                                   "0.05"]) == 0
+    out = capsys.readouterr().out.splitlines()
+    tail = next(json.loads(t) for t in out if t.startswith('{"tail_save"'))
+    assert tail["tail_save"]["compaction"] is None
+    assert tail["tail_save"]["seq"] == tail["chain_after"] == 3
+    result = json.loads(out[-1])
+    assert result["correct"] is True and result["attempted"] == 64
+    for name in ("chain_mismatch_rows", "chain_rows_off", "chain_late_rows"):
+        assert result["compared"][name] == {"value": 0, "limit": 0}
+
+
+@pytest.mark.parametrize("budget", [{"compact_chain_len": 2},
+                                    {"compact_bytes_ratio": 0.15}],
+                         ids=["entries", "bytes"])
+def test_a_budget_met_inside_the_window_ends_the_run_in_one_line(
+        monkeypatch, capsys, budget):
+    _with_budget(monkeypatch, **budget)
+    with pytest.raises(SystemExit) as refused:
+        autosave_controls.main(["tiny_array_ckpt", "none", str(SEED),
+                                "0.05"])
+    said = str(refused.value)
+    assert "\n" not in said and said.startswith("benchmark: ")
+    assert "lists 0 entries where the warm save and the window made 2" \
+        in said and "met the compactor's budget" in said
+    entries = budget.get("compact_chain_len", 8)
+    ratio = budget.get("compact_bytes_ratio", 0.5)
+    assert f"a chain of {entries} entries, or {ratio} of the base's " \
+        "34079232 bytes" in said
+    assert "(6553288 bytes)" in said and "window_periods" in said
+    assert '{"tail_save"' not in capsys.readouterr().out
+
+
+def _array_chain(tmp_path, base, entries):
+    """A chain directory of one array variable in the documented layout,
+    written by hand."""
+    path = str(tmp_path)
+    os.makedirs(os.path.join(path, "var_0_t.d"))
+    for field, rows in base.items():
+        np.save(os.path.join(path, "var_0_t.d", f"{field}.npy"), rows)
+    chain = []
+    for seq, (ids, payload) in enumerate(entries, 1):
+        np.savez(os.path.join(path, f"delta_{seq:06d}_0.npz"),
+                 chunks=np.asarray(ids, np.int64), rows_per_chunk=1,
+                 vocab=len(base["weights"]), **payload)
+        chain.append({"seq": seq, "step": seq, "vars": {
+            "t": {"file": f"delta_{seq:06d}_0.npz"}}})
+    with open(os.path.join(path, "delta_manifest"), "w") as f:
+        json.dump({"format": 2, "chain": chain}, f)
+    return path
+
+
+def test_reference_replays_a_chain_a_fold_left_empty(tmp_path):
+    base = {"weights": np.arange(12, dtype=np.float32).reshape(6, 2),
+            "slot_sum": np.full((6, 1), 0.1, np.float32)}
+    path = _array_chain(tmp_path / "folded", base, [])
+    assert reference_chain.manifest(path)["chain"] == []
+    assert reference_chain.entry_rows(path) == []
+    assert reference_chain.base_bytes(path) == sum(
+        os.path.getsize(os.path.join(path, "var_0_t.d", f"{f}.npy"))
+        for f in base)
+    np.testing.assert_array_equal(
+        reference_chain.replayed(path, 0, "weights"), base["weights"])
+    live = {f: a.copy() for f, a in base.items()}
+
+    def read(vid, field, lo, hi):
+        return live[field][lo:hi]
+
+    assert reference_chain.mismatch_rows(path, read, block=4) == 0
+    live["slot_sum"][4] = 0.2
+    assert reference_chain.mismatch_rows(path, read, block=4) == 1
+    # and a chain that is listed, from its ``first`` entry on
+    path = _array_chain(tmp_path / "listed", base, [
+        ([1, 4], {"weights": np.ones((2, 2), np.float32),
+                  "slot_sum": np.ones((2, 1), np.float32)}),
+        ([4], {"weights": np.zeros((1, 2), np.float32),
+               "slot_sum": np.zeros((1, 1), np.float32)})])
+    assert reference_chain.entry_rows(path) == [{0: 2}, {0: 1}]
+    assert reference_chain.entry_rows(path, first=1) == [{0: 1}]
+    assert reference_chain.replayed(path, 0, "weights")[[1, 4]].tolist() \
+        == [[1., 1.], [0., 0.]]
+
+
+def test_entries_are_counted_against_what_the_feed_handed_out():
+    off = train_autosave_runner.entries_off
+    assert off([{0: 5, 1: 5}, {0: 3, 1: 3}], [5, 3]) == 0
+    assert off([{0: 5, 1: 5}, {0: 3, 1: 4}], [5, 3]) == 1
+    assert off([{0: 5, 1: 5}, {}], [5, 3]) == 1     # an entry of no file
+    assert off([{0: 5, 1: 5}], [5, 3]) == 2         # an entry too few
+    assert off([], []) == 0
+
+
+def test_settle_joins_the_compactor_before_it_reads_the_manifest(tmp_path):
+    base = {"weights": np.zeros((4, 2), np.float32)}
+    path = _array_chain(tmp_path, base, [
+        ([1], {"weights": np.ones((1, 2), np.float32)})])
+    order = []
+
+    class Adapter:
+        @staticmethod
+        def join_compactor(at):
+            order.append(("joined", at, os.path.isfile(
+                os.path.join(at, "delta_manifest"))))
+
+        @staticmethod
+        def compaction_budget():
+            return 8, 0.5
+
+    chain = train_autosave_runner.settle(Adapter, path)
+    assert [e["seq"] for e in chain] == [1]
+    assert train_autosave_runner.settle(Adapter, path, made=1,
+                                        made_bytes=7) == chain
+    assert order == [("joined", path, True)] * 2
+    with pytest.raises(SystemExit, match="lists 1 entries where the warm "
+                       "save and the window made 3 .7 bytes."):
+        train_autosave_runner.settle(Adapter, path, made=3, made_bytes=7)
+
+
+def test_the_last_save_is_counted_and_a_chain_folded_anyway_is_off(
+        tmp_path, capsys):
+    base = {"weights": np.zeros((4, 2), np.float32)}
+    one = ([1], {"weights": np.ones((1, 2), np.float32)})
+    two = ([0, 2], {"weights": np.ones((2, 2), np.float32)})
+
+    class Adapter:
+        join_compactor = staticmethod(lambda at: None)
+
+        def __init__(self, path):
+            self.path = path
+
+        def save_last(self, system, state, path, step):
+            assert (system, state, path, step) == ("sys", "st", self.path, 9)
+            return {"seq": 2, "rows": 2, "bytes": 16}
+
+    def tail(path, want):
+        return train_autosave_runner.save_the_tail(
+            Adapter(path), reference_chain.entry_rows, "sys", "st", path, 9,
+            made=1, want=want)
+
+    listed = _array_chain(tmp_path / "listed", base, [one, two])
+    assert tail(listed, 2) == 0 and tail(listed, 3) == 1
+    said = json.loads(capsys.readouterr().out.splitlines()[0])
+    assert said == {"tail_save": {"seq": 2, "rows": 2, "bytes": 16,
+                                  "compaction": None}, "chain_after": 2}
+    # a program that folds all the same: no entry to count, not a crash
+    assert tail(_array_chain(tmp_path / "folded", base, []), 2) == 1
+    # ... or that kept no entry of the save at all
+    assert tail(_array_chain(tmp_path / "short", base, [one]), 2) == 1
+
+
+def test_the_adapter_reads_the_budget_a_save_runs_under(monkeypatch,
+                                                        tmp_path):
+    from benchmark import autosave_keys_system, autosave_system
+    assert autosave_system.compaction_budget() == (8, 0.5)
+    assert autosave_keys_system.compaction_budget is \
+        autosave_system.compaction_budget
+    assert autosave_keys_system.join_compactor is \
+        autosave_system.join_compactor
+    assert autosave_keys_system.save_last is autosave_system.save_last
+    _with_budget(monkeypatch, compact_chain_len=3)
+    assert autosave_system.compaction_budget() == (3, 0.5)
+    autosave_system.join_compactor(str(tmp_path))   # none runs: returns
 
 
 @pytest.mark.parametrize("fault", autosave_controls.FAULTS)

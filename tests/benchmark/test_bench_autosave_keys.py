@@ -4,8 +4,12 @@ rehearses the cell end to end, the four planted faults come out as not
 correct, the checkpoint's plain reference imports nothing of the program,
 and the new readers reduce what a run leaves them. Also what two accepted
 assertions of ``test_bench_autosave.py`` stood for before the benchmark had
-a sixth cell (``tests/conftest.py`` marks them as expected to fail)."""
+a sixth cell (``tests/conftest.py`` marks them as expected to fail). Since
+PR 43 also what ``test_bench_autosave.py`` holds of the array cell: a window
+of a stated number of periods, a trace started by step count, and a
+comparison that no fold runs under."""
 
+import functools
 import json
 import os
 import subprocess
@@ -16,7 +20,7 @@ import numpy as np
 import pytest
 
 from benchmark import (autosave_keys_controls, counts_chain_keys,
-                       reference_chain_keys, run)
+                       reference_chain_keys, run, train_autosave_runner)
 from benchmark.metrics import _autosave_keys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -96,8 +100,12 @@ def test_the_configuration_is_the_hash_cells_plus_the_deployment():
     assert ckpt["guarantees"][:3] == hashed["guarantees"]
     assert len(ckpt["guarantees"]) == 8
     assert "none missing, none extra" in ckpt["guarantees"][-1]
-    assert ckpt["checkpoint"] == array_ckpt["checkpoint"]
-    assert ckpt["reduced"] == ["hash_capacity", "autosave_every"]
+    # the array deployment's, in a window of four periods for its six
+    assert ckpt["checkpoint"] == dict(array_ckpt["checkpoint"],
+                                      window_periods=4)
+    assert array_ckpt["checkpoint"]["window_periods"] == 6
+    assert ckpt["reduced"] == ["hash_capacity", "autosave_every",
+                               "window_periods"]
     assert set(ckpt["why_reduced"]) == set(ckpt["reduced"])
     assert {k: v for k, v in ckpt["assumed"].items()
             if k != "autosave_dir"} == hashed["assumed"]
@@ -108,12 +116,17 @@ def test_the_configuration_is_the_hash_cells_plus_the_deployment():
     traffic = run.load("traffic", "train_zipf_autosave_keys")
     same = run.load("traffic", "train_zipf_autosave")
     for key in ("generator", "zipf_a", "steps_in_flight", "warmup_steps",
-                "lead_in_steps", "trace_seconds"):
+                "lead_in_steps", "trace_periods"):
         assert traffic[key] == same[key]
+    assert "trace_seconds" not in traffic
     assert traffic["kind"] == "train_autosave_keys"
-    # the window's call stays in the pool's first pass: 24 + 3 periods
-    assert traffic["pool_batches"] == 768 >= traffic["lead_in_steps"] \
-        + 3 * ckpt["checkpoint"]["autosave_every"]
+    # the window's call: 24 + 4 periods, of which the last 56 steps lie
+    # past the pool's first pass (left so: PERF.md section 7)
+    to_hand, traced_from = train_autosave_runner.window_batches(
+        ckpt, traffic)
+    assert (to_hand, traced_from) == (824, 624)
+    assert to_hand - traffic["pool_batches"] == 56
+    assert "24 + 800 steps" in traffic["why"] and "last 56" in traffic["why"]
     bench = run.manifest()
     cells = {w["name"]: w for w in bench["workloads"]}
     assert cells[CELL]["chips"] == 1 and len(cells[CELL]["why"]) <= 200
@@ -134,8 +147,11 @@ def test_the_configuration_is_the_hash_cells_plus_the_deployment():
             assert m["workloads"] == [CELL]
             assert m["layer"] == layers.get(m["name"], "checkpoint")
             assert m["moves"] == "examples_per_s"
+    assert "4 a window" in cells[CELL]["why"] \
+        and "24 + 800 steps" in cells[CELL]["why"]
     tiny = run.load("configs", "tiny_hash_ckpt")
     assert tiny["rehearsal"] and tiny["checkpoint"]["autosave_every"] == 64
+    assert tiny["checkpoint"]["window_periods"] == 1
     assert tiny["guarantees"] == ckpt["guarantees"]
 
 
@@ -257,16 +273,28 @@ def test_reference_replays_newest_wins_by_key(tmp_path, wide):
     assert found["new_keys"] == {0: [1]}
 
 
-@pytest.mark.parametrize("trace", ["0", "1"])
-def test_tiny_hash_ckpt_runs_end_to_end_with_null_timings(trace):
+@functools.lru_cache(maxsize=None)
+def _tiny_run(seconds, trace):
+    """One run of the rehearsal, once a test process (the file's tests run
+    in one)."""
     env = {k: v for k, v in os.environ.items() if k != "BENCH_RUN"}
     env["JAX_PLATFORMS"] = "cpu"
-    # a window shorter than one period: it closes at the first one, so
-    # the run holds one save and the 24 lead-in steps' worth after it
-    out = subprocess.run(
+    return subprocess.run(
         [sys.executable, "-m", "benchmark.run", "--workload", TINY,
-         "--seed", str(SEED), "--seconds", "0.05", "--trace", trace],
+         "--seed", str(SEED), "--seconds", seconds, "--trace", trace],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+
+
+def _line_of(out, start):
+    return next(json.loads(text) for text in out.stdout.splitlines()
+                if text.startswith(start))
+
+
+@pytest.mark.parametrize("trace", ["0", "1"])
+def test_tiny_hash_ckpt_runs_end_to_end_with_null_timings(trace):
+    # one period (``window_periods``): the run holds one save and the 24
+    # lead-in steps' worth after it
+    out = _tiny_run("0.05", trace)
     assert out.returncode == 0, out.stderr[-2000:]
     line = json.loads(out.stdout.splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0
@@ -304,6 +332,148 @@ def test_tiny_hash_ckpt_runs_end_to_end_with_null_timings(trace):
     assert window["autosave"]["ckpt_delta_saves"] == 1
     assert window["autosave"]["trainer.autosave"]["calls"] == 1
     assert window["autosave"]["ckpt_delta_keys_absent"] == 0
+
+
+def test_the_window_holds_the_same_work_at_any_seconds():
+    short, long = _tiny_run("0.05", "0"), _tiny_run("5", "0")
+    assert long.returncode == 0, long.stderr[-2000:]
+    lines = [json.loads(out.stdout.splitlines()[-1])
+             for out in (short, long)]
+    assert [line["attempted"] for line in lines] == [64, 64]
+    assert all(line["correct"] is True for line in lines)
+    windows = [_line_of(out, '{"window_s"') for out in (short, long)]
+    assert [w["steps"] for w in windows] == [64, 64]
+    assert [w["autosave"]["ckpt_delta_saves"] for w in windows] == [1, 1]
+    compared = [_line_of(out, '{"compared_at"') for out in (short, long)]
+    assert compared[0]["entry_keys"] == compared[1]["entry_keys"]
+    assert len(compared[0]["entry_keys"]) == 3
+    assert compared[0]["entry_new_keys"] == compared[1]["entry_new_keys"]
+    tails = [_line_of(out, '{"tail_save"') for out in (short, long)]
+    assert tails[0] == tails[1] and tails[0]["chain_after"] == 3
+
+
+def test_the_traced_tail_holds_a_save_at_any_seconds():
+    short, long = _tiny_run("0.05", "1"), _tiny_run("5", "1")
+    assert long.returncode == 0, long.stderr[-2000:]
+    metrics = [json.loads(out.stdout.splitlines()[-1])["metrics"]
+               for out in (short, long)]
+    assert set(metrics[0]) == set(metrics[1])
+    per_save = {"train_autosave_stall_ms_per_save",
+                "train_autosave_d2h_ms_per_save",
+                "train_autosave_write_ms_per_save",
+                "train_autosave_commit_lag_ms",
+                "train_autosave_rows_per_save",
+                "train_autosave_mb_per_save",
+                "train_autosave_keys_absent_per_save"}
+    assert per_save <= set(metrics[1])
+    for name in ("train_autosave_rows_per_save",
+                 "train_autosave_mb_per_save",
+                 "train_autosave_keys_absent_per_save",
+                 "train_compiles_in_window"):
+        assert metrics[0][name] == metrics[1][name]
+    assert metrics[1]["train_autosave_rows_per_save"]["value"] > 0
+    assert os.path.isdir(os.path.join(
+        ROOT, "benchmark", "out", f"{TINY}.{SEED}.trace"))
+
+
+def _with_budget(monkeypatch, **budget):
+    """Lower the budgets the program's saves start a fold at, in this
+    process alone: its saves read them as ``begin_delta``'s defaults."""
+    from openembedding_tpu import checkpoint_delta
+    for name, value in budget.items():
+        monkeypatch.setitem(checkpoint_delta.begin_delta.__kwdefaults__,
+                            name, value)
+
+
+# the rehearsal's chain: 2.42 MB (warm), 3.38 MB (the window's), 1.49 MB
+# (the last steps') over a base of 51.6 MB
+@pytest.mark.parametrize("budget", [{"compact_chain_len": 3},
+                                    {"compact_bytes_ratio": 0.13}],
+                         ids=["entries", "bytes"])
+def test_the_runners_own_last_save_starts_no_fold(monkeypatch, capsys,
+                                                  budget):
+    """A budget that the last save's entry meets, and no save before it:
+    as the array cell's stands (its last save is the chain's eighth
+    entry). The runner's own save asks for no fold (a fold of the cell's
+    base holds the machine 140 s and writes 6.5 GB: PERF.md), so the
+    chain it leaves lists every entry and nothing writes the directory
+    under the comparison."""
+    _with_budget(monkeypatch, **budget)
+    assert autosave_keys_controls.main(["tiny_hash_ckpt", "none",
+                                        str(SEED), "0.05"]) == 0
+    out = capsys.readouterr().out.splitlines()
+    tail = next(json.loads(t) for t in out if t.startswith('{"tail_save"'))
+    assert tail["tail_save"]["compaction"] is None
+    assert tail["tail_save"]["seq"] == tail["chain_after"] == 3
+    result = json.loads(out[-1])
+    assert result["correct"] is True and result["attempted"] == 64
+    for name in ("chain_mismatch_rows", "chain_missing_keys",
+                 "chain_extra_keys", "chain_rows_off", "chain_late_rows"):
+        assert result["compared"][name] == {"value": 0, "limit": 0}
+
+
+@pytest.mark.parametrize("budget", [{"compact_chain_len": 2},
+                                    {"compact_bytes_ratio": 0.08}],
+                         ids=["entries", "bytes"])
+def test_a_budget_met_inside_the_window_ends_the_run_in_one_line(
+        monkeypatch, capsys, budget):
+    _with_budget(monkeypatch, **budget)
+    with pytest.raises(SystemExit) as refused:
+        autosave_keys_controls.main(["tiny_hash_ckpt", "none", str(SEED),
+                                     "0.05"])
+    said = str(refused.value)
+    assert "\n" not in said and said.startswith("benchmark: ")
+    assert "lists 0 entries where the warm save and the window made 2" \
+        in said and "met the compactor's budget" in said
+    entries = budget.get("compact_chain_len", 8)
+    ratio = budget.get("compact_bytes_ratio", 0.5)
+    assert f"a chain of {entries} entries, or {ratio} of the base's " \
+        "51587328 bytes" in said
+    assert "(5807296 bytes)" in said and "window_periods" in said
+    assert '{"tail_save"' not in capsys.readouterr().out
+
+
+def test_reference_replays_by_key_a_chain_a_fold_left_empty(tmp_path):
+    base = {"keys": np.array([3, 9, 4], np.int32),
+            "weights": np.array([[1.], [2.], [3.]], np.float32)}
+    path = _chain(tmp_path, base, [])
+    assert reference_chain_keys.entry_keys(path) == []
+    index = reference_chain_keys.Index(path, 0)
+    assert index.rows == 3 and index.new_keys == []
+    assert index.at(np.array([9, 5], np.int64)).tolist() == [1, -1]
+    np.testing.assert_array_equal(
+        reference_chain_keys.replayed(path, 0, "weights", index),
+        base["weights"])
+    empty = np.iinfo(np.int32).min
+    live_keys = np.array([empty, 4, empty, 3, 9, empty], np.int32)
+    live_rows = np.array([[0.], [3.], [0.], [1.], [2.], [0.]], np.float32)
+
+    def live(vid, field, lo, hi):
+        return (live_keys if field == "keys" else live_rows)[lo:hi]
+
+    found = reference_chain_keys.compare(path, live, 6, block=4)
+    assert found == {"mismatch_rows": 0, "missing_keys": 0,
+                     "extra_keys": 0, "new_keys": {0: []}}
+    live_rows[4] = 2.5
+    live_keys[0] = 11
+    found = reference_chain_keys.compare(path, live, 6, block=4)
+    assert (found["mismatch_rows"], found["missing_keys"],
+            found["extra_keys"]) == (1, 1, 0)
+
+
+def test_entry_keys_reads_the_chain_from_an_entry_on(tmp_path):
+    base = {"keys": np.array([3], np.int32),
+            "weights": np.array([[1.]], np.float32)}
+    entries = [{"keys": np.array([9, 7], np.int32),
+                "weights": np.array([[20.], [70.]], np.float32)},
+               {"keys": np.array([5], np.int32),
+                "weights": np.array([[50.]], np.float32)}]
+    path = _chain(tmp_path, base, entries)
+    assert reference_chain_keys.entry_keys(path) == [{0: 2}, {0: 1}]
+    assert reference_chain_keys.entry_keys(path, first=1) == [{0: 1}]
+    assert reference_chain_keys.entry_keys(path, first=2) == []
+    assert train_autosave_runner.entries_off(
+        reference_chain_keys.entry_keys(path, first=1), [1]) == 0
 
 
 @pytest.mark.parametrize("fault", autosave_keys_controls.FAULTS)

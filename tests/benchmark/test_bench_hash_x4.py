@@ -178,7 +178,8 @@ def test_what_the_accepted_counts_of_seven_cells_stood_for():
                "assumed"}
     assert {k: array[k] for k in array if k not in differs} \
         == {k: ckpt[k] for k in array if k not in differs}
-    assert ckpt["reduced"] == ["rows_per_feature", "autosave_every"]
+    assert ckpt["reduced"] == ["rows_per_feature", "autosave_every",
+                               "window_periods"]      # the last: PR 43
     found = {m["name"]: m for m in bench["per_layer"]}
     array_ckpt = "deepfm_dim9_array_ckpt.train_zipf_autosave"
     hash_ckpt = "deepfm_dim9_hash_ckpt.train_zipf_autosave_keys"
@@ -192,7 +193,11 @@ def test_what_the_accepted_counts_of_seven_cells_stood_for():
     bounded = "deepfm_dim9_offload.train_zipf_offload"
     both = [m for m in bench["per_layer"] + bench["end_to_end"]
             if bounded in m.get("workloads", ())]
-    assert len(both) == 32
+    # 32 until PR 43 took the bounded cell off the insert program's device
+    # time, which its traced tail never runs (benchmark/README.md)
+    assert len(both) == 31
+    assert found["train_offload_insert_device_ms_per_step"]["workloads"] \
+        == [KEYED]
     for m in both:
         assert m["workloads"].index(KEYED) > m["workloads"].index(bounded)
         assert m["workloads"][-1] in (KEYED, CELL)

@@ -323,11 +323,18 @@ def test_an_accepted_offload_reader_lists_both_cells(name):
     """What fourteen cases of ``test_a_reader_finds_nothing_where_the_
     program_has_no_tier`` stood for, whose last line held each list to the
     bounded cell alone: the reader still finds nothing on a program with
-    no tier, and its entry lists the bounded cell, then this one."""
+    no tier, and its entry lists the bounded cell, then this one. But
+    the insert program's device time (PR 43): the bounded cell's traced
+    tail lies past its pool of 512 batches, where no row misses and the
+    program does not run, so the reader finds nothing there and the
+    driver flagged it on seven PRs; this cell, which reads 5.8 ms, keeps
+    it, and a pool of 1,024 brings the bounded cell back."""
     reader = importlib.import_module(f"benchmark.metrics.{name}")
     assert reader.read({"steps": 100, "trace": None,
                         "trace_dir": None}) is None
-    assert _per_layer()[name]["workloads"] == [OFFLOAD_CELL, CELL]
+    assert _per_layer()[name]["workloads"] == (
+        [CELL] if name == "train_offload_insert_device_ms_per_step"
+        else [OFFLOAD_CELL, CELL])
 
 
 def test_stored_and_fresh_keys_are_told_apart_by_rank_and_by_hash():
