@@ -415,7 +415,7 @@ def run_offload(name, config, *, steps, warmup):
             # lookahead thread): overlapped when step_ms ~= max(this,
             # device time) rather than their sum
             "prepare_ms": round(1000 * prep_sum / max(steps, 1), 3),
-            "mode": "serial" if serial else f"pipelined_k{depth}",
+            "mode": "serial" if serial else f"lookahead_k{depth}",
             "host_store_gb": round(store_gb, 2),
             "cache_rows": cache,
             "cache_hit_rate": round(hits / max(hits + misses, 1), 4),
@@ -754,119 +754,6 @@ def run_cache_ab(name, config, *, steps, warmup):
     }
 
 
-def run_pipelined_ab(name, config, *, steps, warmup):
-    """Pipelined-vs-serial A/B on one config: identical data + seeds,
-    ``plane="a2a"`` vs ``plane="a2a+pipelined"`` (the double-buffered
-    step schedule, ``parallel/pipelined.py``). Reports both planes'
-    examples/s, the speedup, and an instrumented whole-step /
-    stage-isolated split (``plane_timings``: step_ms + overlap_hidden_ms
-    = step minus the serially-dispatched pull+push walls) sampled
-    outside the timed blocks. ``value`` is the PIPELINED plane's
-    examples/s so ``vs_baseline`` stays comparable with the plain
-    ``deepfm_dim9*`` entries.
-    """
-    import jax
-    from openembedding_tpu.parallel.mesh import create_mesh
-    from openembedding_tpu.utils import observability as obs
-
-    n_dev = len(jax.devices())
-    platform = jax.devices()[0].platform
-    data_ax = 2 if n_dev % 2 == 0 and n_dev > 1 else 1
-    mesh = create_mesh(data_ax, n_dev // data_ax)
-    batch = config["batch"]
-    planes = {}
-    stage_split = {}
-    for plane in ("a2a", "a2a+pipelined"):
-        cfg = dict(config, plane=plane)
-        features, coll, trainer, mapper = build(cfg, mesh)
-        batches = make_batches(cfg, features, mapper)
-
-        def step(state, i):
-            # the lookahead the fit loop would provide: the pipelined
-            # arm prefetches batch i+1 inside step i's program; the
-            # serial arm ignores it
-            return trainer.train_step(
-                state, batches[i % len(batches)],
-                next_batch=batches[(i + 1) % len(batches)])
-
-        state = trainer.init(jax.random.PRNGKey(0),
-                             trainer.shard_batch(batches[0]))
-        # ONE batch index across warmup, blocks and the instrumented
-        # sample: restarting at 0 per block would make every block
-        # open on a lookahead miss (an eager re-prime the pipelined
-        # arm alone pays, inside the timed window)
-        gi = 0
-        # the pipelined schedule has a 2-step compile warmup (prime
-        # pull + step program, step 2 may legally recompile once)
-        for _ in range(max(warmup, 3)):
-            state, m = step(state, gi)
-            gi += 1
-        jax.block_until_ready(m["loss"])
-        block_eps = []
-        for _ in range(3):
-            t0 = time.perf_counter()
-            for _ in range(steps):
-                state, m = step(state, gi)
-                gi += 1
-            jax.block_until_ready(m["loss"])
-            block_eps.append(steps * batch / (time.perf_counter() - t0))
-        planes[plane] = _median(block_eps)
-        if plane == "a2a+pipelined":
-            # instrumented sample OUTSIDE the timed blocks: whole-step
-            # wall (blocking) + one eager stage-isolation round so
-            # plane_timings can report overlap_hidden_ms (the in-step
-            # pull/push are not separable host-side — the satellite fix
-            # for double-counted stage attribution)
-            obs.set_evaluate_performance(True)
-            try:
-                sb = trainer.shard_batch(batches[0])
-                inputs = {k2: v for k2, v in sb["sparse"].items()
-                          if k2 in coll.specs}
-
-                def stage_round():
-                    rows = coll.pull(state.emb, inputs)
-                    jax.block_until_ready(jax.tree.leaves(rows))
-                    emb2 = coll.apply_gradients(state.emb, inputs, rows)
-                    jax.block_until_ready(jax.tree.leaves(emb2))
-
-                # warm the instrumented eager stage programs (the
-                # record gate keys their jit cache: first dispatch
-                # compiles) so the sampled walls are run time, not
-                # compile time; then ONE full stage-isolation round per
-                # recorded step — the normalization plane_timings'
-                # overlap_hidden_ms estimate assumes
-                stage_round()
-                obs.GLOBAL.reset()
-                for _ in range(3):
-                    state, m = step(state, gi)
-                    gi += 1
-                    stage_round()
-                jax.effects_barrier()
-                t = obs.plane_timings().get(trainer.pipeline_plane, {})
-                stage_split = {
-                    k: round(t[k], 3)
-                    for k in ("step_ms", "pull_ms", "push_ms",
-                              "stage_serial_ms", "overlap_hidden_ms")
-                    if k in t}
-            finally:
-                obs.set_evaluate_performance(False)
-        del state
-        gc.collect()
-    eps = planes["a2a+pipelined"]
-    return {
-        "metric": f"{name}_examples_per_sec_{platform}{n_dev}",
-        "value": round(eps, 1),
-        "unit": "examples/s",
-        "vs_baseline": round(eps / n_dev / REF_PER_CHIP, 3),
-        "per_chip": round(eps / n_dev, 1),
-        "serial_eps": round(planes["a2a"], 1),
-        "pipelined_speedup": round(eps / planes["a2a"], 3),
-        "plane_timings": stage_split,
-        **_hbm_stats(),
-        "config": dict(config),
-    }
-
-
 def run_compressed_ab(name, config, *, steps, warmup):
     """Compressed-vs-f32 exchange A/B on one config: identical data +
     seeds on ``plane="a2a"`` vs ``"a2a+bf16"`` (bf16 wire rows both
@@ -880,7 +767,7 @@ def run_compressed_ab(name, config, *, steps, warmup):
     ``vs_baseline`` stays comparable with the plain ``deepfm_dim9*``
     entries. NOTE the byte claim is NOT this wall-clock number: on the
     shared-memory cpu8 mesh exchange bytes are nearly free, so timing
-    flattens or inverts exactly like the cache/grouped/pipelined A/Bs —
+    flattens or inverts exactly like the cache/grouped A/Bs —
     the halving itself is the compiled-HLO contract ``tools.graftcheck``
     asserts (exchange collective bytes <= 0.55x f32, pull and push
     separately).
@@ -971,8 +858,8 @@ def run_compressed_ab(name, config, *, steps, warmup):
 def run_ingest_ab(name, config, *, steps, warmup):
     """Streaming-ingest A/B: the SAME shard data trained from on-disk
     shards through the parallel reader pool (``data/stream.py``) vs
-    pre-materialized in-memory batch dicts, both on the pipelined
-    plane with the fit-style lookahead. This is the first bench where
+    pre-materialized in-memory batch dicts, both on the a2a plane with
+    the fit-style lookahead. This is the first bench where
     the input pipeline is on the critical path (ROADMAP item 5: every
     prior eps number fed synthetic in-memory batches). ``value`` is the
     STREAMED eps; ``stream_vs_mem`` is the honest cost of ingest
@@ -990,20 +877,19 @@ def run_ingest_ab(name, config, *, steps, warmup):
     import jax
     from openembedding_tpu.data import stream as stream_lib
     from openembedding_tpu.parallel.mesh import create_mesh
-    from openembedding_tpu.utils import observability as obs
 
     n_dev = len(jax.devices())
     platform = jax.devices()[0].platform
     data_ax = 2 if n_dev % 2 == 0 and n_dev > 1 else 1
     mesh = create_mesh(data_ax, n_dev // data_ax)
     batch = config["batch"]
-    cfg = dict(config, plane=config.get("plane", "a2a+pipelined"))
+    cfg = dict(config, plane=config.get("plane", "a2a"))
     readers = int(config.get("readers", 2))
     ring = int(config.get("ring_batches", 8))
     num_shards = int(config.get("shards", 8))
     shard_rows = int(config.get("shard_rows", 12288))
     shard_dir = tempfile.mkdtemp(prefix="bench_ingest_")
-    warm = max(warmup, 3)   # pipelined schedule: 2-step compile warmup
+    warm = max(warmup, 3)
     blocks = 3
     try:
         stream_lib.write_synthetic_shards(
@@ -1068,7 +954,6 @@ def run_ingest_ab(name, config, *, steps, warmup):
             first = next(it)
             state = trainer.init(jax.random.PRNGKey(0),
                                  trainer.shard_batch(first))
-            obs.GLOBAL.reset()
             state, cur = drive(state, lambda: next(it), first, warm)
             live.reset_stall_stats()   # measured window excludes warmup
             stream_eps = []
@@ -1078,8 +963,6 @@ def run_ingest_ab(name, config, *, steps, warmup):
                 stream_eps.append(steps * batch
                                   / (time.perf_counter() - t0))
             stalls = live.stall_summary()
-            primes = obs.GLOBAL.snapshot().get(
-                "pipeline_primes", {}).get("count", 0.0)
             bad = live.bad_rows()
             ring_stats = live.memory_stats()
         finally:
@@ -1106,7 +989,6 @@ def run_ingest_ab(name, config, *, steps, warmup):
             "stalled_pops": int(stalls["stalled"]),
             "pops": int(stalls["pops"]),
             "bad_rows": int(bad),
-            "pipeline_primes": int(primes),
             "readers": readers,
             "ring_batches": int(ring_stats["ring_capacity_batches"]),
             "rows_read": int(ring_stats["rows_read"]),
@@ -1542,16 +1424,6 @@ CONFIGS = {
                          "cache_k": 4096, "cache_refresh_every": 16},
     "deepfm_dim64": {"model": "deepfm", "dim": 64, "vocab": 1 << 18,
                      "batch": 4096, "zipf": True},
-    # pipelined-vs-serial A/B: the double-buffered step schedule
-    # (parallel/pipelined.py) on the headline shape and on dim64, whose
-    # wider rows make the pull a larger share of the step
-    "deepfm_dim9_pipelined_ab": {"kind": "pipelined_ab", "model": "deepfm",
-                                 "dim": 9, "vocab": 1 << 20,
-                                 "batch": 4096, "zipf": True},
-    "deepfm_dim64_pipelined_ab": {"kind": "pipelined_ab",
-                                  "model": "deepfm", "dim": 64,
-                                  "vocab": 1 << 18, "batch": 4096,
-                                  "zipf": True},
     # compressed-vs-f32 exchange A/B (parallel/precision.py): f32 vs
     # bf16-wire vs int8-error-feedback push on the headline shape and on
     # dim64 (where the wire bytes — and so the device-side win — are
@@ -1566,8 +1438,8 @@ CONFIGS = {
                                    "zipf": True},
     # streaming-ingest A/B (data/stream.py): the headline shape trained
     # from generated on-disk TSV shards through the parallel reader
-    # pool vs the same rows pre-materialized in memory, pipelined
-    # plane + lookahead both arms; value = streamed eps, plus the
+    # pool vs the same rows pre-materialized in memory, a2a plane +
+    # lookahead both arms; value = streamed eps, plus the
     # stream_vs_mem ratio and post-warmup stall evidence (cpu-window
     # acceptance: >= 0.9x and stall p95 == 0)
     "deepfm_dim9_ingest_ab": {"kind": "ingest_ab", "model": "deepfm",
@@ -1612,7 +1484,7 @@ CONFIGS = {
     "offload_sweep": {"kind": "offload_sweep", "dim": 8,
                       "vocab": 50_000_000, "batch": 4096, "zipf_a": 1.08,
                       "caches": [1 << 18, 1 << 20, 1 << 22]},
-    # pipelined-vs-serial A/B at identical config + the depth curve: what
+    # lookahead-vs-serial A/B at identical config + the depth curve: what
     # the prepare/step overlap buys, and whether K > 2 buys more when the
     # host half is the long pole (reference prefetch `steps` budget,
     # exb_ops.cpp:148-156)
@@ -1654,7 +1526,7 @@ CONFIGS = {
 }
 HEADLINE = "deepfm_dim9"
 RUNNERS = {"offload": run_offload, "offload_sweep": run_offload_sweep,
-           "cache_ab": run_cache_ab, "pipelined_ab": run_pipelined_ab,
+           "cache_ab": run_cache_ab,
            "compressed_ab": run_compressed_ab,
            "ingest_ab": run_ingest_ab,
            "hash_probe": run_hash_probe,

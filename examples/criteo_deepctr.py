@@ -57,12 +57,11 @@ def parse_args(argv=None):
                    "exb.py:617-632); needs --no-fused")
     p.add_argument("--plane", default="a2a",
                    choices=["a2a", "psum", "a2a+cache", "a2a+grouped",
-                            "a2a+pipelined", "a2a+grouped+pipelined",
                             # compressed-exchange rungs (precision.py):
                             # bf16 wire rows / bf16 pull + int8
                             # error-feedback push
                             "a2a+bf16", "a2a+int8",
-                            "a2a+grouped+bf16", "a2a+pipelined+bf16"],
+                            "a2a+grouped+bf16"],
                    help="sparse data plane: owner-routed all-to-all "
                    "(default), the psum/all_gather baseline, a2a plus "
                    "the hot-row replica cache (parallel/hot_cache.py), "
@@ -192,7 +191,7 @@ def main(argv=None):
             if args.readers > 0 and args.format in ("tsv", "tfrecord"):
                 # parallel shard reader pool: parse + hash on worker
                 # threads, bounded ring, identity-stable batches (the
-                # pipelined plane's lookahead contract), stall-accounted
+                # offload lookahead's contract), stall-accounted
                 from openembedding_tpu.data import stream as stream_lib
                 reader = stream_lib.ShardStream(
                     args.data, batch_size=args.batch_size,

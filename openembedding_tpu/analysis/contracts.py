@@ -272,20 +272,8 @@ def check_no_host_transfers(hlo_text: str) -> None:
             "pipelining")
 
 
-# --- overlap contract (pipelined step programs) ------------------------------
+# --- HLO def-use parsing -----------------------------------------------------
 
-# The pipelined plane's promise is a SCHEDULING property of the compiled
-# step program (parallel/pipelined.py): the dense fwd/bwd consumes a
-# prefetched row buffer (an input), so no dense op waits on an exchange
-# collective, while the NEXT batch's exchange rides the same program —
-# its index/key legs free of any dense dependency (overlappable) and its
-# row resolution committed behind the push (the version barrier). These
-# are def-use-graph facts, checkable on any backend's HLO text; the
-# async -start/-done pairing leg only binds on backends that emit async
-# collective forms (TPU post-optimization dumps).
-
-_DOT_OPS = frozenset({"dot", "convolution"})
-_EXCHANGE_OPS = frozenset({"all-to-all", "all-to-all-start"})
 # attributes whose %refs name CALLED COMPUTATIONS, not data operands
 _CALL_ATTRS = ("calls", "to_apply", "body", "condition",
                "branch_computations", "called_computations")
@@ -384,196 +372,6 @@ def parse_hlo_computations(hlo_text: str
     return entry, comps
 
 
-def _comp_contains(comps: Dict[str, List[HloInstr]],
-                   ops: frozenset) -> Dict[str, bool]:
-    """computation -> does it (transitively) contain one of ``ops``."""
-    out = {name: any(i.opcode in ops for i in instrs)
-           for name, instrs in comps.items()}
-    changed = True
-    while changed:
-        changed = False
-        for name, instrs in comps.items():
-            if out[name]:
-                continue
-            if any(out.get(c, False) for i in instrs for c in i.calls):
-                out[name] = changed = True
-    return out
-
-
-@dataclasses.dataclass(frozen=True)
-class OverlapReport:
-    """Def-use facts of one step program the overlap contract audits."""
-
-    pull_exchanges: int             # pull-scoped exchange nodes (entry)
-    free_pull_exchanges: int        # ... with NO dense dependency
-    push_exchanges: int             # push-scoped exchange nodes
-    committed_push_exchanges: int   # ... depending on the dense grads
-    dense_nodes: int                # dot/convolution-carrying nodes
-    dense_waiting_on_exchange: int  # dense nodes downstream of an exchange
-    async_pairs: int                # -start/-done collective pairs
-    async_pairs_spanning_dense: int  # pairs with dense scheduled between
-
-
-def analyze_overlap(hlo_text: str) -> OverlapReport:
-    """Classify the entry computation's nodes and their reachability.
-
-    A node is *dense* if it is (or calls a computation containing) a
-    dot/convolution; an *exchange* if it is (or contains) an
-    all-to-all. Taint flows along data operands and control
-    predecessors within the entry computation (called computations are
-    atomic nodes — a while-loop residue round or a conditional push
-    branch counts as one exchange node). Exchange nodes are scoped
-    pull/push by their ``op_name`` trace paths — the plane-identifiable
-    ``jit(pull_*)`` / ``jit(push_*)`` scopes every data-plane program
-    carries (``parallel/sharded.py``, ``parallel/grouped.py``).
-    """
-    entry, comps = parse_hlo_computations(hlo_text)
-    instrs = comps.get(entry, [])
-    has_dot = _comp_contains(comps, _DOT_OPS)
-    has_a2a = _comp_contains(comps, _EXCHANGE_OPS)
-
-    def _is_dense(i: HloInstr) -> bool:
-        return i.opcode in _DOT_OPS or any(has_dot.get(c, False)
-                                           for c in i.calls)
-
-    def _is_exchange(i: HloInstr) -> bool:
-        return i.opcode in _EXCHANGE_OPS or any(has_a2a.get(c, False)
-                                                for c in i.calls)
-
-    def _scopes(i: HloInstr) -> set:
-        """{"pull", "push"} memberships of one exchange node, from its
-        own trace path plus those of the collectives inside any called
-        computation (a residue while-loop's scope lives on its body's
-        ops, not on the while node itself)."""
-        names = [i.op_name]
-        seen = set()
-        stack = list(i.calls)
-        while stack:
-            c = stack.pop()
-            if c in seen or c not in comps:
-                continue
-            seen.add(c)
-            for j in comps[c]:
-                if j.opcode in _EXCHANGE_OPS:
-                    names.append(j.op_name)
-                stack.extend(j.calls)
-        out = set()
-        for n in names:
-            if "pull" in n:
-                out.add("pull")
-            if "push" in n:
-                out.add("push")
-        return out
-
-    def _taint(sources) -> set:
-        tainted = set(sources)
-        changed = True
-        while changed:
-            changed = False
-            for i in instrs:
-                if i.name not in tainted and \
-                        any(op in tainted for op in i.operands):
-                    tainted.add(i.name)
-                    changed = True
-        return tainted
-
-    dense = [i for i in instrs if _is_dense(i)]
-    exchange = [i for i in instrs if _is_exchange(i)]
-    scopes = {i.name: _scopes(i) for i in exchange}
-    dot_downstream = _taint({i.name for i in dense})
-    a2a_downstream = _taint({i.name for i in exchange})
-    pulls = [i for i in exchange if "pull" in scopes[i.name]]
-    pushes = [i for i in exchange if "push" in scopes[i.name]]
-    free = [i for i in pulls if i.name not in dot_downstream]
-    committed = [i for i in pushes
-                 if i.name in dot_downstream and i.name
-                 not in {d.name for d in dense}]
-    waiting = [i for i in dense if i.name in a2a_downstream
-               and i.name not in {e.name for e in exchange}]
-
-    # async pairing: every exchange -start needs a -done consuming it;
-    # "spanning dense" = a dense node sits between them in schedule
-    # order (the module prints is_scheduled post-optimization). ONLY
-    # exchange ops count — the dense-grad all-reduce's pair brackets
-    # dense by construction and would satisfy the check vacuously
-    starts = {i.name: i for i in instrs
-              if i.opcode in _EXCHANGE_OPS
-              and i.opcode.endswith("-start")}
-    pairs = spanning = 0
-    dense_lines = sorted(i.line_no for i in dense)
-    import bisect
-    for i in instrs:
-        if i.opcode.endswith("-done"):
-            for op in i.operands:
-                if op in starts:
-                    pairs += 1
-                    lo = starts[op].line_no
-                    k = bisect.bisect_right(dense_lines, lo)
-                    if k < len(dense_lines) and dense_lines[k] < i.line_no:
-                        spanning += 1
-                    break
-    return OverlapReport(
-        pull_exchanges=len(pulls), free_pull_exchanges=len(free),
-        push_exchanges=len(pushes),
-        committed_push_exchanges=len(committed), dense_nodes=len(dense),
-        dense_waiting_on_exchange=len(waiting), async_pairs=pairs,
-        async_pairs_spanning_dense=spanning)
-
-
-def check_overlap(hlo_text: str, label: str = "") -> OverlapReport:
-    """Enforce the pipelined step's overlap contract; returns the report.
-
-    * pull-scoped AND push-scoped exchange nodes both present: the
-      prefetch pull and the push commit compiled into ONE program (the
-      fused schedule exists at all);
-    * >= 1 *free* pull-scoped exchange (no dense dependency): the
-      prefetch index/key legs are schedulable concurrently with the
-      dense dots — a forced dense->prefetch dependency (the
-      serialization regression) taints every pull leg and fails here;
-    * >= 1 push-scoped exchange downstream of the dense grads: the push
-      commits inside the program — the version barrier that keeps the
-      plane bit-identical was not optimized away;
-    * NO dense node downstream of an exchange: the dense compute reads
-      the prefetched row buffer, never this program's exchange — the
-      serial schedule (dense waiting on its own pull) fails here;
-    * on backends emitting async collective forms: every ``-start``
-      pairs with a ``-done``, and at least one pair BRACKETS dense HLO
-      in schedule order — overlap in the scheduled program, not just in
-      the dependence structure.
-    """
-    r = analyze_overlap(hlo_text)
-    where = f"{label}: " if label else ""
-    if r.dense_nodes < 1:
-        raise ContractViolation(
-            f"{where}no dense dot/convolution in the step program — the "
-            f"overlap audit has nothing to overlap against ({r})")
-    if r.pull_exchanges < 1 or r.push_exchanges < 1:
-        raise ContractViolation(
-            f"{where}prefetch pull and push must both ride ONE step "
-            f"program (pull={r.pull_exchanges}, "
-            f"push={r.push_exchanges} exchange nodes) ({r})")
-    if r.free_pull_exchanges < 1:
-        raise ContractViolation(
-            f"{where}every pull-scoped exchange collective depends on "
-            f"the dense compute — the prefetch was serialized behind "
-            f"the dots (forced dependency?) and cannot overlap ({r})")
-    if r.committed_push_exchanges < 1:
-        raise ContractViolation(
-            f"{where}no push-scoped exchange depends on the dense grads "
-            f"— the push commit is missing from the step program ({r})")
-    if r.dense_waiting_on_exchange:
-        raise ContractViolation(
-            f"{where}{r.dense_waiting_on_exchange} dense node(s) wait on "
-            f"an exchange collective — the dense compute must consume "
-            f"the prefetched row buffer, not this program's pull ({r})")
-    if r.async_pairs and r.async_pairs_spanning_dense < 1:
-        raise ContractViolation(
-            f"{where}async collective pairs present but none brackets "
-            f"dense HLO in schedule order — the scheduler serialized "
-            f"the exchange ({r})")
-    return r
-
-
 # --- peak-temp-bytes audit (the memory-level copy check) ---------------------
 
 # calibrated against the shipped planes on the cpu8 mesh (graftwatch
@@ -586,12 +384,6 @@ def check_overlap(hlo_text: str, label: str = "") -> OverlapReport:
 TEMP_FLOOR_BYTES = 1 << 18
 TEMP_BATCH_FACTOR = 2
 TEMP_STATE_SLACK = 1.1
-# a whole STEP program holds several exchange pipelines' scratch live at
-# once (one pull + one push per sparse variable, vs the single pipeline
-# a pull/push program audits); its batch term scales by the pipeline
-# count at a tighter per-pipeline factor (calibrated on the cpu8
-# pipelined deepfm step: 8 pipelines, temp ~10.7 scratch units)
-TEMP_STEP_PIPELINE_FACTOR = 1.5
 
 
 def peak_temp_bound(params: Mapping[str, int], program: str,
@@ -599,7 +391,7 @@ def peak_temp_bound(params: Mapping[str, int], program: str,
     """Allowed compiled temp bytes for one plane program.
 
     Pull programs are read-only: temp must stay batch-scale scratch. A
-    push/step program whose donation the backend declined legitimately
+    push program whose donation the backend declined legitimately
     materializes the updated state once in temp — that is the
     ``state_shard_bytes - alias_bytes`` term. Anything beyond is an
     accidental extra materialization (a table-shard-sized gather or a
@@ -610,30 +402,11 @@ def peak_temp_bound(params: Mapping[str, int], program: str,
     unit = int(params["global_batch"]) * (int(params["dim"]) + 2) \
         * int(params.get("itemsize", 4)) \
         * int(params.get("num_shards", 1))
-    if program == "step":
-        scratch = int(TEMP_STEP_PIPELINE_FACTOR
-                      * int(params.get("num_exchange_pipelines", 2))
-                      * unit)
-    else:
-        scratch = TEMP_BATCH_FACTOR * unit
-    bound = TEMP_FLOOR_BYTES + scratch
+    bound = TEMP_FLOOR_BYTES + TEMP_BATCH_FACTOR * unit
     if program != "pull":
         unaliased = max(0, int(params.get("state_shard_bytes", 0))
                         - int(alias_bytes))
         bound += int(TEMP_STATE_SLACK * unaliased)
-    # a pipelined step earns EXACTLY one extra pulled-row buffer (the
-    # prefetched double buffer, batch-scale; the harness passes the
-    # primed buffer's byte size in pipeline_rows_bytes) plus — on a
-    # backend that does not alias in place — ONE weights-shard
-    # materialization per pipelined table (the version barrier's cost:
-    # the push-updated weights live in temp between the in-place update
-    # and the prefetch's read; measured +1 shard/table vs the serial
-    # step on cpu8). step_weight_shards caps that count; anything past
-    # it is an accidental extra table-sized buffer and busts the bound.
-    bound += int(TEMP_STATE_SLACK
-                 * (int(params.get("pipeline_rows_bytes", 0))
-                    + int(params.get("step_weight_shards", 0))
-                    * int(params.get("table_shard_bytes", 0))))
     return bound
 
 
@@ -782,7 +555,6 @@ class ProgramContract:
     no_f64: bool = True
     no_host_transfers: bool = True
     min_aliased: int = 0              # donation floor (step programs)
-    overlap: bool = False             # enforce :func:`check_overlap`
     # compressed planes: exchange bytes <= byte_ratio x the baseline
     # plane's compiled program (enforced by check_compressed_program,
     # which needs BOTH HLO texts; check() alone cannot see the baseline)
@@ -852,8 +624,6 @@ class ProgramContract:
             check_no_host_transfers(hlo_text)
         if self.min_aliased:
             check_donation(hlo_text, self.min_aliased)
-        if self.overlap:
-            check_overlap(hlo_text, label)
         return summary
 
 
@@ -911,25 +681,6 @@ _register(ProgramContract(
          "all-gather": OpBudget(max_buffer=_grouped_prereduce,
                                 max_total=_grouped_prereduce),
          "all-reduce": OpBudget(max_buffer=_scalar)}))
-# The pipelined plane: per-table pull/push entry points run the PLAIN
-# a2a programs (pipelining only changes the Trainer's step schedule) so
-# they inherit a2a's exchange contract verbatim; the plane's own promise
-# — dense never waits on an exchange, prefetch legs schedulable under
-# the dots, push committed in-program — is the STEP program's overlap
-# contract below.
-_register(ProgramContract(
-    plane="a2a+pipelined", program="pull",
-    ops={"all-to-all": OpBudget(min_count=1),
-         "all-gather": OpBudget(max_buffer=_row_assembly),
-         "all-reduce": OpBudget(max_buffer=_scalar)}))
-_register(ProgramContract(
-    plane="a2a+pipelined", program="push",
-    ops={"all-to-all": OpBudget(min_count=1),
-         "all-gather": OpBudget(max_buffer=_global_prereduce),
-         "all-reduce": OpBudget(max_buffer=_scalar)}))
-_register(ProgramContract(
-    plane="a2a+pipelined", program="step",
-    min_aliased=1, overlap=True))
 # The compressed-exchange planes (parallel/precision.py): same owner
 # exchange as a2a, but the row/grad payloads cross the wire narrowed —
 # bf16 rows both directions ("a2a+bf16"), or bf16 pull + per-row-scale
@@ -1095,11 +846,6 @@ def _cache_hbm(p: Mapping[str, Any]) -> int:
     return int(p.get("cache_k", 128) * (p["dim"] + 1) * p["itemsize"])
 
 
-def _pipelined_hbm(p: Mapping[str, Any]) -> int:
-    # the prefetched double buffer: one extra pulled-row batch resident
-    return int(p["global_batch"] * p["dim"] * p["itemsize"])
-
-
 def _grouped_hbm(p: Mapping[str, Any]) -> int:
     # bucket-padding waste across the concatenated stream
     return int(p["num_tables"] * p["global_batch"]
@@ -1154,14 +900,6 @@ _register_spec(PlaneSpec(
     # per-step host dispatch cost stays ~one table's worth
     launches={"pull": 7, "push": 5},
     hbm_overhead_bytes=_grouped_hbm, host_step_units=0.5))
-_register_spec(PlaneSpec(
-    plane="a2a+pipelined",
-    exchange_bytes={"pull": _a2a_pull_bytes, "push": _a2a_push_bytes},
-    launches={"pull": 7, "push": 5},
-    hbm_overhead_bytes=_pipelined_hbm,
-    # the fused step hides exchange latency under the dense compute —
-    # modelled as a host/launch discount, not a byte discount
-    host_step_units=0.75))
 _register_spec(PlaneSpec(
     plane="a2a+bf16",
     exchange_bytes={"pull": _compressed_pull_bytes,

@@ -11,7 +11,7 @@ bytes, plus the derived peak estimate. Two consumers:
 * **The peak-temp contract** (:func:`..analysis.contracts.
   check_peak_temp_bytes`): compiled temp must stay batch-scale scratch
   (pull) plus at most one declined-donation state materialization
-  (push/step). This catches what the HLO-text ``copy`` audit cannot —
+  (push). This catches what the HLO-text ``copy`` audit cannot —
   XLA materializations that never appear as an explicit ``copy`` op
   (fusion outputs, gather results) still land in the temp allocation.
   Enforced by ``python -m tools.graftcheck`` per plane.
@@ -48,7 +48,7 @@ class MemoryRow:
     """One plane program's per-device compiled-memory ledger entry."""
 
     plane: str
-    program: str                       # "pull" | "push" | "step"
+    program: str                       # "pull" | "push"
     kind: str                          # "array" | "hash"
     mem: Optional[Mapping[str, int]]   # compiled_memory_stats dict or None
     params: Mapping[str, int]
@@ -99,35 +99,6 @@ def plane_memory(mesh, plane: str, program: str, *,
     return MemoryRow(plane=plane, program=program,
                      kind="hash" if use_hash else "array", mem=mem,
                      params=params, temp_bound=bound)
-
-
-def pipelined_step_memory(mesh, *, batch: int = AUDIT_BATCH,
-                          dim: int = AUDIT_DIM,
-                          vocab: Optional[int] = None,
-                          check: bool = True) -> MemoryRow:
-    """Memory-ledger row for the PIPELINED STEP program
-    (``parallel/pipelined.py``): the whole-step peak-temp bound plus
-    exactly one extra pulled-row buffer (``pipeline_rows_bytes``,
-    measured from the primed buffer itself) — never anything
-    table-sized. The vocab defaults low enough that the deepfm harness
-    compiles quickly; pass ``vocab=AUDIT_VOCAB`` for the
-    shard-dominates-scratch sizing when hunting a regression."""
-    from . import contracts, programs
-    from ..utils import jaxcompat
-    compiled, params = programs.compile_pipelined_step(
-        mesh, vocab=vocab or (1 << 17), batch=batch, dim=dim)
-    mem = jaxcompat.compiled_memory_stats(compiled)
-    bound = None
-    if mem is not None:
-        if check:
-            bound = contracts.check_peak_temp_bytes(
-                mem, params, program="step",
-                label="a2a+pipelined/step (deepfm)")
-        else:
-            bound = contracts.peak_temp_bound(
-                params, "step", int(mem.get("alias_bytes", 0)))
-    return MemoryRow(plane="a2a+pipelined", program="step", kind="array",
-                     mem=mem, params=params, temp_bound=bound)
 
 
 def registered_planes() -> List[str]:

@@ -355,79 +355,3 @@ def lower_train_step(mesh, plane: str = "a2a", *, vocab: int = 4096,
                                           dim=dim, batch=batch,
                                           model=model)
     return compiled.as_text(), params
-
-
-def compile_pipelined_step(mesh, *, vocab: int = 4096, dim: int = 8,
-                           batch: int = 256, model: str = "deepfm",
-                           force_serialize: bool = False):
-    """Compiled PIPELINED Trainer step + contract params.
-
-    Builds the same deepfm harness as :func:`compile_train_step` with
-    every variable on ``plane="a2a+pipelined"``, primes the pipeline
-    (the warmup prologue), and lowers the steady-state step program —
-    dense(N) on the prefetched buffer, push(N), prefetch pull(N+1) —
-    exactly as ``Trainer.train_step`` dispatches it. The params carry
-    ``pipeline_rows_bytes`` (the primed row buffer's size) so the
-    peak-temp bound earns exactly one extra pulled-row buffer.
-
-    ``force_serialize=True`` compiles the deliberately-serialized
-    variant (the loss routed into the prefetch indices): the overlap
-    contract's negative shape. Test-only.
-    """
-    import numpy as np
-    import jax
-    import optax
-    from ..embedding import EmbeddingCollection
-    from ..models import deepctr
-    from ..training import Trainer
-    features = ("c0", "c1")
-    specs = deepctr.make_feature_specs(features, vocab, dim,
-                                       plane="a2a+pipelined")
-    coll = EmbeddingCollection(
-        specs, mesh,
-        default_optimizer={"category": "adagrad", "learning_rate": 0.1})
-    trainer = Trainer(deepctr.build_model(model, features), coll,
-                      optax.adam(1e-2))
-    rng = np.random.RandomState(0)
-    batch_data = {
-        "label": rng.randint(0, 2, size=batch).astype(np.float32),
-        "dense": rng.randn(batch, 4).astype(np.float32),
-        "sparse": {f: rng.randint(0, vocab, size=batch).astype(np.int32)
-                   for f in features}
-    }
-    for f in features:
-        batch_data["sparse"][f + deepctr.LINEAR_SUFFIX] = \
-            batch_data["sparse"][f]
-    state = trainer.init(jax.random.PRNGKey(0),
-                         trainer.shard_batch(batch_data))
-    state = trainer._prime_pipeline(state, batch_data)
-    pull_inputs, _ = trainer._split_sparse(batch_data["sparse"])
-    next_pull = trainer.shard_batch(pull_inputs)
-    step = trainer._build_pipelined_train_step(
-        force_serialize=force_serialize)
-    compiled = step.lower(state, trainer.shard_batch(batch_data),
-                          next_pull).compile()
-    # the pipe buffer is accounted ONCE, via pipeline_rows_bytes — the
-    # state term must exclude it or the bound earns the buffer twice
-    params = contract_params(
-        mesh, batch=batch, dim=dim, vocab=vocab,
-        state_nbytes=_state_nbytes(state.replace(pipe=None)))
-    params["pipeline_rows_bytes"] = _state_nbytes(state.pipe)
-    # one pull + one push exchange pipeline per sparse variable live in
-    # the step — the peak-temp bound's step-scratch multiplier — and
-    # one sanctioned post-push weights-shard materialization per
-    # dim-carrying table (the linears ride the 1.1 slack)
-    params["num_exchange_pipelines"] = 2 * len(coll.specs)
-    params["step_weight_shards"] = len(features)
-    return compiled, params
-
-
-def lower_pipelined_step(mesh, *, vocab: int = 4096, dim: int = 8,
-                         batch: int = 256, model: str = "deepfm",
-                         force_serialize: bool = False
-                         ) -> Tuple[str, Dict[str, int]]:
-    """Compiled HLO text of the pipelined Trainer step program."""
-    compiled, params = compile_pipelined_step(
-        mesh, vocab=vocab, dim=dim, batch=batch, model=model,
-        force_serialize=force_serialize)
-    return compiled.as_text(), params

@@ -26,12 +26,9 @@ path: it keeps the step loop fed at step rate from on-disk shards.
   as ``oe_mem_*{source="ingest/<name>"}`` gauges.
 * IDENTITY-STABLE batches: every batch dict is constructed exactly once
   (on the worker) and yielded exactly once. This matters: the Trainer's
-  offload lookahead and the pipelined plane's prefetch are keyed on batch
-  OBJECT IDENTITY (``training.py`` ``_pipe_for``) — a driver that
-  rebuilds value-equal dicts per step misses every lookahead and pays a
-  discarded prefetch plus an eager re-prime, silently doubling the
-  exchange cost. A steady ``fit`` over this stream primes the pipeline
-  exactly once (``pipeline_primes`` counter — integration-pinned). Apply
+  offload lookahead is keyed on batch OBJECT IDENTITY (``training.py``
+  ``_prep_started``) — a driver that rebuilds value-equal dicts per step
+  misses every lookahead and prepares each batch on the step. Apply
   per-batch rewrites (``FusedMapper.fuse_batch``) via ``transform=``, on
   the worker, NOT by wrapping the iterator in a rebuilding generator.
 * STALL ACCOUNTING: a consumer pop that finds data ready costs no wait

@@ -104,11 +104,6 @@ class EmbeddingSpec:
                                      # same-shape tables into ONE exchange
                                      # per group per step,
                                      # parallel/grouped.py)
-                                     # | "a2a+pipelined" (Trainer double-
-                                     # buffers the exchange: batch N+1's
-                                     # pull rides step N's program,
-                                     # parallel/pipelined.py)
-                                     # | "a2a+grouped+pipelined" (both)
     a2a_capacity: int = 0            # per-destination bucket rows; 0 = auto
     a2a_slack: float = 2.0           # auto bucket = slack * mean
     cache_k: int = 0                 # hot-row replica slots; 0 = default
@@ -356,12 +351,6 @@ class EmbeddingCollection:
         ``parallel/grouped.py``)."""
         return tuple(name for name, s in self._shardings.items()
                      if s.is_grouped)
-
-    def pipelined_names(self) -> tuple:
-        """Variables on a pipelined plane (Trainer-level double-buffered
-        exchange schedule, ``parallel/pipelined.py``)."""
-        return tuple(name for name, s in self._shardings.items()
-                     if s.is_pipelined)
 
     def make_hot_cache_manager(self, name: str):
         """Admission/refresh driver for one cached variable (the Trainer

@@ -78,10 +78,6 @@ from ..utils import observability
 from . import alltoall as a2a
 
 GROUPED_PLANE = "a2a+grouped"
-# the composed plane: grouped collection-level exchange AND the
-# Trainer's pipelined step schedule (parallel/pipelined.py) — the
-# prefetched exchange stays one collective round per group
-GROUPED_PLANES = ("a2a+grouped", "a2a+grouped+pipelined")
 
 # array offset streams are int32: a group's concatenated padded vocabs
 # must stay addressable (the planner splits groups at this boundary)
@@ -148,21 +144,17 @@ def plan_groups(collection, names, *, read_only: bool = False
     for name in ordered:
         spec = collection.specs[name]
         ss = collection.sharding_spec(name)
-        if ss.plane not in GROUPED_PLANES:
-            raise ValueError(f"{name!r} is not on a grouped plane "
-                             f"({GROUPED_PLANES})")
-        # ss.plane is part of the key: a plain-grouped and a
-        # grouped+pipelined table must never share a plan — the
-        # per-plan timing attribution labels by member plane, and the
-        # Trainer pulls the two sets at different schedule points anyway
+        if ss.plane != GROUPED_PLANE:
+            raise ValueError(f"{name!r} is not on the grouped plane "
+                             f"({GROUPED_PLANE!r})")
         if spec.use_hash:
-            key = ("hash", ss.plane, spec.key_dtype,
+            key = ("hash", spec.key_dtype,
                    dim_bucket(spec.output_dim),
                    ss.num_shards, ss.data_axis, ss.model_axis,
                    ss.a2a_capacity, ss.a2a_slack, spec.dtype,
                    ss.exchange_precision, ss.push_precision)
         else:
-            key = ("array", ss.plane, dim_bucket(spec.output_dim),
+            key = ("array", dim_bucket(spec.output_dim),
                    ss.num_shards,
                    ss.layout, ss.data_axis, ss.model_axis,
                    ss.a2a_capacity, ss.a2a_slack, spec.dtype,
@@ -182,20 +174,20 @@ def plan_groups(collection, names, *, read_only: bool = False
                     slot_names=tuple(collection.optimizer(n).slot_shapes(
                         collection.specs[n].output_dim)))
                 for n in group_names)
-            plans.append(GroupPlan(kind="hash", bucket_dim=key[3],
-                                   key_dtype=key[2], members=members))
+            plans.append(GroupPlan(kind="hash", bucket_dim=key[2],
+                                   key_dtype=key[1], members=members))
             continue
         # array: accumulate members until the offset space would overflow
         run, span = [], 0
         for n in group_names:
             ss = collection.sharding_spec(n)
             if run and span + ss.padded_vocab > _MAX_OFFSET_SPAN:
-                plans.append(_array_plan(collection, tuple(run), key[2]))
+                plans.append(_array_plan(collection, tuple(run), key[1]))
                 run, span = [], 0
             run.append(n)
             span += ss.padded_vocab
         if run:
-            plans.append(_array_plan(collection, tuple(run), key[2]))
+            plans.append(_array_plan(collection, tuple(run), key[1]))
     plans.sort(key=lambda p: collection.variable_id(p.members[0].name))
     return tuple(plans)
 

@@ -60,7 +60,7 @@ PLANE_SUFFIXES = {
 # slice) next to the per-table exchange — the grouped plane's
 # concatenated multi-table streams and the cache plane's replica psum
 # would each need their own residual story, and psum has no routed wire
-INT8_EF_PLANES = ("a2a", "a2a+pipelined")
+INT8_EF_PLANES = ("a2a",)
 
 
 def parse_plane(plane: str) -> Tuple[str, str, str]:

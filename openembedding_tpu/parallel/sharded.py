@@ -38,15 +38,6 @@ Data planes (``PlaneSpec.plane``, shared by array and hash tables):
   (``parallel/grouped.py``): a T-table model pays O(#groups) collective
   rounds instead of O(T). Per-table calls on this plane (serving probes,
   checkpoint paths) behave exactly like ``"a2a"``.
-* ``"a2a+pipelined"`` — the a2a layout, but the TRAINER double-buffers
-  the exchange (``parallel/pipelined.py``): batch N+1's rows are pulled
-  inside step N's jitted program (after step N's push commits, so
-  results stay bit-identical to ``"a2a"``) and the pull's index/key-leg
-  collectives overlap step N's dense compute. Per-table calls behave
-  exactly like ``"a2a"`` — the plane only changes the step schedule.
-* ``"a2a+grouped+pipelined"`` — both: grouped collection-level exchange
-  AND the pipelined step schedule, so the prefetched exchange is one
-  collective round per GROUP.
 
 One builder, two stores. Every plane variant (routed, masked-local,
 hot-cache, int8-EF) is written here once; what differs between an array
@@ -113,8 +104,8 @@ combine a table, one find-or-insert a hash table behind the push's
 conditional. What
 round 1 did not hold runs as it does without a plan (the pull's residue
 rounds, the push's gathered branch), and so does any call without one:
-the cached and grouped planes, an ``int8_ef`` push, the pipelined
-schedule, serving, ``eval_step``.
+the cached and grouped planes, an ``int8_ef`` push, serving,
+``eval_step``.
 One resolve a key a step, too: the planned pull returns, beside the rows,
 what each shard resolved for the plan's slots (``dedup.Resolution``, one a
 table; on the routed body the owner's, of the keys it received), and the
@@ -157,7 +148,6 @@ class PlaneSpec:
     data_axis: str = DATA_AXIS
     model_axis: str = MODEL_AXIS
     plane: str = "a2a"   # "a2a" | "psum" | "a2a+cache" | "a2a+grouped"
-                         # | "a2a+pipelined" | "a2a+grouped+pipelined"
     a2a_capacity: int = 0    # per-destination bucket rows; 0 = auto
     a2a_slack: float = 2.0   # auto capacity = slack * mean bucket size
     cache_k: int = 0         # hot-row replica slots ("a2a+cache" plane)
@@ -209,13 +199,7 @@ class PlaneSpec:
     @property
     def is_grouped(self) -> bool:
         """Collection-level multi-table exchange (``parallel/grouped.py``)."""
-        return self.plane in ("a2a+grouped", "a2a+grouped+pipelined")
-
-    @property
-    def is_pipelined(self) -> bool:
-        """Trainer-level double-buffered exchange schedule
-        (``parallel/pipelined.py``)."""
-        return self.plane in ("a2a+pipelined", "a2a+grouped+pipelined")
+        return self.plane == "a2a+grouped"
 
     @property
     def shard_axes(self) -> tuple:

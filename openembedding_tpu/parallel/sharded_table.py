@@ -40,8 +40,7 @@ from .mesh import MODEL_AXIS
 
 # every plane riding the owner-routed exchange layout (tables sharded
 # over the whole mesh grid); "psum" is the lone broadcast-style ablation
-A2A_PLANES = ("a2a", "a2a+cache", "a2a+grouped", "a2a+pipelined",
-              "a2a+grouped+pipelined")
+A2A_PLANES = ("a2a", "a2a+cache", "a2a+grouped")
 PLANES = A2A_PLANES + ("psum",)
 
 
@@ -110,7 +109,7 @@ def _resolve_plane(mesh: Mesh, plane: str, num_shards: int, cache_k: int,
     plane, exchange_precision, push_precision = _resolve_precision(
         plane, exchange_precision, push_precision)
     if plane not in PLANES:
-        raise ValueError(f"unknown plane {plane!r}")
+        raise ValueError(f"unknown plane {plane!r}; known: {PLANES}")
     want = mesh.shape[MODEL_AXIS] if plane == "psum" else mesh.size
     if num_shards == -1:
         num_shards = want

@@ -36,7 +36,6 @@ from .analysis.concurrency import sync_point
 from .analysis.retrace import LEDGER, RetraceGuard
 from .utils import observability
 from .embedding import EmbeddingCollection, SameColumns
-from .parallel import pipelined as pipeline_lib
 from .parallel.mesh import DATA_AXIS
 
 
@@ -59,10 +58,6 @@ class TrainState:
                                  # the error-feedback state rides the
                                  # TrainState and is donated with it
                                  # (derived: never checkpointed)
-    # pipelined-plane prefetched row buffer (parallel/pipelined.py);
-    # None outside the pipelined schedule. Derived state: checkpoints
-    # never carry it, a restore re-primes from the tables
-    pipe: Any = None
 
 
 def binary_logloss(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
@@ -190,26 +185,6 @@ class Trainer:
                 if base is not None:
                     mgr.share_sketch(base)
         self.pipeline_depth = max(1, int(pipeline_depth))
-        # pipelined-exchange plane (parallel/pipelined.py): variables
-        # whose pull is double-buffered through the step program. The
-        # offload tier's host->HBM inserts mutate table state BETWEEN
-        # steps — a prefetched buffer cannot see them, so the two
-        # schedules must not share a variable.
-        self._pipelined = collection.pipelined_names()
-        clash = sorted(set(self._pipelined) & set(self.offload))
-        if clash:
-            raise ValueError(
-                f"offloaded variable(s) {clash} cannot ride a pipelined "
-                "plane: offload host-prepare inserts rows between steps, "
-                "invalidating the prefetched row buffer")
-        self._pipelined_step = None
-        # the batch the live row buffer was prefetched FOR plus the
-        # identity of the buffer it lives in (host-side, like the
-        # offload prep queue); the buffer id catches a caller replaying
-        # an OLD state object — its pipe holds a different batch's rows
-        # even when the batch argument matches, and must re-prime
-        self._pipe_for = None
-        self._pipe_token = None
         # in-flight lookahead prepares, oldest first; each entry's thread
         # CHAINS on the previous one, so host_prepare calls run strictly
         # in batch order (the planned-residency bookkeeping requires it)
@@ -266,13 +241,10 @@ class Trainer:
     def _dense_update_and_push(self, state: TrainState, batch, rows,
                                pull_inputs, dense_ids, plan=None,
                                resolved=None):
-        """Shared core of the serial AND pipelined step programs: loss
-        + grads on ``rows``, dense optimizer update, sparse push. ONE
-        definition traced by both schedules — the pipelined plane's
-        exact-equivalence guarantee rests on them never diverging.
-        ``plan`` is the serial step's ``collection.plan`` of
-        ``pull_inputs``, the one ``rows`` were pulled with, and
-        ``resolved`` what that pull resolved
+        """The step program's core: loss + grads on ``rows``, dense
+        optimizer update, sparse push. ``plan`` is the step's
+        ``collection.plan`` of ``pull_inputs``, the one ``rows`` were
+        pulled with, and ``resolved`` what that pull resolved
         (``collection.pull_resolved``): the push takes it."""
         def lfn(params, rows):
             logits = self._apply(params, batch.get("dense"), rows,
@@ -351,110 +323,6 @@ class Trainer:
 
         return jax.jit(step_fn, donate_argnums=(0,))
 
-    # --- pipelined-exchange schedule (parallel/pipelined.py) ---------------
-    @property
-    def pipeline_plane(self) -> str:
-        """Step-span label for the pipelined schedule (plane_timings)."""
-        if self._pipelined and all(
-                self.collection.sharding_spec(n).is_grouped
-                for n in self._pipelined):
-            return "a2a+grouped+pipelined"
-        return "a2a+pipelined"
-
-    def _build_pipelined_train_step(self, force_serialize: bool = False):
-        """One SPMD program per step N: dense fwd/bwd(N) on the
-        PREFETCHED row buffer (no collective ahead of the dots), push(N)
-        commit, then the prefetch pull for batch N+1 — whose index/
-        key-leg collectives depend only on the input index stream, so
-        XLA overlaps them with the dense compute, while its row
-        resolution reads the post-push tables (the reference's
-        per-batch version barrier as an op dependency: bit-identical to
-        the serial ``"a2a"`` schedule). ``force_serialize`` is the
-        negative-contract knob: it routes the loss into the prefetch
-        indices (a zero-valued but real dependency), re-serializing the
-        program — the overlap contract must catch it.
-        """
-        collection = self.collection
-
-        def pipelined_step_fn(state: TrainState, batch, next_pull) -> tuple:
-            pull_inputs, dense_ids = self._split_sparse(batch["sparse"])
-            _pre, inline = pipeline_lib.split_columns(collection,
-                                                      pull_inputs)
-            rows = dict(state.pipe.rows)
-            if inline:
-                # non-pipelined variables (psum/cache members of a mixed
-                # model) keep their serial in-step pull
-                rows.update(collection.pull(state.emb, inline))
-            params, opt_state, emb, loss = self._dense_update_and_push(
-                state, batch, rows, pull_inputs, dense_ids)
-            if force_serialize:
-                zero = (loss * 0).astype(jnp.int32)
-                next_pull = {n: v + zero.astype(v.dtype)
-                             for n, v in next_pull.items()}
-            pipe = pipeline_lib.prefetch_pull(collection, emb, next_pull)
-            new_state = TrainState(step=state.step + 1, params=params,
-                                   opt_state=opt_state, emb=emb, pipe=pipe)
-            return new_state, {"loss": loss}
-
-        return jax.jit(pipelined_step_fn, donate_argnums=(0,))
-
-    def _prime_pipeline(self, state: TrainState, batch) -> TrainState:
-        """Warmup prologue / re-prime: pull ``batch``'s pipelined rows
-        eagerly from the authoritative tables (the exact pull a serial
-        step would have opened with) into a fresh buffer."""
-        pull_inputs, _ = self._split_sparse(batch["sparse"])
-        pre, _ = pipeline_lib.split_columns(self.collection, pull_inputs)
-        pipe = pipeline_lib.prefetch_pull(self.collection, state.emb,
-                                          self.shard_batch(pre))
-        return state.replace(pipe=pipe)
-
-    def drain_pipeline(self, state: TrainState) -> TrainState:
-        """Discard the prefetched row buffer. The tables are
-        authoritative after every step (the pipelined schedule leaves no
-        pending pushes), so draining loses nothing — the next
-        ``train_step`` re-primes. Eval needs no drain at all."""
-        self._pipe_for = None
-        self._pipe_token = None
-        return pipeline_lib.drain(state)
-
-    def _pipelined_train_step(self, state: TrainState, batch,
-                              next_batch, at) -> tuple:
-        if self._pipelined_step is None:
-            self._pipelined_step = self._build_pipelined_train_step()
-        if state.pipe is None or self._pipe_for is not batch \
-                or self._pipe_token != id(state.pipe):
-            # first step, drain, a batch the lookahead didn't predict,
-            # or a REPLAYED older state (its buffer holds some other
-            # batch's rows): fill the pipeline for THIS batch now.
-            # NOTE the lookahead is keyed on batch OBJECT IDENTITY
-            # (like the offload prep queue): a driver that rebuilds a
-            # value-equal batch dict per step misses EVERY time and
-            # pays the in-program prefetch (discarded) PLUS this eager
-            # re-prime — two exchanges per step, slower than serial.
-            # The counter makes that visible: a steady fit loop primes
-            # exactly once.
-            observability.GLOBAL.add("pipeline_primes", 1)
-            state = self._prime_pipeline(state, batch)
-        nxt = next_batch if next_batch is not None else batch
-        next_inputs, _ = self._split_sparse(nxt["sparse"])
-        pre, _ = pipeline_lib.split_columns(self.collection, next_inputs)
-        # whole-step wall time recorded under the plane (gated, blocking;
-        # the in-program pull/push are NOT separable host-side — see
-        # observability.plane_timings overlap attribution)
-        record = observability.evaluate_performance()
-        with scope.span("trainer.place_batch", detail=at):
-            placed = self.shard_batch(batch), self.shard_batch(pre)
-        with scope.span("trainer.dispatch", detail=at):
-            state, metrics = observability.plane_timed(
-                "step", self.pipeline_plane, record, self._pipelined_step,
-                state, *placed)
-        # a lookahead miss self-prefetches the CURRENT batch — still a
-        # valid buffer if the caller steps the same batch again (single-
-        # batch smoke loops); any other batch re-primes
-        self._pipe_for = nxt
-        self._pipe_token = id(state.pipe)
-        return state, metrics
-
     def _build_eval_step(self):
         collection = self.collection
 
@@ -469,7 +337,7 @@ class Trainer:
 
     def train_step(self, state: TrainState, batch, *,
                    next_batch=None) -> tuple:
-        """One pipelined step. With ``next_batch``, the HOST half of the
+        """One step. With ``next_batch``, the HOST half of the
         next batch's offload prepare (residency math + host-store row
         gather) runs on a background thread WHILE the device executes this
         step — the reference's PrefetchPullWeights issuing pulls ahead of
@@ -478,21 +346,10 @@ class Trainer:
         max(host prepare, device step) instead of their sum. ``fit`` keeps
         up to ``pipeline_depth`` prepared batches in flight automatically;
         callers driving steps by hand pass ``next_batch`` themselves (or
-        skip it and keep the serial path).
-
-        With pipelined-plane variables in the collection, ``next_batch``
-        additionally feeds the prefetch: batch N+1's pull rides THIS
-        step's jitted program (``parallel/pipelined.py``). The
-        lookahead is keyed on batch OBJECT IDENTITY (like the offload
-        prep queue): pass the SAME object you will step next, not a
-        rebuilt copy — a value-equal copy misses and the plane pays a
-        discarded prefetch plus an eager re-prime every step (the
-        ``pipeline_primes`` counter stays at 1 over a correct steady
-        loop). Without ``next_batch`` the step self-prefetches and the
-        next call re-primes eagerly — correct at any call pattern, just
-        unoverlapped.
+        skip it and keep the serial path). On the device the step is the
+        same program either way: ``next_batch`` only feeds the lookahead.
         """
-        if self._train_step is None and not self._pipelined:
+        if self._train_step is None:
             self._train_step = self._build_train_step()
         # graftscope: one span per whole host-visible step, with
         # StepTraceAnnotation pass-through so a concurrent jax.profiler
@@ -511,22 +368,17 @@ class Trainer:
                     observability.record_batch_stats(batch["sparse"])
                     state, uniqs = self._apply_prepared_offload(state,
                                                                 batch)
-                if self._pipelined:
-                    state, metrics = self._pipelined_train_step(
-                        state, batch, next_batch, at)
-                else:
-                    with scope.span("trainer.place_batch", detail=at):
-                        placed = self._place_step_batch(batch)
-                    with scope.span("trainer.dispatch", detail=at):
-                        state, metrics = self._train_step(state, placed)
+                with scope.span("trainer.place_batch", detail=at):
+                    placed = self._place_step_batch(batch)
+                with scope.span("trainer.dispatch", detail=at):
+                    state, metrics = self._train_step(state, placed)
                 with scope.span("trainer.bookkeeping", detail=at):
                     if self.collection.dirty_trackers:
                         # delta-checkpoint dirty marks from the HOST
                         # batch: the jitted step's in-trace ids are
                         # tracers, so the collection cannot mark there
                         # (once per compile); here marks land once per
-                        # step, pipelined plane included (its push(N)
-                        # commits inside step N)
+                        # step
                         cols, _ = self._split_sparse(batch["sparse"])
                         self.collection.mark_dirty(cols)
                     for name, table in self.offload.items():
@@ -1038,7 +890,7 @@ class Trainer:
         sync_point("trainer.resume.restore")
         return state.replace(step=jnp.asarray(step, jnp.int32),
                              params=params, opt_state=opt_state,
-                             emb=states, pipe=None), cursor
+                             emb=states), cursor
 
     def _autosave_fit(self, state: TrainState, path: str,
                       cursor: int, step: int) -> None:

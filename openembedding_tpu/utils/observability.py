@@ -278,28 +278,6 @@ def plane_timings(accumulator: Optional[Accumulator] = None
     Returns ``{plane: {"pull_ms": avg, "pull_calls": n, "push_ms": ...}}``
     — empty unless :func:`set_evaluate_performance` was on while the
     plane dispatches ran (``cache_stats``-style gating).
-
-    Pipelined planes dispatch pull and push INSIDE one jitted step, so
-    per-stage host timers cannot see them (``under_trace`` guard) and
-    summing eager stage times against the step would double-count
-    overlapped work. The Trainer instead records the whole step under
-    ``step/<plane>``; such planes report ``step_ms``/``step_calls``
-    plus — when eager stage samples also exist (bench stage-isolation
-    loops) — ``stage_serial_ms`` (the per-step wall of the
-    serially-dispatched pull+push stages) and ``overlap_hidden_ms`` =
-    ``stage_serial_ms - step_ms``: positive means the eager serial
-    exchange wall exceeds the WHOLE fused step, so at least that much
-    exchange time left the critical path; negative means the fused
-    step costs more than even the serial exchange walls (CPU meshes:
-    overhead, nothing to hide). A conservative indicator, not an exact
-    decomposition — the dense wall inside the step is not separable
-    host-side, and the instrumented eager stages carry blocking +
-    callback overhead the fused step avoids. The stage wall is the
-    TOTAL recorded pull+push time normalized by ``step_calls`` — stage
-    timers fire once per TABLE per eager round, so per-dispatch
-    averages alone would omit every table but one; callers must
-    therefore sample one full eager stage-isolation round per recorded
-    step (``bench.py``'s pipelined_ab instrumented sample does).
     """
     snap = (accumulator or GLOBAL).snapshot()
     out: Dict[str, Dict[str, float]] = {}
@@ -307,17 +285,11 @@ def plane_timings(accumulator: Optional[Accumulator] = None
         if "/" not in name:
             continue
         verb, plane = name.split("/", 1)
-        if verb not in ("pull", "push", "step") or "calls" not in fields:
+        if verb not in ("pull", "push") or "calls" not in fields:
             continue
         d = out.setdefault(plane, {})
         d[f"{verb}_ms"] = fields.get("avg_ms", 0.0)
         d[f"{verb}_calls"] = fields["calls"]
-    for plane, d in out.items():
-        if "step_ms" in d and "pull_ms" in d and "push_ms" in d:
-            stage_total = d["pull_ms"] * d["pull_calls"] \
-                + d["push_ms"] * d["push_calls"]
-            d["stage_serial_ms"] = stage_total / max(1.0, d["step_calls"])
-            d["overlap_hidden_ms"] = d["stage_serial_ms"] - d["step_ms"]
     return out
 
 

@@ -17,12 +17,7 @@ B, DIM = 1024, 16
 
 @pytest.mark.parametrize(
     "plane",
-    ["psum", "a2a", "a2a+cache",
-     # the pipelined plane's per-table programs ARE the a2a programs
-     # (pipelining lives in the Trainer schedule) — the fallback must
-     # keep honoring the a2a exchange contract; slow lane like hash
-     # (graftcheck + tests/test_pipelined.py cover it in tier-1)
-     pytest.param("a2a+pipelined", marks=pytest.mark.slow)])
+    ["psum", "a2a", "a2a+cache"])
 def test_pull_push_contracts_array(devices8, plane):
     mesh = create_mesh(2, 4, devices8)
     txt, params = programs.lower_pull(mesh, plane, batch=B, dim=DIM)

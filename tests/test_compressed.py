@@ -85,8 +85,8 @@ def test_plane_token_parsing():
 
 def test_spec_normalizes_plane_suffix():
     spec = EmbeddingSpec(name="x", input_dim=8, output_dim=2,
-                         plane="a2a+pipelined+bf16")
-    assert spec.plane == "a2a+pipelined"
+                         plane="a2a+bf16")
+    assert spec.plane == "a2a"
     assert spec.exchange_precision == "bf16"
     assert spec.push_precision == "bf16"
 

@@ -432,8 +432,8 @@ def test_the_routed_hash_push_has_no_table_in_its_conditional(devices8,
 # sha256 of the one-plan step of the benchmark's rehearsal configurations,
 # lowered on the CPU backend. The array step on one chip (the saving cells
 # run it too) and routed over 2x2 are the text they were at the parent of
-# PR 40 (4d7b794); the cached, grouped, pipelined and ``int8_ef`` steps
-# are held by ``tests/test_plan.py``'s own pins, which stand. The hash
+# PR 40 (4d7b794); the cached, grouped and ``int8_ef`` steps are held by
+# ``tests/test_plan_step.py``'s own pins, which stand. The hash
 # steps are as PR 41 left them: their compact insert loop walks its
 # misses in trips (``hash_table._insert_trips``), and nothing else of
 # them changed (the one-chip hash step 254,067 -> 267,241 characters).
@@ -459,7 +459,7 @@ def _lowered_step(name):
     from benchmark import offload_keys_system, offload_system, system
     from benchmark import run as bench_run
     from benchmark.traffic_gen import zipf_train
-    from test_plan import _abstract_step
+    from plan_helpers import _abstract_step
 
     config = bench_run.load("configs", name)
     lib = offload_keys_system if name == "tiny_hash_offload" else \

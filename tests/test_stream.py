@@ -1,6 +1,5 @@
 """Streaming ingest: shard pool determinism, bad-row/crash lanes, ring
-bounds + ledger, stall accounting, and the pipelined-plane integration
-(prime-once + bit-identical vs in-memory)."""
+bounds + ledger, and stall accounting."""
 
 import threading
 import time
@@ -297,75 +296,3 @@ def test_record_ingest_stall_counter_and_histogram():
     assert abs(snap["ingest_stall"]["seconds"] - 0.002) < 1e-9
     assert scope.HISTOGRAMS.count("ingest_stall_ms") == before + 2
     assert stream.ShardStream.ingest_accounted is True
-
-
-# --- pipelined-plane integration (slow: two full fit runs) -------------------
-
-@pytest.mark.slow
-def test_streamed_batches_prime_pipeline_once_and_train_bit_identical(
-        tmp_path):
-    """The tentpole contract: identity-stable streamed batches prime
-    the pipelined plane EXACTLY once over a steady fit
-    (`pipeline_primes` == 1 — a rebuilding driver would re-prime every
-    step and pay a double exchange), and live-streamed training is
-    BIT-identical to the same shard data materialized in memory."""
-    import jax
-    import optax
-    from openembedding_tpu import EmbeddingCollection, Trainer
-    from openembedding_tpu.fused import make_fused_specs
-    from openembedding_tpu.models import deepctr
-    from openembedding_tpu.parallel.mesh import create_mesh
-
-    d, _ = _shards(tmp_path, num_shards=2, rows_per_shard=512, seed=11)
-    mesh = create_mesh(2, 4)
-
-    def run(live):
-        specs, mapper = make_fused_specs(
-            tuple(criteo.SPARSE_NAMES), 1 << 12, 4,
-            optimizer={"category": "adagrad", "learning_rate": 0.01},
-            plane="a2a+pipelined")
-        coll = EmbeddingCollection(specs, mesh)
-        tr = Trainer(deepctr.build_model("deepfm",
-                                         tuple(criteo.SPARSE_NAMES)),
-                     coll, optax.adagrad(0.01))
-        s = stream.ShardStream(d, batch_size=128, readers=2, epochs=1,
-                               num_buckets=1 << 12,
-                               transform=mapper.fuse_batch)
-        try:
-            if live:
-                import itertools
-                it = iter(s)
-                first = next(it)
-                src = itertools.chain([first], it)
-            else:
-                src = list(s)
-                first = src[0]
-            state = tr.init(jax.random.PRNGKey(0),
-                            tr.shard_batch(first))
-            observability.GLOBAL.reset()
-            state, m = tr.fit(state, src)
-            snap = observability.GLOBAL.snapshot()
-            primes = snap["pipeline_primes"]["count"]
-            stall_calls = snap.get("ingest_stall", {}).get("calls", 0)
-            stalls = s.stall_summary()
-        finally:
-            s.close()
-        return (tr.drain_pipeline(state), float(m["loss"]), primes,
-                stalls, stall_calls, tr.pipeline_depth)
-
-    st_mem, loss_mem, primes_mem, _, _, _ = run(live=False)
-    (st_live, loss_live, primes_live, stalls, stall_calls,
-     depth) = run(live=True)
-    assert primes_mem == primes_live == 1.0
-    assert loss_mem == loss_live
-    # no double-counting through the chain wrapper: the stream records
-    # each pop itself; fit may add at most one ~0 record per drain step
-    # after the stream exhausts (plus the post-prime refill) — a 2x
-    # count here means fit re-timed waits the stream already accounted
-    assert stall_calls <= stalls["pops"] + depth + 1, \
-        (stall_calls, stalls["pops"], depth)
-    for a, b in zip(jax.tree.leaves(st_mem.emb),
-                    jax.tree.leaves(st_live.emb)):
-        assert bool((np.asarray(a) == np.asarray(b)).all())
-    # every pop recorded a stall sample (0.0 when ready)
-    assert stalls["pops"] == 8

@@ -67,7 +67,7 @@ def main(argv=None) -> int:
             print(f"FAIL {label}: {type(e).__name__}: {e}",
                   file=sys.stderr)
 
-    for plane in ("psum", "a2a", "a2a+cache", "a2a+pipelined"):
+    for plane in ("psum", "a2a", "a2a+cache"):
         for use_hash in (False, True):
             kind = "hash" if use_hash else "array"
             for prog, lower in (("pull", programs.lower_pull),
@@ -197,43 +197,6 @@ def main(argv=None) -> int:
         audit("memory ledger (all planes, peak-temp contract)", run_mem)
 
     if not args.skip_step:
-        # pipelined STEP program: the overlap contract (prefetch key
-        # legs free of the dense dots, push committed in-program, dense
-        # never waiting on an exchange, donation honored) plus the
-        # no-shard-sized-copy bound and — unless --skip-mem — the
-        # step's peak-temp audit (one extra pulled-row buffer + one
-        # post-push weights shard per table, nothing else table-sized)
-        def run_pipelined_step():
-            vocab, dim = 1 << 16, 16
-            txt, params = programs.lower_pipelined_step(
-                mesh, vocab=vocab, dim=dim, batch=args.batch // 4)
-            summary = contracts.check_program(txt, "a2a+pipelined",
-                                              "step", **params)
-            shard_bytes = vocab * dim * 4 // mesh.size
-            worst = contracts.max_copy_bytes(txt)
-            if worst >= shard_bytes:
-                raise contracts.ContractViolation(
-                    f"pipelined step copies a {worst}-byte buffer >= "
-                    f"table shard size {shard_bytes} — donation "
-                    "silently declined for a table")
-            report = contracts.analyze_overlap(txt)
-            return {"collectives": summary, "overlap": report}
-        audit("a2a+pipelined/step (deepfm, overlap contract)",
-              run_pipelined_step)
-        if not args.skip_mem:
-            from openembedding_tpu.analysis import memwatch as mw
-
-            def run_pipelined_mem():
-                row = mw.pipelined_step_memory(mesh)
-                print(mw.format_memory_table([row]))
-                if row.mem is None:
-                    raise RuntimeError(
-                        "no compiled memory analysis for the pipelined "
-                        "step — the peak-temp audit is blind")
-                return "pipelined step peak-temp bound holds"
-            audit("a2a+pipelined/step memory (peak-temp contract)",
-                  run_pipelined_mem)
-
         def run_step():
             # vocab/dim sized so each table shard dwarfs every dense
             # buffer: a copy at/above shard size can only be a table

@@ -1,19 +1,17 @@
-"""Streaming-ingest CI smoke: shards -> reader pool -> pipelined steps,
-with the zero-post-warmup-stall and prime-once contracts ASSERTED.
+"""Streaming-ingest CI smoke: shards -> reader pool -> train steps, with
+the zero-post-warmup-stall contract ASSERTED.
 
     python -m tools.ingest_smoke --out /tmp/ingest_smoke.json
 
 Generates a small synthetic shard set (real TSV files, zipf marginals,
 hex categoricals — ``data.stream.write_synthetic_shards``), streams it
-through the parallel reader pool into a pipelined-plane deepfm Trainer
+through the parallel reader pool into an a2a-plane deepfm Trainer
 for ``--steps`` steps on the virtual CPU mesh, and exits nonzero
 unless:
 
 * post-warmup ingest stalls are ZERO (every measured pop found its
   batch ready — the stream records literal 0.0 for ready pops, so the
   assertion is exact, not a histogram approximation);
-* the pipelined plane primed exactly once (identity-stable batch
-  dicts: a rebuilding driver would re-prime per step);
 * no rows were dropped as bad and no reader died;
 * the ingest spans (``ingest.read`` / ``ingest.hash``) actually
   recorded — a silent instrumentation regression must fail the smoke,
@@ -60,7 +58,6 @@ def main(argv=None) -> int:
     from openembedding_tpu.fused import make_fused_specs
     from openembedding_tpu.models import deepctr
     from openembedding_tpu.parallel.mesh import create_mesh
-    from openembedding_tpu.utils import observability
 
     n_dev = len(jax.devices())
     mesh = create_mesh(2 if n_dev % 2 == 0 else 1,
@@ -75,7 +72,7 @@ def main(argv=None) -> int:
         specs, mapper = make_fused_specs(
             tuple(criteo.SPARSE_NAMES), 1 << 14, 8,
             optimizer={"category": "adagrad", "learning_rate": 0.01},
-            plane="a2a+pipelined")
+            plane="a2a")
         coll = EmbeddingCollection(specs, mesh)
         trainer = Trainer(deepctr.build_model(
             "deepfm", tuple(criteo.SPARSE_NAMES)), coll,
@@ -90,7 +87,6 @@ def main(argv=None) -> int:
             cur = next(it)
             state = trainer.init(jax.random.PRNGKey(0),
                                  trainer.shard_batch(cur))
-            observability.GLOBAL.reset()
             t0 = time.perf_counter()
             for i in range(args.steps):
                 nxt = next(it)
@@ -103,8 +99,6 @@ def main(argv=None) -> int:
             jax.block_until_ready(m["loss"])
             dt = time.perf_counter() - t0
             stalls = src.stall_summary()
-            primes = observability.GLOBAL.snapshot().get(
-                "pipeline_primes", {}).get("count", 0.0)
             mem = src.memory_stats()
             summary = {
                 "steps": args.steps,
@@ -113,7 +107,6 @@ def main(argv=None) -> int:
                 "stall_max_ms": stalls["max_ms"],
                 "stalled_pops": stalls["stalled"],
                 "measured_pops": stalls["pops"],
-                "pipeline_primes": int(primes),
                 "bad_rows": int(src.bad_rows()),
                 "rows_read": int(mem["rows_read"]),
                 "ring_capacity_batches":
@@ -129,10 +122,6 @@ def main(argv=None) -> int:
                     f"{stalls['stalled']} post-warmup stall(s), max "
                     f"{stalls['max_ms']:.3f} ms — the ring fell behind "
                     "the step rate")
-            if primes != 1:
-                problems.append(
-                    f"pipeline_primes == {primes}, expected 1 — the "
-                    "batch identity contract broke (rebuilt dicts?)")
             if src.bad_rows():
                 problems.append(f"{src.bad_rows()} bad row(s) in a "
                                 "clean synthetic shard set")
